@@ -1,10 +1,10 @@
 #!/usr/bin/env python
 """CPU-measurable perf gates: the tier-1-safe microbench suite.
 
-BENCH_r03-r05 postmortem: three bench rounds produced zero perf signal
-because the TPU fabric hung at backend init. Perf must not be hostage to
-one flaky chip attach — this suite measures the paddle_tpu host/compiler
-surfaces that move on every PR, on JAX_PLATFORMS=cpu, in seconds:
+CPU-pinned by construction (it never needs the chip, so it may run beside
+a process that holds it): measures the paddle_tpu host/compiler surfaces
+that move on every PR, on JAX_PLATFORMS=cpu, in seconds — counts and CPU
+wall clocks, never a device number:
 
   * trace_lower_s          — Program -> StableHLO trace+lower wall time
                              of a small train step (the compile-path
@@ -62,9 +62,7 @@ surfaces that move on every PR, on JAX_PLATFORMS=cpu, in seconds:
 
 Output contract: ONE JSON line (dict with "metric": "bench_micro" and a
 "metrics" sub-dict). tests/test_bench_micro.py re-runs the suite
-in-process and checks every metric against the REGRESSION BUDGETS below,
-so every PR gets a perf verdict even when bench.py's chip probe fails
-(bench.py --micro falls back to this suite).
+in-process and checks every metric against the REGRESSION BUDGETS below.
 
 Budgets are deliberately loose upper bounds for shared-CI noise: they
 catch order-of-magnitude regressions (a trace blowup, a cache-key bug, a
@@ -92,16 +90,11 @@ def _force_cpu():
     """Standalone entry: pin the CPU backend with 8 virtual devices
     BEFORE jax import (same shape as tests/conftest.py). A no-op when
     jax is already imported/configured (pytest in-process use)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-    import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:  # pragma: no cover - older jax
-        pass
 
 
 # metric -> ("max"|"min", budget). Checked by check_budgets(); loose on

@@ -1,0 +1,463 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that paddle_tpu still starts on the chip.
+
+Drives the main path once, through the entry points a user calls
+(``layers`` -> ``Program`` -> ``append_backward`` -> ``Executor.run`` /
+``run_steps`` and the ``CompiledProgram`` mesh variant), at the full
+width of ERNIE-base, with random weights from a seed:
+
+    python3 chip_smoke.py                 # every phase; needs a TPU
+    python3 chip_smoke.py four_chips      # attach + the named phases
+
+One process, one attach (a chip belongs to one process at a time; this
+script starts no process that could want it). Phases run in order and
+the first failed phase ends the run: non-zero exit, the phase's own
+error on stderr, no result line. Every stdout line is one JSON object —
+one per passed phase, with what it saw, its compile seconds and its
+persistent-cache hits — carrying ``platform``, ``device_kind`` and
+``n_devices``; on success the last line is ``{"ok": true, "device":
+{...}}``.
+
+Without a TPU the run fails at ``attach``, naming the platform found.
+``--rehearse-cpu`` is the caller's explicit choice of a tiny-size CPU
+walk through the same phase code (Pallas kernels interpreted); it never
+happens by failing to find a chip, and its lines say ``"platform":
+"cpu"``.
+
+Seconds printed here (compile, first call, step) are observations of a
+smoke run — a few unrepeated steps — not benchmark numbers.
+"""
+import argparse
+import json
+import sys
+import time
+import traceback
+
+import numpy as np
+
+# what each phase runs at on the chip: ERNIE-base and the long-context
+# GPT at full width and depth
+CHIP_SIZES = dict(
+    ernie=dict(batch=128, seq=128, preds=20, lr=1e-4, cfg=dict(
+        dtype="bfloat16")),
+    gpt=dict(batch=2, seq=4096, cfg=dict(
+        vocab_size=32000, hidden_size=768, num_layers=12, num_heads=12,
+        ff_size=3072, max_position=4096, dropout=0.0, dtype="bfloat16",
+        attn_impl="flash", recompute=True)),
+    fleet=dict(batch=256, seq=128, preds=20, lr=1e-4, cfg=dict(
+        dtype="bfloat16", tp=True)),
+    ring=dict(shape=(2, 12, 8192, 64), dtype="bfloat16", tol=2e-2))
+# the rehearsal's sizes only have to reach every line of the phase code
+REHEARSAL_SIZES = dict(
+    ernie=dict(batch=8, seq=32, preds=4, lr=1e-3, cfg=dict(
+        vocab_size=1024, hidden_size=64, num_layers=2, num_heads=2,
+        ff_size=128, max_position=64)),
+    gpt=dict(batch=1, seq=256, cfg=dict(
+        vocab_size=512, hidden_size=64, num_layers=2, num_heads=2,
+        ff_size=128, max_position=256, dropout=0.0, attn_impl="flash",
+        recompute=True)),
+    fleet=dict(batch=8, seq=32, preds=4, lr=1e-3, cfg=dict(
+        vocab_size=1024, hidden_size=64, num_layers=2, num_heads=2,
+        ff_size=128, max_position=64, tp=True)),
+    ring=dict(shape=(1, 2, 512, 64), dtype="float32", tol=1e-4))
+
+RUN_STEPS = 10        # exe.run calls on the fixed batch (>= 9: 8 updates)
+WINDOW = 4            # length of the run_steps window
+
+
+class PhaseFailed(Exception):
+    """A check of the running phase did not hold."""
+
+
+def check(cond, msg):
+    if not cond:
+        raise PhaseFailed(msg)
+
+
+class Run(object):
+    """State of one smoke run: the device fields every line carries, the
+    sizes in effect, and JAX's own compile / persistent-cache counters
+    (read per phase, so cold and warm runs can be told apart)."""
+
+    def __init__(self, rehearsal):
+        self.rehearsal = rehearsal
+        self.sizes = REHEARSAL_SIZES if rehearsal else CHIP_SIZES
+        self.device = {}
+        self._compile_s = 0.0
+        self._cache_hits = 0
+        self._cache_misses = 0
+
+    def watch_compiles(self):
+        import jax
+
+        def on_duration(event, duration, **kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                self._compile_s += duration
+
+        def on_event(event, **kw):
+            if event == "/jax/compilation_cache/cache_hits":
+                self._cache_hits += 1
+            elif event == "/jax/compilation_cache/cache_misses":
+                self._cache_misses += 1
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(on_event)
+
+    def compile_counters(self):
+        return (self._compile_s, self._cache_hits, self._cache_misses)
+
+    def place(self):
+        import paddle_tpu as pt
+        return pt.CPUPlace() if self.rehearsal else pt.TPUPlace(0)
+
+    def emit(self, phase, **fields):
+        line = {"phase": phase}
+        line.update(self.device)
+        line.update(fields)
+        print(json.dumps(line), flush=True)
+
+
+def _loss(fetched):
+    return float(np.asarray(fetched).reshape(-1)[0])
+
+
+def _timed(fn):
+    t0 = time.perf_counter()
+    out = fn()
+    return out, time.perf_counter() - t0
+
+
+# ---------------------------------------------------------------------------
+def attach(run):
+    """The one attach. Places the compile cache before JAX compiles
+    anything, then requires the platform this invocation was asked for."""
+    from importlib import metadata
+    from paddle_tpu.framework.compile_cache import place_compile_cache
+    cache_dir = place_compile_cache()
+    import jax
+    run.watch_compiles()
+    devices = jax.devices()
+    dev = devices[0]
+    want = "cpu" if run.rehearsal else "tpu"
+    if dev.platform != want:
+        raise PhaseFailed(
+            "chip_smoke.py needs platform %r; JAX found %r (%s x%d)%s"
+            % (want, dev.platform, dev.device_kind, len(devices),
+               "" if run.rehearsal else
+               " — there is no CPU fallback; --rehearse-cpu is the "
+               "explicit tiny-size walk-through"))
+    run.device = {"platform": dev.platform, "device_kind": dev.device_kind,
+                  "n_devices": len(devices)}
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = None
+    from paddle_tpu.native import build as native_build
+    built = native_build.native_available()
+    return dict(jax=jax.__version__, jaxlib=metadata.version("jaxlib"),
+                libtpu=libtpu, compile_cache_dir=cache_dir,
+                rehearsal=run.rehearsal, native_dataplane_built=built,
+                native_dataplane_error=None if built
+                else repr(native_build.build_error()))
+
+
+# ---------------------------------------------------------------------------
+def _train_fixed_batch(phase, exe, program, feed, loss_var, steps):
+    """`steps` exe.run calls on one fixed batch. Checks: every loss
+    finite, the last below the first, no cache miss after the first
+    call. Returns (losses, first_call_s, later step seconds)."""
+    losses, secs = [], []
+    misses_after_first = None
+    for i in range(steps):
+        out, dt = _timed(lambda: exe.run(program, feed=feed,
+                                         fetch_list=[loss_var]))
+        losses.append(_loss(out[0]))
+        secs.append(dt)
+        if i == 0:
+            misses_after_first = exe.cache_misses
+    check(np.isfinite(losses).all(), "%s: non-finite loss in %r"
+          % (phase, losses))
+    check(exe.cache_misses == misses_after_first,
+          "%s: the step recompiled after its first call (cache misses "
+          "%d -> %d)" % (phase, misses_after_first, exe.cache_misses))
+    check(losses[-1] < losses[0],
+          "%s: loss on the fixed batch did not fall in %d steps: %r"
+          % (phase, steps, losses))
+    return losses, secs[0], secs[1:]
+
+
+def _on_device(arr, device):
+    import jax
+    return isinstance(arr, jax.Array) and set(arr.devices()) == {device}
+
+
+def train_ernie_base(run):
+    """ERNIE-base MLM+NSP pretraining through Executor on one chip."""
+    import paddle_tpu as pt
+    from paddle_tpu import optimizer
+    from paddle_tpu.framework.scope import Scope, scope_guard
+    from paddle_tpu.models import bert
+    sz = run.sizes["ernie"]
+    batch, seq, preds = sz["batch"], sz["seq"], sz["preds"]
+    cfg = bert.bert_base(**sz["cfg"])
+    adam = optimizer.Adam(sz["lr"])
+    main, startup, _feeds, fetch = bert.bert_pretrain_program(
+        cfg, batch, seq, preds, optimizer_fn=adam.minimize)
+    loss = fetch["loss"]
+    scope = Scope()
+    with scope_guard(scope):
+        place = run.place()
+        exe = pt.Executor(place)
+        _, startup_s = _timed(lambda: exe.run(startup))
+        feed = bert.synthetic_batch(cfg, batch, seq, preds)
+        losses, first_s, step_secs = _train_fixed_batch(
+            "train_ernie_base", exe, main, feed, loss, RUN_STEPS)
+
+        # one run_steps window (lax.scan over WINDOW distinct batches),
+        # then the same window again: the second call must be a cache hit
+        batches = [bert.synthetic_batch(cfg, batch, seq, preds, seed=1 + i)
+                   for i in range(WINDOW)]
+        stacked = {k: np.stack([b[k] for b in batches]) for k in feed}
+        misses = exe.cache_misses
+        win, win_first_s = _timed(lambda: exe.run_steps(
+            main, feed=stacked, fetch_list=[loss]))
+        check(exe.cache_misses == misses + 1,
+              "run_steps window: expected exactly one new cache entry")
+        win2, win_s = _timed(lambda: exe.run_steps(
+            main, feed=stacked, fetch_list=[loss]))
+        check(exe.cache_misses == misses + 1,
+              "run_steps window recompiled on its second call")
+        win_losses = [float(v) for v in np.asarray(win[0]).reshape(-1)] + \
+            [float(v) for v in np.asarray(win2[0]).reshape(-1)]
+        check(len(win_losses) == 2 * WINDOW and np.isfinite(win_losses).all(),
+              "run_steps window losses %r" % (win_losses,))
+
+        # state lives on the device the place names
+        device = place.jax_device()
+        param = scope.find_var("word_embedding")
+        moment_name = adam._accumulators[("moment1", "word_embedding")].name
+        moment = scope.find_var(moment_name)
+        check(_on_device(param, device) and _on_device(moment, device),
+              "state is not a jax.Array on %r: word_embedding %r, %s %r"
+              % (device, type(param), moment_name, type(moment)))
+
+        # which dropout RNG the step that ran was lowered with: read it
+        # off the step's own StableHLO (the rbg path emits
+        # rng_bit_generator; threefry lowers to plain integer arithmetic)
+        lowered = exe.dump_hlo(main, feed=feed, fetch_list=[loss],
+                               include_compiled=False)["lowered"]
+        rng_path = "rbg" if "rng_bit_generator" in lowered else "threefry"
+    return dict(
+        batch=batch, seq=seq, preds=preds, hidden=cfg.hidden_size,
+        layers=cfg.num_layers, dtype=cfg.dtype,
+        losses=[round(v, 4) for v in losses],
+        window_losses=[round(v, 4) for v in win_losses],
+        dropout_rng=rng_path, state_device=str(device),
+        startup_s=round(startup_s, 2), smoke_first_call_s=round(first_s, 2),
+        smoke_step_s=round(float(np.median(step_secs)), 4),
+        smoke_window_first_call_s=round(win_first_s, 2),
+        smoke_window_s=round(win_s, 4), window=WINDOW)
+
+
+# ---------------------------------------------------------------------------
+def kernels(run):
+    """The Pallas-vs-XLA oracle, compiled by Mosaic on the chip."""
+    import bench
+    result = bench.pallas_selfcheck(interpret=run.rehearsal)
+    failed = {k: c for k, c in result["checks"].items() if not c["ok"]}
+    check(result["ok"], "kernels: %d of %d checks failed:\n%s" % (
+        len(failed), len(result["checks"]),
+        "\n".join("--- %s: %s" % (k, json.dumps(c, indent=1))
+                  for k, c in failed.items())))
+    # per check: its max error relative to the oracle's range
+    return dict(interpret=result["interpret"],
+                max_rel_err={k: c["max_rel_err"]
+                             for k, c in result["checks"].items()})
+
+
+# ---------------------------------------------------------------------------
+def train_gpt_flash(run):
+    """GPT causal LM at T=4096 through the flash kernel inside a whole
+    fused train step — the ERNIE step at T=128 never reaches Pallas."""
+    import paddle_tpu as pt
+    from paddle_tpu import optimizer
+    from paddle_tpu.framework.scope import Scope, scope_guard
+    from paddle_tpu.models import gpt
+    sz = run.sizes["gpt"]
+    batch, seq = sz["batch"], sz["seq"]
+    cfg = gpt.GPTConfig(**sz["cfg"])
+    main, startup, _feeds, fetch = gpt.gpt_pretrain_program(
+        cfg, batch, seq, optimizer_fn=optimizer.Adam(1e-4).minimize)
+    loss = fetch["loss"]
+    feed = gpt.synthetic_batch(cfg, batch, seq)
+    with scope_guard(Scope()):
+        exe = pt.Executor(run.place())
+        exe.run(startup)
+        losses, first_s, step_secs = _train_fixed_batch(
+            "train_gpt_flash", exe, main, feed, loss, 3)
+        lowered = exe.dump_hlo(main, feed=feed, fetch_list=[loss],
+                               include_compiled=False)["lowered"]
+    mosaic_calls = lowered.count("tpu_custom_call")
+    # interpreted kernels lower to plain HLO: nothing to find off-chip
+    check(run.rehearsal or mosaic_calls > 0,
+          "train_gpt_flash: no Mosaic custom call in the lowered step — "
+          "attention did not go through the Pallas kernel")
+    return dict(
+        batch=batch, seq=seq, hidden=cfg.hidden_size, layers=cfg.num_layers,
+        dtype=cfg.dtype, losses=[round(v, 4) for v in losses],
+        mosaic_custom_calls=mosaic_calls,
+        smoke_first_call_s=round(first_s, 2),
+        smoke_step_s=round(float(np.median(step_secs)), 4))
+
+
+# ---------------------------------------------------------------------------
+def four_chips(run):
+    """The README's fleet path on a dp2 x mp2 mesh, then ring attention
+    over sp=4 against single-chip flash. Runs when >= 4 devices."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+    if run.device["n_devices"] < 4:
+        return dict(ran=False, reason="needs >= 4 devices, found %d"
+                    % run.device["n_devices"])
+    import paddle_tpu as pt
+    from paddle_tpu import optimizer
+    from paddle_tpu.distributed import fleet, DistributedStrategy
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.distributed.ring_attention import ring_attention
+    from paddle_tpu.framework.scope import Scope, scope_guard
+    from paddle_tpu.models import bert
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    devices = jax.devices()[:4]
+
+    sz = run.sizes["fleet"]
+    batch, seq, preds = sz["batch"], sz["seq"], sz["preds"]
+    strategy = DistributedStrategy()
+    strategy.mesh_axes = {"dp": 2, "mp": 2}
+    fleet.init(strategy=strategy)
+    try:
+        cfg = bert.bert_base(**sz["cfg"])
+        opt = fleet.distributed_optimizer(optimizer.Adam(sz["lr"]))
+        main, startup, _feeds, fetch = bert.bert_pretrain_program(
+            cfg, batch, seq, preds, optimizer_fn=opt.minimize)
+        loss = fetch["loss"]
+        compiled = fleet.main_program_compiled(main)
+        scope = Scope()
+        with scope_guard(scope):
+            exe = pt.Executor(run.place())
+            exe.run(startup)
+            feed = bert.synthetic_batch(cfg, batch, seq, preds)
+            losses, first_s, step_secs = _train_fixed_batch(
+                "four_chips", exe, compiled, feed, loss, 5)
+            # an mp-annotated weight: (hidden, ff) split on its ff dim
+            w_name = "encoder_layer_0_ffn_fc_0.w_0"
+            w = scope.find_var(w_name)
+            shard_devices = {s.device for s in w.addressable_shards}
+            shard_shapes = {tuple(s.data.shape)
+                            for s in w.addressable_shards}
+            check(shard_devices == set(devices),
+                  "%s sits on %r, expected the four mesh devices"
+                  % (w_name, sorted(map(str, shard_devices))))
+            check(shard_shapes == {(cfg.hidden_size, cfg.ff_size // 2)},
+                  "%s shards are %r, expected half-width (%d, %d)"
+                  % (w_name, shard_shapes, cfg.hidden_size,
+                     cfg.ff_size // 2))
+            in_use = [(d.memory_stats() or {}).get("bytes_in_use")
+                      for d in devices]
+    finally:
+        mesh_mod.reset_mesh()
+    if None in in_use:
+        check(run.rehearsal, "memory_stats() reports no bytes_in_use: %r"
+              % (in_use,))
+    else:
+        # nothing piled on chip 0: every chip holds state, same order
+        check(min(in_use) > 0 and max(in_use) < 2 * min(in_use),
+              "device memory in use is uneven across the mesh: %r"
+              % (in_use,))
+
+    rs = run.sizes["ring"]
+    b, h, t, d = rs["shape"]
+    dtype = jnp.dtype(rs["dtype"])
+    rng = np.random.RandomState(0)
+    q, k, v = [jnp.asarray(rng.randn(b, h, t, d), dtype) for _ in range(3)]
+    sp_mesh = Mesh(np.array(devices), ("sp",))
+    scale = 1.0 / np.sqrt(d)
+    ring, ring_first_s = _timed(lambda: jax.block_until_ready(jax.jit(
+        lambda q, k, v: ring_attention(
+            q, k, v, mesh=sp_mesh, axis_name="sp", causal=True,
+            scale=scale))(q, k, v)))
+    flash = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, scale=scale, causal=True,
+        interpret=run.rehearsal))(q, k, v)
+    ring32 = np.asarray(ring.astype(jnp.float32))
+    flash32 = np.asarray(flash.astype(jnp.float32))
+    check(np.isfinite(ring32).all(), "ring attention output not finite")
+    err = float(np.max(np.abs(ring32 - flash32)) /
+                max(float(np.max(np.abs(flash32))), 1.0))
+    check(err < rs["tol"],
+          "ring attention over sp=4 differs from single-chip flash: max "
+          "rel err %.3g >= %.3g" % (err, rs["tol"]))
+    ring_devices = {s.device for s in ring.addressable_shards}
+    check(ring_devices == set(devices),
+          "ring attention output sits on %r"
+          % sorted(map(str, ring_devices)))
+    return dict(
+        ran=True, mesh={"dp": 2, "mp": 2}, batch=batch, seq=seq,
+        hidden=cfg.hidden_size, layers=cfg.num_layers, dtype=cfg.dtype,
+        losses=[round(v, 4) for v in losses], sharded_weight=w_name,
+        shard_shape=list(shard_shapes)[0],
+        shard_devices=sorted(map(str, shard_devices)), bytes_in_use=in_use,
+        smoke_first_call_s=round(first_s, 2),
+        smoke_step_s=round(float(np.median(step_secs)), 4),
+        ring_shape=[b, h, t, d], ring_dtype=rs["dtype"],
+        ring_vs_flash_max_rel_err=round(err, 6),
+        smoke_ring_first_call_s=round(ring_first_s, 2))
+
+
+# ---------------------------------------------------------------------------
+# phase name -> function(run) -> the fields of its line, in run order
+PHASES = {"attach": attach, "train_ernie_base": train_ernie_base,
+          "kernels": kernels, "train_gpt_flash": train_gpt_flash,
+          "four_chips": four_chips}
+
+
+def main(argv):
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("phases", nargs="*", metavar="phase",
+                        help="phases to run after attach (default: all of "
+                        "%s)" % ", ".join(list(PHASES)[1:]))
+    parser.add_argument("--rehearse-cpu", action="store_true",
+                        help="tiny-size CPU walk-through of the phase code")
+    args = parser.parse_args(argv)
+    unknown = sorted(set(args.phases) - set(PHASES))
+    if unknown:
+        parser.error("unknown phase(s) %s" % ", ".join(unknown))
+    run = Run(args.rehearse_cpu)
+    for name, phase in PHASES.items():
+        if name != "attach" and args.phases and name not in args.phases:
+            continue
+        compile_s, hits, misses = run.compile_counters()
+        t0 = time.perf_counter()
+        try:
+            fields = phase(run)
+        except Exception:   # the phase's own error, then a non-zero exit
+            sys.stderr.write("chip_smoke: phase %s FAILED after %.1fs\n%s\n"
+                             % (name, time.perf_counter() - t0,
+                                traceback.format_exc()))
+            return 1
+        after = run.compile_counters()
+        run.emit(name, ok=True, phase_s=round(time.perf_counter() - t0, 2),
+                 compile_s=round(after[0] - compile_s, 2),
+                 persistent_cache_hits=after[1] - hits,
+                 persistent_cache_misses=after[2] - misses, **fields)
+    result = {"ok": True, "device": {
+        "platform": run.device["platform"],
+        "kind": run.device["device_kind"],
+        "count": run.device["n_devices"]}}
+    if run.rehearsal:
+        result["rehearsal"] = True
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -39,6 +39,19 @@ class DistributedStrategy(object):
         self.pp_num_micro = 1
 
 
+def shard_map_unchecked(fn, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with varying-axes (VMA) checking off — the one
+    convention for bodies VMA typing cannot describe: a Pallas call's
+    outputs carry no varying-axes annotation, and the compiled step
+    bodies replay cached per-op vjps whose cotangents were never marked
+    varying. Bodies that are differentiated THROUGH the shard_map from
+    outside (distributed/pipeline.py, sharded_embedding.py) need the
+    checked ``jax.shard_map``: its psum transposes are only right with
+    VMA on."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 def init_mesh(mesh_axes=None, devices=None, multihost=False):
     """Create and install the global mesh. mesh_axes e.g. {"dp":2,"mp":4}."""
     global _mesh, _mesh_axes

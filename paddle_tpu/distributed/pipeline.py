@@ -18,13 +18,8 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except Exception:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def _pvary(x, axis_names):
@@ -39,11 +34,6 @@ def _pvary(x, axis_names):
             x = lax.pcast(x, (a,), to="varying")
         except ValueError:      # already varying on this axis
             pass
-        except (AttributeError, TypeError):
-            try:
-                x = lax.pvary(x, (a,))
-            except (AttributeError, ValueError):
-                pass
     return x
 
 
@@ -67,7 +57,7 @@ def pipeline_forward_local(stage_fn, n_stage, n_micro, axis_name="pp",
     replicate_out=False skips the final pp psum and returns each
     shard's LOCAL outputs buffer (real results only on the last stage)
     — what a caller that differentiates INSIDE the shard_map needs:
-    under check_rep=False the psum's transpose miscounts the replicated
+    with VMA checking off the psum's transpose miscounts the replicated
     cotangent, so the loss must be masked to the last stage instead
     (see pipeline_gpipe_local)."""
     ticks = n_micro + n_stage - 1
@@ -153,7 +143,7 @@ def pipeline_gpipe_local(stage_fn, loss_fn, n_stage, n_micro,
     :func:`pipeline_1f1b_local` the dp reduction is LEFT TO THE CALLER
     (grads come back dp-varying) so a quantized or otherwise custom dp
     gradient sync can slot in."""
-    # NO final psum in the differentiated forward: under check_rep=False
+    # NO final psum in the differentiated forward: with VMA checking off
     # the psum transpose miscounts a replicated cotangent. The loss is
     # masked to the last stage instead — its cotangent rides the reverse
     # ppermute ring back through the stages, and the scalar loss is

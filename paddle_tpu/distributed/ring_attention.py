@@ -11,29 +11,16 @@ this is the capability the north star demands for pod-scale long sequences.
 """
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map_mod
-    shard_map = _shard_map_mod
-except Exception:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-
-from .pipeline import _pvary as _vary  # shared pcast/pvary compat shim
+from .pipeline import _pvary as _vary
 
 
 def _ring_perm(n):
     """Neighbor rotation i -> i+1; backward MUST replay the forward's exact
     rotation order (both sides call this one factory)."""
     return [(i, (i + 1) % n) for i in range(n)]
-
-
-# canonical jax-version compat shim (0.4.x has no lax.axis_size) lives
-# beside the collective kernels; ops never imports distributed at module
-# level, so this direction is cycle-free
-from ..ops.collective_ops import _axis_size  # noqa: E402
 
 
 def _block_logits(q, kk, my_idx, kv_idx, scale, causal, mm=None):
@@ -58,7 +45,7 @@ def _ring_forward(q, k, v, axis_name, causal, scale, mask=None):
     per-row log-sum-exp — the only statistic backward needs. `mask` is
     this shard's additive key-padding block (..., 1, Tk_local); it rides
     the ring with its K/V block."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, h, tl, d = q.shape
 
@@ -113,7 +100,7 @@ def _make_local(axis_name, causal, scale):
     def _bwd_ring(q, k, v, mask, out, lse, dout):
         """Shared ring-replay backward; mask (or None) rides the ring in
         lockstep with its K/V block exactly as in forward."""
-        n = _axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         my_idx = lax.axis_index(axis_name)
         dout32 = dout.astype(jnp.float32)
         # delta_i = sum_j dOut_ij * Out_ij (standard flash backward term)

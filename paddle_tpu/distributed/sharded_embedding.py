@@ -11,13 +11,8 @@ to the owner shard. Pair with Adam(lazy_mode=True) for row-sparse moments.
 """
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except Exception:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def sharded_embedding_lookup(table, ids, mesh, axis="mp"):

@@ -21,11 +21,7 @@ exchanges on ICI.
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map_mod
-    shard_map = _shard_map_mod
-except Exception:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from .mesh import shard_map_unchecked
 
 
 def _local_attention(q, k, v, scale, causal, mask=None):
@@ -102,12 +98,5 @@ def ulysses_attention(q, k, v, mask=None, mesh=None, axis_name="sp",
         in_specs = in_specs + (P(*mspec),)
         args = args + (mask,)
     local = _make_local(axis_name, causal, scale, gather_axis)
-    try:
-        # the flash pallas_call's output avals carry no vma annotation,
-        # so varying-mode checking must be off inside this body
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=spec, check_vma=False)
-    except TypeError:  # pragma: no cover - older jax: check_rep
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=spec, check_rep=False)
-    return fn(*args)
+    # the flash pallas_call's output avals carry no vma annotation
+    return shard_map_unchecked(local, mesh, in_specs, spec)(*args)

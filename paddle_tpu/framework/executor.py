@@ -173,8 +173,7 @@ class Executor(object):
         thread pool maps to background batch prefetch + JAX async
         dispatch: the host stages batch N+1 while the chip runs batch N.
         steps_per_dispatch=W batches W steps into one fused lax.scan
-        device program (run_steps) — the reference's in-C++ trainer loop,
-        recommended over remote/tunneled TPU links.
+        device program (run_steps) — the reference's in-C++ trainer loop.
         Returns (steps_run, last_fetch_values)."""
         from ..trainer_factory import TrainerFactory
         if dataset is None:
@@ -427,9 +426,8 @@ class Executor(object):
         through on-device and the host dispatches ONE computation for the
         whole window. This is the reference's C++ trainer loop
         (`framework/trainer.cc` runs many steps without returning to
-        Python) done the XLA way — and it takes per-step host/link
-        latency (significant over remote TPU tunnels) off the critical
-        path entirely.
+        Python) done the XLA way — and it takes per-step host dispatch
+        latency off the critical path entirely.
 
         Returns the fetches of every step, stacked on a leading axis of
         length N. Per-step semantics (dropout PRNG folding, state
@@ -642,8 +640,8 @@ class Executor(object):
     # ------------------------------------------------------------------
     def _convert_feed(self, program, feed, steps_axis=False):
         """Host-side dtype normalization + ONE batched device_put for all
-        feeds (a single transfer keeps per-array latency — significant over
-        remote/tunneled TPU links — off the step critical path).
+        feeds (a single transfer keeps per-array latency off the step
+        critical path).
         steps_axis=True (run_steps): each array carries a leading steps
         axis; shape validation applies to the per-step remainder."""
         out = {}

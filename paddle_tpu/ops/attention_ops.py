@@ -104,12 +104,9 @@ def _sdpa(ctx, ins, attrs):
                                          axis_name=axis, causal=causal,
                                          scale=scale)}
     if impl in ("auto", "flash"):
-        try:
-            from .pallas.flash_attention import flash_attention
-            out = flash_attention(q, k, v, mask=mask, scale=scale,
-                                  causal=causal)
-            return {"Out": out}
-        except Exception:
-            if impl == "flash":
-                raise
+        # the shape rules above (and flash_attention's own tile guards)
+        # choose the path; an error from the chosen kernel propagates
+        from .pallas.flash_attention import flash_attention
+        return {"Out": flash_attention(q, k, v, mask=mask, scale=scale,
+                                       causal=causal)}
     return {"Out": _sdpa_xla(q, k, v, mask, scale, causal)}

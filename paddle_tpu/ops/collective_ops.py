@@ -28,15 +28,6 @@ def _axis(ctx, attrs):
     return name if name in bound else None
 
 
-def _axis_size(axis_name):
-    """lax.axis_size compat: jax 0.4.x has no lax.axis_size, but psum of
-    a literal 1 constant-folds to the static axis size at trace time."""
-    size = getattr(lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def _make_allreduce(op_name, reduce_fn):
     @register_op(op_name, differentiable=True)
     def _kernel(ctx, ins, attrs, _fn=reduce_fn):
@@ -105,7 +96,7 @@ def _ppermute(ctx, ins, attrs):
     ax = _axis(ctx, attrs)
     if ax is None:
         return {"Out": x}
-    n = _axis_size(ax)
+    n = lax.axis_size(ax)
     shift = attrs.get("shift", 1)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return {"Out": lax.ppermute(x, ax, perm)}
@@ -138,7 +129,7 @@ def quantized_psum(x, axis_name, block_size=quant_ops.DEFAULT_BLOCK_SIZE,
         * (jnp.maximum(gs, 1e-12) / qmax)[..., None]
     tot = jnp.sum(deq, axis=0)
     if mean:
-        tot = tot / _axis_size(axis_name)
+        tot = tot / lax.axis_size(axis_name)
     size = int(np.prod(x.shape)) if x.shape else 1
     return tot.reshape(-1)[:size].reshape(x.shape).astype(x.dtype)
 

@@ -605,16 +605,24 @@ _RBG_PROBE = {}
 def _rbg_supported():
     """One eager probe per backend: RngBitGenerator availability surfaces
     at COMPILE time, so a trace-time try/except around the traced op could
-    never catch it — run a tiny real computation once instead."""
+    never catch it — run a tiny real computation once instead
+    (compile-time eval: the caller is usually inside a jit trace, where
+    the probe would otherwise be staged, not run). Only the runtime's
+    "not implemented on this backend" means threefry, and on a TPU — whose
+    hardware generator is the reason this path exists — it is an error,
+    not a downgrade."""
     backend = jax.default_backend()
     ok = _RBG_PROBE.get(backend)
     if ok is None:
         try:
-            k = jax.random.wrap_key_data(jnp.zeros(4, jnp.uint32),
-                                         impl="rbg")
-            np.asarray(jax.random.bernoulli(k, 0.5, (8,)))
+            with jax.ensure_compile_time_eval():
+                k = jax.random.wrap_key_data(jnp.zeros(4, jnp.uint32),
+                                             impl="rbg")
+                np.asarray(jax.random.bernoulli(k, 0.5, (8,)))
             ok = True
-        except Exception:
+        except jax.errors.JaxRuntimeError as e:
+            if backend == "tpu" or "UNIMPLEMENTED" not in str(e):
+                raise
             ok = False
         _RBG_PROBE[backend] = ok
     return ok

@@ -1,11 +1,11 @@
-"""bench_micro perf gates: the CPU-measurable perf verdict every PR
-gets regardless of TPU fabric health (ROADMAP item 5, scoped slice).
+"""bench_micro perf gates: the CPU-measurable host/compiler verdict
+every PR gets without a chip.
 
 Runs the microbench suite in-process and checks every metric against
 the per-metric regression budgets declared in bench_micro.BUDGETS —
 an order-of-magnitude regression (trace blowup, cache-key churn, a
 codec that stopped compressing, a feed hot-loop slowdown) fails tier-1
-instead of waiting for a healthy chip attach."""
+instead of waiting for a chip run."""
 import json
 import os
 import subprocess
@@ -307,9 +307,9 @@ def test_run_all_with_rounds_dir_persists_and_reports(tmp_path):
 
 @pytest.mark.slow
 def test_bench_micro_cli_emits_json():
-    """End-to-end: `python bench_micro.py` (what bench.py --micro falls
-    back to) prints one JSON line and exits 0. Subprocess = a fresh jax
-    import, so this rides the slow marker."""
+    """End-to-end: `python bench_micro.py` prints one JSON line and
+    exits 0. Subprocess = a fresh jax import, so this rides the slow
+    marker."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run(
         [sys.executable, os.path.join(root, "bench_micro.py")],

@@ -8,10 +8,7 @@ import pytest
 
 from paddle_tpu.ops.registry import get_op
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

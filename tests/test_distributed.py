@@ -120,10 +120,7 @@ def test_full_train_step_dp_mp_mesh():
 def test_collective_ops_shardmap():
     """c_allreduce_sum / c_allgather kernels inside shard_map."""
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from paddle_tpu.ops.registry import get_op
 
     devs = np.array(jax.devices()[:8])
@@ -604,17 +601,6 @@ def _run_workers(tmp_path, script, base_port, n=2, extra_env=None):
     return logs
 
 
-@pytest.mark.xfail(
-    reason="jax 0.4.37 CPU backend: 'Multiprocess computations aren't "
-    "implemented on the CPU backend' — ONLY the XLA-compute leg (the "
-    "jitted collective) needs a real TPU/GPU runtime. The launch/env "
-    "contract is covered by test_pod_config, and the cross-process "
-    "COORDINATION leg now runs for real over SocketCoordinator in "
-    "test_pod_transport.py (procpod battery: TCP rendezvous, gathers, "
-    "SIGKILL chaos — actual OS processes, no accelerator needed). "
-    "Re-enable on accelerator CI or a jax with multiprocess CPU "
-    "collectives.",
-    strict=False)
 def test_multiprocess_jax_distributed_e2e(tmp_path):
     """REAL multi-host validation: 2 OS processes form a jax.distributed
     job through launch.start_procs + init_on_pod (the PADDLE_TRAINER env
@@ -623,7 +609,6 @@ def test_multiprocess_jax_distributed_e2e(tmp_path):
     code path a TPU pod runs, minus the ICI."""
     logs = _run_workers(tmp_path, """
         import jax
-        jax.config.update("jax_platforms", "cpu")
         import numpy as np
         from paddle_tpu.distributed import launch
         pid, n = launch.init_on_pod()
@@ -642,17 +627,6 @@ def test_multiprocess_jax_distributed_e2e(tmp_path):
     assert "OK 0" in logs and "OK 1" in logs
 
 
-@pytest.mark.xfail(
-    reason="jax 0.4.37 CPU backend: 'Multiprocess computations aren't "
-    "implemented on the CPU backend' — ONLY the XLA-compute leg (the "
-    "cross-process sharded array) needs a real multi-host runtime. The "
-    "sharded save/stitch/reshard logic is covered single-process by "
-    "test_io, and the cross-process agreement (who writes, who "
-    "commits, who restores what step) now runs for real over "
-    "SocketCoordinator in test_pod_transport.py (procpod battery: "
-    "elect_restore_step across actual OS processes). Re-enable on "
-    "accelerator CI or a jax with multiprocess CPU collectives.",
-    strict=False)
 def test_multiprocess_sharded_checkpoint_e2e(tmp_path):
     """REAL multi-host checkpoint contract: 2 OS processes in one
     jax.distributed job save a dp-sharded array — each process writes
@@ -662,7 +636,6 @@ def test_multiprocess_sharded_checkpoint_e2e(tmp_path):
     per-pserver _save_distributed_persistables."""
     logs = _run_workers(tmp_path, """
         import jax
-        jax.config.update("jax_platforms", "cpu")
         import json
         import os
         import numpy as np

@@ -326,11 +326,7 @@ def test_tail_load_tensor(tmp_path):
 
 def test_tail_collectives_on_mesh():
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map as _sm
-        shard_map = _sm.shard_map
-    except Exception:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     devs = np.array(jax.devices("cpu")[:4])
     mesh = Mesh(devs, ("dp",))
 

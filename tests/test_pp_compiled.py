@@ -117,8 +117,24 @@ def test_pp_matches_dp_baseline_loss_curve(schedule, quant):
     pp_losses, pp_params = _train(main, startup, loss,
                                   _pp_strategy(schedule, quant), data)
     assert base_losses[-1] < base_losses[0]      # it actually trains
-    np.testing.assert_allclose(pp_losses, base_losses, rtol=1e-4,
-                               atol=1e-6)
+    if not quant:
+        np.testing.assert_allclose(pp_losses, base_losses, rtol=1e-4,
+                                   atol=1e-6)
+    else:
+        # Two quantized runs on different topologies each carry the
+        # codec's own rounding (different shard slices -> different block
+        # scales): on this model dp8-quant sits 6e-5 off dp8-exact and
+        # dp4-quant 8e-5 off dp8-quant with no pipeline anywhere, while
+        # the pipeline lowering itself is exact to 1e-7 (the case above).
+        # So the quantized pipeline is held to the EXACT curve, inside
+        # twice the deviation the quantized dp baseline shows from it.
+        exact_losses, _ = _train(main, startup, loss, _dp_strategy(False),
+                                 data)
+        codec_dev = np.max(np.abs(np.subtract(base_losses, exact_losses))
+                           / np.abs(exact_losses))
+        assert 0 < codec_dev < 1e-3
+        np.testing.assert_allclose(pp_losses, exact_losses,
+                                   rtol=2 * codec_dev, atol=1e-6)
     # params: tight when exact; the quantized codec rounds differently
     # per topology (different shard slices -> different block scales),
     # so quant configs get the PR 6 guardrail envelope instead

@@ -153,10 +153,7 @@ def test_quantized_psum_matches_numpy_reference():
     """quantized_psum == sum over shards of independently dequantized
     per-shard contributions (the EQuARX accuracy model), bit-for-bit
     replicated on every shard."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from paddle_tpu.distributed.mesh import shard_map_unchecked
     from jax.sharding import PartitionSpec as P
     n = 4
     mesh = _mesh(n)
@@ -166,8 +163,7 @@ def test_quantized_psum_matches_numpy_reference():
     def local(xs):
         return co.quantized_psum(xs[0], "dp", block_size=64)
 
-    fn = shard_map(local, mesh=mesh, in_specs=P("dp"), out_specs=P(),
-                   check_rep=False)
+    fn = shard_map_unchecked(local, mesh, P("dp"), P())
     got = np.asarray(jax.jit(fn)(jnp.asarray(x)))
     want = np.zeros(300, np.float32)
     for i in range(n):
@@ -175,10 +171,10 @@ def test_quantized_psum_matches_numpy_reference():
         want += qo.np_block_dequantize(q, s, (300,), np.float32)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
     # mean variant
-    fn_m = shard_map(
+    fn_m = shard_map_unchecked(
         lambda xs: co.quantized_psum(xs[0], "dp", block_size=64,
                                      mean=True),
-        mesh=mesh, in_specs=P("dp"), out_specs=P(), check_rep=False)
+        mesh, P("dp"), P())
     got_m = np.asarray(jax.jit(fn_m)(jnp.asarray(x)))
     np.testing.assert_allclose(got_m, want / n, rtol=1e-5, atol=1e-6)
 
@@ -198,10 +194,7 @@ def test_quant_allreduce_op_identity_outside_shard_map():
 
 
 def test_quant_allreduce_op_inside_shard_map():
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from paddle_tpu.distributed.mesh import shard_map_unchecked
     from jax.sharding import PartitionSpec as P
     from paddle_tpu.ops.registry import get_op
     n = 4
@@ -218,8 +211,7 @@ def test_quant_allreduce_op_inside_shard_map():
             Ctx(), {"X": [xs[0]]},
             {"axis_name": "dp", "block_size": 64})["Out"]
 
-    fn = shard_map(local, mesh=mesh, in_specs=P("dp"), out_specs=P(),
-                   check_rep=False)
+    fn = shard_map_unchecked(local, mesh, P("dp"), P())
     got = np.asarray(jax.jit(fn)(jnp.asarray(x)))
     exact = x.sum(axis=0)
     # quantization error bounded by the per-shard block bound, summed
@@ -237,18 +229,15 @@ def test_sync_context_byte_accounting_and_min_size():
     assert ctx.min_size == 256
     # small grads ride exact: raw == wire contribution
     import jax as _jax
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from paddle_tpu.distributed.mesh import shard_map_unchecked
     from jax.sharding import PartitionSpec as P
     mesh = _mesh(2)
 
     def local(a, b):
         return ctx.sync("big", a[0]), ctx.sync("small", b[0])
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P("dp"), P("dp")),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = shard_map_unchecked(local, mesh, (P("dp"), P("dp")),
+                             (P(), P()))
     big = jnp.ones((2, 1024), jnp.float32)
     small = jnp.ones((2, 8), jnp.float32)
     _jax.jit(fn)(big, small)
