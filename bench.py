@@ -881,47 +881,6 @@ def run_all():
     return 1 if failed else 0
 
 
-def profile_headline():
-    """Per-op attribution of the flagship step (profiler.profile_program
-    runs it op-by-op eagerly — use for WHICH ops dominate, not absolute
-    time) + the fused step's HLO dumped to /tmp for inspection. The
-    input for SURVEY §6's profile analysis."""
-    import jax
-    import paddle_tpu as pt
-    from paddle_tpu import optimizer, profiler
-    from paddle_tpu.models import bert
-    from paddle_tpu.framework.scope import Scope, scope_guard
-
-    on_tpu = _on_tpu()
-    if on_tpu:
-        batch, seq, preds = 128, 128, 20
-        cfg = bert.bert_base(dtype="bfloat16")
-    else:
-        batch, seq, preds = 8, 64, 8
-        cfg = bert.BertConfig(vocab_size=8192, hidden_size=256,
-                              num_layers=4, num_heads=4, ff_size=1024,
-                              max_position=128)
-    main_prog, startup, feeds, fetch = bert.bert_pretrain_program(
-        cfg, batch, seq, preds,
-        optimizer_fn=lambda loss: optimizer.Adam(1e-4).minimize(loss))
-    with scope_guard(Scope()):
-        exe = pt.Executor()
-        exe.run(startup)
-        feed = bert.synthetic_batch(cfg, batch, seq, preds)
-        profiler.profile_program(main_prog, feed, repeat=2, top_k=25)
-        hlo = exe.dump_hlo(main_prog, feed=feed,
-                           fetch_list=[fetch["loss"]])
-        path = "/tmp/paddle_tpu_headline_hlo.txt"
-        text = "\n\n".join("==== %s ====\n%s" % (k, v)
-                           for k, v in hlo.items()) \
-            if isinstance(hlo, dict) else str(hlo)
-        with open(path, "w") as f:
-            f.write(text)
-        print("fused-step HLO written to %s (%d bytes)"
-              % (path, len(text)))
-        dot_inventory(text)
-
-
 def dot_inventory(hlo_text, top_k=20):
     """Classify every dot_general in the fused step's HLO by operand
     dtypes and analytic FLOPs — the r4 bf16 audit (which found the f32
@@ -992,12 +951,8 @@ _SECTIONS = {
 def main(argv):
     if not argv:
         return run_all()
-    if argv[0] == "profile":
-        _attach(require_tpu=False)
-        profile_headline()
-        return 0
     if argv[0] not in _SECTIONS:
-        sys.exit("unknown section %r; one of %s, profile"
+        sys.exit("unknown section %r; one of %s"
                  % (argv[0], ", ".join(sorted(_SECTIONS))))
     fields = _attach(require_tpu=False)
     result = _SECTIONS[argv[0]]()

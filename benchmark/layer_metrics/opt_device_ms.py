@@ -1,0 +1,10 @@
+"""Step program: per traced step, the device time (operations clipped to
+the step's `XLA Modules` run, as `device_step_ms` takes it) of the
+operations whose role scope is `optimize` or `lr_sched`; median over steps, in ms. Read
+from the `tf_op` of each operation's metadata (`_scopes.py`)."""
+from benchmark.layer_metrics import _scopes
+
+
+def read(record):
+    roles = _scopes.roles_of(record)
+    return roles["opt_ms"] if roles else None

@@ -6,6 +6,13 @@ outside with ``JAX_COMPILATION_CACHE_DIR`` (JAX reads that variable
 itself); otherwise it goes to ONE fixed, git-ignored directory inside
 the checkout, the same whatever the working directory, process id or
 time.
+
+An entry's key includes the operations' metadata
+(``jax_compilation_cache_include_metadata_in_key``): the names the program
+lowers its ops under (``framework/trace.py``: ``<role>/<op type>``) are
+what a device trace is read by, and without this a cached executable
+compiled from the same arithmetic under OTHER names (an older checkout
+sharing the directory) would be loaded in its place, names and all.
 """
 import os
 
@@ -22,6 +29,7 @@ def place_compile_cache():
     alone when ``JAX_COMPILATION_CACHE_DIR`` is set; otherwise points
     ``jax_compilation_cache_dir`` at the in-checkout directory. Returns
     the directory in effect."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

@@ -276,9 +276,10 @@ class Executor(object):
             return out
 
         # ---- the jitted single-step path ---------------------------------
-        # phase spans (exec.step > compile/execute/writeback) + the
-        # always-on executor_step_seconds{kind=} histograms — the obs
-        # layer's executor leg
+        # phase spans that tile the step (exec.step > feed/prepare/
+        # compile/execute/writeback > fetch) + the always-on
+        # executor_step_seconds{kind=} histograms — the obs layer's
+        # executor leg
         with obs.span("exec.step", entry="run") as sp:
             out = self._run_jitted(program, feed, fetch_names, scope,
                                    return_numpy, use_program_cache,
@@ -291,14 +292,20 @@ class Executor(object):
     def _run_jitted(self, program, feed, fetch_names, scope,
                     return_numpy, use_program_cache, strategy, sp):
         t_total = time.perf_counter()
-        state_names, uses_rng = self._prepare_state(program, feed, scope)
-        feed_vals = self._convert_feed(program, feed)
-        check_numerics, policy, skip_budget = _numeric_config(program,
-                                                             strategy)
-        key = (id(program), program._version, _feed_signature(feed_vals),
-               tuple(fetch_names), tuple(state_names), check_numerics,
-               None if strategy is None else strategy._cache_token())
-        entry = self._cache.get(key) if use_program_cache else None
+        with obs.span("exec.feed"):
+            feed_vals = self._convert_feed(program, feed)
+        with obs.span("exec.prepare"):
+            state_names, uses_rng = self._prepare_state(program, feed,
+                                                        scope)
+            check_numerics, policy, skip_budget = _numeric_config(
+                program, strategy)
+            key = (id(program), program._version,
+                   _feed_signature(feed_vals), tuple(fetch_names),
+                   tuple(state_names), check_numerics,
+                   None if strategy is None else strategy._cache_token())
+            entry = self._cache.get(key) if use_program_cache else None
+            state_vals = tuple(scope.find_var(n) for n in state_names)
+            feed_tuple = tuple(feed_vals[k] for k in sorted(feed_vals))
         if entry is None:
             self.cache_misses += 1
             sp.set(cache="miss")
@@ -316,8 +323,6 @@ class Executor(object):
             sp.set(cache="hit")
         step_fn = entry
 
-        state_vals = tuple(scope.find_var(n) for n in state_names)
-        feed_tuple = tuple(feed_vals[k] for k in sorted(feed_vals))
         t0 = time.perf_counter()
         with obs.span("exec.execute"):
             if check_numerics:
@@ -347,11 +352,14 @@ class Executor(object):
     @staticmethod
     def _writeback(scope, state_names, new_state, fetches, return_numpy):
         """Shared run()/run_steps() tail: persist the new state, convert
-        fetches."""
+        fetches. ``exec.fetch`` holds the wait for the device and the copy
+        back; what is left of the caller's ``exec.writeback`` is the scope
+        writes."""
         for n, v in zip(state_names, new_state):
             scope.set_var(n, v)
         if return_numpy:
-            return [np.asarray(f) for f in fetches]
+            with obs.span("exec.fetch"):
+                return [np.asarray(f) for f in fetches]
         return list(fetches)
 
     @staticmethod
@@ -497,17 +505,21 @@ class Executor(object):
     def _run_steps_jitted(self, program, strategy, feed, fetch_names,
                           scope, return_numpy, use_program_cache,
                           n_steps, sp):
-        staged = self._convert_feed(program, feed, steps_axis=True)
-
-        check_numerics, policy, skip_budget = _numeric_config(program,
-                                                              strategy)
-        state_names, uses_rng = self._prepare_state(program, staged, scope)
-        key = (id(program), program._version,
-               _feed_signature(staged), tuple(fetch_names),
-               tuple(state_names), check_numerics, "scan",
-               None if strategy is None else strategy._cache_token())
+        with obs.span("exec.feed"):
+            staged = self._convert_feed(program, feed, steps_axis=True)
+        with obs.span("exec.prepare"):
+            check_numerics, policy, skip_budget = _numeric_config(
+                program, strategy)
+            state_names, uses_rng = self._prepare_state(program, staged,
+                                                        scope)
+            key = (id(program), program._version,
+                   _feed_signature(staged), tuple(fetch_names),
+                   tuple(state_names), check_numerics, "scan",
+                   None if strategy is None else strategy._cache_token())
+            fn = self._cache.get(key) if use_program_cache else None
+            state_vals = tuple(scope.find_var(n) for n in state_names)
+            feed_tuple = tuple(staged[k] for k in sorted(staged))
         t_total = time.perf_counter()
-        fn = self._cache.get(key) if use_program_cache else None
         if fn is not None:
             self.cache_hits += 1
             sp.set(cache="hit")
@@ -556,8 +568,6 @@ class Executor(object):
             resilience.observe_executor_step(
                 "compile", time.perf_counter() - t_compile)
             obs.record("exec.compile", w_compile, obs.now())
-        state_vals = tuple(scope.find_var(n) for n in state_names)
-        feed_tuple = tuple(staged[k] for k in sorted(staged))
         t_exec = time.perf_counter()
         with obs.span("exec.execute"):
             ys, new_state = fn(state_vals, feed_tuple)

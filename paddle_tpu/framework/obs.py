@@ -42,6 +42,17 @@ Design:
     number of per-process :func:`dump_dict` blobs —
     ``tools/traceview.py`` is the CLI (files and/or live
     ``/admin/trace`` pulls).
+  * **Mirror into the profiler's trace**: while the engine is enabled
+    an open span also enters a ``jax.profiler.TraceAnnotation`` of the
+    same name, so that when a ``jax.profiler`` session is running the
+    span sits on the host plane's thread line ON THE PROFILER'S CLOCK,
+    beside the device's operations — how a device-idle gap is named
+    after the Executor phase the host was in (``exec.feed``,
+    ``exec.execute``, ``exec.fetch``). It is the only clock bridge: no
+    offset is estimated. This module never imports JAX (coordination
+    servers and routers use it without): the annotation class is
+    picked up lazily, and only if ``jax`` is already in
+    ``sys.modules``. Retroactive :func:`record` spans stay obs-only.
 
 Span taxonomy (what the built-in instrumentation emits) is documented
 in PORTING.md "Observability & tracing".
@@ -51,6 +62,7 @@ import collections
 import json
 import os
 import random
+import sys
 import threading
 import time
 
@@ -84,6 +96,20 @@ _tls = threading.local()
 # ids from the process-seeded global RNG would correlate across forked
 # workers; a dedicated SystemRandom never collides
 _rng = random.SystemRandom()
+
+
+_annotation = None
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` once the process has imported
+    JAX, else None. Never imports JAX itself."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
 
 
 def now():
@@ -186,7 +212,8 @@ class _Span(object):
     """An OPEN span (context manager). ``set(**labels)`` annotates it
     mid-flight (outcome labels land just before close)."""
 
-    __slots__ = ("trace", "id", "parent", "name", "t0", "labels")
+    __slots__ = ("trace", "id", "parent", "name", "t0", "labels",
+                 "mirror")
 
     def __init__(self, name, trace, parent, labels):
         self.name = name
@@ -194,6 +221,8 @@ class _Span(object):
         self.id = _new_span_id()
         self.parent = parent
         self.labels = labels
+        annotation = _annotation_class()
+        self.mirror = None if annotation is None else annotation(name)
         self.t0 = now()
 
     def set(self, **labels):
@@ -202,9 +231,13 @@ class _Span(object):
 
     def __enter__(self):
         _push(self.trace, self.id)
+        if self.mirror is not None:
+            self.mirror.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self.mirror is not None:
+            self.mirror.__exit__(exc_type, exc, tb)
         _pop()
         if exc_type is not None and "error" not in self.labels:
             self.labels["error"] = exc_type.__name__

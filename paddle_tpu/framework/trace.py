@@ -242,10 +242,27 @@ def trace_block(block, env, ctx):
         trace_op(op, env, ctx, _rng_tag(block, i))
 
 
-def trace_op(op, env, ctx, rng_tag=0):
+def op_scope(op):
+    """``<role>/<op type>``: the name every op is lowered under
+    (``jax.named_scope``), so that the compiled step's ``op_name``s — and
+    with them the device trace — start with the program's own structure:
+    ``jit(step)/forward/fc/...``, ``jit(step)/backward/fc/...``,
+    ``jit(step)/optimize/adam/...``. The role is the op's ``op_role``
+    (forward where it has none); a ``grad_of`` op is ``backward/<fwd
+    type>``. Metadata only: nothing of the computation changes."""
     if op.type == GRAD_OP_TYPE:
-        return _trace_grad_op(op, env, ctx)
+        return "backward/" + op.attrs["fwd_type"]
+    return "%s/%s" % (op.attrs.get("op_role", "forward"), op.type)
 
+
+def trace_op(op, env, ctx, rng_tag=0):
+    with jax.named_scope(op_scope(op)):
+        if op.type == GRAD_OP_TYPE:
+            return _trace_grad_op(op, env, ctx)
+        return _trace_forward_op(op, env, ctx, rng_tag)
+
+
+def _trace_forward_op(op, env, ctx, rng_tag):
     opdef = get_op(op.type)
     ins = _gather_inputs(op, env)
     ctx.begin_op(rng_tag)

@@ -162,6 +162,7 @@ def _ce_call_fwd(logits, labels, block_t, block_v, interpret):
         ],
         scratch_shapes=[pltpu.VMEM((block_t, 128), jnp.float32)
                         for _ in range(3)],
+        name="blockwise_ce_fwd",
         interpret=interpret,
     )(logits, _rows8(labels, jnp.int32))
     return loss[0], lse[0]
@@ -194,6 +195,7 @@ def _ce_bwd(block_t, block_v, interpret, res, dloss):
         out_specs=pl.BlockSpec((block_t, block_v),
                                lambda ti, vj: (ti, vj)),
         out_shape=jax.ShapeDtypeStruct((t, v), logits.dtype),
+        name="blockwise_ce_bwd_dx",
         interpret=interpret,
     )(logits, _rows8(labels, jnp.int32), _rows8(lse, jnp.float32),
       _rows8(dloss, jnp.float32))
@@ -333,6 +335,7 @@ def _head_call_fwd(hidden, weight, bias, labels, block_t, block_v,
         ],
         scratch_shapes=[pltpu.VMEM((block_t, 128), jnp.float32)
                         for _ in range(3)],
+        name="blockwise_ce_head_fwd",
         interpret=interpret,
     )(hidden, weight, _rows8(bias, jnp.float32),
       _rows8(labels, jnp.int32))
@@ -377,6 +380,7 @@ def _head_bwd(block_t, block_v, interpret, res, dloss):
         out_specs=pl.BlockSpec((block_t, d), lambda ti, vj: (ti, 0)),
         out_shape=jax.ShapeDtypeStruct((t, d), hidden.dtype),
         scratch_shapes=[pltpu.VMEM((block_t, d), jnp.float32)],
+        name="blockwise_ce_head_bwd_dh",
         interpret=interpret,
     )(hidden, weight, bias8, lab8, lse8, dl8)
 
@@ -403,6 +407,7 @@ def _head_bwd(block_t, block_v, interpret, res, dloss):
             pltpu.VMEM((d, block_v), jnp.float32),
             pltpu.VMEM((8, block_v), jnp.float32),
         ],
+        name="blockwise_ce_head_bwd_dwb",
         interpret=interpret,
     )(hidden, weight, bias8, lab8, lse8, dl8)
 

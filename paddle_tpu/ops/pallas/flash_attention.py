@@ -231,6 +231,7 @@ def _pallas_forward(q, k, v, mask, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((bh, 8, tq), jnp.float32),
         ],
         scratch_shapes=scratch,
+        name="flash_fwd",
         interpret=interpret,
     )(q3, k3, v3, mask_in)
     return out.reshape(b, h, tq, d), stats
@@ -359,6 +360,7 @@ def _pallas_backward(q, k, v, mask, out, stats, g, scale, causal, block_q,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q3, k3, v3, do3, stats, delta, mask_in)
 
@@ -380,6 +382,7 @@ def _pallas_backward(q, k, v, mask, out, stats, g, scale, causal, block_q,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bb, i, j: (bb, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q3, k3, v3, do3, stats, delta, mask_in)
 

@@ -94,6 +94,7 @@ def fused_adam(p, g, m1, m2, lr_t, beta1=0.9, beta2=0.999, eps=1e-8,
             jax.ShapeDtypeStruct((rows_p, _LANES), jnp.float32),
             jax.ShapeDtypeStruct((rows_p, _LANES), jnp.float32),
         ],
+        name="fused_adam",
         interpret=bool(interpret),
     )(lr2, p2, g2, m12, m22)
 

@@ -104,6 +104,7 @@ def _ln_call_fwd(x, scale, bias, eps, block_rows, interpret):
             jax.ShapeDtypeStruct((8, rows_p), jnp.float32),
             jax.ShapeDtypeStruct((8, rows_p), jnp.float32),
         ],
+        name="layer_norm_fwd",
         interpret=interpret,
     )(x2, _rows8(scale, jnp.float32), _rows8(bias, jnp.float32))
     return y[:rows], mean[0, :rows], rstd[0, :rows]
@@ -146,6 +147,7 @@ def _ln_bwd(eps, block_rows, interpret, res, g):
             pltpu.VMEM((8, cols), jnp.float32),
             pltpu.VMEM((8, cols), jnp.float32),
         ],
+        name="layer_norm_bwd",
         interpret=interpret,
     )(x2, g2, _rows8(scale, jnp.float32), _rows8(mean_p, jnp.float32),
       _rows8(rstd_p, jnp.float32))

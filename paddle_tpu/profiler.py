@@ -3,38 +3,129 @@
 Reference parity: python/paddle/fluid/profiler.py — but TPU profiling goes
 through jax.profiler (XPlane traces viewable in TensorBoard/Perfetto).
 
+``stop_profiler`` (and the ``profiler()`` context) print, as the
+reference's does, a sorted table — of the FUSED step's real device time:
+one row per ``<role>/<op type>`` the program lowered its ops under
+(``forward/mul``, ``backward/layer_norm``, ``optimize/adam``; Pallas
+kernels under their own names, ``backward/flash_bwd_dkv``; ``unscoped``
+for what XLA added), with calls, total, average and share, read from the
+trace just written (``framework/xplane.py``). A CPU trace has no device
+plane and gives no table.
+
 Rides the framework.obs spans engine as well: ``annotate`` opens an obs
-span alongside the jax TraceAnnotation (so user annotations land BOTH
-inside the XLA trace and on the cross-process obs timeline), and
-``profile_program`` records per-op obs spans — one merged
-``tools/traceview.py`` timeline can therefore show user annotations,
-executor phases, router/replica serving legs and coordination waits
-together, with jax.profiler covering the XLA interior.
+span, and while obs is enabled every obs span (the Executor's
+``exec.step`` > ``exec.feed``/``prepare``/``compile``/``execute``/
+``writeback`` > ``exec.fetch`` among them) is mirrored as a jax
+``TraceAnnotation`` — so inside a running profiler session they sit on
+the host's thread line on the profiler's clock, beside the device's
+operations, and on the cross-process obs timeline
+(``tools/traceview.py``) as before.
 """
 import contextlib
 
 import jax
 
-from .framework import obs
+from .framework import obs, xplane
+
+
+DEFAULT_PATH = "/tmp/paddle_tpu_profile"
+_SORT_COLUMN = {"calls": 1, "total": 2, "ave": 3, "max": 4, "min": 5}
+_session = {"path": None}
 
 
 @contextlib.contextmanager
-def profiler(state="All", sorted_key=None, profile_path="/tmp/paddle_tpu_profile"):
-    jax.profiler.start_trace(profile_path)
+def profiler(state="All", sorted_key=None, profile_path=DEFAULT_PATH):
+    start_profiler(state, profile_path=profile_path)
     try:
         with obs.span("profiler.trace", path=str(profile_path)):
             yield
     finally:
-        jax.profiler.stop_trace()
+        stop_profiler(sorted_key, profile_path)
 
 
 def start_profiler(state="All", tracer_option=None,
-                   profile_path="/tmp/paddle_tpu_profile"):
+                   profile_path=DEFAULT_PATH):
     jax.profiler.start_trace(profile_path)
+    _session["path"] = profile_path
 
 
 def stop_profiler(sorted_key=None, profile_path=None):
+    """Stop the trace and print the device-time table of what it holds
+    (``sorted_key``: "total" (default), "calls", "ave", "max", "min").
+    Returns the table's rows."""
     jax.profiler.stop_trace()
+    path = profile_path or _session["path"] or DEFAULT_PATH
+    rows = op_table(path, sorted_key)
+    print_table(rows)
+    return rows
+
+
+def op_table(path, sorted_key=None):
+    """Rows ``(name, calls, total_ms, ave_ms, max_ms, min_ms, share_pct)``
+    of the device time in the trace at ``path`` (a file or a directory),
+    one per ``<role>/<op type>`` / kernel name. Each instant is given to
+    the innermost running operation (a ``while`` holds its body's ops), so
+    the totals add up to the device-busy time; several chips add up."""
+    col = _SORT_COLUMN.get(sorted_key or "total")
+    if col is None:
+        raise ValueError("sorted_key %r: one of %s"
+                         % (sorted_key, sorted(_SORT_COLUMN)))
+    acc = {}
+    for ops in xplane.device_ops(path).values():
+        for (name, ns) in _exclusive_ns(ops):
+            row = acc.setdefault(xplane.scope_row(name), [0, 0.0, 0.0, None])
+            row[0] += 1
+            row[1] += ns
+            row[2] = max(row[2], ns)
+            row[3] = ns if row[3] is None else min(row[3], ns)
+    busy = sum(r[1] for r in acc.values())
+    rows = [(name, c, tot / 1e6, tot / c / 1e6, mx / 1e6, mn / 1e6,
+             100.0 * tot / busy if busy else 0.0)
+            for name, (c, tot, mx, mn) in acc.items()]
+    rows.sort(key=lambda r: (-r[col], r[0]))
+    return rows
+
+
+def _exclusive_ns(ops):
+    """[(op_name, ns)] per device operation of one chip, each instant
+    counted once, for the innermost operation running."""
+    events = sorted(ops, key=lambda ev: (ev[1], -ev[2]))
+    own = [0.0] * len(events)
+    stack, at = [], 0.0     # stack of (end, index)
+
+    def advance(to):
+        nonlocal at
+        while stack:
+            end, i = stack[-1]
+            upto = min(end, to)
+            if upto > at:
+                own[i] += upto - at
+                at = upto
+            if end > to:
+                return
+            stack.pop()
+        at = max(at, to)
+
+    for i, (_name, start, end, _op) in enumerate(events):
+        advance(start)
+        at = max(at, start)
+        stack.append((end, i))
+    advance(float("inf"))
+    return [(ev[3], ns) for ev, ns in zip(events, own)]
+
+
+def print_table(rows, top_k=None):
+    if not rows:
+        print("profiler: the trace holds no device operations (a CPU "
+              "run); no table")
+        return
+    print("%-44s %8s %12s %12s %8s" % ("Role/Op", "Calls", "Total(ms)",
+                                       "Ave(ms)", "Share%"))
+    for name, calls, total, ave, _mx, _mn, share in rows[:top_k]:
+        print("%-44s %8d %12.3f %12.4f %8.2f" % (name, calls, total, ave,
+                                                 share))
+    print("%-44s %8d %12.3f" % ("device busy", sum(r[1] for r in rows),
+                                sum(r[2] for r in rows)))
 
 
 def reset_profiler():
@@ -43,71 +134,14 @@ def reset_profiler():
 
 @contextlib.contextmanager
 def annotate(name):
-    with jax.profiler.TraceAnnotation(name):
+    """A named region in the profiler's trace and, while obs is enabled,
+    on the obs timeline too (obs mirrors its spans into the trace)."""
+    if obs.enabled():
         with obs.span(str(name)):
             yield
-
-
-def profile_program(program, feed, scope=None, repeat=3, sorted_key="total",
-                    top_k=30, print_table=True):
-    """Per-op time attribution (the reference profiler's sorted op table,
-    ref python/paddle/fluid/profiler.py stop_profiler output).
-
-    The production Executor fuses the whole Program into ONE XLA
-    computation, so per-op times don't exist there; this runs the
-    program OP-BY-OP eagerly (like the reference's per-kernel timers),
-    blocking after each op.  Absolute times are therefore pessimistic —
-    use the table for *attribution* (which ops dominate), and the fused
-    step for real throughput.  Returns rows of
-    (op_type, calls, total_s, avg_s) sorted by ``sorted_key``
-    ("total" | "calls" | "ave").
-    """
-    import time
-    from collections import defaultdict
-
-    import numpy as np
-
-    from .framework.executor import _persistable_names, _want_vjp_set
-    from .framework.trace import TraceContext, trace_op, _rng_tag
-    from .framework.scope import global_scope
-
-    scope = scope or global_scope()
-    totals = defaultdict(float)
-    calls = defaultdict(int)
-    for rep in range(repeat):
-        env = {}
-        for n in _persistable_names(program):
-            v = scope.find_var(n)
-            if v is not None:
-                env[n] = v
-        for k, v in (feed or {}).items():
-            env[k] = jax.numpy.asarray(v)
-        ctx = TraceContext(program, jax.random.PRNGKey(rep),
-                           _want_vjp_set(program))
-        block = program.global_block()
-        for i, op in enumerate(block.ops):
-            t0 = time.perf_counter()
-            with obs.span("op.%s" % op.type, repeat=rep):
-                trace_op(op, env, ctx, _rng_tag(block, i))
-                for out_name in op.output_names():
-                    v = env.get(out_name)
-                    if hasattr(v, "block_until_ready"):
-                        v.block_until_ready()
-            dt = time.perf_counter() - t0
-            if rep > 0:  # first pass pays compilation; attribute after
-                totals[op.type] += dt
-                calls[op.type] += 1
-    rows = [(t, calls[t], totals[t], totals[t] / max(calls[t], 1))
-            for t in totals]
-    key_idx = {"total": 2, "calls": 1, "ave": 3}[sorted_key]
-    rows.sort(key=lambda r: -r[key_idx])
-    rows = rows[:top_k]
-    if print_table:
-        print("%-28s %8s %12s %12s" % ("Op", "Calls", "Total(s)",
-                                       "Avg(s)"))
-        for t, c, tot, avg in rows:
-            print("%-28s %8d %12.6f %12.6f" % (t, c, tot, avg))
-    return rows
+    else:
+        with jax.profiler.TraceAnnotation(str(name)):
+            yield
 
 
 @contextlib.contextmanager

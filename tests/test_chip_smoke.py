@@ -33,8 +33,11 @@ def test_chip_smoke_fails_without_a_tpu_and_names_the_platform():
 @pytest.fixture
 def restored_cache_config():
     before = jax.config.jax_compilation_cache_dir
+    in_key = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      in_key)
 
 
 def test_compile_cache_defers_to_the_jax_variable(monkeypatch, tmp_path,
@@ -54,6 +57,42 @@ def test_compile_cache_default_is_one_path_in_the_checkout(
         seen.append(compile_cache.place_compile_cache())
         assert jax.config.jax_compilation_cache_dir == seen[-1]
     assert seen[0] == seen[1] == os.path.join(ROOT, ".jax_compile_cache")
+
+
+def test_a_cached_step_keeps_the_names_it_was_lowered_under(tmp_path):
+    """The same arithmetic lowered under other scope names must not load
+    the first one's executable from the persistent cache: the names are
+    what a device trace is read by (framework/trace.py's role/op scopes),
+    so `place_compile_cache` puts the metadata into the key."""
+    code = (
+        "import re, sys\n"
+        "sys.path.insert(0, %r)\n"
+        "from paddle_tpu.framework.compile_cache import "
+        "place_compile_cache\n"
+        "place_compile_cache()\n"
+        "import jax, jax.numpy as jnp\n"
+        "jax.config.update("
+        "'jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.config.update("
+        "'jax_persistent_cache_min_entry_size_bytes', -1)\n"
+        "def make(scope):\n"
+        "    def f(x):\n"
+        "        with jax.named_scope(scope):\n"
+        "            return jnp.sin(x) @ x\n"
+        "    return f\n"
+        "x = jnp.ones((32, 32))\n"
+        "for scope in ('forward/mul', 'backward/mul'):\n"
+        "    text = jax.jit(make(scope)).lower(x).compile().as_text()\n"
+        "    names = set(re.findall(r'op_name=\"([^\"]+)\"', text))\n"
+        "    assert any(scope in n for n in names), (scope, names)\n"
+        "print('ok')\n" % ROOT)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, env=env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().endswith("ok")
+    assert os.listdir(str(tmp_path))        # the cache was in use
 
 
 def test_tpu_place_raises_in_a_cpu_only_process():

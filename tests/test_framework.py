@@ -115,30 +115,67 @@ def test_prune():
     assert "softmax" in types and "scale" not in types
 
 
-def test_profile_program_op_table():
-    """profiler.profile_program: per-op attribution table (the
-    reference profiler's sorted op-time print, eager re-run design)."""
-    import paddle_tpu as pt
-    from paddle_tpu import layers, optimizer, profiler
+def _tiny_train_hlo(recompute):
+    import re
+    from paddle_tpu import optimizer
     from paddle_tpu.framework.scope import Scope, scope_guard
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
-        x = layers.data('px', [8], 'float32')
-        h = layers.fc(x, size=16, act='relu')
-        loss = layers.reduce_mean(layers.square(h))
-        optimizer.SGD(0.1).minimize(loss)
-    sc = Scope()
-    with scope_guard(sc):
+        x = layers.data("x", [8, 16], "float32", append_batch_size=False)
+        y = layers.data("y", [8, 1], "float32", append_batch_size=False)
+        h = layers.fc(x, 32, act="relu")
+        if recompute:
+            h = layers.recompute_segment(
+                lambda t: layers.fc(t, 32, act="tanh"), [h])
+        h = layers.layer_norm(h)
+        loss = layers.reduce_mean(layers.square(layers.fc(h, 1) - y))
+        optimizer.Adam(1e-3).minimize(loss)
+    with scope_guard(Scope()):
         exe = pt.Executor()
         exe.run(startup)
-        rows = profiler.profile_program(
-            main, {'px': np.ones((4, 8), np.float32)}, scope=sc,
-            repeat=2, print_table=False)
-    types = [r[0] for r in rows]
-    assert "mul" in types and "grad_of" in types
-    # sorted by total descending
-    tot = [r[2] for r in rows]
-    assert tot == sorted(tot, reverse=True)
-    # avg * calls == total
-    for t, c, total, avg in rows:
-        assert abs(avg * c - total) < 1e-9
+        feed = {"x": np.ones((8, 16), np.float32),
+                "y": np.ones((8, 1), np.float32)}
+        text = exe.dump_hlo(main, feed=feed, fetch_list=[loss],
+                            include_compiled=True)["compiled"]
+    return set(re.findall(r'op_name="([^"]+)"', text))
+
+
+@pytest.mark.parametrize("recompute", [False, True])
+def test_compiled_step_names_every_op_by_role_and_type(recompute):
+    """trace_op lowers every op under jax.named_scope("<role>/<op type>"):
+    the first path component after jit(step)/ is the role, the second the
+    Fluid op type, whatever JAX appends; a grad_of op is backward/<fwd
+    type>; a recompute segment's replayed forward sits under backward,
+    its body's ops nested with their own scopes."""
+    names = _tiny_train_hlo(recompute)
+    scoped = [n for n in names if n.startswith("jit(step)/")]
+    assert scoped
+    roles = {n.split("/")[1] for n in scoped}
+    assert roles == {"forward", "backward", "optimize"}, roles
+    for want in ("jit(step)/forward/mul/", "jit(step)/backward/mul/",
+                 "jit(step)/forward/layer_norm/",
+                 "jit(step)/backward/layer_norm/",
+                 "jit(step)/optimize/adam/"):
+        assert any(n.startswith(want) for n in scoped), (want, scoped)
+    if recompute:
+        replayed = [n for n in scoped
+                    if n.startswith("jit(step)/backward/remat_block/")
+                    and "rematted_computation" in n]
+        assert replayed and all("forward/" in n.split("/", 3)[3]
+                                for n in replayed), replayed
+        assert any(n.startswith("jit(step)/forward/remat_block/")
+                   and "forward/tanh" in n for n in scoped)
+
+
+def test_op_scope_of_roles_and_grad_ops():
+    from paddle_tpu import optimizer
+    from paddle_tpu.framework.trace import op_scope
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        x = layers.data("x", [4], dtype="float32")
+        loss = layers.reduce_mean(layers.fc(x, 1))
+        optimizer.SGD(layers.exponential_decay(0.1, 10, 0.9)).minimize(loss)
+    scopes = {op_scope(op) for op in main.global_block().ops}
+    assert {"forward/mul", "backward/mul", "optimize/sgd"} <= scopes
+    assert any(s.startswith("lr_sched/") for s in scopes), scopes
+    assert not any(s.endswith("/grad_of") for s in scopes)

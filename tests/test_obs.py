@@ -199,6 +199,77 @@ def test_clock_offset_probe_against_live_coordserver():
     assert obs.clock_offset() == off
 
 
+class _FakeAnnotation(object):
+    """Stands in for jax.profiler.TraceAnnotation: records the order."""
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+def test_an_enabled_span_is_mirrored_as_a_trace_annotation(monkeypatch):
+    """While obs is on, an open span enters an annotation of the same name
+    (so a running profiler session sees it on its own clock), properly
+    nested; retroactive records stay obs-only; the disabled path is still
+    the shared no-op and builds no annotation."""
+    monkeypatch.setattr(obs, "_annotation", _FakeAnnotation)
+    monkeypatch.setattr(_FakeAnnotation, "log", [])
+    assert obs.span("off") is obs.span("off2")
+    with obs.span("off"):
+        pass
+    assert _FakeAnnotation.log == []
+    obs.enable("unit")
+    with obs.span("exec.step"):
+        with obs.span("exec.feed"):
+            pass
+        obs.record("exec.compile", obs.now(), obs.now())
+    assert _FakeAnnotation.log == [
+        ("enter", "exec.step"), ("enter", "exec.feed"),
+        ("exit", "exec.feed"), ("exit", "exec.step")]
+    assert {s["name"] for s in obs.spans()} == {
+        "exec.step", "exec.feed", "exec.compile"}
+
+
+def test_the_mirror_is_jax_own_annotation_once_jax_is_imported(
+        monkeypatch):
+    import jax
+    monkeypatch.setattr(obs, "_annotation", None)
+    assert obs._annotation_class() is jax.profiler.TraceAnnotation
+    obs.enable("unit")
+    with obs.span("real") as sp:
+        assert isinstance(sp.mirror, jax.profiler.TraceAnnotation)
+    assert [s["name"] for s in obs.spans()] == ["real"]
+
+
+def test_importing_obs_alone_does_not_import_jax():
+    """Coordination servers and routers use obs without JAX: the module
+    picks the annotation class up only if jax is already imported."""
+    code = (
+        "import importlib.util, sys\n"
+        "spec = importlib.util.spec_from_file_location('obs_alone', %r)\n"
+        "m = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(m)\n"
+        "m.enable('alone')\n"
+        "with m.span('a') as sp:\n"
+        "    assert sp.mirror is None\n"
+        "assert [s['name'] for s in m.spans()] == ['a']\n"
+        "assert 'jax' not in sys.modules, 'obs imported jax'\n"
+        "print('ok')\n" % os.path.join(ROOT, "paddle_tpu", "framework",
+                                       "obs.py"))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok"
+
+
 # ---------------------------------------------------------------------------
 # executor phases
 # ---------------------------------------------------------------------------
@@ -279,6 +350,61 @@ def test_run_steps_phases_share_one_exec_step_parent():
         tree = [sp for sp in obs.spans(trace_id=s["trace"])]
         assert {sp["name"] for sp in tree} >= {
             "exec.step", "exec.execute", "exec.writeback"}
+
+
+def _mlp_train_program(width=1024, depth=6, batch=256):
+    from paddle_tpu import optimizer
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        x = layers.data("x", [batch, width], "float32",
+                        append_batch_size=False)
+        y = layers.data("y", [batch, 1], "float32",
+                        append_batch_size=False)
+        h = x
+        for _ in range(depth):
+            h = layers.fc(h, width, act="relu")
+        loss = layers.reduce_mean(layers.square(layers.fc(h, 1) - y))
+        optimizer.Adam(1e-3).minimize(loss)
+    feed = {"x": np.random.rand(batch, width).astype(np.float32),
+            "y": np.zeros((batch, 1), np.float32)}
+    return main, startup, feed, loss
+
+
+@pytest.mark.parametrize("entry", ["run", "run_steps"])
+def test_exec_step_children_tile_the_step(entry):
+    """exec.step's children (feed, prepare, compile, execute, writeback)
+    cover it: what they leave out is a few statements between them, under
+    3% of a step that does some milliseconds of work. exec.fetch sits
+    inside exec.writeback, so that the scope writes are what is left."""
+    from paddle_tpu.framework.scope import Scope, scope_guard
+    with scope_guard(Scope()):
+        main, startup, feed, loss = _mlp_train_program()
+        exe = pt.Executor()
+        exe.run(startup)
+        if entry == "run_steps":
+            feed = {k: np.stack([v] * 3) for k, v in feed.items()}
+        call = getattr(exe, entry)
+        call(main, feed=feed, fetch_list=[loss])        # compiles
+        obs.enable("exec")
+        for _ in range(5):
+            call(main, feed=feed, fetch_list=[loss])
+    steps = obs.spans(name="exec.step")
+    assert len(steps) == 5
+    by_id = {}
+    for s in obs.spans():
+        by_id.setdefault(s["parent"], []).append(s)
+    covers = []
+    for step in steps:
+        kids = by_id[step["id"]]
+        assert [k["name"] for k in sorted(kids, key=lambda k: k["t0"])] \
+            == ["exec.feed", "exec.prepare", "exec.execute",
+                "exec.writeback"]
+        covers.append(sum(k["t1"] - k["t0"] for k in kids)
+                      / (step["t1"] - step["t0"]))
+        wb = next(k for k in kids if k["name"] == "exec.writeback")
+        assert [k["name"] for k in by_id[wb["id"]]] == ["exec.fetch"]
+    # the median step: one preempted step must not fail the suite
+    assert sorted(covers)[len(covers) // 2] >= 0.97, covers
 
 
 # ---------------------------------------------------------------------------

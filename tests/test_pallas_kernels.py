@@ -635,3 +635,56 @@ def test_bert_and_gpt_heads_emit_the_fused_op():
     gmain, _, _, _ = gpt_mod.gpt_pretrain_program(gcfg, 2, 8)
     gops = [op.type for op in gmain.global_block().ops]
     assert "fused_mlm_head_loss" in gops
+
+
+# ---------------------------------------------------------------------------
+# every kernel carries a name of the program's choosing
+# ---------------------------------------------------------------------------
+
+def _pallas_call_sites():
+    """(file, line, name or None) of every pl.pallas_call( in ops/pallas."""
+    import ast
+    import glob
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "paddle_tpu", "ops", "pallas")
+    sites = []
+    for path in sorted(glob.glob(os.path.join(root, "*.py"))):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and isinstance(
+                    node.func, ast.Attribute) \
+                    and node.func.attr == "pallas_call":
+                name = next((kw.value.value for kw in node.keywords
+                             if kw.arg == "name"
+                             and isinstance(kw.value, ast.Constant)), None)
+                sites.append((os.path.basename(path), node.lineno, name))
+    return sites
+
+
+_SITES = _pallas_call_sites()
+
+
+@pytest.mark.parametrize("site", _SITES,
+                         ids=["%s:%d" % s[:2] for s in _SITES])
+def test_every_pallas_call_has_a_stable_name(site):
+    """The device trace tells the kernels apart by `name=` (it becomes the
+    instruction's name and a component of its op_name), not by whatever
+    JAX scope they were traced in."""
+    fname, _line, name = site
+    assert name and name.isidentifier(), site
+    stem = fname[:-3]
+    assert name.startswith({"flash_attention": "flash_"}.get(stem, stem))
+
+
+def test_pallas_call_names_are_distinct():
+    names = [s[2] for s in _SITES]
+    assert len(names) == 11
+    assert len(set(names)) == len(names), names
+
+
+def test_a_kernel_name_reaches_the_traced_step():
+    x = jnp.ones((128, 128), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda x: fused_layer_norm(
+        x, jnp.ones((128,)), jnp.zeros((128,)), interpret=True))(x)
+    assert "name=layer_norm_fwd" in str(jaxpr)
