@@ -431,7 +431,7 @@ def bench_gpt_longctx():
 
 
 def _timed_attn_tokens(loss_fn, q, k, v, b, t, steps):
-    """Shared fwd+bwd attention timing harness (longseq + flashtune):
+    """Fwd+bwd attention timing harness (longseq):
     warm compile, then `steps` grad evaluations; returns tokens/sec."""
     import jax
     g = jax.jit(jax.grad(loss_fn, argnums=(0, 1, 2)))
@@ -443,47 +443,100 @@ def _timed_attn_tokens(loss_fn, q, k, v, b, t, steps):
     return b * t * steps / (time.perf_counter() - t0)
 
 
-def bench_flashtune():
-    """Flash-attention block-size sweep at the long-context shape
-    (T=4096 bf16 fwd+bwd): reports tokens/sec per (block_q, block_k) and
-    the winner — apply fleet-wide via PADDLE_TPU_FLASH_BLOCK_Q/_K."""
+def flash_kernel_ms(b, h, t, d, blocks, causal=True, key_mask=False,
+                    dtype="bfloat16", interpret=False, budget_s=0.25):
+    """Milliseconds a call of each of the three flash kernels (forward,
+    dK/dV, dQ) takes at `blocks` = (block_q, block_k), each kernel timed
+    on its own: warm (the compile), then enough back-to-back calls to fill
+    `budget_s` behind one `block_until_ready`. A kernel the compiler
+    refuses reads "failed: ..."."""
     import jax
     import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    rng = np.random.RandomState(0)
+    q, k, v, g = (jnp.asarray(rng.randn(b, h, t, d), dtype)
+                  for _ in range(4))
+    mask = None
+    if key_mask:
+        pad = np.zeros((b, 1, 1, t), np.float32)
+        pad[..., 3 * t // 4:] = -1e9
+        mask = jnp.asarray(pad, dtype)
+    scale = 1.0 / np.sqrt(d)
+    bq, bk = blocks
+    fwd = jax.jit(lambda q, k, v: fa._pallas_forward(
+        q, k, v, mask, scale, causal, bq, bk, interpret))
+    mode = fa._mask_mode(mask)
+    calls = {"fwd": (fwd, (q, k, v))}
+    try:
+        out, stats = fwd(q, k, v)
+        ops = jax.jit(fa._bwd_inputs)(q, k, v, mask, out, stats, g)
+        for name, kernel in (("bwd_dkv", fa._pallas_bwd_dkv),
+                             ("bwd_dq", fa._pallas_bwd_dq)):
+            calls[name] = (jax.jit(lambda *o, kernel=kernel: kernel(
+                o, h, mode, scale, causal, bq, bk, interpret)), ops)
+    except Exception as e:  # the forward itself was refused
+        return {"fwd": "failed: %s" % str(e)[-200:]}
+    ms = {}
+    for name, (fn, args) in calls.items():
+        try:
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            once = time.perf_counter() - t0
+            n = max(2, min(50, int(budget_s / max(once, 1e-4))))
+            t0 = time.perf_counter()
+            for _ in range(n):
+                res = fn(*args)
+            jax.block_until_ready(res)
+            ms[name] = round((time.perf_counter() - t0) / n * 1e3, 3)
+        except Exception as e:
+            ms[name] = "failed: %s" % str(e)[-200:]
+    return ms
+
+
+def bench_flashtune():
+    """Flash-attention tile sweep: ms a call of each kernel (forward,
+    dK/dV, dQ) per (block_q, block_k), at the attention shapes of the
+    benchmark's GPT cells and of BERT's phase 2 (key-padding mask), bf16.
+    "rule" is the tile `flash_attention.pick_blocks` gives each kernel at
+    that shape — the code applies it by itself; a sweep that disagrees
+    with the rule is a reason to change `pick_blocks`, not to set a knob."""
     from paddle_tpu.ops.pallas import flash_attention as fa
 
     on_tpu = _on_tpu()
     if on_tpu:
-        b, h, t, d, steps = 4, 12, 4096, 64, 6
-        grid = [(128, 128), (128, 256), (256, 128), (256, 256),
-                (128, 512), (512, 128), (512, 512)]
+        shapes = [(4, 12, 4096, 64, True, False),
+                  (16, 12, 1024, 64, True, False),
+                  (32, 12, 512, 64, False, True)]
+        sizes = (256, 512, 1024)
     else:
-        b, h, t, d, steps = 1, 2, 256, 32, 2
-        grid = [(128, 128), (128, 256)]
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(b, h, t, d), jnp.bfloat16)
-    k = jnp.asarray(rng.randn(b, h, t, d), jnp.bfloat16)
-    v = jnp.asarray(rng.randn(b, h, t, d), jnp.bfloat16)
-    scale = 1.0 / np.sqrt(d)
-    interp = not on_tpu
-
+        shapes = [(1, 2, 256, 32, True, False)]
+        sizes = (128, 256)
     results = {}
-    for bq, bk in grid:
-        def loss(q, k, v, bq=bq, bk=bk):
-            o = fa.flash_attention(q, k, v, scale=scale, causal=True,
-                                   block_q=bq, block_k=bk,
-                                   interpret=interp)
-            return jnp.sum(o.astype(jnp.float32))
-        try:
-            results["%dx%d" % (bq, bk)] = round(
-                _timed_attn_tokens(loss, q, k, v, b, t, steps), 1)
-        except Exception as e:  # VMEM overflow at big tiles etc.
-            results["%dx%d" % (bq, bk)] = "failed: %r" % (e,)
-    numeric = {kk: vv for kk, vv in results.items()
-               if isinstance(vv, float)}
-    best = max(numeric, key=numeric.get) if numeric else None
-    return {"metric": "flash-attention block tuning T=%d" % t,
-            "unit": "tokens/sec/chip", "results": results,
-            "best": best, "value": numeric.get(best, 0.0)}
+    for b, h, t, d, causal, key_mask in shapes:
+        tiles = [(128, 128)] + [(bq, bk) for bq in sizes for bk in sizes
+                                if bq <= t and bk <= t]
+        table = {"%dx%d" % tile: flash_kernel_ms(
+            b, h, t, d, tile, causal, key_mask, interpret=not on_tpu)
+            for tile in dict.fromkeys(tiles)}
+        rule = {kern: "%dx%d" % fa.pick_blocks(t, t, d, "bfloat16", kern,
+                                                causal)
+                for kern in fa.KERNELS}
+        best = {}
+        for kern in fa.KERNELS:
+            timed = {tile: row[kern] for tile, row in table.items()
+                     if isinstance(row.get(kern), float)}
+            best[kern] = min(timed, key=timed.get) if timed else None
+        results["%dx%dx%dx%d%s" % (b, h, t, d, "" if causal else "-kmask")] = {
+            "ms": table, "best": best, "rule": rule}
+    # headline: the rule's three kernels at the first shape
+    first = next(iter(results.values()))
+    rule_ms = [first["ms"].get(first["rule"][kern], {}).get(kern)
+               for kern in fa.KERNELS]
+    timed = all(isinstance(x, float) for x in rule_ms)
+    return {"metric": "flash-attention tile sweep, ms per kernel call",
+            "unit": "ms", "results": results,
+            "value": round(sum(rule_ms), 3) if timed else 0.0}
 
 
 def bench_beam_decode():

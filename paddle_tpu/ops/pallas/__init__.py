@@ -6,10 +6,12 @@ pattern: a pure-JAX reference in tests, interpret-mode execution on CPU
 don't tile, and — for the registry-wired ops — trace-time dispatch via
 ``BuildStrategy.use_pallas`` + the ``ops.pallas_dispatch`` scope.
 
-  flash_attention   VMEM-tiled online-softmax attention (exported as
-                    the MODULE for back-compat: bench.py and the
-                    attention layers call ``flash_attention.
-                    flash_attention(...)``)
+  flash_attention   VMEM-tiled online-softmax attention, forward and
+                    backward; each kernel's tile comes from the call's
+                    shape (``pick_blocks``), bf16 operands go to the
+                    MXU as they are (exported as the MODULE for
+                    back-compat: bench.py and the attention layers
+                    call ``flash_attention.flash_attention(...)``)
   blockwise_softmax_cross_entropy / fused_mlm_head_loss
                     blockwise CE + fused MLM head (the [tokens, vocab]
                     logits never materialize; ``blockwise_ce``)

@@ -4,17 +4,28 @@ The hot op of every BASELINE transformer config. Tiles Q/K/V blocks through
 VMEM with online-softmax accumulation — the (T,T) score matrix never touches
 HBM, so attention becomes MXU-bound instead of HBM-bound for long sequences.
 
-Forward: Pallas kernel, grid (B*H, Tq/BQ, Tk/BK), f32 accumulators in VMEM
-scratch persisting across the (innermost, sequential) k-block dimension;
-emits the softmax statistics (row max m, normalizer l) alongside the output.
-Backward: Pallas dK/dV and dQ kernels that recompute p = exp(s - m) / l
-per tile from the saved (out, m, l) residuals — flash-attention-2 style, no
-(T,T) matrix in HBM in either direction, with the additive mask applied
-in-kernel. The statistics stay separate on purpose: folding them into
+Three kernels, each with the tile `pick_blocks` gives it for the call's
+shape (on a v5e up to 1024x1024: a grid step costs microseconds whatever it
+holds, so few wide steps win). bfloat16 q, k, v, dO go to the MXU as they
+are and p, ds are cast down for the second matmuls, all with float32
+results; every other dtype runs float32 operands at HIGHEST. The online
+softmax state (m, l, the accumulators), exp and the scale stay float32.
+
+Forward: grid (B*H, Tq/BQ, Tk/BK), f32 accumulators in VMEM scratch
+persisting across the (innermost, sequential) k-block dimension; emits the
+softmax statistics (row max m, normalizer l) alongside the output.
+Backward: dK/dV (grid (B*H, Tk/BK, Tq/BQ), q-blocks innermost) and dQ
+kernels that recompute p = exp(s - m) / l per tile from the saved
+(out, m, l) residuals — flash-attention-2 style, no (T,T) matrix in HBM in
+either direction, with the additive mask applied in-kernel. Under a causal
+mask a grid step above the diagonal runs no body, and its index maps name
+the block the nearest working step holds, so it fetches nothing either.
+The statistics stay separate on purpose: folding them into
 lse = m + log(l) puts the hardware log/exp approximation error into the
 exponent — measured on a v5e, sum(p) then sat ~3e-5 off 1 and the f32
-gradients 2e-5..9e-5 off a float64 oracle (XLA's own: 1e-6). The mask cotangent (needed only for learned biases) is a
-separate XLA expression that DCEs away when unused.
+gradients 2e-5..9e-5 off a float64 oracle (XLA's own: 1e-6). The mask
+cotangent (needed only for learned biases) is a separate XLA expression
+that DCEs away when unused.
 
 Layout contract: q, k, v are (B, H, T, D); additive mask broadcastable
 (B, 1, 1, Tk) or (B, 1, Tq, Tk). On CPU (tests) the kernel runs in
@@ -53,6 +64,17 @@ def _dot_precision(dtype):
             jax.lax.Precision.HIGHEST)
 
 
+def _mxu_dtype(dtype):
+    """What the kernels hand the MXU. bfloat16 inputs go in as they are:
+    DEFAULT precision rounds 32-bit operands to bfloat16 for its one pass
+    anyway, so an upcast only costs converts and twice the vector
+    registers; the probabilities (and ds) are cast down for the second
+    matmuls exactly as `_xla_attention` does. Every other dtype keeps
+    float32 operands at HIGHEST."""
+    return (jnp.bfloat16 if jnp.dtype(dtype) == jnp.bfloat16
+            else jnp.float32)
+
+
 def _causal_keep(qi, kj, causal_offset, block_q, block_k):
     """Bool (BQ, BK) tile of the bottom-right-aligned causal mask
     (query i sees keys j <= i + causal_offset) — shared by all kernels."""
@@ -63,17 +85,43 @@ def _causal_keep(qi, kj, causal_offset, block_q, block_k):
     return q_pos + causal_offset >= k_pos
 
 
+def _last_key(qi, causal_offset, block_q):
+    """Last key that q-block `qi`'s last query sees under the causal mask."""
+    return qi * block_q + (block_q - 1) + causal_offset
+
+
+def _last_k_block(qi, causal_offset, block_q, block_k):
+    """Last k-block that q-block `qi` sees under the causal mask."""
+    return _last_key(qi, causal_offset, block_q) // block_k
+
+
+def _first_q_block(kj, causal_offset, block_q, block_k):
+    """First q-block that sees k-block `kj` under the causal mask."""
+    return jnp.maximum(kj * block_k - causal_offset, 0) // block_q
+
+
+def _for_visible_tile(body, qi, kj, *, causal, causal_offset, block_q,
+                      block_k):
+    """Run `body` unless the (qi, kj) tile lies wholly above the causal
+    diagonal (no query of the tile sees any of its keys)."""
+    if not causal:
+        body()
+        return
+    pl.when(kj * block_k <= _last_key(qi, causal_offset, block_q))(body)
+
+
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
               qi, kj, *, scale, causal, causal_offset, block_q, block_k,
               mask_mode, precision):
     """Recompute the probability tile p = exp(s - m) / l — the forward's
     own normalization — and the logit cotangent ds = p * (dO V^T - delta)
     from the forward residuals: the shared core of both backward
-    kernels."""
-    q = q_ref[0].astype(jnp.float32)            # (BQ, D)
-    k = k_ref[0].astype(jnp.float32)            # (BK, D)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)          # (BQ, D)
+    kernels. p and ds come back in the MXU's operand dtype."""
+    mxu = _mxu_dtype(q_ref.dtype)
+    q = q_ref[0].astype(mxu)                    # (BQ, D)
+    k = k_ref[0].astype(mxu)                    # (BK, D)
+    v = v_ref[0].astype(mxu)
+    do = do_ref[0].astype(mxu)                  # (BQ, D)
     m = stats_ref[0, 0]                         # (BQ,) row max
     inv_l = 1.0 / stats_ref[0, 1]               # (BQ,) 1 / normalizer
     delta = delta_ref[0, 0].astype(jnp.float32)  # row 0 is real
@@ -94,7 +142,7 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
         preferred_element_type=jnp.float32,
         precision=precision)                    # (BQ, BK)
     ds = p * (dp - delta[:, None])
-    return q, k, do, p, ds
+    return q, k, do, p.astype(mxu), ds.astype(mxu)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
@@ -103,6 +151,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
+    mxu = _mxu_dtype(q_ref.dtype)
 
     @pl.when(kj == 0)
     def _init():
@@ -111,8 +160,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     def body():
-        q = q_ref[0].astype(jnp.float32)          # (BQ, D)
-        k = k_ref[0].astype(jnp.float32)          # (BK, D)
+        q = q_ref[0].astype(mxu)                  # (BQ, D)
+        k = k_ref[0].astype(mxu)                  # (BK, D)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -133,23 +182,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
         p = jnp.exp(s - m_new)                     # (BQ, BK)
         corr = jnp.exp(m_prev - m_new)             # (BQ, 1)
         l_new = corr * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)
         pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(mxu), v_ref[0].astype(mxu), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=precision)                   # (BQ, D)
         acc_ref[:] = acc_ref[:] * corr + pv
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    if causal:
-        # skip k-blocks strictly above the (offset) diagonal
-        @pl.when(kj * block_k <= qi * block_q + (block_q - 1) +
-                 causal_offset)
-        def _():
-            body()
-    else:
-        body()
+    _for_visible_tile(body, qi, kj, causal=causal,
+                      causal_offset=causal_offset, block_q=block_q,
+                      block_k=block_k)
 
     @pl.when(kj == nk - 1)
     def _finalize():
@@ -163,29 +206,105 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
                                  l[:, 0][None, :])
 
 
-def _mask_spec(mask, h, q_dtype, block_q, block_k, kj_innermost):
-    """(mask_mode, mask_input, BlockSpec) for an additive mask broadcastable
-    (B,1,1,Tk) ["k" mode] or (B,1,Tq,Tk) ["qk"]. Grid index order is
+def _seq_specs(h, d, mask_mode, causal, causal_offset, block_q, block_k,
+               kj_innermost):
+    """BlockSpecs of one kernel: `q_spec` for what is tiled along the
+    queries (q, dO, out, dq: (bh, block_q, D)), `row_spec` for the per-row
+    statistics ((bh, 8, block_q)), `kv_spec` for k, v, dk, dv and
+    `mask_spec` for the additive mask. Grid index order is
     (bh, i, j) for the forward/dQ kernels (kj_innermost) and (bh, j, i)
-    for dK/dV."""
-    if mask is None:
-        return "none", jnp.zeros((1, 1, 1, 1), q_dtype), pl.BlockSpec(
-            (1, 1, 1, 1), lambda bb, a, b_: (0, 0, 0, 0))
-    if mask.shape[2] == 1:
-        if kj_innermost:
-            def _idx(bb, i, j, hh=h):
-                return (bb // hh, 0, 0, j)
-        else:
-            def _idx(bb, j, i, hh=h):
-                return (bb // hh, 0, 0, j)
-        return "k", mask, pl.BlockSpec((1, 1, 1, block_k), _idx)
+    for dK/dV. A causal grid step above the diagonal does no work, so its
+    innermost index is clamped to the nearest block that does: the step
+    then names the block its neighbour holds and fetches nothing."""
     if kj_innermost:
-        def _idx(bb, i, j, hh=h):
-            return (bb // hh, 0, i, j)
+        def ij(a, b_):
+            if causal:
+                b_ = jnp.minimum(b_, _last_k_block(a, causal_offset,
+                                                   block_q, block_k))
+            return a, b_
     else:
-        def _idx(bb, j, i, hh=h):
-            return (bb // hh, 0, i, j)
-    return "qk", mask, pl.BlockSpec((1, 1, block_q, block_k), _idx)
+        def ij(a, b_):
+            if causal:
+                b_ = jnp.maximum(b_, _first_q_block(a, causal_offset,
+                                                    block_q, block_k))
+            return b_, a
+
+    def q_map(bb, a, b_):
+        return (bb, ij(a, b_)[0], 0)
+
+    def row_map(bb, a, b_):
+        return (bb, 0, ij(a, b_)[0])
+
+    def kv_map(bb, a, b_):
+        return (bb, ij(a, b_)[1], 0)
+
+    if mask_mode == "none":
+        mask_spec = pl.BlockSpec((1, 1, 1, 1), lambda bb, a, b_: (0, 0, 0, 0))
+    elif mask_mode == "k":
+        mask_spec = pl.BlockSpec(
+            (1, 1, 1, block_k),
+            lambda bb, a, b_: (bb // h, 0, 0, ij(a, b_)[1]))
+    else:
+        mask_spec = pl.BlockSpec(
+            (1, 1, block_q, block_k),
+            lambda bb, a, b_: (bb // h, 0) + ij(a, b_))
+    return (pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, 8, block_q), row_map),
+            pl.BlockSpec((1, block_k, d), kv_map), mask_spec)
+
+
+def _mask_mode(mask):
+    """An additive mask broadcastable (B,1,1,Tk) is "k", (B,1,Tq,Tk) "qk"."""
+    if mask is None:
+        return "none"
+    return "k" if mask.shape[2] == 1 else "qk"
+
+
+def _mask_input(mask, dtype):
+    """The kernels' mask operand: a (1,1,1,1) dummy where there is none."""
+    return jnp.zeros((1, 1, 1, 1), dtype) if mask is None else mask
+
+
+# Score-shaped (block_q, block_k) f32 tiles that Mosaic keeps in VMEM at
+# once, beside the blocks and the accumulators: found by bisecting
+# `vmem_limit_bytes` on compiles for a v5e (bf16 D=64 and f32 D=128 with a
+# key mask, 512x512 and 1024x1024: at most 2.0 / 4.4 / 3.3) and rounded up
+_TILE_TEMPS = {"fwd": 3, "bwd_dkv": 6, "bwd_dq": 5}
+_VMEM_DEFAULT = 16 * 2 ** 20    # Mosaic's scoped limit on a v5e
+_VMEM_CEILING = 96 * 2 ** 20    # of the chip's 128 MiB
+
+
+def vmem_bytes(kernel, block_q, block_k, d, itemsize, mask_mode="none"):
+    """Upper reckoning of the VMEM one grid step of `kernel` holds: every
+    block twice (the pipeline's two buffers) with its lanes padded to 128,
+    the f32 accumulators, and `_TILE_TEMPS` f32 score-shaped tiles."""
+    lanes = -(-d // 128) * 128
+    q_blk, k_blk = block_q * lanes, block_k * lanes
+    row_blk = 8 * block_q * 4
+    mask_blk = {"none": 0, "k": 8 * block_k,
+                "qk": block_q * block_k}[mask_mode] * itemsize
+    if kernel == "fwd":
+        blocks = (2 * q_blk + 2 * k_blk) * itemsize + row_blk
+        scratch = (q_blk + 2 * block_q * 128) * 4
+    elif kernel == "bwd_dkv":
+        blocks = (2 * q_blk + 4 * k_blk) * itemsize + 2 * row_blk
+        scratch = 2 * k_blk * 4
+    else:
+        blocks = (3 * q_blk + 2 * k_blk) * itemsize + 2 * row_blk
+        scratch = q_blk * 4
+    return (2 * (blocks + mask_blk) + scratch
+            + _TILE_TEMPS[kernel] * block_q * block_k * 4)
+
+
+def _compiler_params(kernel, block_q, block_k, d, dtype, mask_mode):
+    """Ask Mosaic for the VMEM the tile is reckoned to need where its
+    default would not do, so that a large tile compiles instead of
+    failing."""
+    need = vmem_bytes(kernel, block_q, block_k, d, jnp.dtype(dtype).itemsize,
+                      mask_mode)
+    if need <= _VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=min(need, _VMEM_CEILING))
 
 
 def _pallas_forward(q, k, v, mask, scale, causal, block_q, block_k,
@@ -199,41 +318,32 @@ def _pallas_forward(q, k, v, mask, scale, causal, block_q, block_k,
     k3 = k.reshape(bh, tk, d)
     v3 = v.reshape(bh, tk, d)
 
-    grid = (bh, tq // block_q, tk // block_k)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bb, i, j: (bb, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bb, i, j: (bb, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bb, i, j: (bb, j, 0)),
-    ]
-    mask_mode, mask_in, mask_spec = _mask_spec(mask, h, q.dtype, block_q,
-                                               block_k, kj_innermost=True)
-    in_specs.append(mask_spec)
-
-    scratch = [
-        pltpu.VMEM((block_q, d), jnp.float32),
-        pltpu.VMEM((block_q, 128), jnp.float32),
-        pltpu.VMEM((block_q, 128), jnp.float32),
-    ]
-
+    mask_mode = _mask_mode(mask)
+    q_spec, row_spec, kv_spec, mask_spec = _seq_specs(
+        h, d, mask_mode, causal, tk - tq, block_q, block_k,
+        kj_innermost=True)
     out, stats = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           causal_offset=tk - tq, block_q=block_q,
                           block_k=block_k, mask_mode=mask_mode,
                           precision=_dot_precision(q.dtype)),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bb, i, j: (bb, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda bb, i, j: (bb, 0, i)),
-        ],
+        grid=(bh, tq // block_q, tk // block_k),
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
+        out_specs=[q_spec, row_spec],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, 8, tq), jnp.float32),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+        ],
+        compiler_params=_compiler_params("fwd", block_q, block_k, d,
+                                         q.dtype, mask_mode),
         name="flash_fwd",
         interpret=interpret,
-    )(q3, k3, v3, mask_in)
+    )(q3, k3, v3, _mask_input(mask, q.dtype))
     return out.reshape(b, h, tq, d), stats
 
 
@@ -266,13 +376,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
 
-    if causal:
-        @pl.when(qi * block_q + (block_q - 1) + causal_offset >=
-                 kj * block_k)
-        def _():
-            body()
-    else:
-        body()
+    _for_visible_tile(body, qi, kj, causal=causal,
+                      causal_offset=causal_offset, block_q=block_q,
+                      block_k=block_k)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -301,91 +407,101 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
 
-    if causal:
-        @pl.when(kj * block_k <= qi * block_q + (block_q - 1) +
-                 causal_offset)
-        def _():
-            body()
-    else:
-        body()
+    _for_visible_tile(body, qi, kj, causal=causal,
+                      causal_offset=causal_offset, block_q=block_q,
+                      block_k=block_k)
 
     @pl.when(kj == nk - 1)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _pallas_backward(q, k, v, mask, out, stats, g, scale, causal, block_q,
-                     block_k, interpret):
+def _bwd_inputs(q, k, v, mask, out, stats, g):
+    """The backward kernels' operands: (bh, T, D) views, and delta =
+    rowsum(dO * O) (a cheap elementwise pass in XLA) with the sublane dim
+    of 8 that the stats carry for Mosaic's block alignment."""
     b, h, tq, d = q.shape
     tk = k.shape[2]
     bh = b * h
-    q3 = q.reshape(bh, tq, d)
-    k3 = k.reshape(bh, tk, d)
-    v3 = v.reshape(bh, tk, d)
-    do3 = g.reshape(bh, tq, d)
-    # stats (from the forward) and delta carry a sublane dim of 8 for
-    # Mosaic block alignment
-    # delta = rowsum(dO * O): cheap elementwise pass in XLA
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, tq)
-    delta = jnp.broadcast_to(delta, (bh, 8, tq))
+    return (q.reshape(bh, tq, d), k.reshape(bh, tk, d),
+            v.reshape(bh, tk, d), g.reshape(bh, tq, d), stats,
+            jnp.broadcast_to(delta, (bh, 8, tq)),
+            _mask_input(mask, q.dtype))
 
-    mask_mode, mask_in, dkv_mask_spec = _mask_spec(
-        mask, h, q.dtype, block_q, block_k, kj_innermost=False)
-    common = dict(scale=scale, causal=causal, causal_offset=tk - tq,
-                  block_q=block_q, block_k=block_k, mask_mode=mask_mode,
-                  precision=_dot_precision(q.dtype))
-    dkv_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bb, j, i: (bb, i, 0)),   # q
-        pl.BlockSpec((1, block_k, d), lambda bb, j, i: (bb, j, 0)),   # k
-        pl.BlockSpec((1, block_k, d), lambda bb, j, i: (bb, j, 0)),   # v
-        pl.BlockSpec((1, block_q, d), lambda bb, j, i: (bb, i, 0)),   # do
-        pl.BlockSpec((1, 8, block_q), lambda bb, j, i: (bb, 0, i)),   # m, l
-        pl.BlockSpec((1, 8, block_q), lambda bb, j, i: (bb, 0, i)),   # delta
-        dkv_mask_spec,
-    ]
-    dk3, dv3 = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **common),
-        grid=(bh, tk // block_k, tq // block_q),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bb, j, i: (bb, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bb, j, i: (bb, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
+
+def _bwd_call(kernel, which, operands, h, mask_mode, scale, causal, block_q,
+              block_k, interpret, kj_innermost):
+    """What the two backward kernels' pallas_calls share (`which` of
+    KERNELS): the kernel with its parameters and the keyword arguments for
+    the seven operands (q, k, v, dO, stats, delta, mask) both take; then
+    the q- and kv-tiled BlockSpecs for the outputs."""
+    q3, k3 = operands[:2]
+    bh, tq, d = q3.shape
+    tk = k3.shape[1]
+    q_spec, row_spec, kv_spec, mask_spec = _seq_specs(
+        h, d, mask_mode, causal, tk - tq, block_q, block_k, kj_innermost)
+    body = functools.partial(kernel, scale=scale, causal=causal,
+                             causal_offset=tk - tq, block_q=block_q,
+                             block_k=block_k, mask_mode=mask_mode,
+                             precision=_dot_precision(q3.dtype))
+    common = dict(
+        grid=((bh, tq // block_q, tk // block_k) if kj_innermost
+              else (bh, tk // block_k, tq // block_q)),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
+                  mask_spec],
+        compiler_params=_compiler_params(which, block_q, block_k, d,
+                                         q3.dtype, mask_mode),
+        interpret=interpret)
+    return body, common, q_spec, kv_spec
+
+
+def _pallas_bwd_dkv(operands, h, mask_mode, scale, causal, block_q, block_k,
+                    interpret):
+    body, common, _, kv_spec = _bwd_call(
+        _bwd_dkv_kernel, "bwd_dkv", operands, h, mask_mode, scale, causal,
+        block_q, block_k, interpret, kj_innermost=False)
+    k3, v3 = operands[1:3]
+    block = (block_k, k3.shape[2])
+    return pl.pallas_call(
+        body,
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+                   jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
+        scratch_shapes=[pltpu.VMEM(block, jnp.float32),
+                        pltpu.VMEM(block, jnp.float32)],
         name="flash_bwd_dkv",
-        interpret=interpret,
-    )(q3, k3, v3, do3, stats, delta, mask_in)
+        **common,
+    )(*operands)
 
-    _, _, dq_mask_spec = _mask_spec(mask, h, q.dtype, block_q, block_k,
-                                    kj_innermost=True)
-    dq_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bb, i, j: (bb, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bb, i, j: (bb, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bb, i, j: (bb, j, 0)),
-        pl.BlockSpec((1, block_q, d), lambda bb, i, j: (bb, i, 0)),
-        pl.BlockSpec((1, 8, block_q), lambda bb, i, j: (bb, 0, i)),
-        pl.BlockSpec((1, 8, block_q), lambda bb, i, j: (bb, 0, i)),
-        dq_mask_spec,
-    ]
-    dq3 = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **common),
-        grid=(bh, tq // block_q, tk // block_k),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bb, i, j: (bb, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+
+def _pallas_bwd_dq(operands, h, mask_mode, scale, causal, block_q, block_k,
+                   interpret):
+    body, common, q_spec, _ = _bwd_call(
+        _bwd_dq_kernel, "bwd_dq", operands, h, mask_mode, scale, causal,
+        block_q, block_k, interpret, kj_innermost=True)
+    q3 = operands[0]
+    return pl.pallas_call(
+        body,
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, q3.shape[2]), jnp.float32)],
         name="flash_bwd_dq",
-        interpret=interpret,
-    )(q3, k3, v3, do3, stats, delta, mask_in)
+        **common,
+    )(*operands)
 
+
+def _pallas_backward(q, k, v, mask, out, stats, g, scale, causal, blocks,
+                     interpret):
+    b, h, tq, d = q.shape
+    tk = k.shape[2]
+    operands = _bwd_inputs(q, k, v, mask, out, stats, g)
+    mask_mode = _mask_mode(mask)
+    dk3, dv3 = _pallas_bwd_dkv(operands, h, mask_mode, scale, causal,
+                               *blocks[1], interpret)
+    dq3 = _pallas_bwd_dq(operands, h, mask_mode, scale, causal,
+                         *blocks[2], interpret)
     return (dq3.reshape(b, h, tq, d), dk3.reshape(b, h, tk, d),
             dv3.reshape(b, h, tk, d))
 
@@ -406,16 +522,17 @@ def _xla_attention(q, k, v, mask, scale, causal):
                       precision=prec)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, mask, scale, causal, block_q, block_k, interpret):
-    out, _ = _pallas_forward(q, k, v, mask, scale, causal, block_q, block_k,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, mask, scale, causal, blocks, interpret):
+    """`blocks`: the (block_q, block_k) of each kernel, in KERNELS' order."""
+    out, _ = _pallas_forward(q, k, v, mask, scale, causal, *blocks[0],
                              interpret)
     return out
 
 
-def _flash_fwd(q, k, v, mask, scale, causal, block_q, block_k, interpret):
-    out, stats = _pallas_forward(q, k, v, mask, scale, causal, block_q,
-                                 block_k, interpret)
+def _flash_fwd(q, k, v, mask, scale, causal, blocks, interpret):
+    out, stats = _pallas_forward(q, k, v, mask, scale, causal, *blocks[0],
+                                 interpret)
     return out, (q, k, v, mask, out, stats)
 
 
@@ -442,13 +559,13 @@ def _xla_dmask(q, k, v, mask, out, lse, g, scale, causal):
     return jnp.sum(ds, axis=reduce_axes, keepdims=True).astype(mask.dtype)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(scale, causal, blocks, interpret, res, g):
     q, k, v, mask, out, stats = res
     # Pallas backward: recompute p from the (m, l, delta) residuals with
     # the mask applied in-kernel — the (T,T) matrix never touches HBM for
     # dq/dk/dv in either direction
     dq, dk, dv = _pallas_backward(q, k, v, mask, out, stats, g, scale,
-                                  causal, block_q, block_k, interpret)
+                                  causal, blocks, interpret)
     if mask is None:
         return dq, dk, dv, None
     lse = (stats[:, 0] + jnp.log(stats[:, 1])).reshape(q.shape[:3])
@@ -459,14 +576,14 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _env_block(name, default=128):
+def _env_block(name):
     """Parse a block-size override; '' counts as unset (same contract as
-    PADDLE_TPU_PALLAS_INTERPRET) and junk/too-small values fall back to
-    the default LOUDLY — a bad tuning knob must not silently route every
-    attention call to the XLA fallback via the auto-path try/except."""
+    PADDLE_TPU_PALLAS_INTERPRET) and junk/too-small values count as unset
+    LOUDLY — a bad tuning knob must not silently route every attention
+    call to the XLA fallback via the auto-path try/except."""
     raw = os.environ.get(name, "")
     if not raw:
-        return default
+        return None
     try:
         val = int(raw)
     except ValueError:
@@ -477,9 +594,49 @@ def _env_block(name, default=128):
     if val < 128 or val & (val - 1):
         import warnings
         warnings.warn("%s=%r is not a power-of-two block size >= 128; "
-                      "using %d" % (name, raw, default))
-        return default
+                      "ignored" % (name, raw))
+        return None
     return val
+
+
+KERNELS = ("fwd", "bwd_dkv", "bwd_dq")
+
+
+def _fit(block, t):
+    """Largest block <= `block` that divides `t`, by halving."""
+    block = min(block, t)
+    while t % block:
+        block //= 2
+    return block
+
+
+def pick_blocks(tq, tk, d, dtype, kernel, causal=False):
+    """(block_q, block_k) of `kernel` (one of KERNELS) for a call's shape.
+
+    What the sweep on a v5e showed (PERF.md, PR 25; bf16, D=64 and 128,
+    T=512..4096): a grid step costs a few microseconds whatever its tile
+    holds, most of it in proportion to block_q and none to block_k, so the
+    widest tile wins up to 1024x1024 and nothing beyond it does. The
+    forward takes that even where one tile is the whole causal square.
+    The backward kernels' work grows with the tile's area (seven matmuls
+    and two transposes), so there skipping blocks above the causal
+    diagonal pays: a quarter of the sequence a side (10 of 16 tiles run),
+    but never under 512, where the step's cost loses more than skipping
+    saves. Other dtypes run float32 operands at HIGHEST: twice the VMEM
+    and six MXU passes a tile, so 512 is their cap (reckoned, not swept).
+    A tile reckoned over the VMEM ceiling is halved until it fits."""
+    itemsize = jnp.dtype(dtype).itemsize
+    side = 1024 if jnp.dtype(dtype) == jnp.bfloat16 else 512
+    if causal and kernel != "fwd":
+        side = min(side, max(512, min(tq, tk) // 4))
+    bq, bk = _fit(side, tq), _fit(side, tk)
+    while (vmem_bytes(kernel, bq, bk, d, itemsize, "qk") > _VMEM_CEILING
+           and max(bq, bk) > 128):
+        if bq >= bk:
+            bq = _fit(bq // 2, tq)
+        else:
+            bk = _fit(bk // 2, tk)
+    return bq, bk
 
 
 def flash_attention(q, k, v, mask=None, scale=1.0, causal=False,
@@ -488,31 +645,32 @@ def flash_attention(q, k, v, mask=None, scale=1.0, causal=False,
     mode off-TPU so tests exercise the same kernel, and to plain fused XLA
     attention when shapes are too small to tile.
 
-    Block sizes default to 128x128; PADDLE_TPU_FLASH_BLOCK_Q/_K override
-    fleet-wide (apply the winner of `bench.py flashtune`)."""
+    Each kernel's tile comes from the call's shape (`pick_blocks`). An
+    explicit `block_q`/`block_k`, or PADDLE_TPU_FLASH_BLOCK_Q/_K where
+    set, replaces that side of all three kernels' tiles."""
     if block_q is None:
         block_q = _env_block("PADDLE_TPU_FLASH_BLOCK_Q")
     if block_k is None:
         block_k = _env_block("PADDLE_TPU_FLASH_BLOCK_K")
     if interpret is None:
         interpret = pd.default_interpret()
-    tq, tk = q.shape[2], k.shape[2]
+    tq, tk, d = q.shape[2], k.shape[2], q.shape[-1]
     if causal and tq > tk:
         # rows i < tq - tk see no keys at all; only the XLA reference
         # defines that edge (uniform over all-masked logits)
         return _xla_attention(q, k, v, mask, scale, causal)
-    bq, bk = min(block_q, tq), min(block_k, tk)
-    while tq % bq:
-        bq //= 2
-    while tk % bk:
-        bk //= 2
-    if bq < 8 or bk < 8 or q.shape[-1] % 8:
+    blocks = []
+    for kernel in KERNELS:
+        bq, bk = pick_blocks(tq, tk, d, q.dtype, kernel, causal)
+        blocks.append((_fit(block_q or bq, tq), _fit(block_k or bk, tk)))
+    least = min(min(pair) for pair in blocks)
+    if least < 8 or d % 8:
         return _xla_attention(q, k, v, mask, scale, causal)
-    if not interpret and (bq < 128 or bk < 128):
+    if not interpret and least < 128:
         # Mosaic wants the last-two block dims 128-lane aligned (the stats
         # block puts block_q on the lane dim); sub-128 tiles are only
         # exercised in interpret mode — on device route them to XLA.
         return _xla_attention(q, k, v, mask, scale, causal)
     return _flash(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                   None if mask is None else jnp.asarray(mask),
-                  scale, causal, bq, bk, interpret)
+                  scale, causal, tuple(blocks), interpret)

@@ -190,3 +190,214 @@ def test_flash_qk_mask_backward_with_mask_cotangent():
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-3, atol=2e-4)
+
+
+# ---- PR 25: tiles from the shape, MXU operands in the input dtype ----
+
+from paddle_tpu.ops.pallas import flash_attention as fa  # noqa: E402
+
+_RULE_SHAPES = [(t, t, d) for t in (128, 256, 384, 512, 1024, 4096, 8192)
+                for d in (64, 128)] + [(512, 1024, 64), (1024, 4096, 128)]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("tq,tk,d", _RULE_SHAPES)
+def test_tile_rule_table(tq, tk, d, dtype, causal):
+    """The tile rule, for the benchmark cells' shapes among others: blocks
+    divide T, are >= 128 (the device path's floor), and the reckoned VMEM
+    stays under what the call asks Mosaic for (its default where it asks
+    for nothing)."""
+    for kernel in fa.KERNELS:
+        bq, bk = fa.pick_blocks(tq, tk, d, dtype, kernel, causal)
+        assert tq % bq == 0 and tk % bk == 0
+        assert bq >= 128 and bk >= 128
+        assert bq % 128 == 0 or bq == tq
+        itemsize = jnp.dtype(dtype).itemsize
+        for mask_mode in ("none", "k", "qk"):
+            need = fa.vmem_bytes(kernel, bq, bk, d, itemsize, mask_mode)
+            params = fa._compiler_params(kernel, bq, bk, d, dtype, mask_mode)
+            limit = (fa._VMEM_DEFAULT if params is None
+                     else params.vmem_limit_bytes)
+            assert need <= limit <= fa._VMEM_CEILING
+
+
+def test_tile_rule_follows_the_shape(monkeypatch):
+    """One algorithm, parameters from the shape (the v5e sweep's winners,
+    PERF.md PR 25): the forward takes the widest tile up to 1024x1024; the
+    causal backward kernels a quarter of the sequence a side, between 512
+    and 1024; float32 stops at 512. Explicit blocks and the environment's
+    keep winning on their side of every kernel's tile."""
+    def rule(t, causal=True, dtype="bfloat16", d=64):
+        return tuple(fa.pick_blocks(t, t, d, dtype, kern, causal)
+                     for kern in fa.KERNELS)
+    assert rule(4096) == ((1024, 1024),) * 3        # gpt2.t4096-b4
+    assert rule(1024) == ((1024, 1024), (512, 512), (512, 512))  # t1024-b16
+    assert rule(2048) == ((1024, 1024), (512, 512), (512, 512))
+    assert rule(512) == ((512, 512),) * 3
+    assert rule(512, causal=False) == ((512, 512),) * 3   # bert-base.s512
+    assert rule(1024, causal=False) == ((1024, 1024),) * 3
+    assert rule(4096, d=128) == rule(4096)
+    assert rule(4096, dtype="float32") == ((512, 512),) * 3
+    assert fa.pick_blocks(512, 4096, 64, "bfloat16", "bwd_dq", True) \
+        == (512, 512)
+    seen = []
+    monkeypatch.setattr(fa, "_flash", lambda *a: seen.append(a[6]))
+    q = jnp.zeros((1, 1, 1024, 64), jnp.bfloat16)
+    flash_attention(q, q, q, causal=True, interpret=True)
+    flash_attention(q, q, q, causal=True, block_q=64, block_k=32,
+                    interpret=True)
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_K", "128")
+    flash_attention(q, q, q, causal=True, interpret=True)
+    rule, explicit, env = seen
+    assert rule == tuple(fa.pick_blocks(1024, 1024, 64, q.dtype, kern, True)
+                         for kern in fa.KERNELS)
+    assert explicit == ((64, 32),) * 3
+    assert env == tuple((bq, 128) for bq, _ in rule)
+
+
+def _loss_grads(fn, args, w):
+    out = fn(*args)
+    grads = jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+                     argnums=tuple(range(len(args))))(*args)
+    return (out,) + tuple(grads)
+
+
+def _mask_case(mode, b, tq, tk, seed):
+    if mode == "key_padding":
+        mask = np.zeros((b, 1, 1, tk), np.float32)
+        mask[..., 3 * tk // 4:] = -1e4
+        return mask, False
+    if mode == "per_query_bias":
+        return _rand((b, 1, tq, tk), seed) * 0.5, False
+    return None, True      # "causal", "causal_rect"
+
+
+@pytest.mark.parametrize("mode,tq,tk", [
+    ("causal", 512, 512), ("key_padding", 512, 512),
+    ("per_query_bias", 256, 512), ("causal_rect", 256, 512)])
+def test_rule_tiles_match_small_tiles_and_reference(mode, tq, tk):
+    """Forward and all three gradients with the rule's large tiles equal
+    the 16x16-tile results and `_xla_attention`; the rectangular causal
+    case crosses tile edges differently at large tiles (bottom-right
+    alignment)."""
+    b, h, d = 1, 2, 16
+    q, k, v = _rand((b, h, tq, d), 20), _rand((b, h, tk, d), 21), \
+        _rand((b, h, tk, d), 22)
+    w = _rand((b, h, tq, d), 23)
+    mask, causal = _mask_case(mode, b, tq, tk, 24)
+    for kernel in fa.KERNELS:
+        assert min(fa.pick_blocks(tq, tk, d, "float32", kernel,
+                                  causal)) >= 128
+
+    def flash(**blocks):
+        return lambda q, k, v: flash_attention(
+            q, k, v, mask=mask, scale=d ** -0.5, causal=causal,
+            interpret=True, **blocks)
+
+    def ref(q, k, v):
+        return _xla_attention(q, k, v, None if mask is None
+                              else jnp.asarray(mask), d ** -0.5, causal)
+
+    large = _loss_grads(flash(), (q, k, v), w)
+    small = _loss_grads(flash(block_q=16, block_k=16), (q, k, v), w)
+    oracle = _loss_grads(ref, (q, k, v), w)
+    for a, s_, o in zip(large, small, oracle):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(s_),
+                                   rtol=2e-3, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(o),
+                                   rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("mode", ["causal", "key_padding", "per_query_bias"])
+def test_bf16_operands_against_f32_oracle(mode):
+    """bf16 inputs feed the MXU as they are, p and ds cast down for the
+    second matmuls: outputs and gradients stay within `pallas_selfcheck`'s
+    bf16 tolerance (1e-2 of the oracle's range) of the f32 oracle."""
+    b, h, t, d = 2, 2, 256, 64
+    mask, causal = _mask_case(mode, b, t, t, 34)
+    q, k, v, w = (jnp.asarray(_rand((b, h, t, d), 30 + i), jnp.bfloat16)
+                  for i in range(4))
+    w = w.astype(jnp.float32)
+    mask_j = None if mask is None else jnp.asarray(mask, jnp.bfloat16)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, mask=mask_j, scale=d ** -0.5,
+                               causal=causal, interpret=True)
+
+    def oracle(q, k, v):
+        return _xla_attention(q, k, v, None if mask_j is None
+                              else mask_j.astype(jnp.float32),
+                              d ** -0.5, causal)
+
+    got = _loss_grads(flash, (q, k, v), w)
+    f32 = tuple(x.astype(jnp.float32) for x in (q, k, v))
+    want = _loss_grads(oracle, f32, w)
+    for a, o in zip(got, want):
+        assert a.dtype == jnp.bfloat16
+        a, o = np.asarray(a, np.float32), np.asarray(o)
+        assert np.abs(a - o).max() / max(np.abs(o).max(), 1.0) < 1e-2
+
+
+def _kernel_dots(dtype):
+    """(lhs dtype, rhs dtype, result dtype, precision) of every
+    dot_general that the three kernels trace for inputs of `dtype`."""
+    q = jnp.zeros((1, 1, 32, 8), dtype)
+    closed = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
+                        interpret=True).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, q, q)
+    dots = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                dots.append(tuple(str(x.aval.dtype) for x in eqn.invars)
+                            + (str(eqn.outvars[0].aval.dtype),
+                               eqn.params["precision"]))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(closed.jaxpr)
+    return dots
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float16", "bfloat16"])
+def test_mxu_operand_dtype_and_precision(dtype):
+    """float32/float16 inputs keep float32 operands at HIGHEST — the same
+    operations, in the same order, as before this rule existed, so the same
+    bits at equal tiles; bfloat16 inputs reach every dot as bfloat16 with
+    a float32 result."""
+    dots = _kernel_dots(dtype)
+    assert len(dots) == 2 + 4 + 3      # forward, dK/dV, dQ
+    highest = jax.lax.Precision.HIGHEST
+    for lhs, rhs, out, precision in dots:
+        assert out == "float32"
+        if dtype == "bfloat16":
+            assert (lhs, rhs) == ("bfloat16", "bfloat16")
+            assert precision is None or highest not in tuple(precision)
+        else:
+            assert (lhs, rhs) == ("float32", "float32")
+            assert tuple(precision) == (highest, highest)
+
+
+def test_f32_unchanged_to_the_bit_at_equal_tiles():
+    """Equal tiles, float32: the rule's path (no blocks given) and the
+    explicit-block path run the same program. (That program's operations
+    are the parent's, see `test_mxu_operand_dtype_and_precision`; on the
+    chip its bits were the parent's too: PERF.md, PR 25.)"""
+    b, h, t, d = 1, 2, 256, 16
+    q, k, v = _rand((b, h, t, d), 40), _rand((b, h, t, d), 41), \
+        _rand((b, h, t, d), 42)
+    w = _rand((b, h, t, d), 43)
+    blocks = {kern: fa.pick_blocks(t, t, d, "float32", kern, True)
+              for kern in fa.KERNELS}
+    assert len(set(blocks.values())) == 1     # one tile: comparable
+    bq, bk = blocks["fwd"]
+
+    def run(**kw):
+        return jax.jit(lambda q, k, v: _loss_grads(
+            lambda q, k, v: flash_attention(q, k, v, scale=0.25,
+                                            causal=True, interpret=True,
+                                            **kw), (q, k, v), w))(q, k, v)
+    for a, b_ in zip(run(), run(block_q=bq, block_k=bk)):
+        assert (np.asarray(a) == np.asarray(b_)).all()
