@@ -671,8 +671,11 @@ def pallas_selfcheck(interpret=None):
     coverage of the compiled kernels: CPU tests run interpret
     mode and the <128-block guards route small shapes to XLA. Flash
     attention fwd + backward in every mask mode (causal, additive
-    key-padding mask, per-query bias) at T=128/256, f32 and bf16, and at
-    the long-context shape (2, 12, 4096, 64) bf16; blockwise CE, the
+    key-padding mask, per-query bias) at T=128/256, f32 and bf16, at
+    the long-context shape (2, 12, 4096, 64) bf16, and with grouped heads,
+    a value width of twice the q/k width and a sliding window (T=512, and
+    T=4096 with a 512 window); the selective-scan forward and backward
+    kernels (T=320: not a multiple of the chunk), f32 and bf16; blockwise CE, the
     fused MLM head, fused Adam and fused LayerNorm, each fwd+bwd against
     its pure-JAX reference. Every check runs; one the compiler refuses (or that
     raises) is recorded with its message and fails the whole result.
@@ -717,13 +720,15 @@ def pallas_selfcheck(interpret=None):
                 type(e).__name__, str(e)[-1500:]),
                 "where": traceback.format_exc(limit=-3)[-600:]}
 
-    def flash_case(dtype, tol, b, h, t, d, mode):
+    def flash_case(dtype, tol, b, h, t, d, mode, hkv=None, dv=None,
+                   window=None):
+        hkv, dv = hkv or h, dv or d
         q = jnp.asarray(rng.randn(b, h, t, d), dtype)
-        k = jnp.asarray(rng.randn(b, h, t, d), dtype)
-        v = jnp.asarray(rng.randn(b, h, t, d), dtype)
+        k = jnp.asarray(rng.randn(b, hkv, t, d), dtype)
+        v = jnp.asarray(rng.randn(b, hkv, t, dv), dtype)
         scale = 1.0 / np.sqrt(d)
         # fixed random cotangent shared by both implementations
-        w = jnp.asarray(rng.randn(b, h, t, d).astype(np.float32))
+        w = jnp.asarray(rng.randn(b, h, t, dv).astype(np.float32))
         mask, causal = None, True
         if mode == "padmask":
             # additive padding mask: last quarter of keys masked out
@@ -736,10 +741,11 @@ def pallas_selfcheck(interpret=None):
 
         def pallas_out(q, k, v):
             return fa.flash_attention(q, k, v, mask=mask, scale=scale,
-                                      causal=causal, interpret=interpret)
+                                      causal=causal, interpret=interpret,
+                                      window=window)
 
         def xla_out(q, k, v):
-            return fa._xla_attention(q, k, v, mask, scale, causal)
+            return fa._xla_attention(q, k, v, mask, scale, causal, window)
 
         def grads(out_fn):
             return jax.jit(jax.grad(
@@ -758,9 +764,48 @@ def pallas_selfcheck(interpret=None):
             for mode in ("causal", "padmask", "qkmask"):
                 run("flash_%s_T%d_%s" % (np.dtype(dtype).name, t, mode),
                     flash_case(dtype, tol, 2, 4, t, 64, mode))
-    if not interpret:   # the interpreter needs minutes at this size
+    # grouped heads (4 query heads to 2 kv heads), a value width of twice
+    # the q/k width and a sliding window: the differential-attention calls
+    for dtype, tol in ((jnp.float32, 1e-5), (jnp.bfloat16, 1e-2)):
+        name = "flash_%s_T512_gqa_dv128" % np.dtype(dtype).name
+        run(name + "_causal", flash_case(dtype, tol, 2, 4, 512, 64,
+                                         "causal", hkv=2, dv=128))
+        run(name + "_window128", flash_case(dtype, tol, 2, 4, 512, 64,
+                                            "causal", hkv=2, dv=128,
+                                            window=128))
+    if not interpret:   # the interpreter needs minutes at these sizes
         run("flash_bfloat16_T4096_causal",
             flash_case(jnp.bfloat16, 1e-2, 2, 12, 4096, 64, "causal"))
+        run("flash_bfloat16_T4096_gqa_dv128_window512",
+            flash_case(jnp.bfloat16, 1e-2, 2, 4, 4096, 64, "causal", hkv=2,
+                       dv=128, window=512))
+
+    def scan_case(dtype, tol, b, t, e, n):
+        from paddle_tpu.ops.pallas import selective_scan as ss
+        args = (jnp.asarray(rng.randn(b, t, e), dtype),
+                jnp.asarray(jax.nn.softplus(rng.randn(b, t, e)), dtype),
+                -jnp.exp(jnp.asarray(0.5 * rng.randn(e, n), jnp.float32)),
+                jnp.asarray(rng.randn(b, t, n), dtype),
+                jnp.asarray(rng.randn(b, t, n), dtype),
+                jnp.asarray(rng.randn(e), jnp.float32))
+        w = jnp.asarray(rng.randn(b, t, e).astype(np.float32))
+
+        def both(fn):
+            out = jax.jit(fn)(*args)
+            grads = jax.jit(jax.grad(
+                lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+                argnums=tuple(range(6))))(*args)
+            return [out] + list(grads)
+
+        def check():
+            return compare(list(zip(
+                both(lambda *a: ss.selective_scan(*a, interpret=interpret)),
+                both(ss.scan_xla))), tol)
+        return check
+
+    for dtype, tol in ((jnp.float32, 1e-5), (jnp.bfloat16, 1e-2)):
+        run("ssm_scan_%s" % np.dtype(dtype).name,
+            scan_case(dtype, tol, 2, 320, 512, 16))
 
     t, v, d = 256, 1024, 256
     labels = jnp.asarray(rng.randint(0, v, (t,)), jnp.int32)

@@ -16,6 +16,7 @@ from .vision import *        # noqa: F401,F403
 from .extras import *        # noqa: F401,F403
 from .rnn import *           # noqa: F401,F403
 from .attention import *     # noqa: F401,F403
+from .ssm import *           # noqa: F401,F403
 from .collective import *    # noqa: F401,F403
 from .distributions import (Normal, Uniform, Categorical,  # noqa: F401
                             MultivariateNormalDiag)

@@ -34,9 +34,13 @@ def scaled_dot_product_attention(queries, keys, values, num_heads=1,
 
 
 def fused_attention(q, k, v, mask=None, scale=None, causal=False,
-                    impl="auto", sp_axis="sp", name=None):
-    """q,k,v: (B, H, T, Dh) — one fused op; Pallas flash path when available.
-    Reference composes this from matmul+softmax+matmul ops.
+                    impl="auto", sp_axis="sp", name=None, window=None):
+    """q: (B, Hq, T, Dh), k: (B, Hkv, T, Dh), v: (B, Hkv, T, Dv) — one
+    fused op; Pallas flash path when available. Reference composes this
+    from matmul+softmax+matmul ops. Hq may be any whole multiple of Hkv
+    (query head h reads kv head h // (Hq // Hkv)), Dv may differ from Dh
+    (the output is Dv wide), and `window=W` with `causal=True` lets query
+    t see keys s with t - W < s <= t.
 
     impl: "auto" | "xla" | "flash" | "ring" | "ulysses" — the last two
     run sequence-parallel attention over the installed mesh's `sp_axis`:
@@ -44,14 +48,15 @@ def fused_attention(q, k, v, mask=None, scale=None, causal=False,
     key-padding masks (..., 1, T) riding the ring; ulysses re-shards
     heads via all_to_all and accepts any additive mask."""
     helper = LayerHelper("fused_attention", name=name)
-    out = helper.create_variable_for_type_inference(q.dtype, q.shape)
+    out = helper.create_variable_for_type_inference(
+        q.dtype, tuple(q.shape[:-1]) + (v.shape[-1],))
     inputs = {"Q": [q.name], "K": [k.name], "V": [v.name]}
     if mask is not None:
         inputs["Mask"] = [mask.name]
     helper.append_op("scaled_dot_product_attention", inputs=inputs,
                      outputs={"Out": [out.name]},
                      attrs={"scale": scale, "causal": causal, "impl": impl,
-                            "sp_axis": sp_axis})
+                            "sp_axis": sp_axis, "window": window})
     return out
 
 

@@ -549,8 +549,16 @@ def split(input, num_or_sections, dim=-1, name=None):
     else:
         num, sections = 0, list(num_or_sections)
         n_out = len(sections)
-    outs = [helper.create_variable_for_type_inference(input.dtype)
-            for _ in range(n_out)]
+    shapes = [None] * n_out
+    if input.shape is not None and input.shape[dim] is not None:
+        whole = input.shape[dim]
+        sizes = sections or [whole // num if whole != -1 else -1] * num
+        if whole != -1 or not sections:
+            axis = dim % len(input.shape)
+            shapes = [tuple(input.shape[:axis]) + (size,)
+                      + tuple(input.shape[axis + 1:]) for size in sizes]
+    outs = [helper.create_variable_for_type_inference(input.dtype, shape)
+            for shape in shapes]
     helper.append_op("split", inputs={"X": [input.name]},
                      outputs={"Out": [o.name for o in outs]},
                      attrs={"num": num, "sections": sections, "axis": dim})

@@ -16,14 +16,16 @@ import jax.numpy as jnp
 from .registry import register_op
 
 
-def _sdpa_xla(q, k, v, mask, scale, causal):
-    # q,k,v: (B, H, T, D)
+def _sdpa_xla(q, k, v, mask, scale, causal, window=None):
+    # q: (B, Hq, Tq, D), k: (B, Hkv, Tk, D), v: (B, Hkv, Tk, Dv): the same
+    # grouped heads, sliding window and value width as the flash kernels
+    from .pallas.flash_attention import _repeat_kv, visible_mask
+    k, v = _repeat_kv(q, k, v)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
-        cm = jnp.tril(jnp.ones((tq, tk), bool), tk - tq)
-        logits = jnp.where(cm, logits, -1e30)
+        logits = jnp.where(visible_mask(tq, tk, window), logits, -1e30)
     if mask is not None:
         logits = logits + mask.astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -56,6 +58,11 @@ def _sdpa(ctx, ins, attrs):
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     causal = attrs.get("causal", False)
+    window = attrs.get("window", None)
+    from .pallas.flash_attention import check_call
+    check_call(q.shape, k.shape, v.shape, causal, window)
+    plain = (window is None and q.shape[1] == k.shape[1]
+             and v.shape[-1] == q.shape[-1])
     impl = attrs.get("impl", "auto")
     if impl == "auto":
         # perf escape hatch: force a path fleet-wide. For ring/ulysses
@@ -68,7 +75,7 @@ def _sdpa(ctx, ins, attrs):
             m = get_mesh()
             if m is not None and attrs.get("sp_axis", "sp") in m.axis_names:
                 n = m.shape[attrs.get("sp_axis", "sp")]
-                if _sp_routable(env_impl, q, k, mask, n):
+                if plain and _sp_routable(env_impl, q, k, mask, n):
                     impl = env_impl
         else:
             impl = env_impl
@@ -78,6 +85,11 @@ def _sdpa(ctx, ins, attrs):
         # (T,T) tile only pays for itself once it stops fitting in VMEM
         impl = "xla"
     if impl in ("ring", "ulysses"):
+        if not plain:
+            raise ValueError(
+                "fused_attention(impl=%r) runs equal heads, equal widths "
+                "and no window; grouped heads, a window or Dv != D need "
+                "impl 'auto', 'flash' or 'xla'" % impl)
         # sequence-parallel attention over the installed mesh's sp axis —
         # the declarative (static-graph) route to the long-context paths
         # in distributed/{ring,ulysses}_attention.py
@@ -108,5 +120,5 @@ def _sdpa(ctx, ins, attrs):
         # choose the path; an error from the chosen kernel propagates
         from .pallas.flash_attention import flash_attention
         return {"Out": flash_attention(q, k, v, mask=mask, scale=scale,
-                                       causal=causal)}
-    return {"Out": _sdpa_xla(q, k, v, mask, scale, causal)}
+                                       causal=causal, window=window)}
+    return {"Out": _sdpa_xla(q, k, v, mask, scale, causal, window)}

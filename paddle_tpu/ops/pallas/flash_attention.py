@@ -27,9 +27,25 @@ gradients 2e-5..9e-5 off a float64 oracle (XLA's own: 1e-6). The mask
 cotangent (needed only for learned biases) is a separate XLA expression
 that DCEs away when unused.
 
-Layout contract: q, k, v are (B, H, T, D); additive mask broadcastable
-(B, 1, 1, Tk) or (B, 1, Tq, Tk). On CPU (tests) the kernel runs in
-interpret mode.
+Layout contract: q is (B, Hq, Tq, D), k (B, Hkv, Tk, D), v (B, Hkv, Tk,
+Dv); additive mask broadcastable (B, 1, 1, Tk) or (B, 1, Tq, Tk). On CPU
+(tests) the kernel runs in interpret mode.
+
+The supported (heads, window, widths) space:
+  - heads: Hq = n * Hkv for any whole n >= 1 (grouped-query attention):
+    query head h reads key/value head h // n. The forward and dQ kernels
+    name the kv head in their index maps; the dK/dV kernel walks the
+    group's n query heads on its innermost grid axis and sums them in its
+    accumulators. `Hq % Hkv != 0` is a ValueError.
+  - window: `window=W` (only with `causal=True`) makes key s visible to
+    query t iff t - W < s <= t (bottom-right aligned like the causal
+    mask). The innermost grid axis then spans only the blocks a tile row
+    can see (not the whole sequence), starting at the row's first visible
+    block. `window <= 0`, or a window without `causal`, is a ValueError.
+  - widths: the value width Dv may differ from the q/k width D (the
+    output and dO are Dv wide). D % 8 or Dv % 8 != 0 goes to XLA.
+With n == 1, no window and Dv == D the kernels, tiles, index maps and
+VMEM request are the ones the plain causal call always had.
 """
 import functools
 import os
@@ -75,13 +91,19 @@ def _mxu_dtype(dtype):
             else jnp.float32)
 
 
-def _causal_keep(qi, kj, causal_offset, block_q, block_k):
-    """Bool (BQ, BK) tile of the bottom-right-aligned causal mask
-    (query i sees keys j <= i + causal_offset) — shared by all kernels."""
+def _tile_positions(qi, kj, block_q, block_k):
+    """(BQ, BK) int32 tiles of each score's query and key position."""
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     k_pos = kj * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
+    return q_pos, k_pos
+
+
+def _causal_keep(qi, kj, causal_offset, block_q, block_k):
+    """Bool (BQ, BK) tile of the bottom-right-aligned causal mask
+    (query i sees keys j <= i + causal_offset) — shared by all kernels."""
+    q_pos, k_pos = _tile_positions(qi, kj, block_q, block_k)
     return q_pos + causal_offset >= k_pos
 
 
@@ -100,19 +122,68 @@ def _first_q_block(kj, causal_offset, block_q, block_k):
     return jnp.maximum(kj * block_k - causal_offset, 0) // block_q
 
 
+def _window_keep(qi, kj, causal_offset, block_q, block_k, window):
+    """Bool (BQ, BK) tile of the causal sliding window: query i sees keys
+    j with i + causal_offset - window < j <= i + causal_offset."""
+    q_pos, k_pos = _tile_positions(qi, kj, block_q, block_k)
+    rel = q_pos + causal_offset - k_pos
+    return (rel >= 0) & (rel < window)
+
+
+def _keep_tile(qi, kj, causal_offset, block_q, block_k, window):
+    if window is None:
+        return _causal_keep(qi, kj, causal_offset, block_q, block_k)
+    return _window_keep(qi, kj, causal_offset, block_q, block_k, window)
+
+
+def _window_first_k_block(qi, causal_offset, block_q, block_k, window):
+    """First k-block that q-block `qi`'s first query sees in its window.
+    Works on Python ints and on traced scalars alike."""
+    lo = qi * block_q + causal_offset - window + 1
+    lo = max(lo, 0) if isinstance(lo, int) else jnp.maximum(lo, 0)
+    return lo // block_k
+
+
+def _window_last_q_block(kj, causal_offset, block_q, block_k, window, nq):
+    """Last q-block that still has k-block `kj`'s last key in a window."""
+    hi = (kj * block_k + block_k - 1 - causal_offset + window - 1) // block_q
+    return min(hi, nq - 1) if isinstance(hi, int) else jnp.minimum(hi, nq - 1)
+
+
+def window_grid(tq, tk, block_q, block_k, window, kj_innermost):
+    """Length of the innermost grid axis of a windowed kernel: the most
+    blocks any tile row (kj_innermost: a q-block's k-blocks; else a
+    k-block's q-blocks) can see. Python ints only."""
+    off, nq, nk = tk - tq, tq // block_q, tk // block_k
+    if kj_innermost:
+        return max(
+            (qi * block_q + block_q - 1 + off) // block_k
+            - _window_first_k_block(qi, off, block_q, block_k, window) + 1
+            for qi in range(nq))
+    return max(
+        _window_last_q_block(kj, off, block_q, block_k, window, nq)
+        - max(kj * block_k - off, 0) // block_q + 1 for kj in range(nk))
+
+
 def _for_visible_tile(body, qi, kj, *, causal, causal_offset, block_q,
-                      block_k):
+                      block_k, last_q=None):
     """Run `body` unless the (qi, kj) tile lies wholly above the causal
-    diagonal (no query of the tile sees any of its keys)."""
+    diagonal (no query of the tile sees any of its keys). Under a window
+    the grid starts each row at its first visible block, so only the far
+    end is left to test: in the dK/dV kernel that is `last_q`, the
+    k-block's last q-block."""
     if not causal:
         body()
+        return
+    if last_q is not None:
+        pl.when(qi <= last_q)(body)
         return
     pl.when(kj * block_k <= _last_key(qi, causal_offset, block_q))(body)
 
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
               qi, kj, *, scale, causal, causal_offset, block_q, block_k,
-              mask_mode, precision):
+              mask_mode, precision, window=None):
     """Recompute the probability tile p = exp(s - m) / l — the forward's
     own normalization — and the logit cotangent ds = p * (dO V^T - delta)
     from the forward residuals: the shared core of both backward
@@ -135,8 +206,8 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
         s = s + mask_ref[0, 0, 0][None, :].astype(jnp.float32)
     p = jnp.exp(s - m[:, None]) * inv_l[:, None]
     if causal:
-        p = jnp.where(_causal_keep(qi, kj, causal_offset, block_q,
-                                   block_k), p, 0.0)
+        p = jnp.where(_keep_tile(qi, kj, causal_offset, block_q, block_k,
+                                 window), p, 0.0)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -147,13 +218,15 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
                 m_ref, l_ref, *, scale, causal, causal_offset, block_q,
-                block_k, mask_mode, precision):
+                block_k, mask_mode, precision, window=None):
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    j = pl.program_id(2)
     nk = pl.num_programs(2)
     mxu = _mxu_dtype(q_ref.dtype)
+    kj = j if window is None else j + _window_first_k_block(
+        qi, causal_offset, block_q, block_k, window)
 
-    @pl.when(kj == 0)
+    @pl.when(j == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -173,8 +246,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
         if causal:
             # bottom-right aligned for Tq != Tk (matches _xla_attention's
             # tril(..., tk - tq)): query i sees keys j <= i + (tk - tq)
-            s = jnp.where(_causal_keep(qi, kj, causal_offset, block_q,
-                                       block_k), s, _NEG_INF)
+            s = jnp.where(_keep_tile(qi, kj, causal_offset, block_q,
+                                     block_k, window), s, _NEG_INF)
 
         m_prev = m_ref[:, :1]                      # (BQ, 1)
         m_blk = jnp.max(s, axis=-1, keepdims=True)
@@ -194,7 +267,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
                       causal_offset=causal_offset, block_q=block_q,
                       block_k=block_k)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(j == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
@@ -207,36 +280,74 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
 
 
 def _seq_specs(h, d, mask_mode, causal, causal_offset, block_q, block_k,
-               kj_innermost):
+               kj_innermost, dv=None, group=1, window=None, n_inner=None,
+               nq=None):
     """BlockSpecs of one kernel: `q_spec` for what is tiled along the
-    queries (q, dO, out, dq: (bh, block_q, D)), `row_spec` for the per-row
-    statistics ((bh, 8, block_q)), `kv_spec` for k, v, dk, dv and
-    `mask_spec` for the additive mask. Grid index order is
-    (bh, i, j) for the forward/dQ kernels (kj_innermost) and (bh, j, i)
-    for dK/dV. A causal grid step above the diagonal does no work, so its
-    innermost index is clamped to the nearest block that does: the step
-    then names the block its neighbour holds and fetches nothing."""
+    queries at the q/k width (q, dq: (bh, block_q, D)), `row_spec` for the
+    per-row statistics ((bh, 8, block_q)), `k_spec` for k and dk,
+    `mask_spec` for the additive mask, and `o_spec` / `v_spec` for what is
+    `dv` wide (out, dO / v, dv; the same objects as `q_spec` / `k_spec`
+    where the widths are equal). Grid index order is (bh, i, j) for the
+    forward/dQ kernels (kj_innermost) and (bh, j, i) for dK/dV. A causal
+    grid step above the diagonal does no work, so its innermost index is
+    clamped to the nearest block that does: the step then names the block
+    its neighbour holds and fetches nothing. Under a `window` the innermost
+    axis counts from the row's first visible block and is clamped at its
+    last. With `group` query heads to a kv head, the first grid axis is
+    over query heads where kj_innermost (the kv row is bb // group) and
+    over kv heads for dK/dV, whose innermost axis then walks the group's
+    heads, `n_inner` q-blocks each. `h` is the heads of the first axis."""
     if kj_innermost:
         def ij(a, b_):
-            if causal:
+            if window is not None:
+                b_ = jnp.minimum(
+                    b_ + _window_first_k_block(a, causal_offset, block_q,
+                                               block_k, window),
+                    _last_k_block(a, causal_offset, block_q, block_k))
+            elif causal:
                 b_ = jnp.minimum(b_, _last_k_block(a, causal_offset,
                                                    block_q, block_k))
             return a, b_
     else:
         def ij(a, b_):
-            if causal:
+            if group != 1:
+                b_ = b_ % n_inner
+            if window is not None:
+                b_ = jnp.minimum(
+                    b_ + _first_q_block(a, causal_offset, block_q, block_k),
+                    _window_last_q_block(a, causal_offset, block_q, block_k,
+                                         window, nq))
+            elif causal:
                 b_ = jnp.maximum(b_, _first_q_block(a, causal_offset,
                                                     block_q, block_k))
             return b_, a
 
+    if group == 1:
+        def q_row(bb, b_):
+            return bb
+
+        kv_row = q_row
+    elif kj_innermost:
+        def q_row(bb, b_):
+            return bb
+
+        def kv_row(bb, b_):
+            return bb // group
+    else:
+        def q_row(bb, b_):
+            return bb * group + b_ // n_inner
+
+        def kv_row(bb, b_):
+            return bb
+
     def q_map(bb, a, b_):
-        return (bb, ij(a, b_)[0], 0)
+        return (q_row(bb, b_), ij(a, b_)[0], 0)
 
     def row_map(bb, a, b_):
-        return (bb, 0, ij(a, b_)[0])
+        return (q_row(bb, b_), 0, ij(a, b_)[0])
 
     def kv_map(bb, a, b_):
-        return (bb, ij(a, b_)[1], 0)
+        return (kv_row(bb, b_), ij(a, b_)[1], 0)
 
     if mask_mode == "none":
         mask_spec = pl.BlockSpec((1, 1, 1, 1), lambda bb, a, b_: (0, 0, 0, 0))
@@ -248,9 +359,15 @@ def _seq_specs(h, d, mask_mode, causal, causal_offset, block_q, block_k,
         mask_spec = pl.BlockSpec(
             (1, 1, block_q, block_k),
             lambda bb, a, b_: (bb // h, 0) + ij(a, b_))
-    return (pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec((1, 8, block_q), row_map),
-            pl.BlockSpec((1, block_k, d), kv_map), mask_spec)
+    q_spec = pl.BlockSpec((1, block_q, d), q_map)
+    k_spec = pl.BlockSpec((1, block_k, d), kv_map)
+    if dv is None or dv == d:
+        o_spec, v_spec = q_spec, k_spec
+    else:
+        o_spec = pl.BlockSpec((1, block_q, dv), q_map)
+        v_spec = pl.BlockSpec((1, block_k, dv), kv_map)
+    return (q_spec, pl.BlockSpec((1, 8, block_q), row_map), k_spec,
+            mask_spec, o_spec, v_spec)
 
 
 def _mask_mode(mask):
@@ -274,90 +391,113 @@ _VMEM_DEFAULT = 16 * 2 ** 20    # Mosaic's scoped limit on a v5e
 _VMEM_CEILING = 96 * 2 ** 20    # of the chip's 128 MiB
 
 
-def vmem_bytes(kernel, block_q, block_k, d, itemsize, mask_mode="none"):
+def vmem_bytes(kernel, block_q, block_k, d, itemsize, mask_mode="none",
+               dv=None):
     """Upper reckoning of the VMEM one grid step of `kernel` holds: every
     block twice (the pipeline's two buffers) with its lanes padded to 128,
-    the f32 accumulators, and `_TILE_TEMPS` f32 score-shaped tiles."""
+    the f32 accumulators, and `_TILE_TEMPS` f32 score-shaped tiles. `dv`
+    is the value width where it differs from `d`."""
     lanes = -(-d // 128) * 128
+    lanes_v = lanes if dv is None else -(-dv // 128) * 128
     q_blk, k_blk = block_q * lanes, block_k * lanes
+    o_blk, v_blk = block_q * lanes_v, block_k * lanes_v
     row_blk = 8 * block_q * 4
     mask_blk = {"none": 0, "k": 8 * block_k,
                 "qk": block_q * block_k}[mask_mode] * itemsize
     if kernel == "fwd":
-        blocks = (2 * q_blk + 2 * k_blk) * itemsize + row_blk
-        scratch = (q_blk + 2 * block_q * 128) * 4
+        blocks = (q_blk + o_blk + k_blk + v_blk) * itemsize + row_blk
+        scratch = (o_blk + 2 * block_q * 128) * 4
     elif kernel == "bwd_dkv":
-        blocks = (2 * q_blk + 4 * k_blk) * itemsize + 2 * row_blk
-        scratch = 2 * k_blk * 4
+        blocks = (q_blk + o_blk + 2 * k_blk + 2 * v_blk) * itemsize \
+            + 2 * row_blk
+        scratch = (k_blk + v_blk) * 4
     else:
-        blocks = (3 * q_blk + 2 * k_blk) * itemsize + 2 * row_blk
+        blocks = (2 * q_blk + o_blk + k_blk + v_blk) * itemsize \
+            + 2 * row_blk
         scratch = q_blk * 4
     return (2 * (blocks + mask_blk) + scratch
             + _TILE_TEMPS[kernel] * block_q * block_k * 4)
 
 
-def _compiler_params(kernel, block_q, block_k, d, dtype, mask_mode):
+def _compiler_params(kernel, block_q, block_k, d, dtype, mask_mode,
+                     dv=None):
     """Ask Mosaic for the VMEM the tile is reckoned to need where its
     default would not do, so that a large tile compiles instead of
     failing."""
     need = vmem_bytes(kernel, block_q, block_k, d, jnp.dtype(dtype).itemsize,
-                      mask_mode)
+                      mask_mode, dv)
     if need <= _VMEM_DEFAULT:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=min(need, _VMEM_CEILING))
 
 
+def _window_kwargs(window):
+    """The kernels' `window` keyword, left out where there is none."""
+    return {} if window is None else {"window": window}
+
+
 def _pallas_forward(q, k, v, mask, scale, causal, block_q, block_k,
-                    interpret):
+                    interpret, window=None):
     if not _HAS_TPU_PALLAS:
         raise NotImplementedError("pallas tpu backend unavailable")
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    hkv, tk, dv = k.shape[1], k.shape[2], v.shape[-1]
     bh = b * h
     q3 = q.reshape(bh, tq, d)
-    k3 = k.reshape(bh, tk, d)
-    v3 = v.reshape(bh, tk, d)
+    k3 = k.reshape(b * hkv, tk, d)
+    v3 = v.reshape(b * hkv, tk, dv)
 
     mask_mode = _mask_mode(mask)
-    q_spec, row_spec, kv_spec, mask_spec = _seq_specs(
+    q_spec, row_spec, k_spec, mask_spec, o_spec, v_spec = _seq_specs(
         h, d, mask_mode, causal, tk - tq, block_q, block_k,
-        kj_innermost=True)
+        kj_innermost=True, dv=dv, group=h // hkv, window=window)
+    nk = tk // block_k if window is None else window_grid(
+        tq, tk, block_q, block_k, window, True)
     out, stats = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           causal_offset=tk - tq, block_q=block_q,
                           block_k=block_k, mask_mode=mask_mode,
-                          precision=_dot_precision(q.dtype)),
-        grid=(bh, tq // block_q, tk // block_k),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=[q_spec, row_spec],
+                          precision=_dot_precision(q.dtype),
+                          **_window_kwargs(window)),
+        grid=(bh, tq // block_q, nk),
+        in_specs=[q_spec, k_spec, v_spec, mask_spec],
+        out_specs=[o_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 8, tq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         compiler_params=_compiler_params("fwd", block_q, block_k, d,
-                                         q.dtype, mask_mode),
+                                         q.dtype, mask_mode, dv),
         name="flash_fwd",
         interpret=interpret,
     )(q3, k3, v3, _mask_input(mask, q.dtype))
-    return out.reshape(b, h, tq, d), stats
+    return out.reshape(b, h, tq, dv), stats
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
                     mask_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale, causal, causal_offset, block_q, block_k,
-                    mask_mode, precision):
+                    mask_mode, precision, window=None, n_inner=None,
+                    nq=None):
     """dK/dV for one k-block, accumulating over q-blocks (innermost grid
-    dim). Recomputes p from the residuals — no (T,T) in HBM."""
+    dim; with grouped heads over the group's heads x their `n_inner`
+    q-blocks). Recomputes p from the residuals — no (T,T) in HBM."""
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    i = pl.program_id(2)
+    nq_steps = pl.num_programs(2)
+    qi = i if n_inner is None else i % n_inner
+    last_q = None
+    if window is not None:
+        qi = qi + _first_q_block(kj, causal_offset, block_q, block_k)
+        last_q = _window_last_q_block(kj, causal_offset, block_q, block_k,
+                                      window, nq)
 
-    @pl.when(qi == 0)
+    @pl.when(i == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -367,7 +507,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
             q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
             qi, kj, scale=scale, causal=causal,
             causal_offset=causal_offset, block_q=block_q,
-            block_k=block_k, mask_mode=mask_mode, precision=precision)
+            block_k=block_k, mask_mode=mask_mode, precision=precision,
+            **_window_kwargs(window))
         # dv += p^T dO ; dk += scale * ds^T q
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -378,9 +519,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
 
     _for_visible_tile(body, qi, kj, causal=causal,
                       causal_offset=causal_offset, block_q=block_q,
-                      block_k=block_k)
+                      block_k=block_k, last_q=last_q)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(i == nq_steps - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -388,12 +529,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
                    mask_ref, dq_ref, dq_acc, *, scale, causal,
-                   causal_offset, block_q, block_k, mask_mode, precision):
+                   causal_offset, block_q, block_k, mask_mode, precision,
+                   window=None):
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    j = pl.program_id(2)
     nk = pl.num_programs(2)
+    kj = j if window is None else j + _window_first_k_block(
+        qi, causal_offset, block_q, block_k, window)
 
-    @pl.when(kj == 0)
+    @pl.when(j == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -402,7 +546,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
             q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
             qi, kj, scale=scale, causal=causal,
             causal_offset=causal_offset, block_q=block_q,
-            block_k=block_k, mask_mode=mask_mode, precision=precision)
+            block_k=block_k, mask_mode=mask_mode, precision=precision,
+            **_window_kwargs(window))
         dq_acc[:] = dq_acc[:] + scale * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
@@ -411,7 +556,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
                       causal_offset=causal_offset, block_q=block_q,
                       block_k=block_k)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(j == nk - 1)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -421,66 +566,79 @@ def _bwd_inputs(q, k, v, mask, out, stats, g):
     rowsum(dO * O) (a cheap elementwise pass in XLA) with the sublane dim
     of 8 that the stats carry for Mosaic's block alignment."""
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    hkv, tk, dv = k.shape[1], k.shape[2], v.shape[-1]
     bh = b * h
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, tq)
-    return (q.reshape(bh, tq, d), k.reshape(bh, tk, d),
-            v.reshape(bh, tk, d), g.reshape(bh, tq, d), stats,
+    return (q.reshape(bh, tq, d), k.reshape(b * hkv, tk, d),
+            v.reshape(b * hkv, tk, dv), g.reshape(bh, tq, dv), stats,
             jnp.broadcast_to(delta, (bh, 8, tq)),
             _mask_input(mask, q.dtype))
 
 
 def _bwd_call(kernel, which, operands, h, mask_mode, scale, causal, block_q,
-              block_k, interpret, kj_innermost):
+              block_k, interpret, kj_innermost, window=None):
     """What the two backward kernels' pallas_calls share (`which` of
     KERNELS): the kernel with its parameters and the keyword arguments for
     the seven operands (q, k, v, dO, stats, delta, mask) both take; then
-    the q- and kv-tiled BlockSpecs for the outputs."""
-    q3, k3 = operands[:2]
+    the BlockSpecs for the outputs (q-tiled, k-tiled, v-tiled). `h` is the
+    query heads of a batch row; the kv heads follow from the operands."""
+    q3, k3, v3 = operands[:3]
     bh, tq, d = q3.shape
-    tk = k3.shape[1]
-    q_spec, row_spec, kv_spec, mask_spec = _seq_specs(
-        h, d, mask_mode, causal, tk - tq, block_q, block_k, kj_innermost)
+    bhkv, tk, dv = k3.shape[0], k3.shape[1], v3.shape[2]
+    group = bh // bhkv
+    nq, nk = tq // block_q, tk // block_k
+    n_q = nq if window is None else window_grid(tq, tk, block_q, block_k,
+                                                window, False)
+    n_k = nk if window is None else window_grid(tq, tk, block_q, block_k,
+                                                window, True)
+    q_spec, row_spec, k_spec, mask_spec, o_spec, v_spec = _seq_specs(
+        h if kj_innermost else h // group, d, mask_mode, causal, tk - tq,
+        block_q, block_k, kj_innermost, dv=dv, group=group, window=window,
+        n_inner=n_q, nq=nq)
+    extra = _window_kwargs(window)
+    if not kj_innermost:
+        if window is not None:
+            extra["nq"] = nq
+        if group != 1:
+            extra["n_inner"] = n_q
     body = functools.partial(kernel, scale=scale, causal=causal,
                              causal_offset=tk - tq, block_q=block_q,
                              block_k=block_k, mask_mode=mask_mode,
-                             precision=_dot_precision(q3.dtype))
+                             precision=_dot_precision(q3.dtype), **extra)
     common = dict(
-        grid=((bh, tq // block_q, tk // block_k) if kj_innermost
-              else (bh, tk // block_k, tq // block_q)),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
+        grid=((bh, nq, n_k) if kj_innermost else (bhkv, nk, group * n_q)),
+        in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec,
                   mask_spec],
         compiler_params=_compiler_params(which, block_q, block_k, d,
-                                         q3.dtype, mask_mode),
+                                         q3.dtype, mask_mode, dv),
         interpret=interpret)
-    return body, common, q_spec, kv_spec
+    return body, common, q_spec, k_spec, v_spec
 
 
 def _pallas_bwd_dkv(operands, h, mask_mode, scale, causal, block_q, block_k,
-                    interpret):
-    body, common, _, kv_spec = _bwd_call(
+                    interpret, window=None):
+    body, common, _, k_spec, v_spec = _bwd_call(
         _bwd_dkv_kernel, "bwd_dkv", operands, h, mask_mode, scale, causal,
-        block_q, block_k, interpret, kj_innermost=False)
+        block_q, block_k, interpret, kj_innermost=False, window=window)
     k3, v3 = operands[1:3]
-    block = (block_k, k3.shape[2])
     return pl.pallas_call(
         body,
-        out_specs=[kv_spec, kv_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
                    jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
-        scratch_shapes=[pltpu.VMEM(block, jnp.float32),
-                        pltpu.VMEM(block, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_k, k3.shape[2]), jnp.float32),
+                        pltpu.VMEM((block_k, v3.shape[2]), jnp.float32)],
         name="flash_bwd_dkv",
         **common,
     )(*operands)
 
 
 def _pallas_bwd_dq(operands, h, mask_mode, scale, causal, block_q, block_k,
-                   interpret):
-    body, common, q_spec, _ = _bwd_call(
+                   interpret, window=None):
+    body, common, q_spec, _, _ = _bwd_call(
         _bwd_dq_kernel, "bwd_dq", operands, h, mask_mode, scale, causal,
-        block_q, block_k, interpret, kj_innermost=True)
+        block_q, block_k, interpret, kj_innermost=True, window=window)
     q3 = operands[0]
     return pl.pallas_call(
         body,
@@ -493,21 +651,37 @@ def _pallas_bwd_dq(operands, h, mask_mode, scale, causal, block_q, block_k,
 
 
 def _pallas_backward(q, k, v, mask, out, stats, g, scale, causal, blocks,
-                     interpret):
+                     interpret, window=None):
     b, h, tq, d = q.shape
-    tk = k.shape[2]
     operands = _bwd_inputs(q, k, v, mask, out, stats, g)
     mask_mode = _mask_mode(mask)
     dk3, dv3 = _pallas_bwd_dkv(operands, h, mask_mode, scale, causal,
-                               *blocks[1], interpret)
+                               *blocks[1], interpret, window=window)
     dq3 = _pallas_bwd_dq(operands, h, mask_mode, scale, causal,
-                         *blocks[2], interpret)
-    return (dq3.reshape(b, h, tq, d), dk3.reshape(b, h, tk, d),
-            dv3.reshape(b, h, tk, d))
+                         *blocks[2], interpret, window=window)
+    return dq3.reshape(q.shape), dk3.reshape(k.shape), dv3.reshape(v.shape)
 
 
-def _xla_attention(q, k, v, mask, scale, causal):
+def visible_mask(tq, tk, window=None):
+    """Bool (Tq, Tk): the bottom-right-aligned causal mask, cut to the
+    last `window` keys where there is one."""
+    cm = jnp.tril(jnp.ones((tq, tk), bool), tk - tq)
+    if window is not None:
+        cm = cm & ~jnp.tril(jnp.ones((tq, tk), bool), tk - tq - window)
+    return cm
+
+
+def _repeat_kv(q, k, v):
+    """k and v with each head repeated for its group of query heads."""
+    group = q.shape[1] // k.shape[1]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+
+
+def _xla_attention(q, k, v, mask, scale, causal, window=None):
     prec = _dot_precision(q.dtype)
+    k, v = _repeat_kv(q, k, v)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32,
                         precision=prec) * scale
@@ -515,40 +689,40 @@ def _xla_attention(q, k, v, mask, scale, causal):
         logits = logits + mask.astype(jnp.float32)
     if causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
-        cm = jnp.tril(jnp.ones((tq, tk), bool), tk - tq)
-        logits = jnp.where(cm, logits, _NEG_INF)
+        logits = jnp.where(visible_mask(tq, tk, window), logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v,
                       precision=prec)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, mask, scale, causal, blocks, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, mask, scale, causal, blocks, interpret, window):
     """`blocks`: the (block_q, block_k) of each kernel, in KERNELS' order."""
     out, _ = _pallas_forward(q, k, v, mask, scale, causal, *blocks[0],
-                             interpret)
+                             interpret, window)
     return out
 
 
-def _flash_fwd(q, k, v, mask, scale, causal, blocks, interpret):
+def _flash_fwd(q, k, v, mask, scale, causal, blocks, interpret, window):
     out, stats = _pallas_forward(q, k, v, mask, scale, causal, *blocks[0],
-                                 interpret)
+                                 interpret, window)
     return out, (q, k, v, mask, out, stats)
 
 
-def _xla_dmask(q, k, v, mask, out, lse, g, scale, causal):
+def _xla_dmask(q, k, v, mask, out, lse, g, scale, causal, window=None):
     """Mask cotangent via the straight softmax-backward formula. This DOES
     materialize (B,H,Tq,Tk) — but it is emitted as a standalone expression,
     so when the mask grad is unused (padding masks, the BERT/ERNIE case)
     XLA dead-code-eliminates it and only the Pallas kernels remain."""
     prec = _dot_precision(q.dtype)
+    k, v = _repeat_kv(q, k, v)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32), precision=prec) * scale
     s = s + mask.astype(jnp.float32)
     p = jnp.exp(s - lse[..., None])
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
-        p = jnp.where(jnp.tril(jnp.ones((tq, tk), bool), tk - tq), p, 0.0)
+        p = jnp.where(visible_mask(tq, tk, window), p, 0.0)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
     dp = jnp.einsum("bhqd,bhkd->bhqk", g.astype(jnp.float32),
@@ -559,17 +733,17 @@ def _xla_dmask(q, k, v, mask, out, lse, g, scale, causal):
     return jnp.sum(ds, axis=reduce_axes, keepdims=True).astype(mask.dtype)
 
 
-def _flash_bwd(scale, causal, blocks, interpret, res, g):
+def _flash_bwd(scale, causal, blocks, interpret, window, res, g):
     q, k, v, mask, out, stats = res
     # Pallas backward: recompute p from the (m, l, delta) residuals with
     # the mask applied in-kernel — the (T,T) matrix never touches HBM for
     # dq/dk/dv in either direction
     dq, dk, dv = _pallas_backward(q, k, v, mask, out, stats, g, scale,
-                                  causal, blocks, interpret)
+                                  causal, blocks, interpret, window)
     if mask is None:
         return dq, dk, dv, None
     lse = (stats[:, 0] + jnp.log(stats[:, 1])).reshape(q.shape[:3])
-    dmask = _xla_dmask(q, k, v, mask, out, lse, g, scale, causal)
+    dmask = _xla_dmask(q, k, v, mask, out, lse, g, scale, causal, window)
     return dq, dk, dv, dmask
 
 
@@ -610,7 +784,8 @@ def _fit(block, t):
     return block
 
 
-def pick_blocks(tq, tk, d, dtype, kernel, causal=False):
+def pick_blocks(tq, tk, d, dtype, kernel, causal=False, window=None,
+                dv=None):
     """(block_q, block_k) of `kernel` (one of KERNELS) for a call's shape.
 
     What the sweep on a v5e showed (PERF.md, PR 25; bf16, D=64 and 128,
@@ -624,13 +799,20 @@ def pick_blocks(tq, tk, d, dtype, kernel, causal=False):
     but never under 512, where the step's cost loses more than skipping
     saves. Other dtypes run float32 operands at HIGHEST: twice the VMEM
     and six MXU passes a tile, so 512 is their cap (reckoned, not swept).
-    A tile reckoned over the VMEM ceiling is halved until it fits."""
+    Under a sliding `window` a tile row sees window + block_q keys whatever
+    the sequence's length, so all three kernels take a tile about the
+    window's size (the next power of two, at least 256): a wider one does
+    masked work, a narrower one pays more steps (reckoned from PR 25's
+    step costs, not swept). A tile reckoned over the VMEM ceiling (`dv`:
+    the value width where it differs from `d`) is halved until it fits."""
     itemsize = jnp.dtype(dtype).itemsize
     side = 1024 if jnp.dtype(dtype) == jnp.bfloat16 else 512
-    if causal and kernel != "fwd":
+    if window is not None:
+        side = min(side, max(256, 1 << (int(window) - 1).bit_length()))
+    elif causal and kernel != "fwd":
         side = min(side, max(512, min(tq, tk) // 4))
     bq, bk = _fit(side, tq), _fit(side, tk)
-    while (vmem_bytes(kernel, bq, bk, d, itemsize, "qk") > _VMEM_CEILING
+    while (vmem_bytes(kernel, bq, bk, d, itemsize, "qk", dv) > _VMEM_CEILING
            and max(bq, bk) > 128):
         if bq >= bk:
             bq = _fit(bq // 2, tq)
@@ -639,11 +821,52 @@ def pick_blocks(tq, tk, d, dtype, kernel, causal=False):
     return bq, bk
 
 
+def plan(q_shape, k_shape, v_shape, causal, window, blocks):
+    """What a call will do, for `flash.plan`: per kernel the tile, the grid
+    steps and how many of the (q-block, k-block) tiles run, how many lie
+    wholly above the causal diagonal and how many wholly outside the
+    window; with the group size and the two widths. Python ints only."""
+    (_b, hq, tq, d), hkv, tk, dv = q_shape, k_shape[1], k_shape[2], \
+        v_shape[-1]
+    off, out = tk - tq, {"group": hq // hkv, "d_qk": d, "d_v": dv,
+                         "window": window, "causal": bool(causal)}
+    for kernel, (bq, bk) in zip(KERNELS, blocks):
+        nq, nk = tq // bq, tk // bk
+        above = outside = 0
+        for qi in range(nq):
+            last = (qi * bq + bq - 1 + off) // bk if causal else nk - 1
+            first = 0 if window is None else _window_first_k_block(
+                qi, off, bq, bk, window)
+            above += nk - 1 - last
+            outside += first
+        inner = nk if kernel != "bwd_dkv" else nq
+        if window is not None:
+            inner = window_grid(tq, tk, bq, bk, window, kernel != "bwd_dkv")
+        out[kernel] = {"block_q": bq, "block_k": bk,
+                       "grid_inner": inner,
+                       "tiles_visited": nq * nk - above - outside,
+                       "tiles_skipped_causal": above,
+                       "tiles_skipped_window": outside}
+    return out
+
+
+def _record_plan(q, k, v, causal, window, blocks):
+    """One `flash.plan` record a lowering, while obs is on."""
+    from ...framework import obs
+    if obs.enabled():
+        now = obs.now()
+        obs.record("flash.plan", now, now,
+                   **plan(q.shape, k.shape, v.shape, causal, window, blocks))
+
+
 def flash_attention(q, k, v, mask=None, scale=1.0, causal=False,
-                    block_q=None, block_k=None, interpret=None):
-    """Flash attention entry. q,k,v: (B,H,T,D). Falls back to interpret
-    mode off-TPU so tests exercise the same kernel, and to plain fused XLA
-    attention when shapes are too small to tile.
+                    block_q=None, block_k=None, interpret=None,
+                    window=None):
+    """Flash attention entry. q: (B,Hq,Tq,D), k: (B,Hkv,Tk,D), v:
+    (B,Hkv,Tk,Dv) (the module docstring states the supported space).
+    Falls back to interpret mode off-TPU so tests exercise the same
+    kernel, and to plain fused XLA attention when shapes are too small to
+    tile.
 
     Each kernel's tile comes from the call's shape (`pick_blocks`). An
     explicit `block_q`/`block_k`, or PADDLE_TPU_FLASH_BLOCK_Q/_K where
@@ -654,23 +877,48 @@ def flash_attention(q, k, v, mask=None, scale=1.0, causal=False,
         block_k = _env_block("PADDLE_TPU_FLASH_BLOCK_K")
     if interpret is None:
         interpret = pd.default_interpret()
-    tq, tk, d = q.shape[2], k.shape[2], q.shape[-1]
+    check_call(q.shape, k.shape, v.shape, causal, window)
+    tq, tk, d, dv = q.shape[2], k.shape[2], q.shape[-1], v.shape[-1]
     if causal and tq > tk:
         # rows i < tq - tk see no keys at all; only the XLA reference
         # defines that edge (uniform over all-masked logits)
-        return _xla_attention(q, k, v, mask, scale, causal)
+        return _xla_attention(q, k, v, mask, scale, causal, window)
     blocks = []
     for kernel in KERNELS:
-        bq, bk = pick_blocks(tq, tk, d, q.dtype, kernel, causal)
+        bq, bk = pick_blocks(tq, tk, d, q.dtype, kernel, causal, window,
+                             None if dv == d else dv)
         blocks.append((_fit(block_q or bq, tq), _fit(block_k or bk, tk)))
     least = min(min(pair) for pair in blocks)
-    if least < 8 or d % 8:
-        return _xla_attention(q, k, v, mask, scale, causal)
+    if least < 8 or d % 8 or dv % 8:
+        return _xla_attention(q, k, v, mask, scale, causal, window)
     if not interpret and least < 128:
         # Mosaic wants the last-two block dims 128-lane aligned (the stats
         # block puts block_q on the lane dim); sub-128 tiles are only
         # exercised in interpret mode — on device route them to XLA.
-        return _xla_attention(q, k, v, mask, scale, causal)
+        return _xla_attention(q, k, v, mask, scale, causal, window)
+    _record_plan(q, k, v, causal, window, blocks)
     return _flash(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                   None if mask is None else jnp.asarray(mask),
-                  scale, causal, tuple(blocks), interpret)
+                  scale, causal, tuple(blocks), interpret, window)
+
+
+def check_call(q_shape, k_shape, v_shape, causal, window):
+    """A clear error for a call outside the supported space, before any
+    kernel is built (Mosaic's own would name a block shape)."""
+    hq, hkv = q_shape[1], k_shape[1]
+    if hkv < 1 or hq % hkv:
+        raise ValueError(
+            "attention: %d query heads are not a whole multiple of %d "
+            "key/value heads" % (hq, hkv))
+    if tuple(k_shape[:3]) != tuple(v_shape[:3]) \
+            or q_shape[-1] != k_shape[-1]:
+        raise ValueError(
+            "attention: k %r and v %r must share batch, heads and length, "
+            "q %r and k the head width" % (tuple(k_shape), tuple(v_shape),
+                                          tuple(q_shape)))
+    if window is not None:
+        if not causal:
+            raise ValueError("attention: a sliding window needs causal=True")
+        if int(window) <= 0:
+            raise ValueError("attention: window must be positive, got %r"
+                             % (window,))

@@ -57,7 +57,18 @@ def stop_profiler(sorted_key=None, profile_path=None):
     path = profile_path or _session["path"] or DEFAULT_PATH
     rows = op_table(path, sorted_key)
     print_table(rows)
+    print_kernel_plans()
     return rows
+
+
+def print_kernel_plans():
+    """What the Pallas kernels lowered while obs was on planned to do: one
+    line per `flash.plan` (tiles, tiles visited and skipped by causality
+    and by the window, group size, widths) and `ssm.plan` (chunk length,
+    chunks, VMEM asked) record, under the table."""
+    for plan in obs.spans(name="flash.plan") + obs.spans(name="ssm.plan"):
+        print("%s %s" % (plan["name"], " ".join(
+            "%s=%s" % kv for kv in sorted(plan["labels"].items()))))
 
 
 def op_table(path, sorted_key=None):

@@ -674,12 +674,13 @@ def test_every_pallas_call_has_a_stable_name(site):
     fname, _line, name = site
     assert name and name.isidentifier(), site
     stem = fname[:-3]
-    assert name.startswith({"flash_attention": "flash_"}.get(stem, stem))
+    assert name.startswith({"flash_attention": "flash_",
+                            "selective_scan": "ssm_scan_"}.get(stem, stem))
 
 
 def test_pallas_call_names_are_distinct():
     names = [s[2] for s in _SITES]
-    assert len(names) == 11
+    assert len(names) == 13
     assert len(set(names)) == len(names), names
 
 
