@@ -280,6 +280,7 @@ def train_gpt_flash(run):
     fused train step — the ERNIE step at T=128 never reaches Pallas."""
     import paddle_tpu as pt
     from paddle_tpu import optimizer
+    from paddle_tpu.framework import obs
     from paddle_tpu.framework.scope import Scope, scope_guard
     from paddle_tpu.models import gpt
     sz = run.sizes["gpt"]
@@ -294,8 +295,21 @@ def train_gpt_flash(run):
         exe.run(startup)
         losses, first_s, step_secs = _train_fixed_batch(
             "train_gpt_flash", exe, main, feed, loss, 3)
-        lowered = exe.dump_hlo(main, feed=feed, fetch_list=[loss],
-                               include_compiled=False)["lowered"]
+        # the lowering made for the dump runs with obs on, so that it
+        # leaves its `head.plan` (the timed steps above ran with obs off)
+        obs.enable()
+        try:
+            lowered = exe.dump_hlo(main, feed=feed, fetch_list=[loss],
+                                   include_compiled=False)["lowered"]
+            head_plans = [p["labels"] for p in obs.spans(name="head.plan")]
+        finally:
+            obs.disable()
+            obs.clear()
+    check([p["form"] for p in head_plans] == ["weighted"]
+          and head_plans[0]["blocks"] * head_plans[0]["block_rows"]
+          == batch * seq,
+          "train_gpt_flash: the head did not lower once in its weighted "
+          "form over all %d rows: %r" % (batch * seq, head_plans))
     mosaic_calls = lowered.count("tpu_custom_call")
     # interpreted kernels lower to plain HLO: nothing to find off-chip
     check(run.rehearsal or mosaic_calls > 0,
@@ -304,7 +318,7 @@ def train_gpt_flash(run):
     return dict(
         batch=batch, seq=seq, hidden=cfg.hidden_size, layers=cfg.num_layers,
         dtype=cfg.dtype, losses=[round(v, 4) for v in losses],
-        mosaic_custom_calls=mosaic_calls,
+        mosaic_custom_calls=mosaic_calls, head_plan=head_plans[0],
         smoke_first_call_s=round(first_s, 2),
         smoke_step_s=round(float(np.median(step_secs)), 4))
 

@@ -41,25 +41,40 @@ def softmax_with_cross_entropy(logits, label, soft_label=False,
 
 
 def fused_mlm_head_loss(hidden, weight, label, bias=None,
-                        cast_bf16=False):
-    """Fused LM/MLM head: ``hidden (T, D) @ weight^T (+ bias)`` ->
-    per-token softmax CE loss ``(T, 1)`` in ONE op, so the
-    ``[tokens, vocab]`` logits can skip HBM entirely when
-    ``BuildStrategy.use_pallas={"fused_mlm_head_loss"}`` routes it to
-    the Pallas kernel (ops/pallas/blockwise_ce). ``weight`` is the
-    (V, D) tied embedding table; ``cast_bf16`` runs the projection in
-    bf16 with f32 accumulation (models/bert._mlm_decode's MXU trick).
-    The XLA fallback computes the identical matmul + CE chain, so
-    wiring a model head through this layer is loss-curve-neutral with
-    Pallas off."""
+                        cast_bf16=False, token_weight=None):
+    """Fused LM/MLM head: ``hidden (T, D) @ weight^T (+ bias)`` -> softmax
+    CE in ONE op, the ``[tokens, vocab]`` logits an op-internal detail.
+    ``weight`` is the (V, D) tied embedding table; ``cast_bf16`` runs the
+    projection in bf16 with f32 accumulation (models/bert._mlm_decode's
+    MXU trick).
+
+    Without ``token_weight`` the result is the per-token loss ``(T, 1)``:
+    the XLA lowering is the matmul + CE chain (wiring a model head
+    through it is loss-curve-neutral) and holds the logits from its
+    forward to its backward;
+    ``BuildStrategy.use_pallas={"fused_mlm_head_loss"}`` routes it to the
+    Pallas kernel (ops/pallas/blockwise_ce), where they skip HBM.
+
+    With ``token_weight`` ``(T, 1)`` float32 (no gradient flows to it:
+    ``mask / (Σ mask + ε)`` for a masked mean) the result is the scalar
+    ``Σ_t w_t · ce_t``, shape ``[1]``. A scalar's cotangent is a scalar,
+    so the op walks the token axis in blocks and forms dHidden, dWeight
+    and dBias in the forward pass (ops/head_loss.py): no
+    ``[tokens, vocab]`` array outlives a block and no matmul is replayed.
+    A program that reduces the per-token loss by a weighted sum anyway
+    should pass the weights instead."""
     helper = LayerHelper("fused_mlm_head_loss")
     t = hidden.shape[0] if hidden.shape else None
-    loss = helper.create_variable_for_type_inference(
-        "float32", (t, 1) if t is not None else None)
     inputs = {"Hidden": [hidden.name], "Weight": [weight.name],
               "Label": [label.name]}
     if bias is not None:
         inputs["Bias"] = [bias.name]
+    if token_weight is not None:
+        inputs["TokenWeight"] = [token_weight.name]
+        shape = (1,)
+    else:
+        shape = (t, 1) if t is not None else None
+    loss = helper.create_variable_for_type_inference("float32", shape)
     helper.append_op(
         "fused_mlm_head_loss", inputs=inputs,
         outputs={"Loss": [loss.name]},

@@ -138,6 +138,16 @@ def gpt_decoder(token_ids, pos_ids, cfg, is_test=False):
                              bias_attr=ParamAttr(name="gpt_lnf_b"))
 
 
+def masked_mean_weights(loss_mask):
+    """``mask / (Σ mask + 1e-8)`` as (tokens, 1): the token weights under
+    which `fused_mlm_head_loss`' scalar is the masked mean."""
+    mask = layers.reshape(loss_mask, [-1, 1])
+    return layers.elementwise_div(
+        mask, layers.elementwise_add(
+            layers.reduce_sum(mask),
+            layers.fill_constant([1], "float32", 1e-8)))
+
+
 def gpt_pretrain_program(cfg, batch_size, seq_len, optimizer_fn=None,
                          is_test=False):
     """Next-token LM: feeds token_ids/pos_ids/labels (N,T,1) int64 +
@@ -151,22 +161,18 @@ def gpt_pretrain_program(cfg, batch_size, seq_len, optimizer_fn=None,
         lmask = layers.data("loss_mask", [seq_len, 1], dtype="float32")
 
         h = gpt_decoder(tok, pos, cfg, is_test=is_test)  # cfg.dtype
-        # fused tied-embedding head: the (N*T, vocab) logits exist only
-        # inside the op (Pallas keeps them out of HBM under use_pallas;
-        # the XLA fallback is the same _tied_logits+CE math). Decode
-        # programs (gpt_logits_program) still materialize logits — they
-        # ARE the output there.
+        # fused tied-embedding head, weighted form: the masked mean is
+        # Σ_t w_t·ce_t with w = mask / (Σ mask + ε), a scalar, so the op
+        # forms its gradients block by block in the forward pass and the
+        # (N*T, vocab) logits never outlive a block. Decode programs
+        # (gpt_logits_program) still materialize logits — they ARE the
+        # output there.
         flat_h = layers.reshape(h, [-1, cfg.hidden_size])
         flat_lbl = layers.reshape(lbl, [-1, 1])
         emb = main.global_block().var("gpt_word_embedding")
-        ce = layers.fused_mlm_head_loss(
-            flat_h, emb, flat_lbl, cast_bf16=cfg.dtype == "bfloat16")
-        mask = layers.reshape(lmask, [-1, 1])
-        loss = layers.elementwise_div(
-            layers.reduce_sum(layers.elementwise_mul(ce, mask)),
-            layers.elementwise_add(
-                layers.reduce_sum(mask),
-                layers.fill_constant([1], "float32", 1e-8)))
+        loss = layers.fused_mlm_head_loss(
+            flat_h, emb, flat_lbl, cast_bf16=cfg.dtype == "bfloat16",
+            token_weight=masked_mean_weights(lmask))
         if optimizer_fn is not None:
             optimizer_fn(loss)
     feeds = ["token_ids", "pos_ids", "labels", "loss_mask"]

@@ -37,6 +37,7 @@ from paddle_tpu.initializer import (ConstantInitializer,
                                     NumpyArrayInitializer,
                                     TruncatedNormalInitializer)
 from paddle_tpu.layers.attention import fused_attention
+from paddle_tpu.models.gpt import masked_mean_weights
 from paddle_tpu.param_attr import ParamAttr
 
 KINDS = ("mamba", "window", "memory", "full", "gmu", "cross")
@@ -334,15 +335,10 @@ def phi4flash_pretrain_program(cfg, batch_size, seq_len, optimizer_fn=None,
         lmask = layers.data("loss_mask", [seq_len, 1], dtype="float32")
         h = phi4flash_decoder(tok, cfg, is_test=is_test)
         emb = main.global_block().var("phi_word_embedding")
-        ce = layers.fused_mlm_head_loss(
+        loss = layers.fused_mlm_head_loss(
             layers.reshape(h, [-1, cfg.hidden_size]), emb,
-            layers.reshape(lbl, [-1, 1]), cast_bf16=cfg.dtype == "bfloat16")
-        mask = layers.reshape(lmask, [-1, 1])
-        loss = layers.elementwise_div(
-            layers.reduce_sum(layers.elementwise_mul(ce, mask)),
-            layers.elementwise_add(
-                layers.reduce_sum(mask),
-                layers.fill_constant([1], "float32", 1e-8)))
+            layers.reshape(lbl, [-1, 1]), cast_bf16=cfg.dtype == "bfloat16",
+            token_weight=masked_mean_weights(lmask))
         if optimizer_fn is not None:
             optimizer_fn(loss)
     return main, startup, ["token_ids", "labels", "loss_mask"], {"loss": loss}

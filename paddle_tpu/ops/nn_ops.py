@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .registry import register_op
+from . import head_loss
 from . import pallas_dispatch as _pd
 from ..framework.dtypes import to_jax_dtype
 
@@ -387,16 +388,40 @@ def _softmax_with_cross_entropy(ctx, ins, attrs):
             "Loss": loss.astype(logits.dtype)}
 
 
-@register_op("fused_mlm_head_loss", nondiff=("Label",))
+def _record_head_plan(hidden, weight, form, cast_bf16):
+    """One `head.plan` record a lowering of the head's XLA path, while
+    obs is on: which form engaged, and in how many blocks of how many
+    rows the ``[tokens, vocab]`` logits are held."""
+    from ..framework import obs
+    if obs.enabled():
+        now, t = obs.now(), hidden.shape[0]
+        rows = head_loss.block_rows(t) if form == "weighted" else t
+        obs.record("head.plan", now, now, rows=t, vocab=weight.shape[0],
+                   block_rows=rows, blocks=t // rows, form=form,
+                   operand_dtype="bfloat16" if cast_bf16
+                   else str(hidden.dtype))
+
+
+@register_op("fused_mlm_head_loss", nondiff=("Label", "TokenWeight"))
 def _fused_mlm_head_loss(ctx, ins, attrs):
     """LM/MLM head + softmax CE in one op: ``Hidden (T, D) @ Weight^T
-    (+ Bias) -> per-token Loss (T, 1)`` — the model-head fusion seam.
-    Behind ``BuildStrategy.use_pallas={"fused_mlm_head_loss"}`` the op
+    (+ Bias)`` -> ``Loss``, in one of two forms, chosen by what the
+    program passes.
+
+    Per-token form (no ``TokenWeight``): Loss is (T, 1). Behind
+    ``BuildStrategy.use_pallas={"fused_mlm_head_loss"}`` the op
     routes to ops/pallas/blockwise_ce.fused_mlm_head_loss and the
     ``[tokens, vocab]`` logits NEVER materialize in fwd or bwd; the XLA
     fallback mirrors the matmul + softmax_with_cross_entropy chain it
-    replaces in models/bert + models/gpt (same math, so the wiring is
-    loss-curve-neutral with Pallas off).
+    replaces in models/bert (same math, so the wiring is
+    loss-curve-neutral with Pallas off), and holds the logits from its
+    forward to its backward.
+
+    Weighted form (``TokenWeight (T, 1)``, no gradient): Loss is the
+    scalar ``Σ_t w_t · ce_t``, shape [1]. Its cotangent is a scalar, so
+    ops/head_loss.py forms the gradients block by block over the token
+    axis in the forward pass and no ``[tokens, vocab]`` array outlives a
+    block (models/gpt, models/phi4flash).
 
     Weight is the (V, D) tied embedding table (``transpose_y=True``
     matmul layout); attr ``cast_bf16`` runs the projection in bf16 with
@@ -406,8 +431,10 @@ def _fused_mlm_head_loss(ctx, ins, attrs):
     bias = ins["Bias"][0] if ins.get("Bias") else None
     lbl = label.reshape(label.shape[:-1]) if label.ndim > 1 and \
         label.shape[-1] == 1 else label
+    token_weight = ins["TokenWeight"][0] if ins.get("TokenWeight") else None
+    cast_bf16 = attrs.get("cast_bf16", False)
     h, w = hidden, weight
-    if attrs.get("cast_bf16", False):
+    if cast_bf16:
         h = h.astype(jnp.bfloat16)
         w = w.astype(jnp.bfloat16)
     # also honor use_pallas={"softmax_with_cross_entropy"}: configs that
@@ -435,7 +462,16 @@ def _fused_mlm_head_loss(ctx, ins, attrs):
                 bias=None if bias is None else bias.astype(jnp.float32),
                 interpret=cfg.interpret, **(tuned or {}))
             if loss is not None:
-                return {"Loss": loss[:, None].astype(jnp.float32)}
+                loss = loss[:, None].astype(jnp.float32)
+                if token_weight is not None:
+                    loss = jnp.sum(token_weight * loss).reshape((1,))
+                return {"Loss": loss}
+    if token_weight is not None:
+        _record_head_plan(hidden, weight, "weighted", cast_bf16)
+        loss = head_loss.weighted_head_loss(
+            hidden, weight, bias, lbl, token_weight, cast_bf16)
+        return {"Loss": loss.reshape((1,))}
+    _record_head_plan(hidden, weight, "per_token", cast_bf16)
     # XLA fallback: the exact chain the models used to emit — matmul
     # (transpose_y, f32 accumulation under cast_bf16) + bias +
     # log_softmax gather

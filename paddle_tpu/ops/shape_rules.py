@@ -627,6 +627,14 @@ def _mlm_head_rule(op, ins, attrs):
             raise ShapeError(
                 "fused_mlm_head_loss Label rows %d != Hidden rows %s"
                 % (lt, t))
+    if ins.get("TokenWeight"):
+        tw = _x(ins, "TokenWeight")
+        if tw.shape is not None and _known(tw.shape) and t is not None \
+                and tuple(tw.shape) != (t, 1):
+            raise ShapeError(
+                "fused_mlm_head_loss TokenWeight %s is not (%s, 1)"
+                % (tuple(tw.shape), t))
+        return {"Loss": [TensorMeta((1,), "float32")]}
     return {"Loss": [TensorMeta((t, 1), "float32")]}
 
 

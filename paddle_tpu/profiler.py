@@ -61,14 +61,19 @@ def stop_profiler(sorted_key=None, profile_path=None):
     return rows
 
 
+PLAN_RECORDS = ("flash.plan", "ssm.plan", "head.plan")
+
+
 def print_kernel_plans():
-    """What the Pallas kernels lowered while obs was on planned to do: one
-    line per `flash.plan` (tiles, tiles visited and skipped by causality
-    and by the window, group size, widths) and `ssm.plan` (chunk length,
-    chunks, VMEM asked) record, under the table."""
-    for plan in obs.spans(name="flash.plan") + obs.spans(name="ssm.plan"):
-        print("%s %s" % (plan["name"], " ".join(
-            "%s=%s" % kv for kv in sorted(plan["labels"].items()))))
+    """What the lowerings made while obs was on planned to do, one line a
+    record, under the table: `flash.plan` (tiles, tiles visited and skipped
+    by causality and by the window, group size, widths), `ssm.plan` (chunk
+    length, chunks, VMEM asked) and `head.plan` (the LM head: rows, vocab,
+    block rows and blocks, weighted or per-token form, operand dtype)."""
+    for name in PLAN_RECORDS:
+        for plan in obs.spans(name=name):
+            print("%s %s" % (plan["name"], " ".join(
+                "%s=%s" % kv for kv in sorted(plan["labels"].items()))))
 
 
 def op_table(path, sorted_key=None):
