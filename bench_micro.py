@@ -21,19 +21,6 @@ wall clocks, never a device number:
                              not hand-waved)
   * feed_samples_per_s     — ShardedFeed draw+commit throughput
                              (the data-plane hot loop)
-  * pallas_*               — the Pallas kernel library vs its XLA
-                             references in interpret mode (blockwise
-                             CE / fused MLM head, fused Adam, fused
-                             LayerNorm): fwd+bwd step wall + max abs
-                             error per kernel — the kernels' tier-1
-                             perf-and-parity canary
-  * costmodel_*            — the kernel-selection cost model (ISSUE
-                             13): fit wall over the committed
-                             tools/tuned/ cache, per-query ranking
-                             cost (what a trace-time cache miss
-                             pays — must be ≪ one sweep probe), and
-                             the measured-best-in-top-3 rate on the
-                             banked keys
   * transport_*            — coordination-plane latency over an
                              in-process CoordServer: single
                              request/response round trip, a 2-host
@@ -106,24 +93,6 @@ BUDGETS = {
     "quant_step_s": ("max", 20.0),
     "collective_wire_ratio": ("max", 0.30),
     "feed_samples_per_s": ("min", 1000.0),
-    # Pallas kernels, interpret mode on tiny shapes: wall budgets catch
-    # an interpreter-path blowup, error budgets catch a numerics break
-    # (the oracle batteries assert tighter bounds; these gate the bench)
-    "pallas_ce_step_s": ("max", 30.0),
-    "pallas_adam_step_s": ("max", 15.0),
-    "pallas_ln_step_s": ("max", 15.0),
-    "pallas_ce_err": ("max", 1e-4),
-    "pallas_adam_err": ("max", 1e-5),
-    "pallas_ln_err": ("max", 1e-4),
-    # kernel-selection cost model (ISSUE 13): fitting over the whole
-    # committed banked cache and ranking a candidate space must stay
-    # FAR below one sweep probe (~ms-to-minutes) — the model only pays
-    # for itself while a query is nearly free. The top-3 rate gates
-    # the committed cache's ranking quality at the same bar
-    # tools/tunecheck.py enforces.
-    "costmodel_fit_s": ("max", 2.0),
-    "costmodel_rank_us": ("max", 20000.0),
-    "costmodel_top3_rate": ("min", 0.8),
     # coordination-plane latency (in-process CoordServer over loopback
     # TCP): a round trip is ~100us healthy; a 2-host gather round adds
     # the poll cadence. Budgets catch a protocol/serialization blowup.
@@ -376,144 +345,6 @@ def bench_feed(n_files=16, per_file=64, batches=200, batch_size=8):
     dt = time.perf_counter() - t0
     return {"feed_samples_per_s": round(served / dt, 1),
             "feed_batches": batches}
-
-
-def bench_pallas(steps=2):
-    """Pallas kernel library vs the XLA references, interpret mode on
-    tiny shapes: per-kernel fwd+bwd step wall (jitted, best-of) + max
-    abs error. The same kernels the use_pallas dispatch routes to —
-    this is their always-on perf-and-parity canary."""
-    import numpy as np
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas.blockwise_ce import \
-        blockwise_softmax_cross_entropy
-    from paddle_tpu.ops.pallas.fused_adam import fused_adam
-    from paddle_tpu.ops.pallas.layer_norm import fused_layer_norm
-
-    rng = np.random.RandomState(0)
-    out = {}
-
-    def best_of(fn):
-        jax.block_until_ready(fn())      # compile + warm
-        best = None
-        for _ in range(steps):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn())
-            dt = time.perf_counter() - t0
-            best = dt if best is None else min(best, dt)
-        return best
-
-    # blockwise CE: fwd+bwd vs log_softmax reference, (32, 256)
-    t, v = 32, 256
-    logits = jnp.asarray(rng.randn(t, v).astype(np.float32))
-    labels = jnp.asarray(rng.randint(0, v, (t,)).astype(np.int32))
-
-    def ce_ref(lg):
-        logp = jax.nn.log_softmax(lg, axis=-1)
-        return -jnp.take_along_axis(logp, labels[:, None], axis=1)[:, 0]
-
-    def ce_pallas(lg):
-        return blockwise_softmax_cross_entropy(
-            lg, labels, block_t=8, block_v=64, interpret=True)
-
-    g_p = jax.jit(jax.grad(lambda lg: jnp.sum(ce_pallas(lg))))
-    g_r = jax.jit(jax.grad(lambda lg: jnp.sum(ce_ref(lg))))
-    out["pallas_ce_step_s"] = round(best_of(lambda: g_p(logits)), 5)
-    out["pallas_ce_err"] = float(max(
-        jnp.max(jnp.abs(ce_pallas(logits) - ce_ref(logits))),
-        jnp.max(jnp.abs(g_p(logits) - g_r(logits)))))
-
-    # fused adam: one update vs the elementwise chain, 4096 elements
-    n = 4096
-    p = jnp.asarray(rng.randn(n).astype(np.float32))
-    gr = jnp.asarray(rng.randn(n).astype(np.float32))
-    m1 = jnp.zeros((n,), jnp.float32)
-    m2 = jnp.zeros((n,), jnp.float32)
-    lr_t = jnp.float32(0.01)
-
-    def adam_pallas(p, gr, m1, m2):
-        return fused_adam(p, gr, m1, m2, lr_t, block_rows=16,
-                          interpret=True)
-
-    def adam_ref(p, gr, m1, m2):
-        m1n = 0.9 * m1 + 0.1 * gr
-        m2n = 0.999 * m2 + 0.001 * gr * gr
-        return p - lr_t * m1n / (jnp.sqrt(m2n) + 1e-8), m1n, m2n
-
-    jp, jr = jax.jit(adam_pallas), jax.jit(adam_ref)
-    out["pallas_adam_step_s"] = round(
-        best_of(lambda: jp(p, gr, m1, m2)), 5)
-    out["pallas_adam_err"] = float(max(
-        jnp.max(jnp.abs(a - b))
-        for a, b in zip(jp(p, gr, m1, m2), jr(p, gr, m1, m2))))
-
-    # fused layernorm: fwd+bwd vs jnp reference, (32, 128)
-    r, c = 32, 128
-    x = jnp.asarray(rng.randn(r, c).astype(np.float32))
-    sc = jnp.asarray(rng.randn(c).astype(np.float32))
-    bi = jnp.asarray(rng.randn(c).astype(np.float32))
-
-    def ln_ref(x, sc, bi):
-        m = jnp.mean(x, -1, keepdims=True)
-        vv = jnp.var(x, -1, keepdims=True)
-        return (x - m) * jax.lax.rsqrt(vv + 1e-5) * sc[None, :] + bi
-
-    def ln_pallas(x, sc, bi):
-        return fused_layer_norm(x, sc, bi, block_rows=8, interpret=True)
-
-    lg_p = jax.jit(jax.grad(
-        lambda *a: jnp.sum(ln_pallas(*a) ** 2), argnums=(0, 1, 2)))
-    lg_r = jax.jit(jax.grad(
-        lambda *a: jnp.sum(ln_ref(*a) ** 2), argnums=(0, 1, 2)))
-    out["pallas_ln_step_s"] = round(best_of(lambda: lg_p(x, sc, bi)), 5)
-    out["pallas_ln_err"] = float(max(
-        [jnp.max(jnp.abs(ln_pallas(x, sc, bi) - ln_ref(x, sc, bi)))] +
-        [jnp.max(jnp.abs(a - b))
-         for a, b in zip(lg_p(x, sc, bi), lg_r(x, sc, bi))]))
-    return out
-
-
-def bench_costmodel(rank_queries=50):
-    """Kernel-selection cost model overhead + quality (ISSUE 13): wall
-    time to fit the model from the committed tools/tuned/ cache, the
-    per-query ranking cost over the interpret candidate space (this is
-    what every trace-time cache miss pays — it must be ≪ one probe),
-    and the in-sample measured-best-in-top-3 rate on the banked keys
-    (the tunecheck quality bar, gated here so a bench round always
-    carries a model verdict too)."""
-    from paddle_tpu.ops.pallas import autotune as at
-    from paddle_tpu.ops.pallas import costmodel as cmod
-
-    out = {}
-    cache = at.AutotuneCache(at.banked_cache_path("cpu"))
-    t0 = time.perf_counter()
-    model = at.fit_cost_model(cache, interpret=True)
-    # force the lazy per-segment fits so fit_s covers the regression —
-    # backend="cpu" targets the segments the banked rows actually live
-    # in (the same query trace-time dispatch issues); the default "-"
-    # segment has no rows and would time the analytic path instead
-    for op in at.CANDIDATES:
-        model.rank(op, at.DRY_SHAPES[op], backend="cpu",
-                   interpret=True)
-    out["costmodel_fit_s"] = round(time.perf_counter() - t0, 5)
-    out["costmodel_rows"] = model.rows_total()
-
-    shapes = [("softmax_with_cross_entropy", (48, 320)),
-              ("adam", (12345,)), ("layer_norm", (96, 192)),
-              ("fused_mlm_head_loss", (40, 384))]
-    t0 = time.perf_counter()
-    for i in range(rank_queries):
-        op, shape = shapes[i % len(shapes)]
-        model.rank(op, shape, backend="cpu", interpret=True)
-    out["costmodel_rank_us"] = round(
-        (time.perf_counter() - t0) / rank_queries * 1e6, 2)
-
-    hits, judged = cmod.measured_best_in_topk(cache, model=model)
-    out["costmodel_top3_rate"] = round(hits / judged, 4) if judged \
-        else 0.0
-    out["costmodel_keys_judged"] = judged
-    return out
 
 
 def bench_transport(roundtrips=200, gathers=20):
@@ -1503,8 +1334,6 @@ def run_all(rounds_dir=None):
                      ("cache_hit", bench_cache_hit),
                      ("quantized_step", bench_quantized_step),
                      ("feed", bench_feed),
-                     ("pallas", bench_pallas),
-                     ("costmodel", bench_costmodel),
                      ("pipeline", bench_pipeline),
                      ("pp_recut", bench_pp_recut),
                      ("buddy", bench_buddy),

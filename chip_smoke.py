@@ -269,7 +269,7 @@ def kernels(run):
         "\n".join("--- %s: %s" % (k, json.dumps(c, indent=1))
                   for k, c in failed.items())))
     # per check: its max error relative to the oracle's range
-    return dict(interpret=result["interpret"],
+    return dict(interpret=result["interpret"], checks=len(result["checks"]),
                 max_rel_err={k: c["max_rel_err"]
                              for k, c in result["checks"].items()})
 

@@ -183,37 +183,6 @@ class BuildStrategy(object):
         # gradients below this element count ride the exact full-width
         # sync (sub-block payloads cost MORE quantized); None = one block
         self.quantize_min_size = None
-        # Pallas kernel dispatch (ops/pallas): ops named here trace
-        # through the fused Pallas kernels — e.g. use_pallas =
-        # {"softmax_with_cross_entropy", "adam", "layer_norm"} — with
-        # per-shape XLA fallback when a shape cannot tile. Part of the
-        # compile-cache token: toggling re-lowers the step.
-        self.use_pallas = frozenset()
-        # autotune-cache source for the Pallas block configs: a JSON
-        # path or an ops.pallas.autotune.AutotuneCache (tools/autotune.py
-        # writes it). None = under kernel_policy "auto", the committed
-        # per-backend cache tools/tuned/{backend}.json when it exists;
-        # otherwise kernel-default block sizes everywhere.
-        self.pallas_tune_cache = None
-        # ONE front door for kernel selection (ISSUE 13), replacing the
-        # three independent knobs (use_pallas / pallas_tune_cache /
-        # per-op quant attrs — all still honored as overrides):
-        #   "auto"   -- resolve XLA vs Pallas(config) vs quantized
-        #               variant PER CALL SITE at trace time: banked
-        #               measured verdicts first (mesh-exact, then the
-        #               topology-agnostic key), cost-model-predicted
-        #               configs on a cache miss. Engages for the ops in
-        #               use_pallas (resolving the banked in-repo cache
-        #               when none is given); with use_pallas empty it
-        #               engages ALL Pallas-backed ops only when an
-        #               EXPLICIT pallas_tune_cache says the operator
-        #               has verdicts to apply — no signal, no change.
-        #   "xla"    -- force every op onto its XLA lowering (kills
-        #               use_pallas for this compile).
-        #   "pallas" -- route all Pallas-backed ops (or the use_pallas
-        #               subset) through their kernels, cache-informed.
-        # Part of the compile-cache token: flipping policy re-lowers.
-        self.kernel_policy = "auto"
         # Pipeline parallelism (reference PipelineOptimizer/section_worker,
         # TPU-native): pp_stages=K cuts the traced Program at its
         # pp_stage stamps (or an even op-count auto-cut when unstamped)
@@ -302,7 +271,7 @@ class CompilePlan(object):
     """How a (program, strategy) pair lowers: trace -> cut -> schedule ->
     jit. Retires the old single-jit assumption: the executor consults
     the plan's ``kind`` to route the step build, and ``token`` (mesh
-    axes + quantize/pallas knobs + pp cut + schedule) keys its compile
+    axes + quantize knobs + pp cut + schedule) keys its compile
     cache, so toggling the cut or schedule re-lowers while repeat runs
     hit the cached executable.
 
@@ -415,56 +384,8 @@ class CompiledProgram(object):
         return self
 
     # ------------------------------------------------------------------
-    def _kernel_policy(self):
-        bs = self._build_strategy
-        from ..ops import pallas_dispatch as pd
-        policy = getattr(bs, "kernel_policy", "auto") or "auto"
-        if policy not in pd.KERNEL_POLICIES:
-            raise ValueError(
-                "kernel_policy must be one of %r, got %r"
-                % (list(pd.KERNEL_POLICIES), policy))
-        return policy
-
-    def _resolve_tune(self):
-        """The EFFECTIVE tuned-cache source of this compile: the
-        strategy's explicit pallas_tune_cache, or — under kernel_policy
-        "auto" with Pallas ops engaged — the committed per-backend
-        cache tools/tuned/{backend}.json when it exists (how CI, bench
-        rounds and serving replicas share one set of verdicts without
-        per-job plumbing). Returns a path/cache-object or None; used by
-        BOTH the dispatch-scope build and the compile-cache token, so
-        the executable can never outlive the cache it baked in."""
-        bs = self._build_strategy
-        tune = getattr(bs, "pallas_tune_cache", None)
-        if tune is None and self._kernel_policy() == "auto" and \
-                getattr(bs, "use_pallas", None):
-            from ..ops.pallas.autotune import banked_cache_path
-            path = banked_cache_path(jax.default_backend())
-            if os.path.exists(path):
-                tune = path
-        return tune
-
     def _cache_token(self):
         bs = self._build_strategy
-        tune = self._resolve_tune()
-        if tune is not None:
-            # identity = path + file stat: re-running tools/autotune.py
-            # into the same file must re-lower in a live process (a
-            # stale executable would keep the old block configs)
-            path = str(getattr(tune, "path", tune))
-            try:
-                st = os.stat(path)
-                tune_tok = (path, st.st_mtime_ns, st.st_size)
-            except OSError:
-                tune_tok = (path, None, None)
-        else:
-            tune_tok = None
-        # the selection layer joins the token too: flipping
-        # kernel_policy between compiles, or changing the cost model /
-        # candidate space across an upgrade, must re-lower — a stale
-        # jitted program would keep the other policy's kernels
-        from ..ops.pallas.autotune import selection_fingerprint
-        sel_tok = (self._kernel_policy(), selection_fingerprint())
         return (tuple(sorted((bs.mesh_axes or {}).items())), bs.data_axis,
                 getattr(bs, "collective_timeout_s", None),
                 (getattr(bs, "quantize_collectives", False),
@@ -472,12 +393,6 @@ class CompiledProgram(object):
                  getattr(bs, "quantize_bits", 8),
                  getattr(bs, "quantize_min_size", None),
                  getattr(bs, "quantize_merge_sync", False)),
-                # Pallas dispatch is baked into the traced step: the op
-                # set, the tuning-cache identity AND the selection
-                # layer (policy + cost-model fingerprint) must key the
-                # executable
-                (tuple(sorted(getattr(bs, "use_pallas", ()) or ())),
-                 tune_tok, sel_tok),
                 # the pipeline cut/schedule selects a whole different
                 # lowering — toggling pp_stages or the schedule must
                 # re-lower, never reuse a single-jit executable
@@ -727,66 +642,6 @@ class CompiledProgram(object):
             return (head, out[1]) + tail
 
         return shard_map_unchecked(quant_step, mesh, in_specs, out_specs)
-
-    # -- Pallas kernel dispatch -------------------------------------------
-    def _pallas_ctx(self, mesh):
-        """Build the per-compile PallasConfig (the KernelChoice layer's
-        trace-time state), or None when this compile routes nothing
-        through Pallas. The config carries the mesh axes + backend so
-        the autotune cache is consulted under the same key the sweep
-        wrote; under kernel_policy "auto" it additionally carries the
-        cost model fitted from that cache's banked rows, so a
-        never-swept shape resolves to a PREDICTED config instead of the
-        hardcoded kernel default."""
-        bs = self._build_strategy
-        from ..ops import pallas_dispatch as pd
-        policy = self._kernel_policy()
-        ops = frozenset(getattr(bs, "use_pallas", ()) or ())
-        if policy == "xla":
-            return None
-        if not ops:
-            if policy == "pallas" or (
-                    policy == "auto" and
-                    getattr(bs, "pallas_tune_cache", None) is not None):
-                ops = frozenset(pd.PALLAS_OPS)
-            else:
-                return None
-        tune = self._resolve_tune()
-        if tune is not None and not hasattr(tune, "lookup"):
-            from ..ops.pallas.autotune import AutotuneCache
-            tune = AutotuneCache(str(tune))
-        try:
-            backend = next(iter(mesh.devices.flat)).platform
-        except Exception:  # pragma: no cover - exotic mesh
-            backend = jax.default_backend()
-        model = None
-        if policy == "auto":
-            model = self._cost_model(tune, backend)
-        return pd.PallasConfig(ops, tuning=tune,
-                               mesh_axes=dict(bs.mesh_axes or {}),
-                               backend=backend, cost_model=model,
-                               policy=policy)
-
-    def _cost_model(self, tune, backend):
-        """The fitted cost model for this compile, memoized per
-        (cache identity, backend): refitting reads and regresses the
-        whole banked file, so repeat compiles against an unchanged
-        cache reuse the fit."""
-        from ..ops.pallas.autotune import fit_cost_model
-        path = None if tune is None else str(getattr(tune, "path", tune))
-        try:
-            st = os.stat(path) if path else None
-            ident = (path, None if st is None else
-                     (st.st_mtime_ns, st.st_size), backend)
-        except OSError:
-            ident = (path, None, backend)
-        memo = getattr(self, "_cm_memo", None)
-        if memo is not None and memo[0] == ident:
-            return memo[1]
-        model = fit_cost_model(tune,
-                               interpret=backend != "tpu")
-        self._cm_memo = (ident, model)
-        return model
 
     # -- pipeline lowering -------------------------------------------------
     def _build_pp_step(self, program, cplan, fetch_names, micro_shapes,
@@ -1076,9 +931,7 @@ class CompiledProgram(object):
         watchdog. With quantize_collectives on, the fn is first lowered
         through shard_map with quantized gradient sync; the per-step wire
         accounting (static, accumulated at trace time) is recorded per
-        dispatch (x window length for run_steps windows). With use_pallas
-        set, the trace runs inside the Pallas dispatch scope so the wired
-        op kernels route to their fused implementations.
+        dispatch (x window length for run_steps windows).
 
         qctx: "auto" builds the QuantizedSyncContext here and wraps fn in
         the dp shard_map; a caller that already lowered its own shard_map
@@ -1089,16 +942,6 @@ class CompiledProgram(object):
             if qctx is not None:
                 fn = self._quantized_fn(fn, mesh, state_sh, feed_sh,
                                         out_sh, qctx)
-        pctx = self._pallas_ctx(mesh)
-        if pctx is not None:
-            from ..ops import pallas_dispatch as pd
-            inner = fn
-
-            def fn(state_tuple, feed_tuple, _inner=inner):
-                # the scope only matters while jit TRACES _inner; entering
-                # it per call is a few thread-local writes
-                with pd.scope(pctx):
-                    return _inner(state_tuple, feed_tuple)
         jitted = jax.jit(fn, in_shardings=(state_sh, feed_sh),
                          out_shardings=out_sh, donate_argnums=(0,))
         timeout_s = getattr(self._build_strategy, "collective_timeout_s",

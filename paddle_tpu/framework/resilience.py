@@ -221,7 +221,6 @@ def clear_events():
     clear_bytes()
     clear_router()
     clear_exec()
-    clear_kernel_choice()
     clear_analysis()
     clear_buddy_gens()
 
@@ -337,35 +336,6 @@ def record_buddy_fetch_ms(ms):
 def buddy_fetch_ms():
     with _BUDDY_P2P_LOCK:
         return _BUDDY_P2P.get("fetch_ms")
-
-
-# Trace-time kernel-selection accounting (ops.pallas_dispatch.choose):
-# one increment per call-site decision at COMPILE rate, so cumulative
-# process counters (not events) keyed (op, impl, source) — "is the
-# fleet actually running the tuned/predicted kernels it thinks it is"
-# becomes a scrapeable series instead of a log grep.
-_KCHOICE = {}
-_KCHOICE_LOCK = threading.Lock()
-
-
-def record_kernel_choice(op, impl, source):
-    """Count one trace-time kernel decision (see pallas_dispatch.
-    KernelChoice): exported by :func:`metrics` as
-    ``<prefix>_kernel_choice_total{op=,impl=,source=}``."""
-    with _KCHOICE_LOCK:
-        k = (str(op), str(impl), str(source))
-        _KCHOICE[k] = _KCHOICE.get(k, 0) + 1
-
-
-def kernel_choice_totals():
-    """Snapshot ``{(op, impl, source): count}``."""
-    with _KCHOICE_LOCK:
-        return dict(_KCHOICE)
-
-
-def clear_kernel_choice():
-    with _KCHOICE_LOCK:
-        _KCHOICE.clear()
 
 
 # Program-verifier accounting (framework/analysis.py): one increment per
@@ -901,15 +871,6 @@ def metrics(event_list=None, by_host=False):
             counters.append(
                 {"name": "%s_%s_bytes_total" % (METRIC_PREFIX, ch),
                  "labels": {"kind": kind}, "value": tot[kind]})
-    # trace-time kernel-selection decisions (pallas_dispatch.choose):
-    # cumulative process counters like the byte pairs — emitted only
-    # once a compile made a decision, so pallas-less jobs export
-    # nothing new
-    for (op, impl, source), n in sorted(kernel_choice_totals().items()):
-        counters.append(
-            {"name": METRIC_PREFIX + "_kernel_choice_total",
-             "labels": {"op": op, "impl": impl, "source": source},
-             "value": n})
     # program-verifier diagnostics (framework/analysis.py): cumulative
     # per-(pass, severity) counters — emitted only once a verification
     # produced diagnostics, so clean jobs export nothing new

@@ -49,11 +49,9 @@ def fused_mlm_head_loss(hidden, weight, label, bias=None,
     MXU trick).
 
     Without ``token_weight`` the result is the per-token loss ``(T, 1)``:
-    the XLA lowering is the matmul + CE chain (wiring a model head
-    through it is loss-curve-neutral) and holds the logits from its
-    forward to its backward;
-    ``BuildStrategy.use_pallas={"fused_mlm_head_loss"}`` routes it to the
-    Pallas kernel (ops/pallas/blockwise_ce), where they skip HBM.
+    the lowering is the matmul + CE chain (wiring a model head through
+    it is loss-curve-neutral) and holds the logits from its forward to
+    its backward.
 
     With ``token_weight`` ``(T, 1)`` float32 (no gradient flows to it:
     ``mask / (Σ mask + ε)`` for a masked mean) the result is the scalar

@@ -162,11 +162,10 @@ def bert_encoder(src_ids, position_ids, sentence_ids, input_mask, cfg,
     return x, pooled
 
 
-# The tied-embedding vocab projection now lives INSIDE
+# The tied-embedding vocab projection lives INSIDE
 # layers.fused_mlm_head_loss (cast_bf16= keeps the bf16-matmul-with-f32-
 # accumulation MXU trick): the (preds x vocab) logits tensor is an op-
-# internal detail, which is what lets the Pallas blockwise kernel keep
-# it out of HBM entirely under BuildStrategy.use_pallas.
+# internal detail.
 
 
 def bert_pretrain_program(cfg, batch_size, seq_len, max_preds_per_seq=20,
@@ -205,9 +204,8 @@ def bert_pretrain_program(cfg, batch_size, seq_len, max_preds_per_seq=20,
             bias_attr=ParamAttr(name="mask_lm_trans_ln_b"))
         # decode with tied word embedding (reference: weight sharing),
         # fused with the CE: the (preds, vocab) logits exist only inside
-        # fused_mlm_head_loss — under use_pallas the blockwise kernel
-        # keeps them out of HBM in fwd AND bwd; the XLA fallback is the
-        # same matmul(+bias)+CE math as the old unfused chain
+        # fused_mlm_head_loss, whose per-token form is the same
+        # matmul(+bias)+CE math as the unfused chain
         word_emb = main.global_block().var("word_embedding")
         mlm_bias = layers.create_parameter(
             [cfg.vocab_size], "float32", name="mask_lm_out_fc.b_0",

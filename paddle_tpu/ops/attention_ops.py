@@ -6,10 +6,6 @@ TPU-native: one fused op so XLA keeps QK^T / softmax / PV in registers, plus
 a Pallas flash-attention path (ops/pallas/) for long sequences that tiles the
 computation through VMEM without materializing the (T,T) scores in HBM.
 """
-import functools
-
-import os
-
 import jax
 import jax.numpy as jnp
 
@@ -32,22 +28,9 @@ def _sdpa_xla(q, k, v, mask, scale, causal, window=None):
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 
 
-def _sp_routable(impl, q, k, mask, n):
-    """Whether this call CAN run sequence-parallel over an n-way axis —
-    the env hint must stay a hint: shapes that don't shard keep their
-    auto fallback instead of raising inside shard_map."""
-    if q.shape[-2] % n or k.shape[-2] % n or q.shape[-2] != k.shape[-2]:
-        return False
-    if impl == "ulysses":
-        if q.shape[1] % n:
-            return False
-        if mask is not None:
-            ax = mask.ndim - 1 if mask.shape[-2] == 1 else mask.ndim - 2
-            return mask.shape[ax] % n == 0
-        return True
-    if mask is not None:
-        return mask.shape[-2] == 1 and mask.shape[-1] % n == 0
-    return True
+#: what the op's `impl` attr (`layers.fused_attention(impl=)`, a model
+#: config's `attn_impl`) may say
+IMPLS = ("auto", "flash", "xla", "ring", "ulysses")
 
 
 @register_op("scaled_dot_product_attention")
@@ -59,31 +42,15 @@ def _sdpa(ctx, ins, attrs):
         scale = 1.0 / (q.shape[-1] ** 0.5)
     causal = attrs.get("causal", False)
     window = attrs.get("window", None)
-    from .pallas.flash_attention import check_call
+    from .pallas.flash_attention import (attention_path, check_call,
+                                         flash_attention)
     check_call(q.shape, k.shape, v.shape, causal, window)
     plain = (window is None and q.shape[1] == k.shape[1]
              and v.shape[-1] == q.shape[-1])
     impl = attrs.get("impl", "auto")
-    if impl == "auto":
-        # perf escape hatch: force a path fleet-wide. For ring/ulysses
-        # the env value is a HINT, not a hard override — ops that can't
-        # run sequence-parallel (additive mask, no sp mesh installed)
-        # keep their auto fallback instead of raising.
-        env_impl = os.environ.get("PADDLE_TPU_ATTN_IMPL", "auto")
-        if env_impl in ("ring", "ulysses"):
-            from ..distributed.mesh import get_mesh
-            m = get_mesh()
-            if m is not None and attrs.get("sp_axis", "sp") in m.axis_names:
-                n = m.shape[attrs.get("sp_axis", "sp")]
-                if plain and _sp_routable(env_impl, q, k, mask, n):
-                    impl = env_impl
-        else:
-            impl = env_impl
-    if impl == "auto" and q.shape[-2] * k.shape[-2] <= 256 * 256:
-        # short sequences: XLA's fused attention beats the tiled kernel
-        # (measured 1026 vs 912 samples/s on BERT-base seq128, v5e) — the
-        # (T,T) tile only pays for itself once it stops fitting in VMEM
-        impl = "xla"
+    if impl not in IMPLS:
+        raise ValueError("fused_attention(impl=%r): impl is one of %s"
+                         % (impl, ", ".join(repr(i) for i in IMPLS)))
     if impl in ("ring", "ulysses"):
         if not plain:
             raise ValueError(
@@ -115,10 +82,15 @@ def _sdpa(ctx, ins, attrs):
         return {"Out": ulysses_attention(q, k, v, mask=mask, mesh=mesh,
                                          axis_name=axis, causal=causal,
                                          scale=scale)}
-    if impl in ("auto", "flash"):
-        # the shape rules above (and flash_attention's own tile guards)
-        # choose the path; an error from the chosen kernel propagates
-        from .pallas.flash_attention import flash_attention
-        return {"Out": flash_attention(q, k, v, mask=mask, scale=scale,
-                                       causal=causal, window=window)}
+    if impl != "xla":
+        # `attention_path` decides from the call's shapes. Its "short" rule
+        # is this op's XLA attention; where it finds no tile,
+        # `flash_attention` runs its own XLA body (two bodies whose
+        # `precision` arguments differ: ROADMAP, named debt)
+        from .pallas.interpret import default_interpret
+        if attention_path(q.shape, k.shape, v.shape, q.dtype, causal, window,
+                          default_interpret(),
+                          auto=impl == "auto").why != "short":
+            return {"Out": flash_attention(q, k, v, mask=mask, scale=scale,
+                                           causal=causal, window=window)}
     return {"Out": _sdpa_xla(q, k, v, mask, scale, causal, window)}

@@ -16,7 +16,6 @@ from jax import lax
 
 from .registry import register_op
 from . import head_loss
-from . import pallas_dispatch as _pd
 from ..framework.dtypes import to_jax_dtype
 
 
@@ -197,43 +196,11 @@ def _batch_norm(ctx, ins, attrs):
             "SavedVariance": lax.stop_gradient(saved_var)}
 
 
-def _pallas_layer_norm(x, ins, eps, begin, cfg):
-    """BuildStrategy.use_pallas={"layer_norm"}: fused one-pass Pallas
-    fwd+bwd over the collapsed (rows, cols) problem. Returns the op's
-    output dict, or None when the autotune cache routed this shape back
-    to XLA / the shape cannot tile — caller keeps the XLA lowering.
-    Mean/Variance are emitted as a standalone (cheap, per-row) XLA
-    expression that DCEs away when unused, exactly like the XLA path's
-    values."""
-    from .pallas.layer_norm import fused_layer_norm
-    rows = int(np.prod(x.shape[:begin], dtype=np.int64)) if begin else 1
-    cols = int(np.prod(x.shape[begin:], dtype=np.int64))
-    x2 = x.reshape(rows, cols)
-    impl, tuned = _pd.choose(cfg, "layer_norm", x2.shape, x2.dtype)
-    if impl == "xla":
-        return None
-    y = fused_layer_norm(
-        x2, ins["Scale"][0].reshape(cols), ins["Bias"][0].reshape(cols),
-        eps=eps, interpret=cfg.interpret, **(tuned or {}))
-    if y is None:
-        return None
-    xf = x.astype(jnp.float32)
-    axes = tuple(range(begin, x.ndim))
-    return {"Y": y.reshape(x.shape).astype(x.dtype),
-            "Mean": jnp.mean(xf, axis=axes),
-            "Variance": jnp.var(xf, axis=axes)}
-
-
 @register_op("layer_norm")
 def _layer_norm(ctx, ins, attrs):
     x = _x(ins)
     eps = attrs.get("epsilon", 1e-5)
     begin = attrs.get("begin_norm_axis", 1)
-    cfg = _pd.enabled("layer_norm")
-    if cfg is not None and ins.get("Scale") and ins.get("Bias"):
-        out = _pallas_layer_norm(x, ins, eps, begin, cfg)
-        if out is not None:
-            return out
     axes = tuple(range(begin, x.ndim))
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=axes, keepdims=True)
@@ -325,52 +292,10 @@ def _cross_entropy(ctx, ins, attrs):
     return {"Y": loss}
 
 
-def _pallas_softmax_ce(logits, lbl, attrs, cfg):
-    """BuildStrategy.use_pallas={"softmax_with_cross_entropy"}: the loss
-    streams over vocab blocks (ops/pallas/blockwise_ce) — no
-    [tokens, vocab] log-softmax/softmax intermediate in fwd or bwd.
-    Returns the per-token loss (lbl.shape + (1,), f32), or None when
-    the autotune cache routed this shape to XLA / it cannot tile."""
-    from .pallas.blockwise_ce import blockwise_softmax_cross_entropy
-    v = logits.shape[-1]
-    l2 = logits.reshape(-1, v)
-    impl, tuned = _pd.choose(cfg, "softmax_with_cross_entropy",
-                             l2.shape, l2.dtype)
-    if impl == "xla":
-        return None
-    loss = blockwise_softmax_cross_entropy(
-        l2, lbl.reshape(-1).astype(jnp.int32), interpret=cfg.interpret,
-        **(tuned or {}))
-    if loss is None:
-        return None
-    loss = loss.reshape(lbl.shape)[..., None]
-    ignore = attrs.get("ignore_index", -100)
-    return jnp.where(lbl[..., None] == ignore, 0.0, loss)
-
-
 @register_op("softmax_with_cross_entropy", nondiff=("Label",))
 def _softmax_with_cross_entropy(ctx, ins, attrs):
     logits, label = ins["Logits"][0], ins["Label"][0]
     axis = attrs.get("axis", -1)
-    if not attrs.get("soft_label", False):
-        lbl = label
-        squeeze = lbl.ndim == logits.ndim and lbl.shape[axis] == 1
-        if squeeze:
-            lbl = jnp.squeeze(lbl, axis=axis)
-        cfg = _pd.enabled("softmax_with_cross_entropy")
-        if cfg is not None and logits.ndim >= 2 and \
-                axis in (-1, logits.ndim - 1) and \
-                lbl.ndim == logits.ndim - 1:
-            loss = _pallas_softmax_ce(logits, lbl, attrs, cfg)
-            if loss is not None:
-                # Softmax is a STANDALONE XLA expression: when the
-                # output is unused (the MLM-loss case) XLA DCEs it and
-                # only the blockwise kernels remain — same pattern as
-                # the flash-attention mask cotangent
-                logp = jax.nn.log_softmax(
-                    logits.astype(jnp.float32), axis=axis)
-                return {"Softmax": jnp.exp(logp).astype(logits.dtype),
-                        "Loss": loss.astype(logits.dtype)}
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=axis)
     if attrs.get("soft_label", False):
         loss = -jnp.sum(label * logp, axis=axis, keepdims=True)
@@ -389,9 +314,9 @@ def _softmax_with_cross_entropy(ctx, ins, attrs):
 
 
 def _record_head_plan(hidden, weight, form, cast_bf16):
-    """One `head.plan` record a lowering of the head's XLA path, while
-    obs is on: which form engaged, and in how many blocks of how many
-    rows the ``[tokens, vocab]`` logits are held."""
+    """One `head.plan` record a lowering of the head, while obs is on:
+    which form engaged, and in how many blocks of how many rows the
+    ``[tokens, vocab]`` logits are held."""
     from ..framework import obs
     if obs.enabled():
         now, t = obs.now(), hidden.shape[0]
@@ -408,14 +333,10 @@ def _fused_mlm_head_loss(ctx, ins, attrs):
     (+ Bias)`` -> ``Loss``, in one of two forms, chosen by what the
     program passes.
 
-    Per-token form (no ``TokenWeight``): Loss is (T, 1). Behind
-    ``BuildStrategy.use_pallas={"fused_mlm_head_loss"}`` the op
-    routes to ops/pallas/blockwise_ce.fused_mlm_head_loss and the
-    ``[tokens, vocab]`` logits NEVER materialize in fwd or bwd; the XLA
-    fallback mirrors the matmul + softmax_with_cross_entropy chain it
-    replaces in models/bert (same math, so the wiring is
-    loss-curve-neutral with Pallas off), and holds the logits from its
-    forward to its backward.
+    Per-token form (no ``TokenWeight``): Loss is (T, 1), by the matmul
+    + softmax_with_cross_entropy chain the op replaced in models/bert
+    (same math); it holds the ``[tokens, vocab]`` logits from its forward
+    to its backward.
 
     Weighted form (``TokenWeight (T, 1)``, no gradient): Loss is the
     scalar ``Σ_t w_t · ce_t``, shape [1]. Its cotangent is a scalar, so
@@ -437,44 +358,14 @@ def _fused_mlm_head_loss(ctx, ins, attrs):
     if cast_bf16:
         h = h.astype(jnp.bfloat16)
         w = w.astype(jnp.bfloat16)
-    # also honor use_pallas={"softmax_with_cross_entropy"}: configs that
-    # enabled the blockwise-CE kernel for the (pre-PR-10, unfused) model
-    # heads keep their Pallas routing now that the heads emit this op —
-    # the fusion is strictly stronger than what they asked for. (Their
-    # autotune entries keyed under the old op name simply miss: default
-    # blocks apply until a re-sweep.)
-    cfg = _pd.enabled("fused_mlm_head_loss") or \
-        _pd.enabled("softmax_with_cross_entropy")
-    if cfg is not None and hidden.ndim == 2 and lbl.ndim == 1:
-        from .pallas.blockwise_ce import fused_mlm_head_loss
-        impl, tuned = _pd.choose(cfg, "fused_mlm_head_loss",
-                                 (h.shape[0], weight.shape[0]), h.dtype)
-        if impl == "pallas_q":
-            # the banked QUANTIZED variant: bf16-cast projection inputs
-            # with f32 accumulation (the cast_bf16 trick, selected per
-            # call site by a measured sweep verdict instead of a model
-            # attr)
-            h = h.astype(jnp.bfloat16)
-            w = w.astype(jnp.bfloat16)
-        if impl != "xla":
-            loss = fused_mlm_head_loss(
-                h, w.T, lbl.astype(jnp.int32),
-                bias=None if bias is None else bias.astype(jnp.float32),
-                interpret=cfg.interpret, **(tuned or {}))
-            if loss is not None:
-                loss = loss[:, None].astype(jnp.float32)
-                if token_weight is not None:
-                    loss = jnp.sum(token_weight * loss).reshape((1,))
-                return {"Loss": loss}
     if token_weight is not None:
         _record_head_plan(hidden, weight, "weighted", cast_bf16)
         loss = head_loss.weighted_head_loss(
             hidden, weight, bias, lbl, token_weight, cast_bf16)
         return {"Loss": loss.reshape((1,))}
     _record_head_plan(hidden, weight, "per_token", cast_bf16)
-    # XLA fallback: the exact chain the models used to emit — matmul
-    # (transpose_y, f32 accumulation under cast_bf16) + bias +
-    # log_softmax gather
+    # the exact chain the models used to emit — matmul (transpose_y, f32
+    # accumulation under cast_bf16) + bias + log_softmax gather
     logits = jnp.matmul(h, w.T,
                         preferred_element_type=jnp.float32) \
         .astype(jnp.float32)

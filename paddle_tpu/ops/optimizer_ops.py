@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from .registry import register_op
-from . import pallas_dispatch as _pd
 
 
 def _p(ins, slot):
@@ -55,24 +54,6 @@ def _lars_momentum(ctx, ins, attrs):
     return {"ParamOut": p - v_new, "VelocityOut": v_new}
 
 
-def _pallas_adam(p, gf, m1, m2, lr, b1p, b2p, b1, b2, eps, cfg):
-    """BuildStrategy.use_pallas={"adam"}: the whole m/v/param
-    read-modify-write in ONE Pallas pass per parameter instead of the
-    elementwise XLA chain below. Returns (p_new, m1_new, m2_new) or None
-    when the autotune cache routed this shape to XLA / the parameter is
-    too small to tile — caller keeps the XLA chain."""
-    from .pallas.fused_adam import fused_adam
-    # keyed on the FLATTENED size — the kernel tiles the flat lane
-    # layout, and tools/autotune.py sweeps flat shapes, so a (64,128)
-    # param and an (8192,) sweep meet on the same cache key
-    impl, tuned = _pd.choose(cfg, "adam", (int(p.size),), p.dtype)
-    if impl == "xla":
-        return None
-    lr_t = lr * jnp.sqrt(1 - b2p) / (1 - b1p)
-    return fused_adam(p, gf, m1, m2, lr_t, beta1=b1, beta2=b2,
-                      eps=eps, interpret=cfg.interpret, **(tuned or {}))
-
-
 @register_op("adam")
 def _adam(ctx, ins, attrs):
     p, g = _p(ins, "Param"), _p(ins, "Grad")
@@ -83,17 +64,6 @@ def _adam(ctx, ins, attrs):
     b1, b2 = attrs.get("beta1", 0.9), attrs.get("beta2", 0.999)
     eps = attrs.get("epsilon", 1e-8)
     gf = g.astype(jnp.float32)
-    cfg = _pd.enabled("adam")
-    if cfg is not None and not attrs.get("lazy_mode"):
-        fused = _pallas_adam(p, gf, m1, m2, lr, b1p, b2p, b1, b2, eps,
-                             cfg)
-        if fused is not None:
-            return {"ParamOut": fused[0], "Moment1Out": fused[1],
-                    "Moment2Out": fused[2],
-                    "Beta1PowOut":
-                        (b1p * b1).reshape(ins["Beta1Pow"][0].shape),
-                    "Beta2PowOut":
-                        (b2p * b2).reshape(ins["Beta2Pow"][0].shape)}
     m1n = b1 * m1 + (1 - b1) * gf
     m2n = b2 * m2 + (1 - b2) * gf * gf
     lr_t = lr * jnp.sqrt(1 - b2p) / (1 - b1p)

@@ -48,7 +48,7 @@ With n == 1, no window and Dv == D the kernels, tiles, index maps and
 VMEM request are the ones the plain causal call always had.
 """
 import functools
-import os
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -61,7 +61,7 @@ except Exception:  # pragma: no cover
     pltpu = None
     _HAS_TPU_PALLAS = False
 
-from .. import pallas_dispatch as pd
+from .interpret import default_interpret
 
 _NEG_INF = -1e30
 
@@ -750,29 +750,6 @@ def _flash_bwd(scale, causal, blocks, interpret, window, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _env_block(name):
-    """Parse a block-size override; '' counts as unset (same contract as
-    PADDLE_TPU_PALLAS_INTERPRET) and junk/too-small values count as unset
-    LOUDLY — a bad tuning knob must not silently route every attention
-    call to the XLA fallback via the auto-path try/except."""
-    raw = os.environ.get(name, "")
-    if not raw:
-        return None
-    try:
-        val = int(raw)
-    except ValueError:
-        val = -1
-    # must be a power of two >= 128: anything else either trips Mosaic's
-    # 128-lane block alignment or gets halved down by the divisibility
-    # loop until the size guards route EVERY call to the XLA fallback
-    if val < 128 or val & (val - 1):
-        import warnings
-        warnings.warn("%s=%r is not a power-of-two block size >= 128; "
-                      "ignored" % (name, raw))
-        return None
-    return val
-
-
 KERNELS = ("fwd", "bwd_dkv", "bwd_dq")
 
 
@@ -859,47 +836,79 @@ def _record_plan(q, k, v, causal, window, blocks):
                    **plan(q.shape, k.shape, v.shape, causal, window, blocks))
 
 
+class AttentionPath(NamedTuple):
+    """What `attention_path` returns: the path ("xla" or "flash"), under
+    "flash" each kernel's (block_q, block_k) in KERNELS' order, under
+    "xla" the rule that sent the call there."""
+    path: str
+    blocks: Optional[tuple]
+    why: Optional[str]
+
+
+def attention_path(q_shape, k_shape, v_shape, dtype, causal, window,
+                   interpret, auto=False, block_q=None, block_k=None):
+    """Which attention a call gets and with which tiles, from the call's
+    own arguments (Python ints and strings; nothing is traced): the one
+    place that decides it. `ops/attention_ops._sdpa` and `flash_attention`
+    both ask here, and `flash.plan` records the tiles it gave. A mask does
+    not enter: the kernels take a key mask or a (Tq, Tk) one as they are.
+
+    The rules, in order; the first that holds sends the call to XLA:
+      "short"    `auto` (the op's impl "auto") and Tq * Tk <= 256 * 256:
+                 XLA's fused attention beats the tiled kernel there
+                 (measured 1026 vs 912 samples/s on BERT-base seq128,
+                 v5e); an explicit "flash" skips this rule.
+      "no_keys"  causal with Tq > Tk: rows i < Tq - Tk see no key at all;
+                 only the XLA reference defines that edge (uniform over
+                 all-masked logits).
+      "no_tile"  a tile side under 8, or D % 8 or Dv % 8.
+      "lanes"    compiled (`interpret` false) with a tile side under 128:
+                 Mosaic wants the last two block dims 128-lane aligned
+                 (the stats block puts block_q on the lane dim).
+    Otherwise "flash", each kernel with `pick_blocks`' tile; an explicit
+    `block_q`/`block_k` replaces that side of all three."""
+    tq, tk, d, dv = q_shape[2], k_shape[2], q_shape[-1], v_shape[-1]
+    if auto and tq * tk <= 256 * 256:
+        return AttentionPath("xla", None, "short")
+    if causal and tq > tk:
+        return AttentionPath("xla", None, "no_keys")
+    blocks = []
+    for kernel in KERNELS:
+        bq, bk = pick_blocks(tq, tk, d, dtype, kernel, causal, window,
+                             None if dv == d else dv)
+        blocks.append((_fit(block_q or bq, tq), _fit(block_k or bk, tk)))
+    least = min(min(pair) for pair in blocks)
+    if least < 8 or d % 8 or dv % 8:
+        return AttentionPath("xla", None, "no_tile")
+    if not interpret and least < 128:
+        return AttentionPath("xla", None, "lanes")
+    return AttentionPath("flash", tuple(blocks), None)
+
+
 def flash_attention(q, k, v, mask=None, scale=1.0, causal=False,
                     block_q=None, block_k=None, interpret=None,
                     window=None):
     """Flash attention entry. q: (B,Hq,Tq,D), k: (B,Hkv,Tk,D), v:
     (B,Hkv,Tk,Dv) (the module docstring states the supported space).
     Falls back to interpret mode off-TPU so tests exercise the same
-    kernel, and to plain fused XLA attention when shapes are too small to
-    tile.
+    kernel, and to plain fused XLA attention where `attention_path` finds
+    no tile for the shape.
 
     Each kernel's tile comes from the call's shape (`pick_blocks`). An
-    explicit `block_q`/`block_k`, or PADDLE_TPU_FLASH_BLOCK_Q/_K where
-    set, replaces that side of all three kernels' tiles."""
-    if block_q is None:
-        block_q = _env_block("PADDLE_TPU_FLASH_BLOCK_Q")
-    if block_k is None:
-        block_k = _env_block("PADDLE_TPU_FLASH_BLOCK_K")
+    explicit `block_q`/`block_k` replaces that side of all three kernels'
+    tiles."""
     if interpret is None:
-        interpret = pd.default_interpret()
+        interpret = default_interpret()
     check_call(q.shape, k.shape, v.shape, causal, window)
-    tq, tk, d, dv = q.shape[2], k.shape[2], q.shape[-1], v.shape[-1]
-    if causal and tq > tk:
-        # rows i < tq - tk see no keys at all; only the XLA reference
-        # defines that edge (uniform over all-masked logits)
-        return _xla_attention(q, k, v, mask, scale, causal, window)
-    blocks = []
-    for kernel in KERNELS:
-        bq, bk = pick_blocks(tq, tk, d, q.dtype, kernel, causal, window,
-                             None if dv == d else dv)
-        blocks.append((_fit(block_q or bq, tq), _fit(block_k or bk, tk)))
-    least = min(min(pair) for pair in blocks)
-    if least < 8 or d % 8 or dv % 8:
-        return _xla_attention(q, k, v, mask, scale, causal, window)
-    if not interpret and least < 128:
-        # Mosaic wants the last-two block dims 128-lane aligned (the stats
-        # block puts block_q on the lane dim); sub-128 tiles are only
-        # exercised in interpret mode — on device route them to XLA.
+    path, blocks, _why = attention_path(
+        q.shape, k.shape, v.shape, q.dtype, causal, window, interpret,
+        block_q=block_q, block_k=block_k)
+    if path == "xla":
         return _xla_attention(q, k, v, mask, scale, causal, window)
     _record_plan(q, k, v, causal, window, blocks)
     return _flash(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                   None if mask is None else jnp.asarray(mask),
-                  scale, causal, tuple(blocks), interpret, window)
+                  scale, causal, blocks, interpret, window)
 
 
 def check_call(q_shape, k_shape, v_shape, causal, window):

@@ -48,7 +48,7 @@ except Exception:  # pragma: no cover
     pltpu = None
     _HAS_TPU_PALLAS = False
 
-from .. import pallas_dispatch as pd
+from .interpret import default_interpret
 
 _LANES = 128
 _VMEM_BUDGET = 24 * 2 ** 20     # what one backward grid step may hold
@@ -457,7 +457,7 @@ def selective_scan(x, delta, a, bm, c, d, chunk=None, interpret=None):
             "fit (B,T,E), (B,T,E), (E,N), (B,T,N), (B,T,N), (E,)"
             % (x.shape, delta.shape, a.shape, bm.shape, c.shape, d.shape))
     if interpret is None:
-        interpret = pd.default_interpret()
+        interpret = default_interpret()
         if interpret:
             return scan_xla(x, delta, a, bm, c, d)
     what = plan(x.shape, a.shape[1], x.dtype.itemsize, chunk)

@@ -48,9 +48,9 @@ def pytest_configure(config):
         "checkpoints, bench_micro perf gates)")
     config.addinivalue_line(
         "markers",
-        "pallas: Pallas kernel-library oracle batteries (blockwise CE / "
-        "fused MLM head, fused Adam, fused LayerNorm, autotune cache, "
-        "use_pallas dispatch) — interpret mode on CPU, tier-1-safe")
+        "pallas: Pallas kernel batteries (the kernels' names in the "
+        "traced step, the fused head op's wiring) — interpret mode on "
+        "CPU, tier-1-safe")
     config.addinivalue_line(
         "markers",
         "fleet: serving-fleet batteries (micro-batching router + "

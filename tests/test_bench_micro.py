@@ -59,16 +59,12 @@ def test_check_budgets_flags_violations():
 def test_budget_table_covers_the_contract():
     """The ISSUE-6 contract metrics are all gated (trace+lower, cache
     hit rate, quantized-vs-exact step wall time, byte ratio, feed
-    throughput) plus the ISSUE-7 pallas section (per-kernel step wall +
-    max abs error) and the ISSUE-8 transport/serving sections (round
+    throughput) plus the ISSUE-8 transport/serving sections (round
     latency, router p50/p99 + shed rate — the last two ROADMAP item 4
     slices)."""
     assert set(bench_micro.BUDGETS) == {
         "trace_lower_s", "cache_hit_rate", "exact_step_s",
         "quant_step_s", "collective_wire_ratio", "feed_samples_per_s",
-        "pallas_ce_step_s", "pallas_adam_step_s", "pallas_ln_step_s",
-        "pallas_ce_err", "pallas_adam_err", "pallas_ln_err",
-        "costmodel_fit_s", "costmodel_rank_us", "costmodel_top3_rate",
         "transport_roundtrip_ms", "transport_gather_ms",
         "transport_failover_ms",
         "serving_p50_ms", "serving_p99_ms", "serving_shed_rate",
@@ -228,27 +224,6 @@ def test_fail_on_drift_is_default_on(tmp_path, capsys):
     finally:
         bench_micro.run_all = real_run_all
     capsys.readouterr()
-
-
-def test_pallas_section_measures_all_three_kernels():
-    m = bench_micro.bench_pallas()
-    for kernel in ("ce", "adam", "ln"):
-        assert m["pallas_%s_step_s" % kernel] > 0
-        assert 0 <= m["pallas_%s_err" % kernel] < 1e-4
-
-
-def test_costmodel_section_gates_overhead_and_quality():
-    """ISSUE-13 satellite: the costmodel section reports fit wall and
-    per-rank-query cost against the COMMITTED banked cache (a model
-    query must be far below one sweep probe — that is the entire
-    pruning economics) plus the in-sample top-3 rate at the tunecheck
-    bar."""
-    m = bench_micro.bench_costmodel(rank_queries=10)
-    assert m["costmodel_rows"] > 0          # the committed cache fed it
-    assert 0 < m["costmodel_fit_s"] < 2.0
-    assert 0 < m["costmodel_rank_us"] < 20000.0
-    assert m["costmodel_keys_judged"] > 0
-    assert m["costmodel_top3_rate"] >= 0.8
 
 
 def _fake_round(rounds_dir, idx, metrics):

@@ -1,12 +1,12 @@
 """tools/codelint.py — the repo's own static-analysis gate (ISSUE 15).
 
 Rule 1 keeps the compile-cache-token bug class extinct (PR 6
-``quantize_min_size``, PR 13 ``kernel_policy``: a BuildStrategy knob
-steering lowering but missing from the token leaves stale executables
-live when the knob flips). Rule 2 catches free-floating locks in
-coordination code. Both must be GREEN on the repo, and both must be
-provably live — a synthetic violation injected into the source must be
-caught.
+``quantize_min_size``: a BuildStrategy knob steering lowering but missing
+from the token leaves stale executables live when the knob flips). Rule 2
+catches free-floating locks in coordination code. Rule 4 keeps the op
+modules off the environment (PR 29). All must be GREEN on the repo, and
+all must be provably live — a synthetic violation injected into the
+source must be caught.
 """
 import os
 import sys
@@ -35,14 +35,14 @@ def test_lint_sees_the_real_knobs():
     with open(codelint.COMPILER_PY) as f:
         tree = ast.parse(f.read())
     knobs = codelint._build_strategy_knobs(tree)
-    for expected in ("quantize_min_size", "kernel_policy", "pp_stages",
-                     "use_pallas", "verify_program"):
+    for expected in ("quantize_min_size", "pp_stages", "numeric_policy",
+                     "verify_program"):
         assert expected in knobs
     reads = codelint._knob_reads(tree, knobs)
-    # the two historic offenders are read on the lowering path AND in
-    # the token today — the exact configuration the lint certifies
+    # the historic offender is read on the lowering path AND in the
+    # token today — the exact configuration the lint certifies
     assert "quantize_min_size" in reads
-    assert "kernel_policy" in reads
+    assert "numeric_policy" in reads
 
 
 def test_synthetic_untokened_knob_read_is_caught():
@@ -179,3 +179,31 @@ def test_computed_failpoint_site_is_caught(tmp_path):
         "def f():\n"
         "    faultinject.hit('transport.send')\n")
     assert codelint.lint_failpoint_sites(paths=[str(q)]) == []
+
+
+def test_no_op_module_reads_the_environment():
+    assert codelint.lint_ops_environment() == []
+    # ... and the lint is not blind: it finds the three reads it allows
+    assert len(codelint.lint_ops_environment(allowlist={})) == 3
+
+
+@pytest.mark.parametrize("source", [
+    "import os\nIMPL = os.environ.get('PADDLE_TPU_ATTN_IMPL', 'auto')\n",
+    "import os\nBLOCK = os.environ['PADDLE_TPU_FLASH_BLOCK_Q']\n",
+    "import os\nBLOCK = os.getenv('PADDLE_TPU_FLASH_BLOCK_K')\n",
+    "from os import environ\nON = 'PADDLE_TPU_X' in environ\n",
+    "import os\ndef f(name):\n    return os.environ.get('PADDLE_' + name)\n",
+])
+def test_an_environment_read_in_an_op_module_is_caught(source):
+    v = codelint.lint_ops_environment(sources={"ops/mod.py": source})
+    assert len(v) == 1 and "reads the environment" in v[0]
+
+
+def test_an_allow_listed_environment_read_passes():
+    source = ("import os\n"
+              "def default_interpret():\n"
+              "    return os.environ.get('PADDLE_TPU_PALLAS_INTERPRET')\n")
+    assert codelint.lint_ops_environment(sources={"ops/mod.py": source}) \
+        == []
+    assert len(codelint.lint_ops_environment(
+        sources={"ops/mod.py": source}, allowlist={})) == 1

@@ -226,8 +226,8 @@ def test_tile_rule_follows_the_shape(monkeypatch):
     """One algorithm, parameters from the shape (the v5e sweep's winners,
     PERF.md PR 25): the forward takes the widest tile up to 1024x1024; the
     causal backward kernels a quarter of the sequence a side, between 512
-    and 1024; float32 stops at 512. Explicit blocks and the environment's
-    keep winning on their side of every kernel's tile."""
+    and 1024; float32 stops at 512. Explicit blocks keep winning on their
+    side of every kernel's tile."""
     def rule(t, causal=True, dtype="bfloat16", d=64):
         return tuple(fa.pick_blocks(t, t, d, dtype, kern, causal)
                      for kern in fa.KERNELS)
@@ -247,13 +247,12 @@ def test_tile_rule_follows_the_shape(monkeypatch):
     flash_attention(q, q, q, causal=True, interpret=True)
     flash_attention(q, q, q, causal=True, block_q=64, block_k=32,
                     interpret=True)
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_K", "128")
-    flash_attention(q, q, q, causal=True, interpret=True)
-    rule, explicit, env = seen
+    flash_attention(q, q, q, causal=True, block_k=128, interpret=True)
+    rule, explicit, one_side = seen
     assert rule == tuple(fa.pick_blocks(1024, 1024, 64, q.dtype, kern, True)
                          for kern in fa.KERNELS)
     assert explicit == ((64, 32),) * 3
-    assert env == tuple((bq, 128) for bq, _ in rule)
+    assert one_side == tuple((bq, 128) for bq, _ in rule)
 
 
 def _loss_grads(fn, args, w):

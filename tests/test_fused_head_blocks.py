@@ -396,13 +396,18 @@ def test_the_shape_rule_knows_both_forms():
 
 # ---------------------------------------------------------------------------
 # BERT's step (the per-token form, through `layers.mean`) is the parent
-# commit's: the digest of its jaxpr was taken there (PR 26). After a
-# deliberate change to what BERT lowers, print the new one with
+# commit's: the digest of its jaxpr was taken there (PR 26), less one dead
+# equation that PR 29 took out with the Pallas gate of
+# `softmax_with_cross_entropy` (`_:i32[4] = squeeze[dimensions=(1,)] kq`: the
+# next-sentence label, squeezed before the gate asked whether it was on;
+# 166,118 characters with it). jit drops dead equations, and the lowered
+# StableHLO of this step is the parent's to the byte (PERF.md, PR 29). After
+# a deliberate change to what BERT lowers, print the new one with
 # `python tests/test_fused_head_blocks.py` and say in PERF.md why.
 # ---------------------------------------------------------------------------
 
-BERT_STEP = {"sha256": "5a8092c86eb70f73b324421fc0ac7b243f6d0e3647fe67f990cab595ea"
-                       "8506bf", "chars": 166118}
+BERT_STEP = {"sha256": "8e83920bc2ca135b0fa0e7460e2a526e90f0c9dc46196c214b92bfa7b5"
+                       "96c235", "chars": 166075}
 
 
 def bert_step_text():

@@ -2,7 +2,7 @@
 """Repo-specific AST lints for the bug classes the generic linters miss.
 
 Rule 1 — **compile-cache-token completeness** (the PR 6
-``quantize_min_size`` / PR 13 ``kernel_policy`` bug class): every
+``quantize_min_size`` bug class): every
 BuildStrategy knob that the lowering paths under
 ``framework/compiler.py`` / ``framework/trace.py`` READ must be folded
 into ``CompiledProgram._cache_token`` (directly or via a helper the
@@ -23,6 +23,13 @@ guard): every ``faultinject.hit("...")`` call site must name its site
 as a string LITERAL that appears in ``framework/faultinject.py``'s
 ``SITES`` catalog. A typo'd or uncatalogued site string would parse,
 arm, and then silently never fire — a chaos test that tests nothing.
+
+Rule 4 — **no op reads the environment**: what an op lowers to is decided
+by the call's own arguments (its inputs' shapes and dtypes, its attrs), so
+no module under ``paddle_tpu/ops/`` touches ``os.environ``/``os.getenv``:
+a variable set in a shell would change every step of a process and is in
+no cache key. The reads that remain are listed in ``OPS_ENV_ALLOWLIST``
+with what each is for.
 
 All rules run as a tier-1 test (tests/test_codelint.py) so the bug
 classes stay extinct. Exit 0 clean, 1 violations.
@@ -211,8 +218,8 @@ def lint_cache_token(compiler_src=None, trace_src=None,
             "BuildStrategy.%s is read on the lowering path (%s) but is "
             "NOT folded into CompiledProgram._cache_token and has no "
             "allowlist entry — flipping it would silently reuse a stale "
-            "executable (the PR 6 quantize_min_size / PR 13 "
-            "kernel_policy bug class)" % (knob, where))
+            "executable (the PR 6 quantize_min_size bug class)"
+            % (knob, where))
     return violations
 
 
@@ -321,10 +328,84 @@ def lint_failpoint_sites(root=None, paths=None, catalog=None):
     return violations
 
 
+OPS_DIR = os.path.join(REPO, "paddle_tpu", "ops")
+# variable -> what the one read that remains is for
+OPS_ENV_ALLOWLIST = {
+    "PADDLE_TPU_PALLAS_INTERPRET": "tests and CPU rehearsals run the "
+                                   "kernels through the interpreter",
+    "PADDLE_TPU_OP_COVERAGE": "where the registry writes which ops ran",
+    "PADDLE_TPU_FAST_DROPOUT": "dropout's generator (PORTING.md)",
+}
+
+
+def _is_environ(node):
+    """`os.environ` / a bare `environ` name."""
+    return (isinstance(node, ast.Attribute) and node.attr == "environ") \
+        or (isinstance(node, ast.Name) and node.id == "environ")
+
+
+def _env_reads(tree):
+    """[(variable or None, lineno)]: every touch of the environment in a
+    module; None where the variable is not a string literal (or the
+    mapping itself is handed on)."""
+    parent = {child: node for node in ast.walk(tree)
+              for child in ast.iter_child_nodes(node)}
+    reads = []
+    for node in ast.walk(tree):
+        key = None
+        if _is_environ(node):
+            up = parent.get(node)
+            call = parent.get(up)
+            if isinstance(up, ast.Subscript):
+                key = up.slice
+            elif isinstance(up, ast.Attribute) and isinstance(call, ast.Call) \
+                    and call.args:
+                key = call.args[0]          # environ.get("X"), .pop, ...
+        elif isinstance(node, ast.Call) and "getenv" in (
+                getattr(node.func, "attr", None),
+                getattr(node.func, "id", None)):
+            key = node.args[0] if node.args else None
+        else:
+            continue
+        literal = isinstance(key, ast.Constant) and isinstance(key.value, str)
+        reads.append((key.value if literal else None, node.lineno))
+    return reads
+
+
+def lint_ops_environment(sources=None, allowlist=None):
+    """Rule 4. `sources` ({path: source}) replaces the walk of
+    paddle_tpu/ops/. Returns a list of violation strings."""
+    allowlist = OPS_ENV_ALLOWLIST if allowlist is None else allowlist
+    if sources is None:
+        sources = {}
+        for dirpath, _, files in os.walk(OPS_DIR):
+            for name in files:
+                if name.endswith(".py"):
+                    path = os.path.join(dirpath, name)
+                    with open(path) as f:
+                        sources[path] = f.read()
+    violations = []
+    for path in sorted(sources):
+        try:
+            tree = ast.parse(sources[path])
+        except SyntaxError as e:
+            violations.append("%s: unparseable: %s" % (path, e))
+            continue
+        for var, lineno in sorted(_env_reads(tree), key=lambda r: r[1]):
+            if var not in allowlist:
+                violations.append(
+                    "%s:%d: an op module reads the environment (%s): what "
+                    "an op lowers to comes from the call's own arguments; a "
+                    "variable set in a shell is in no cache key"
+                    % (path, lineno, var or "a computed name"))
+    return violations
+
+
 def run_all():
     return {"cache_token": lint_cache_token(),
             "free_floating_locks": lint_free_floating_locks(),
-            "failpoint_sites": lint_failpoint_sites()}
+            "failpoint_sites": lint_failpoint_sites(),
+            "ops_environment": lint_ops_environment()}
 
 
 def main(argv=None):
