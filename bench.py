@@ -445,11 +445,13 @@ def _timed_attn_tokens(loss_fn, q, k, v, b, t, steps):
 
 def flash_kernel_ms(b, h, t, d, blocks, causal=True, key_mask=False,
                     dtype="bfloat16", interpret=False, budget_s=0.25):
-    """Milliseconds a call of each of the three flash kernels (forward,
-    dK/dV, dQ) takes at `blocks` = (block_q, block_k), each kernel timed
-    on its own: warm (the compile), then enough back-to-back calls to fill
-    `budget_s` behind one `block_until_ready`. A kernel the compiler
-    refuses reads "failed: ..."."""
+    """Milliseconds a call of each of the four flash kernels (forward,
+    dK/dV, dQ, and "bwd": the fused backward that stands for the last two
+    where `flash_attention.backward_rule` says so) takes at `blocks` =
+    (block_q, block_k), each kernel timed on its own: warm (the compile),
+    then enough back-to-back calls to fill `budget_s` behind one
+    `block_until_ready`. A kernel the compiler refuses reads
+    "failed: ..."."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas import flash_attention as fa
@@ -471,7 +473,8 @@ def flash_kernel_ms(b, h, t, d, blocks, causal=True, key_mask=False,
         out, stats = fwd(q, k, v)
         ops = jax.jit(fa._bwd_inputs)(q, k, v, mask, out, stats, g)
         for name, kernel in (("bwd_dkv", fa._pallas_bwd_dkv),
-                             ("bwd_dq", fa._pallas_bwd_dq)):
+                             ("bwd_dq", fa._pallas_bwd_dq),
+                             ("bwd", fa._pallas_bwd)):
             calls[name] = (jax.jit(lambda *o, kernel=kernel: kernel(
                 o, h, mode, scale, causal, bq, bk, interpret)), ops)
     except Exception as e:  # the forward itself was refused
@@ -496,11 +499,13 @@ def flash_kernel_ms(b, h, t, d, blocks, causal=True, key_mask=False,
 
 def bench_flashtune():
     """Flash-attention tile sweep: ms a call of each kernel (forward,
-    dK/dV, dQ) per (block_q, block_k), at the attention shapes of the
-    benchmark's GPT cells and of BERT's phase 2 (key-padding mask), bf16.
-    "rule" is the tile `flash_attention.pick_blocks` gives each kernel at
-    that shape — the code applies it by itself; a sweep that disagrees
-    with the rule is a reason to change `pick_blocks`, not to set a knob."""
+    dK/dV, dQ, the fused backward) per (block_q, block_k), at the
+    attention shapes of the benchmark's GPT cells and of BERT's phase 2
+    (key-padding mask), bf16. "rule" is the tile
+    `flash_attention.pick_blocks` gives each kernel at that shape, and
+    "backward" what `backward_rule` gives the call — the code applies both
+    by itself; a sweep that disagrees with the rule is a reason to change
+    `pick_blocks`, not to set a knob."""
     from paddle_tpu.ops.pallas import flash_attention as fa
 
     on_tpu = _on_tpu()
@@ -519,20 +524,26 @@ def bench_flashtune():
         table = {"%dx%d" % tile: flash_kernel_ms(
             b, h, t, d, tile, causal, key_mask, interpret=not on_tpu)
             for tile in dict.fromkeys(tiles)}
+        kernels = fa.KERNELS + fa.FUSED_KERNELS[1:]
         rule = {kern: "%dx%d" % fa.pick_blocks(t, t, d, "bfloat16", kern,
                                                 causal)
-                for kern in fa.KERNELS}
+                for kern in kernels}
         best = {}
-        for kern in fa.KERNELS:
+        for kern in kernels:
             timed = {tile: row[kern] for tile, row in table.items()
                      if isinstance(row.get(kern), float)}
             best[kern] = min(timed, key=timed.get) if timed else None
+        shape = (b, h, t, d)
         results["%dx%dx%dx%d%s" % (b, h, t, d, "" if causal else "-kmask")] = {
-            "ms": table, "best": best, "rule": rule}
-    # headline: the rule's three kernels at the first shape
+            "ms": table, "best": best, "rule": rule,
+            "backward": fa.backward_rule(shape, shape, shape, "bfloat16",
+                                         causal, None)}
+    # headline: the kernels a call at the first shape runs, at the rule's
+    # tiles
     first = next(iter(results.values()))
     rule_ms = [first["ms"].get(first["rule"][kern], {}).get(kern)
-               for kern in fa.KERNELS]
+               for kern in (fa.FUSED_KERNELS if first["backward"] == "fused"
+                            else fa.KERNELS)]
     timed = all(isinstance(x, float) for x in rule_ms)
     return {"metric": "flash-attention tile sweep, ms per kernel call",
             "unit": "ms", "results": results,
@@ -674,7 +685,11 @@ def pallas_selfcheck(interpret=None):
     key-padding mask, per-query bias) at T=128/256, f32 and bf16, at
     the long-context shape (2, 12, 4096, 64) bf16, and with grouped heads,
     a value width of twice the q/k width and a sliding window (T=512, and
-    T=4096 with a 512 window); the selective-scan forward and backward
+    T=4096 with a 512 window); the fused backward kernel against the
+    dK/dV + dQ pair it stands for, each at its rule's tile (T=256, and the
+    GPT cells' (4, 12, 4096, 64) and (16, 12, 1024, 64) bf16: the calls
+    above without grouped heads or a window already take the fused one
+    against XLA); the selective-scan forward and backward
     kernels (T=320: not a multiple of the chunk), f32 and bf16; each fwd+bwd
     against its pure-JAX reference. Every check runs; one the compiler
     refuses (or that raises) is recorded with its message and fails the
@@ -769,12 +784,38 @@ def pallas_selfcheck(interpret=None):
         run(name + "_window128", flash_case(dtype, tol, 2, 4, 512, 64,
                                             "causal", hkv=2, dv=128,
                                             window=128))
+
+    def fused_case(dtype, tol, b, h, t, d):
+        q, k, v = (jnp.asarray(rng.randn(b, h, t, d), dtype)
+                   for _ in range(3))
+        w = jnp.asarray(rng.randn(b, h, t, d).astype(np.float32))
+
+        def grads(kernels):
+            blocks = tuple(fa.pick_blocks(t, t, d, dtype, kern, True)
+                           for kern in kernels)
+            return jax.jit(jax.grad(
+                lambda q, k, v: jnp.sum(fa._flash(
+                    q, k, v, None, 1.0 / np.sqrt(d), True, blocks,
+                    interpret, None).astype(jnp.float32) * w),
+                argnums=(0, 1, 2)))(q, k, v)
+
+        def check():
+            return compare(list(zip(grads(fa.FUSED_KERNELS),
+                                    grads(fa.KERNELS))), tol)
+        return check
+
+    for dtype, tol in ((jnp.float32, 1e-5), (jnp.bfloat16, 1e-2)):
+        run("flash_%s_T256_fused_vs_split" % np.dtype(dtype).name,
+            fused_case(dtype, tol, 2, 4, 256, 64))
     if not interpret:   # the interpreter needs minutes at these sizes
         run("flash_bfloat16_T4096_causal",
             flash_case(jnp.bfloat16, 1e-2, 2, 12, 4096, 64, "causal"))
         run("flash_bfloat16_T4096_gqa_dv128_window512",
             flash_case(jnp.bfloat16, 1e-2, 2, 4, 4096, 64, "causal", hkv=2,
                        dv=128, window=512))
+        for b, t in ((4, 4096), (16, 1024)):    # the GPT cells' calls
+            run("flash_bfloat16_%dx12x%dx64_fused_vs_split" % (b, t),
+                fused_case(jnp.bfloat16, 1e-2, b, 12, t, 64))
 
     def scan_case(dtype, tol, b, t, e, n):
         from paddle_tpu.ops.pallas import selective_scan as ss

@@ -8,10 +8,11 @@ own shapes, beside the kernel, and by nothing else.
 
   flash_attention   VMEM-tiled online-softmax attention, forward and
                     backward. ``attention_path`` maps a call's shapes to
-                    "xla" or "flash" and each kernel's tile
-                    (``pick_blocks``); bf16 operands go to the MXU as
-                    they are (exported as the MODULE: bench.py and the
-                    attention layers call
+                    "xla" or "flash", each kernel's tile
+                    (``pick_blocks``) and the fused or the split
+                    backward (``backward_rule``); bf16 operands go to
+                    the MXU as they are (exported as the MODULE:
+                    bench.py and the attention layers call
                     ``flash_attention.flash_attention(...)``)
   selective_scan    the state-space layer's scan, forward and backward,
                     in chunks ``pick_chunk`` sizes from the shape

@@ -4,22 +4,35 @@ The hot op of every BASELINE transformer config. Tiles Q/K/V blocks through
 VMEM with online-softmax accumulation — the (T,T) score matrix never touches
 HBM, so attention becomes MXU-bound instead of HBM-bound for long sequences.
 
-Three kernels, each with the tile `pick_blocks` gives it for the call's
-shape (on a v5e up to 1024x1024: a grid step costs microseconds whatever it
-holds, so few wide steps win). bfloat16 q, k, v, dO go to the MXU as they
-are and p, ds are cast down for the second matmuls, all with float32
-results; every other dtype runs float32 operands at HIGHEST. The online
-softmax state (m, l, the accumulators), exp and the scale stay float32.
+Three or four kernels a call, each with the tile `pick_blocks` gives it
+for the call's shape (on a v5e up to 1024x1024: a grid step costs
+microseconds whatever it holds, so few wide steps win). bfloat16 q, k, v,
+dO go to the MXU as they are and p, ds are cast down for the second
+matmuls, all with float32 results; every other dtype runs float32 operands
+at HIGHEST. The online softmax state (m, l, the accumulators), exp and the
+scale stay float32.
 
-Forward: grid (B*H, Tq/BQ, Tk/BK), f32 accumulators in VMEM scratch
-persisting across the (innermost, sequential) k-block dimension; emits the
-softmax statistics (row max m, normalizer l) alongside the output.
-Backward: dK/dV (grid (B*H, Tk/BK, Tq/BQ), q-blocks innermost) and dQ
-kernels that recompute p = exp(s - m) / l per tile from the saved
+Forward (`flash_fwd`): grid (B*H, Tq/BQ, Tk/BK), f32 accumulators in VMEM
+scratch persisting across the (innermost, sequential) k-block dimension;
+emits the softmax statistics (row max m, normalizer l) alongside the
+output.
+Backward: the kernels recompute p = exp(s - m) / l per tile from the saved
 (out, m, l) residuals — flash-attention-2 style, no (T,T) matrix in HBM in
-either direction, with the additive mask applied in-kernel. Under a causal
-mask a grid step above the diagonal runs no body, and its index maps name
-the block the nearest working step holds, so it fetches nothing either.
+either direction, with the additive mask applied in-kernel. Which of two
+forms a call gets is `backward_rule`'s answer, from its shapes:
+  - fused (`flash_bwd`; one query head a key/value head, no window,
+    Dv == D, and dQ's row fits VMEM: GPT's plain causal call, BERT's
+    key-masked one): grid (B*H, Tk/BK, Tq/BQ), q-blocks innermost. A tile's
+    s, p, dp and ds are formed once and feed all three gradients: dK/dV in
+    accumulators written when the k-block's last q-block is done, dQ in a
+    float32 scratch of the head's whole query length, written to HBM once
+    a head. Five matmuls a tile.
+  - split (`flash_bwd_dkv`, grid as above, and `flash_bwd_dq`, grid as the
+    forward's): each forms s, p, dp, ds for itself, seven matmuls a tile
+    between them. Grouped heads, windows and unequal widths run here.
+Under a causal mask a grid step above the diagonal runs no body, and its
+index maps name the block the nearest working step holds, so it fetches
+nothing either.
 The statistics stay separate on purpose: folding them into
 lse = m + log(l) puts the hardware log/exp approximation error into the
 exponent — measured on a v5e, sum(p) then sat ~3e-5 off 1 and the f32
@@ -44,8 +57,9 @@ The supported (heads, window, widths) space:
     block. `window <= 0`, or a window without `causal`, is a ValueError.
   - widths: the value width Dv may differ from the q/k width D (the
     output and dO are Dv wide). D % 8 or Dv % 8 != 0 goes to XLA.
-With n == 1, no window and Dv == D the kernels, tiles, index maps and
-VMEM request are the ones the plain causal call always had.
+With n == 1, no window and Dv == D the forward kernel, its tile, index
+maps and VMEM request are the ones the plain causal call always had, and
+the backward is the fused kernel.
 """
 import functools
 from typing import NamedTuple, Optional
@@ -385,18 +399,21 @@ def _mask_input(mask, dtype):
 # Score-shaped (block_q, block_k) f32 tiles that Mosaic keeps in VMEM at
 # once, beside the blocks and the accumulators: found by bisecting
 # `vmem_limit_bytes` on compiles for a v5e (bf16 D=64 and f32 D=128 with a
-# key mask, 512x512 and 1024x1024: at most 2.0 / 4.4 / 3.3) and rounded up
-_TILE_TEMPS = {"fwd": 3, "bwd_dkv": 6, "bwd_dq": 5}
+# key mask, 512x512 and 1024x1024: at most 2.0 / 4.4 / 3.3, and 6.8 for
+# the fused backward, whose bf16 tiles need 1.9) and rounded up
+_TILE_TEMPS = {"fwd": 3, "bwd_dkv": 6, "bwd_dq": 5, "bwd": 8}
 _VMEM_DEFAULT = 16 * 2 ** 20    # Mosaic's scoped limit on a v5e
 _VMEM_CEILING = 96 * 2 ** 20    # of the chip's 128 MiB
 
 
 def vmem_bytes(kernel, block_q, block_k, d, itemsize, mask_mode="none",
-               dv=None):
+               dv=None, tq=0):
     """Upper reckoning of the VMEM one grid step of `kernel` holds: every
     block twice (the pipeline's two buffers) with its lanes padded to 128,
     the f32 accumulators, and `_TILE_TEMPS` f32 score-shaped tiles. `dv`
-    is the value width where it differs from `d`."""
+    is the value width where it differs from `d`; `tq` the query length,
+    which only the fused backward ("bwd") holds whole: its dQ block and
+    the f32 row that dQ is summed in."""
     lanes = -(-d // 128) * 128
     lanes_v = lanes if dv is None else -(-dv // 128) * 128
     q_blk, k_blk = block_q * lanes, block_k * lanes
@@ -411,6 +428,10 @@ def vmem_bytes(kernel, block_q, block_k, d, itemsize, mask_mode="none",
         blocks = (q_blk + o_blk + 2 * k_blk + 2 * v_blk) * itemsize \
             + 2 * row_blk
         scratch = (k_blk + v_blk) * 4
+    elif kernel == "bwd":
+        blocks = (q_blk + o_blk + 2 * k_blk + 2 * v_blk
+                  + tq * lanes) * itemsize + 2 * row_blk
+        scratch = (k_blk + v_blk + tq * lanes) * 4
     else:
         blocks = (2 * q_blk + o_blk + k_blk + v_blk) * itemsize \
             + 2 * row_blk
@@ -420,15 +441,21 @@ def vmem_bytes(kernel, block_q, block_k, d, itemsize, mask_mode="none",
 
 
 def _compiler_params(kernel, block_q, block_k, d, dtype, mask_mode,
-                     dv=None):
+                     dv=None, tq=0):
     """Ask Mosaic for the VMEM the tile is reckoned to need where its
     default would not do, so that a large tile compiles instead of
-    failing."""
+    failing. The fused backward also says that its two inner grid axes
+    run in order: dQ's row and dK/dV's accumulators live across them."""
     need = vmem_bytes(kernel, block_q, block_k, d, jnp.dtype(dtype).itemsize,
-                      mask_mode, dv)
-    if need <= _VMEM_DEFAULT:
+                      mask_mode, dv, tq)
+    limit = None if need <= _VMEM_DEFAULT else min(need, _VMEM_CEILING)
+    if kernel == "bwd":
+        return pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=limit)
+    if limit is None:
         return None
-    return pltpu.CompilerParams(vmem_limit_bytes=min(need, _VMEM_CEILING))
+    return pltpu.CompilerParams(vmem_limit_bytes=limit)
 
 
 def _window_kwargs(window):
@@ -479,6 +506,16 @@ def _pallas_forward(q, k, v, mask, scale, causal, block_q, block_k,
     return out.reshape(b, h, tq, dv), stats
 
 
+def _add_dkv(dk_acc, dv_acc, q, do, p, ds, scale, precision):
+    """dv += p^T dO ; dk += scale * ds^T q, into the f32 accumulators."""
+    dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
+        p, do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision)
+    dk_acc[:] = dk_acc[:] + scale * jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision)
+
+
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
                     mask_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale, causal, causal_offset, block_q, block_k,
@@ -509,13 +546,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
             causal_offset=causal_offset, block_q=block_q,
             block_k=block_k, mask_mode=mask_mode, precision=precision,
             **_window_kwargs(window))
-        # dv += p^T dO ; dk += scale * ds^T q
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision)
-        dk_acc[:] = dk_acc[:] + scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision)
+        _add_dkv(dk_acc, dv_acc, q, do, p, ds, scale, precision)
 
     _for_visible_tile(body, qi, kj, causal=causal,
                       causal_offset=causal_offset, block_q=block_q,
@@ -561,6 +592,57 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale,
+                causal, causal_offset, block_q, block_k, mask_mode,
+                precision):
+    """The fused backward: p and ds of a tile are formed once and feed all
+    three gradients. Grid (bh, k-blocks, q-blocks) as dK/dV's, whose
+    accumulators it keeps; dQ is summed in `dq_acc`, a float32 scratch of
+    the head's whole query length (one (block_q, D) slab a q-block), over
+    ascending k-blocks as the dQ kernel sums it, and leaves for HBM once,
+    after the head's last tile."""
+    kj = pl.program_id(1)
+    qi = pl.program_id(2)
+    nk, nq = pl.num_programs(1), pl.num_programs(2)
+
+    @pl.when((kj == 0) & (qi == 0))
+    def _init_head():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def body():
+        q, k, do, p, ds = _bwd_p_ds(
+            q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
+            qi, kj, scale=scale, causal=causal,
+            causal_offset=causal_offset, block_q=block_q,
+            block_k=block_k, mask_mode=mask_mode, precision=precision)
+        _add_dkv(dk_acc, dv_acc, q, do, p, ds, scale, precision)
+        # dq[q-block] += scale * ds k
+        dq_acc[qi] = dq_acc[qi] + scale * jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+
+    _for_visible_tile(body, qi, kj, causal=causal,
+                      causal_offset=causal_offset, block_q=block_q,
+                      block_k=block_k)
+
+    @pl.when(qi == nq - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when((kj == nk - 1) & (qi == nq - 1))
+    def _finalize_head():
+        for n in range(dq_acc.shape[0]):
+            dq_ref[0, n * block_q:(n + 1) * block_q, :] = \
+                dq_acc[n].astype(dq_ref.dtype)
+
+
 def _bwd_inputs(q, k, v, mask, out, stats, g):
     """The backward kernels' operands: (bh, T, D) views, and delta =
     rowsum(dO * O) (a cheap elementwise pass in XLA) with the sublane dim
@@ -578,11 +660,12 @@ def _bwd_inputs(q, k, v, mask, out, stats, g):
 
 def _bwd_call(kernel, which, operands, h, mask_mode, scale, causal, block_q,
               block_k, interpret, kj_innermost, window=None):
-    """What the two backward kernels' pallas_calls share (`which` of
-    KERNELS): the kernel with its parameters and the keyword arguments for
-    the seven operands (q, k, v, dO, stats, delta, mask) both take; then
-    the BlockSpecs for the outputs (q-tiled, k-tiled, v-tiled). `h` is the
-    query heads of a batch row; the kv heads follow from the operands."""
+    """What the backward kernels' pallas_calls share (`which`: the
+    kernel's name in KERNELS or FUSED_KERNELS): the kernel with its
+    parameters and the keyword arguments for the seven operands (q, k, v,
+    dO, stats, delta, mask) all take; then the BlockSpecs for the outputs
+    (q-tiled, k-tiled, v-tiled). `h` is the query heads of a batch row;
+    the kv heads follow from the operands."""
     q3, k3, v3 = operands[:3]
     bh, tq, d = q3.shape
     bhkv, tk, dv = k3.shape[0], k3.shape[1], v3.shape[2]
@@ -611,7 +694,7 @@ def _bwd_call(kernel, which, operands, h, mask_mode, scale, causal, block_q,
         in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec,
                   mask_spec],
         compiler_params=_compiler_params(which, block_q, block_k, d,
-                                         q3.dtype, mask_mode, dv),
+                                         q3.dtype, mask_mode, dv, tq),
         interpret=interpret)
     return body, common, q_spec, k_spec, v_spec
 
@@ -650,15 +733,44 @@ def _pallas_bwd_dq(operands, h, mask_mode, scale, causal, block_q, block_k,
     )(*operands)
 
 
+def _pallas_bwd(operands, h, mask_mode, scale, causal, block_q, block_k,
+                interpret):
+    """The fused backward's call (one query head a kv head, no window,
+    equal widths: `backward_rule`). dQ's block is the head's whole row
+    under a constant index map, so it is written back once a head."""
+    body, common, _, k_spec, v_spec = _bwd_call(
+        _bwd_kernel, "bwd", operands, h, mask_mode, scale, causal, block_q,
+        block_k, interpret, kj_innermost=False)
+    q3, k3, v3 = operands[:3]
+    _bh, tq, d = q3.shape
+    return pl.pallas_call(
+        body,
+        out_specs=[pl.BlockSpec((1, tq, d), lambda bb, a, b_: (bb, 0, 0)),
+                   k_spec, v_spec],
+        out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+                   jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+                   jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
+        scratch_shapes=[pltpu.VMEM((tq // block_q, block_q, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        name="flash_bwd",
+        **common,
+    )(*operands)
+
+
 def _pallas_backward(q, k, v, mask, out, stats, g, scale, causal, blocks,
                      interpret, window=None):
     b, h, tq, d = q.shape
     operands = _bwd_inputs(q, k, v, mask, out, stats, g)
     mask_mode = _mask_mode(mask)
-    dk3, dv3 = _pallas_bwd_dkv(operands, h, mask_mode, scale, causal,
-                               *blocks[1], interpret, window=window)
-    dq3 = _pallas_bwd_dq(operands, h, mask_mode, scale, causal,
-                         *blocks[2], interpret, window=window)
+    if _kernels_of(blocks) is FUSED_KERNELS:
+        dq3, dk3, dv3 = _pallas_bwd(operands, h, mask_mode, scale, causal,
+                                    *blocks[1], interpret)
+    else:
+        dk3, dv3 = _pallas_bwd_dkv(operands, h, mask_mode, scale, causal,
+                                   *blocks[1], interpret, window=window)
+        dq3 = _pallas_bwd_dq(operands, h, mask_mode, scale, causal,
+                             *blocks[2], interpret, window=window)
     return dq3.reshape(q.shape), dk3.reshape(k.shape), dv3.reshape(v.shape)
 
 
@@ -697,7 +809,8 @@ def _xla_attention(q, k, v, mask, scale, causal, window=None):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash(q, k, v, mask, scale, causal, blocks, interpret, window):
-    """`blocks`: the (block_q, block_k) of each kernel, in KERNELS' order."""
+    """`blocks`: the (block_q, block_k) of each kernel the call runs, in
+    KERNELS' order, or in FUSED_KERNELS' where the backward is fused."""
     out, _ = _pallas_forward(q, k, v, mask, scale, causal, *blocks[0],
                              interpret, window)
     return out
@@ -751,6 +864,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 KERNELS = ("fwd", "bwd_dkv", "bwd_dq")
+FUSED_KERNELS = ("fwd", "bwd")      # what a call with the fused backward runs
 
 
 def _fit(block, t):
@@ -763,7 +877,8 @@ def _fit(block, t):
 
 def pick_blocks(tq, tk, d, dtype, kernel, causal=False, window=None,
                 dv=None):
-    """(block_q, block_k) of `kernel` (one of KERNELS) for a call's shape.
+    """(block_q, block_k) of `kernel` (of KERNELS or FUSED_KERNELS) for a
+    call's shape.
 
     What the sweep on a v5e showed (PERF.md, PR 25; bf16, D=64 and 128,
     T=512..4096): a grid step costs a few microseconds whatever its tile
@@ -774,7 +889,12 @@ def pick_blocks(tq, tk, d, dtype, kernel, causal=False, window=None,
     and two transposes), so there skipping blocks above the causal
     diagonal pays: a quarter of the sequence a side (10 of 16 tiles run),
     but never under 512, where the step's cost loses more than skipping
-    saves. Other dtypes run float32 operands at HIGHEST: twice the VMEM
+    saves. The fused backward ("bwd") takes the dK/dV kernel's tile: swept
+    on a v5e at GPT's two shapes (PERF.md, PR 30; ms a call), 1024x1024
+    4.71 at T=4096 (1024x512 4.78, 512x1024 4.80, 512x512 5.25) and
+    512x512 2.24 at T=1024 (1024x1024 2.32, 512x1024 2.34); the room its
+    dQ row takes is `backward_rule`'s to weigh, not this tile's.
+    Other dtypes run float32 operands at HIGHEST: twice the VMEM
     and six MXU passes a tile, so 512 is their cap (reckoned, not swept).
     Under a sliding `window` a tile row sees window + block_q keys whatever
     the sequence's length, so all three kernels take a tile about the
@@ -798,16 +918,23 @@ def pick_blocks(tq, tk, d, dtype, kernel, causal=False, window=None,
     return bq, bk
 
 
-def plan(q_shape, k_shape, v_shape, causal, window, blocks):
+def _kernels_of(blocks):
+    """The names of the kernels a call with these tiles runs."""
+    return FUSED_KERNELS if len(blocks) == len(FUSED_KERNELS) else KERNELS
+
+
+def plan(q_shape, k_shape, v_shape, causal, window, blocks, backward=None):
     """What a call will do, for `flash.plan`: per kernel the tile, the grid
     steps and how many of the (q-block, k-block) tiles run, how many lie
     wholly above the causal diagonal and how many wholly outside the
-    window; with the group size and the two widths. Python ints only."""
+    window; with the group size, the two widths and which backward the
+    call got (`backward_rule`'s answer). Python ints only."""
     (_b, hq, tq, d), hkv, tk, dv = q_shape, k_shape[1], k_shape[2], \
         v_shape[-1]
     off, out = tk - tq, {"group": hq // hkv, "d_qk": d, "d_v": dv,
-                         "window": window, "causal": bool(causal)}
-    for kernel, (bq, bk) in zip(KERNELS, blocks):
+                         "window": window, "causal": bool(causal),
+                         "backward": backward}
+    for kernel, (bq, bk) in zip(_kernels_of(blocks), blocks):
         nq, nk = tq // bq, tk // bk
         above = outside = 0
         for qi in range(nq):
@@ -816,9 +943,10 @@ def plan(q_shape, k_shape, v_shape, causal, window, blocks):
                 qi, off, bq, bk, window)
             above += nk - 1 - last
             outside += first
-        inner = nk if kernel != "bwd_dkv" else nq
+        kj_innermost = kernel not in ("bwd_dkv", "bwd")
+        inner = nk if kj_innermost else nq
         if window is not None:
-            inner = window_grid(tq, tk, bq, bk, window, kernel != "bwd_dkv")
+            inner = window_grid(tq, tk, bq, bk, window, kj_innermost)
         out[kernel] = {"block_q": bq, "block_k": bk,
                        "grid_inner": inner,
                        "tiles_visited": nq * nk - above - outside,
@@ -827,22 +955,51 @@ def plan(q_shape, k_shape, v_shape, causal, window, blocks):
     return out
 
 
-def _record_plan(q, k, v, causal, window, blocks):
+def _record_plan(q, k, v, causal, window, blocks, backward):
     """One `flash.plan` record a lowering, while obs is on."""
     from ...framework import obs
     if obs.enabled():
         now = obs.now()
         obs.record("flash.plan", now, now,
-                   **plan(q.shape, k.shape, v.shape, causal, window, blocks))
+                   **plan(q.shape, k.shape, v.shape, causal, window, blocks,
+                          backward))
 
 
 class AttentionPath(NamedTuple):
-    """What `attention_path` returns: the path ("xla" or "flash"), under
-    "flash" each kernel's (block_q, block_k) in KERNELS' order, under
-    "xla" the rule that sent the call there."""
+    """What `attention_path` returns: the path ("xla" or "flash"); under
+    "flash" the (block_q, block_k) of each kernel the call runs (KERNELS'
+    order, or FUSED_KERNELS' with the fused backward) and which backward
+    it got, "fused" or "split: <the rule that kept the two kernels>";
+    under "xla" the rule that sent the call there."""
     path: str
     blocks: Optional[tuple]
     why: Optional[str]
+    backward: Optional[str] = None
+
+
+def backward_rule(q_shape, k_shape, v_shape, dtype, causal, window):
+    """Which backward a flash call gets, from its shapes: "fused" (one
+    kernel, `_bwd_kernel`) or "split: <rule>" (dK/dV and dQ kernels), the
+    first rule that holds:
+      "group"   more than one query head a key/value head: dQ's row would
+                be group x Tq long, and dK/dV walks the group's heads;
+      "window"  a sliding window: its grids walk another inner axis;
+      "widths"  Dv != D;
+      "vmem"    the fused kernel at its tile, with dQ's whole row (Tq x D
+                in float32 and the output block), is reckoned over the
+                VMEM ceiling."""
+    tq, tk, d, dv = q_shape[2], k_shape[2], q_shape[-1], v_shape[-1]
+    if q_shape[1] != k_shape[1]:
+        return "split: group"
+    if window is not None:
+        return "split: window"
+    if dv != d:
+        return "split: widths"
+    bq, bk = pick_blocks(tq, tk, d, dtype, "bwd", causal)
+    if vmem_bytes("bwd", bq, bk, d, jnp.dtype(dtype).itemsize, "qk",
+                  tq=tq) > _VMEM_CEILING:
+        return "split: vmem"
+    return "fused"
 
 
 def attention_path(q_shape, k_shape, v_shape, dtype, causal, window,
@@ -865,15 +1022,18 @@ def attention_path(q_shape, k_shape, v_shape, dtype, causal, window,
       "lanes"    compiled (`interpret` false) with a tile side under 128:
                  Mosaic wants the last two block dims 128-lane aligned
                  (the stats block puts block_q on the lane dim).
-    Otherwise "flash", each kernel with `pick_blocks`' tile; an explicit
-    `block_q`/`block_k` replaces that side of all three."""
+    Otherwise "flash", with the backward `backward_rule` names and each
+    kernel with `pick_blocks`' tile; an explicit `block_q`/`block_k`
+    replaces that side of every kernel's."""
     tq, tk, d, dv = q_shape[2], k_shape[2], q_shape[-1], v_shape[-1]
     if auto and tq * tk <= 256 * 256:
         return AttentionPath("xla", None, "short")
     if causal and tq > tk:
         return AttentionPath("xla", None, "no_keys")
+    backward = backward_rule(q_shape, k_shape, v_shape, dtype, causal,
+                             window)
     blocks = []
-    for kernel in KERNELS:
+    for kernel in (FUSED_KERNELS if backward == "fused" else KERNELS):
         bq, bk = pick_blocks(tq, tk, d, dtype, kernel, causal, window,
                              None if dv == d else dv)
         blocks.append((_fit(block_q or bq, tq), _fit(block_k or bk, tk)))
@@ -882,7 +1042,7 @@ def attention_path(q_shape, k_shape, v_shape, dtype, causal, window,
         return AttentionPath("xla", None, "no_tile")
     if not interpret and least < 128:
         return AttentionPath("xla", None, "lanes")
-    return AttentionPath("flash", tuple(blocks), None)
+    return AttentionPath("flash", tuple(blocks), None, backward)
 
 
 def flash_attention(q, k, v, mask=None, scale=1.0, causal=False,
@@ -894,18 +1054,18 @@ def flash_attention(q, k, v, mask=None, scale=1.0, causal=False,
     kernel, and to plain fused XLA attention where `attention_path` finds
     no tile for the shape.
 
-    Each kernel's tile comes from the call's shape (`pick_blocks`). An
-    explicit `block_q`/`block_k` replaces that side of all three kernels'
-    tiles."""
+    Each kernel's tile comes from the call's shape (`pick_blocks`), as
+    does the backward it runs (`backward_rule`). An explicit
+    `block_q`/`block_k` replaces that side of every kernel's tile."""
     if interpret is None:
         interpret = default_interpret()
     check_call(q.shape, k.shape, v.shape, causal, window)
-    path, blocks, _why = attention_path(
+    path, blocks, _why, backward = attention_path(
         q.shape, k.shape, v.shape, q.dtype, causal, window, interpret,
         block_q=block_q, block_k=block_k)
     if path == "xla":
         return _xla_attention(q, k, v, mask, scale, causal, window)
-    _record_plan(q, k, v, causal, window, blocks)
+    _record_plan(q, k, v, causal, window, blocks, backward)
     return _flash(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                   None if mask is None else jnp.asarray(mask),
                   scale, causal, blocks, interpret, window)

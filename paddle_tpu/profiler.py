@@ -7,8 +7,10 @@ through jax.profiler (XPlane traces viewable in TensorBoard/Perfetto).
 reference's does, a sorted table — of the FUSED step's real device time:
 one row per ``<role>/<op type>`` the program lowered its ops under
 (``forward/mul``, ``backward/layer_norm``, ``optimize/adam``; Pallas
-kernels under their own names, ``backward/flash_bwd_dkv``; ``unscoped``
-for what XLA added), with calls, total, average and share, read from the
+kernels under their own names: ``backward/flash_bwd``, or
+``backward/flash_bwd_dkv`` and ``backward/flash_bwd_dq`` where a call's
+shapes keep the two backward kernels; ``unscoped`` for what XLA added),
+with calls, total, average and share, read from the
 trace just written (``framework/xplane.py``). A CPU trace has no device
 plane and gives no table.
 
@@ -67,9 +69,10 @@ PLAN_RECORDS = ("flash.plan", "ssm.plan", "head.plan")
 def print_kernel_plans():
     """What the lowerings made while obs was on planned to do, one line a
     record, under the table: `flash.plan` (tiles, tiles visited and skipped
-    by causality and by the window, group size, widths), `ssm.plan` (chunk
-    length, chunks, VMEM asked) and `head.plan` (the LM head: rows, vocab,
-    block rows and blocks, weighted or per-token form, operand dtype)."""
+    by causality and by the window, group size, widths, the fused or the
+    split backward), `ssm.plan` (chunk length, chunks, VMEM asked) and
+    `head.plan` (the LM head: rows, vocab, block rows and blocks, weighted
+    or per-token form, operand dtype)."""
     for name in PLAN_RECORDS:
         for plan in obs.spans(name=name):
             print("%s %s" % (plan["name"], " ".join(

@@ -208,27 +208,36 @@ def test_tile_rule_table(tq, tk, d, dtype, causal):
     divide T, are >= 128 (the device path's floor), and the reckoned VMEM
     stays under what the call asks Mosaic for (its default where it asks
     for nothing)."""
-    for kernel in fa.KERNELS:
+    for kernel in fa.KERNELS + fa.FUSED_KERNELS[1:]:
         bq, bk = fa.pick_blocks(tq, tk, d, dtype, kernel, causal)
         assert tq % bq == 0 and tk % bk == 0
         assert bq >= 128 and bk >= 128
         assert bq % 128 == 0 or bq == tq
         itemsize = jnp.dtype(dtype).itemsize
+        # the fused backward also holds dQ's whole row: Tq is part of it
+        row = {"tq": tq} if kernel == "bwd" else {}
         for mask_mode in ("none", "k", "qk"):
-            need = fa.vmem_bytes(kernel, bq, bk, d, itemsize, mask_mode)
-            params = fa._compiler_params(kernel, bq, bk, d, dtype, mask_mode)
-            limit = (fa._VMEM_DEFAULT if params is None
-                     else params.vmem_limit_bytes)
+            need = fa.vmem_bytes(kernel, bq, bk, d, itemsize, mask_mode,
+                                 **row)
+            params = fa._compiler_params(kernel, bq, bk, d, dtype, mask_mode,
+                                         **row)
+            limit = (params and params.vmem_limit_bytes) \
+                or fa._VMEM_DEFAULT
             assert need <= limit <= fa._VMEM_CEILING
+        if kernel == "bwd":     # and it runs its inner axes in order
+            assert tuple(params.dimension_semantics) \
+                == ("parallel", "arbitrary", "arbitrary")
 
 
 def test_tile_rule_follows_the_shape(monkeypatch):
     """One algorithm, parameters from the shape (the v5e sweep's winners,
     PERF.md PR 25): the forward takes the widest tile up to 1024x1024; the
     causal backward kernels a quarter of the sequence a side, between 512
-    and 1024; float32 stops at 512. Explicit blocks keep winning on their
-    side of every kernel's tile."""
+    and 1024, the fused backward as dK/dV; float32 stops at 512. Explicit
+    blocks keep winning on their side of every kernel's tile."""
     def rule(t, causal=True, dtype="bfloat16", d=64):
+        assert fa.pick_blocks(t, t, d, dtype, "bwd", causal) \
+            == fa.pick_blocks(t, t, d, dtype, "bwd_dkv", causal)
         return tuple(fa.pick_blocks(t, t, d, dtype, kern, causal)
                      for kern in fa.KERNELS)
     assert rule(4096) == ((1024, 1024),) * 3        # gpt2.t4096-b4
@@ -249,10 +258,19 @@ def test_tile_rule_follows_the_shape(monkeypatch):
                     interpret=True)
     flash_attention(q, q, q, causal=True, block_k=128, interpret=True)
     rule, explicit, one_side = seen
+    # a plain causal call: the forward and the fused backward
     assert rule == tuple(fa.pick_blocks(1024, 1024, 64, q.dtype, kern, True)
-                         for kern in fa.KERNELS)
-    assert explicit == ((64, 32),) * 3
+                         for kern in fa.FUSED_KERNELS)
+    assert explicit == ((64, 32),) * 2
     assert one_side == tuple((bq, 128) for bq, _ in rule)
+    del seen[:]
+    k = jnp.zeros((1, 1, 1024, 128), jnp.bfloat16)     # Dv != D: split
+    flash_attention(q, q, k, causal=True, interpret=True)
+    flash_attention(q, q, k, causal=True, block_q=64, block_k=32,
+                    interpret=True)
+    assert seen == [tuple(fa.pick_blocks(1024, 1024, 64, q.dtype, kern,
+                                         True, None, 128)
+                          for kern in fa.KERNELS), ((64, 32),) * 3]
 
 
 def _loss_grads(fn, args, w):
@@ -338,14 +356,16 @@ def test_bf16_operands_against_f32_oracle(mode):
         assert np.abs(a - o).max() / max(np.abs(o).max(), 1.0) < 1e-2
 
 
-def _kernel_dots(dtype):
+def _kernel_dots(dtype, kv_heads):
     """(lhs dtype, rhs dtype, result dtype, precision) of every
-    dot_general that the three kernels trace for inputs of `dtype`."""
-    q = jnp.zeros((1, 1, 32, 8), dtype)
+    dot_general that a call's kernels trace for inputs of `dtype`: with
+    two query heads to `kv_heads` key/value heads."""
+    q = jnp.zeros((1, 2, 32, 8), dtype)
+    k = jnp.zeros((1, kv_heads, 32, 8), dtype)
     closed = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
         flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
                         interpret=True).astype(jnp.float32)),
-        argnums=(0, 1, 2)))(q, q, q)
+        argnums=(0, 1, 2)))(q, k, k)
     dots = []
 
     def walk(jaxpr):
@@ -360,14 +380,18 @@ def _kernel_dots(dtype):
     return dots
 
 
+@pytest.mark.parametrize("backward,count", [
+    ("fused", 2 + 5),           # forward; s and dp once, then dV, dK, dQ
+    ("split", 2 + 4 + 3)])      # forward, dK/dV, dQ: s and dp in both
 @pytest.mark.parametrize("dtype", ["float32", "float16", "bfloat16"])
-def test_mxu_operand_dtype_and_precision(dtype):
+def test_mxu_operand_dtype_and_precision(dtype, backward, count):
     """float32/float16 inputs keep float32 operands at HIGHEST — the same
     operations, in the same order, as before this rule existed, so the same
     bits at equal tiles; bfloat16 inputs reach every dot as bfloat16 with
-    a float32 result."""
-    dots = _kernel_dots(dtype)
-    assert len(dots) == 2 + 4 + 3      # forward, dK/dV, dQ
+    a float32 result. The fused backward runs the mathematics' five
+    matmuls, the split kernels seven."""
+    dots = _kernel_dots(dtype, 2 if backward == "fused" else 1)
+    assert len(dots) == count
     highest = jax.lax.Precision.HIGHEST
     for lhs, rhs, out, precision in dots:
         assert out == "float32"

@@ -34,19 +34,21 @@ from paddle_tpu.ops.registry import get_op
 
 def site(name, q, k=None, v=None, dtype="bfloat16", mask=None, causal=True,
          window=None, interpret=False, impl="auto", blocks=None, why=None,
-         visited=None, through_op=False, **explicit):
-    """One row. `blocks` (a pair stands for all three kernels) means
-    "flash"; `why` names the rule that sends the call to XLA. `visited`:
-    (tiles the forward kernel runs, tiles of its grid), as PERF.md prints
-    them. `through_op` also traces the registry op at these shapes."""
+         backward="fused", visited=None, through_op=False, **explicit):
+    """One row. `blocks` means "flash", with the `backward` the call gets:
+    "fused" runs two kernels (forward, `flash_bwd`) and "split: <rule>"
+    three (forward, dK/dV, dQ); a pair stands for the tile of each. `why`
+    names the rule that sends the call to XLA. `visited`: (tiles the
+    forward kernel runs, tiles of its grid), as PERF.md prints them.
+    `through_op` also traces the registry op at these shapes."""
     if isinstance(blocks, tuple) and isinstance(blocks[0], int):
-        blocks = (blocks,) * 3
+        blocks = (blocks,) * (2 if backward == "fused" else 3)
     k = k or q
     return pytest.param(dict(
         q=q, k=k, v=v or k, dtype=dtype, mask=mask, causal=causal,
         window=window, interpret=interpret, impl=impl, blocks=blocks,
-        why=why, visited=visited, through_op=through_op, explicit=explicit),
-        id=name)
+        why=why, backward=backward if blocks else None, visited=visited,
+        through_op=through_op, explicit=explicit), id=name)
 
 
 PHI_Q, PHI_K, PHI_V = (2, 20, 8192, 64), (2, 10, 8192, 64), (2, 10, 8192, 128)
@@ -56,15 +58,18 @@ SITES = [
     site("bert-base.s128-b256", (256, 12, 128, 64), mask="key", causal=False,
          why="short", through_op=True),
     site("gpt2.t1024-b16", (16, 12, 1024, 64), through_op=True,
-         blocks=((1024, 1024), (512, 512), (512, 512)), visited=(1, 1)),
+         blocks=((1024, 1024), (512, 512)), visited=(1, 1)),
     site("gpt2.t4096-b4", (4, 12, 4096, 64), through_op=True,
          blocks=(1024, 1024), visited=(10, 16)),
     site("phi4-mini-flash.t8192-b1/window", PHI_Q, PHI_K, PHI_V, window=512,
-         through_op=True, blocks=(512, 512), visited=(31, 256)),
+         through_op=True, blocks=(512, 512), backward="split: group",
+         visited=(31, 256)),
     site("phi4-mini-flash.t8192-b1/full", PHI_Q, PHI_K, PHI_V,
-         through_op=True, blocks=(1024, 1024), visited=(36, 64)),
+         through_op=True, blocks=(1024, 1024), backward="split: group",
+         visited=(36, 64)),
     site("phi4-mini-flash.t8192-b1/cross", PHI_Q, PHI_K, PHI_V,
-         through_op=True, blocks=(1024, 1024), visited=(36, 64)),
+         through_op=True, blocks=(1024, 1024), backward="split: group",
+         visited=(36, 64)),
     # the cells that wait (PERF.md §7)
     site("bert-base.s512-b32", (32, 12, 512, 64), mask="key", causal=False,
          through_op=True, blocks=(512, 512), visited=(1, 1)),
@@ -100,7 +105,21 @@ SITES = [
     site("float32_t1024", (1, 2, 1024, 64), dtype="float32",
          blocks=(512, 512)),
     site("window_64_t512", (1, 2, 512, 16), window=64, blocks=(256, 256),
-         visited=(3, 4)),
+         backward="split: window", visited=(3, 4)),
+    # which backward: the first rule that holds keeps the two kernels
+    site("grouped_heads", (1, 4, 1024, 64), (1, 2, 1024, 64),
+         blocks=((1024, 1024), (512, 512), (512, 512)),
+         backward="split: group"),
+    site("value_width_differs", (1, 2, 1024, 64), (1, 2, 1024, 64),
+         (1, 2, 1024, 128), blocks=((1024, 1024), (512, 512), (512, 512)),
+         backward="split: widths"),
+    site("dq_row_of_32768_fits", (1, 1, 32768, 64), blocks=(1024, 1024)),
+    site("dq_row_of_65536_passes_the_ceiling", (1, 1, 65536, 64),
+         blocks=(1024, 1024), backward="split: vmem"),
+    site("dq_row_of_65536_in_float32_passes_it", (1, 1, 65536, 128),
+         dtype="float32", blocks=(512, 512), backward="split: vmem"),
+    site("not_causal_with_a_key_mask_is_fused_too", (2, 4, 2048, 64),
+         mask="key", causal=False, blocks=(1024, 1024)),
 ]
 
 
@@ -129,7 +148,8 @@ def test_attention_path_and_tiles_come_from_the_call(c, monkeypatch):
         got = fa.attention_path(c["q"], c["k"], c["v"], c["dtype"],
                                 c["causal"], c["window"], c["interpret"],
                                 auto=c["impl"] == "auto", **c["explicit"])
-        assert got == ("flash" if flash else "xla", c["blocks"], c["why"])
+        assert got == ("flash" if flash else "xla", c["blocks"], c["why"],
+                       c["backward"])
         # pure: strings and ints in, the same answer again
         assert got == fa.attention_path(
             list(c["q"]), list(c["k"]), list(c["v"]), jnp.dtype(c["dtype"]),
@@ -279,8 +299,12 @@ def digest(text):
 # the step is the parent commit's (PR 28, e0886ae), under both executors
 # ---------------------------------------------------------------------------
 
-GPT_STEP = {"sha256": "820f05f5bf92bda6c1d9e72426cf2c6e3103f97a521012ac4ba3e6bd82"
-                      "2d3a11", "chars": 181920}
+# GPT's was re-recorded in PR 30: its attention calls are plain causal ones
+# and differentiate through one `flash_bwd` where the parent ran
+# `flash_bwd_dkv` + `flash_bwd_dq` (181,920 characters at the parent);
+# Phi's grouped heads keep the two kernels, and its step the parent's text
+GPT_STEP = {"sha256": "19dfc12216236a754b9404fec054bee688a6f738e0d9618a2965c4cc4f"
+                      "a5843c", "chars": 171662}
 PHI_STEP = {"sha256": "690d521276607cd31eb4bc8c5ae0a007b80f684f3efceb81f3c6967f40"
                       "9f4ce3", "chars": 491187}
 # BERT's is the parent's less one dead equation: test_fused_head_blocks.py,

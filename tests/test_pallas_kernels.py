@@ -140,7 +140,7 @@ def test_every_pallas_call_has_a_stable_name(site):
 
 def test_pallas_call_names_are_distinct():
     names = [s[2] for s in _SITES]
-    assert len(names) == 5
+    assert len(names) == 6      # three flash + the fused backward; two scan
     assert len(set(names)) == len(names), names
 
 
