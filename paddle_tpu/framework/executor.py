@@ -347,7 +347,25 @@ class Executor(object):
             "writeback", time.perf_counter() - t0)
         resilience.observe_executor_step(
             "total", time.perf_counter() - t_total)
+        if obs.enabled() and getattr(program, "step_records", None):
+            self._record_step_state(program, scope)
         return out
+
+    @staticmethod
+    def _record_step_state(program, scope):
+        """One obs span a step for every counter a layer registered
+        (`Program.record_step_state`), from the state the step itself
+        wrote. Reads scope arrays only: no program runs and `cache_misses`
+        stays."""
+        for span, name, labels, summarize in program.step_records:
+            kept = scope.find_var(name)
+            if kept is None:
+                continue
+            value = np.asarray(kept)
+            at = obs.now()
+            obs.record(span, at, at, **dict(
+                labels, **(summarize(value) if summarize
+                           else {"value": value.tolist()})))
 
     @staticmethod
     def _writeback(scope, state_names, new_state, fetches, return_numpy):

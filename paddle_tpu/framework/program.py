@@ -349,6 +349,7 @@ class Program(object):
         self._version = 0
         self.random_seed = 0
         self._op_role = "forward"   # forward | backward | optimize | lr_sched
+        self.step_records = []      # record_step_state's entries
 
     # ---- block management -------------------------------------------------
     def global_block(self):
@@ -375,6 +376,15 @@ class Program(object):
     @property
     def num_blocks(self):
         return len(self.blocks)
+
+    def record_step_state(self, span, var_name, labels=None, summarize=None):
+        """Ask for an obs span named `span` a step from the persistable
+        `var_name` the step writes: while obs is on, `Executor.run` reads the
+        array after the step and records the span with `labels` and with
+        what `summarize(array)` returns (a dict; default {"value": the
+        array as a list}). Any layer may register its counters here."""
+        self.step_records.append((span, var_name, dict(labels or {}),
+                                  summarize))
 
     # ---- introspection ----------------------------------------------------
     def all_parameters(self):
@@ -424,6 +434,7 @@ class Program(object):
         allow = getattr(self, "_analysis_allowlist", None)
         if allow:
             p._analysis_allowlist = dict(allow)
+        p.step_records = list(self.step_records)
         for blk in self.blocks:
             nb = Block(p, blk.idx, blk.parent_idx)
             for v in blk.vars.values():
@@ -487,6 +498,7 @@ class Program(object):
         p._version = 0
         p.random_seed = d.get("random_seed", 0)
         p._op_role = "forward"
+        p.step_records = []     # callables are no part of the saved form
         for bd in d["blocks"]:
             blk = Block(p, bd["idx"], bd["parent_idx"])
             for vd in bd["vars"]:

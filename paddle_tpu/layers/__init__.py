@@ -17,6 +17,7 @@ from .extras import *        # noqa: F401,F403
 from .rnn import *           # noqa: F401,F403
 from .attention import *     # noqa: F401,F403
 from .ssm import *           # noqa: F401,F403
+from .moe import *           # noqa: F401,F403
 from .collective import *    # noqa: F401,F403
 from .distributions import (Normal, Uniform, Categorical,  # noqa: F401
                             MultivariateNormalDiag)

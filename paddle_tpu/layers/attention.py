@@ -60,6 +60,40 @@ def fused_attention(q, k, v, mask=None, scale=None, causal=False,
     return out
 
 
+def rope_qk_norm(q, k, head_dim, theta=10000.0, epsilon=1e-5,
+                 q_norm_attr=None, k_norm_attr=None, name=None):
+    """q (B, T, Hq*D), k (B, T, Hkv*D) -> (q', k') head-major, (B, H, T, D):
+    an RMS norm over each head's D numbers with a learned float32 scale of
+    D (`*_norm_attr=False`: no norm), then rotary positions over the whole
+    head (pairs (i, i + D/2), base `theta`), in one elementwise op in
+    front of `fused_attention`."""
+    from ..initializer import ConstantInitializer
+    helper = LayerHelper("rope_qk_norm", name=name)
+    inputs = {"Q": [q.name], "K": [k.name]}
+    for slot, attr, suffix in (("QScale", q_norm_attr, "_q_norm_s"),
+                               ("KScale", k_norm_attr, "_k_norm_s")):
+        if attr is False:
+            continue
+        attr = ParamAttr._to_attr(attr)
+        if attr.name is None:
+            attr.name = helper.name + suffix
+        scale = helper.create_parameter(
+            attr, shape=[head_dim], dtype="float32",
+            default_initializer=ConstantInitializer(1.0))
+        inputs[slot] = [scale.name]
+    outs = []
+    for x in (q, k):
+        width = x.shape[2]
+        outs.append(helper.create_variable_for_type_inference(
+            x.dtype, (x.shape[0], width // head_dim, x.shape[1], head_dim)))
+    helper.append_op("rope_qk_norm", inputs=inputs,
+                     outputs={"QOut": [outs[0].name],
+                              "KOut": [outs[1].name]},
+                     attrs={"head_dim": int(head_dim), "theta": float(theta),
+                            "epsilon": float(epsilon)})
+    return outs[0], outs[1]
+
+
 def mha_kv_projection(keys, values, d_key, d_value, n_head,
                       param_initializer=None, name="multi_head_att"):
     """Project encoder output once into head-split K/V for cross-attention

@@ -1,0 +1,156 @@
+"""Sparse experts: `moe_ffn`, a dropless expert layer that is told which
+experts it holds (ops/moe_ops.py), and `moe_balance`, what the step does with
+the layer's load once a step: keep the count and move the expert bias."""
+import numpy as np
+
+from ..initializer import ConstantInitializer
+from ..layer_helper import LayerHelper
+from ..param_attr import ParamAttr
+from .tensor import assign
+
+__all__ = ["moe_ffn", "moe_balance"]
+
+
+def moe_ffn(x, num_experts, top_k, ffn_size, experts_held=None,
+            norm_topk_prob=True, routed_scaling_factor=1.0,
+            router_attr=None, gate_up_attr=None, down_attr=None, name=None):
+    """(out, load). out is the sum over the picks e of
+    w_e * W2_e(silu(W1_e x) * W3_e x) for the tokens x (tokens, d): the
+    scores are sigmoid(x W_r) over all `num_experts`, the picks the top
+    `top_k` of scores + bias, w the picks' scores (over their sum + 1e-6
+    where `norm_topk_prob`, times `routed_scaling_factor`). load is the
+    count of picks each of the `num_experts` experts received this step
+    (int32 (num_experts,)): hand it to `moe_balance`, out of the
+    `recompute_segment` if the layer is in one.
+
+    `experts_held=(first, count)` says which experts live here (default:
+    all): the result is the part THEY give, and a pick on an absent expert
+    adds nothing (an expert-parallel rank's share; the sum over the ranks'
+    results is the whole layer's). No row is dropped under any imbalance.
+
+    The router's (d, num_experts) matrix is float32; each of the held
+    experts' two matrices is one stacked leaf in x's dtype, (count, d,
+    2*ffn_size) gate and up side by side and (count, ffn_size, d). The
+    expert bias is a persistable float32 buffer of zeros named
+    `<name>_expert_bias`, not a Parameter: it takes no gradient and the
+    optimizer never sees it; `moe_balance` moves it."""
+    helper = LayerHelper("moe_ffn", name=name)
+    name = helper.name
+    first, count = experts_held or (0, num_experts)
+    if first < 0 or count < 1 or first + count > num_experts:
+        raise ValueError("moe_ffn: experts_held=(%d, %d) is no range of "
+                         "%d experts" % (first, count, num_experts))
+    if not 1 <= top_k <= num_experts:
+        raise ValueError("moe_ffn: top_k %d of %d experts"
+                         % (top_k, num_experts))
+    d = x.shape[-1]
+
+    def attr(given, suffix):
+        given = ParamAttr._to_attr(given)
+        if given.name is None:
+            given.name = name + suffix
+        return given
+
+    w_r = helper.create_parameter(attr(router_attr, "_router.w_0"),
+                                  shape=[d, num_experts], dtype="float32")
+    w13 = helper.create_parameter(attr(gate_up_attr, "_experts_gate_up"),
+                                  shape=[count, d, 2 * ffn_size],
+                                  dtype=x.dtype)
+    w2 = helper.create_parameter(attr(down_attr, "_experts_down"),
+                                 shape=[count, ffn_size, d], dtype=x.dtype)
+    held = [int(first), int(count)]
+    bias = _expert_bias(helper, name, num_experts)
+
+    def tmp(dtype, shape=None, stop_gradient=False):
+        return helper.create_variable_for_type_inference(
+            dtype, shape, stop_gradient=stop_gradient)
+
+    tokens = x.shape[0]
+    top_w = tmp("float32", (tokens, top_k))
+    top_e = tmp("int32", (tokens, top_k), True)
+    load = tmp("int32", (num_experts,), True)
+    helper.append_op(
+        "moe_route",
+        inputs={"X": [x.name], "W": [w_r.name], "Bias": [bias.name]},
+        outputs={"TopW": [top_w.name], "TopE": [top_e.name],
+                 "Load": [load.name]},
+        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob),
+               "routed_scaling_factor": float(routed_scaling_factor)})
+    rows, pos, row_pair = tmp(x.dtype), tmp("int32", None, True), \
+        tmp("int32", None, True)
+    sizes, tile_group = tmp("int32", (count,), True), tmp("int32", None, True)
+    helper.append_op(
+        "moe_dispatch", inputs={"X": [x.name], "TopE": [top_e.name]},
+        outputs={"Rows": [rows.name], "Pos": [pos.name],
+                 "RowPair": [row_pair.name], "GroupSizes": [sizes.name],
+                 "TileGroup": [tile_group.name]},
+        attrs={"experts_held": held})
+    y = tmp(x.dtype)
+    helper.append_op(
+        "moe_experts",
+        inputs={"Rows": [rows.name], "W13": [w13.name], "W2": [w2.name],
+                "GroupSizes": [sizes.name], "TileGroup": [tile_group.name]},
+        outputs={"Out": [y.name]})
+    out = tmp(x.dtype, x.shape)
+    helper.append_op(
+        "moe_combine",
+        inputs={"Y": [y.name], "TopW": [top_w.name], "Pos": [pos.name],
+                "RowPair": [row_pair.name]},
+        outputs={"Out": [out.name]})
+    return out, load
+
+
+def _expert_bias(helper, layer, num_experts):
+    block, name = helper.main_program.global_block(), layer + "_expert_bias"
+    if block.has_var(name):
+        return block.var(name)
+    bias = helper.create_global_variable(
+        name=name, persistable=True, dtype="float32", shape=[num_experts])
+    helper.set_variable_initializer(bias, ConstantInitializer(0.0))
+    return bias
+
+
+def moe_balance(load, layer, experts_held=None, bias_update_rate=0.0):
+    """What a step does once with `load` (`moe_ffn`'s second result for the
+    layer named `layer`), in the main block, where a segment's results
+    arrive (a segment's own writes do not leave it, and its replay in the
+    backward would write twice):
+    - keeps it as the persistable `<layer>_expert_load`. While obs is on,
+      `Executor.run` reads it back after the step and records a `moe.load`
+      span: `layer`, and over the experts held `rows_held` (their sum: the
+      picks that landed here), `rows_max`, `rows_mean`;
+    - where `bias_update_rate` > 0, the loss-free balance step on
+      `<layer>_expert_bias`: + rate for every expert under the mean load,
+      - rate for every one over it. The load over ALL experts is known on
+      every expert-parallel rank, so each rank makes the same update alone.
+      The forward pass and its replay read the bias the step began with."""
+    helper = LayerHelper("moe_balance")
+    program = helper.main_program
+    if program.current_block().idx != 0:
+        raise ValueError(
+            "moe_balance(%r) inside a sub-block would be lost with the "
+            "block's own values: call it where the segment's results "
+            "arrive" % layer)
+    num_experts = int(load.shape[0])
+    first, count = experts_held or (0, num_experts)
+    kept = helper.create_or_get_global_variable(
+        layer + "_expert_load", persistable=True, dtype="int32",
+        shape=[num_experts])
+    helper.set_variable_initializer(kept, ConstantInitializer(0))
+    assign(load, output=kept)
+
+    def summarize(counts, first=int(first), count=int(count)):
+        rows = np.asarray(counts)[first:first + count]
+        return {"rows_held": int(rows.sum()), "rows_max": int(rows.max()),
+                "rows_mean": float(rows.mean())}
+
+    program.record_step_state("moe.load", kept.name, {"layer": layer},
+                              summarize)
+    if bias_update_rate:
+        bias = _expert_bias(helper, layer, num_experts)
+        helper.append_op(
+            "moe_bias_update",
+            inputs={"Bias": [bias.name], "Load": [load.name]},
+            outputs={"Out": [bias.name]},
+            attrs={"rate": float(bias_update_rate)})
+    return kept

@@ -9,7 +9,8 @@ computation through VMEM without materializing the (T,T) scores in HBM.
 import jax
 import jax.numpy as jnp
 
-from .registry import register_op
+from .registry import register_op, register_shape_rule
+from .shape_rules import TensorMeta, _x
 
 
 def _sdpa_xla(q, k, v, mask, scale, causal, window=None):
@@ -94,3 +95,56 @@ def _sdpa(ctx, ins, attrs):
             return {"Out": flash_attention(q, k, v, mask=mask, scale=scale,
                                            causal=causal, window=window)}
     return {"Out": _sdpa_xla(q, k, v, mask, scale, causal, window)}
+
+
+def rotate_half(x, theta):
+    """Rotary positions over the whole head of x (..., T, D), position t at
+    row t: pairs (i, i + D/2) turn by t * theta^(-2i/D). float32 in and
+    out."""
+    t, d = x.shape[-2], x.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+
+
+@register_op("rope_qk_norm")
+def _rope_qk_norm(ctx, ins, attrs):
+    """What stands between the q/k projections and the attention call: an
+    RMS norm over each head's D numbers with a learned float32 scale (QScale,
+    KScale: (D,); left out where the slot is empty), then rotary positions
+    (`rotate_half`), one elementwise pass in float32. Q (B, T, Hq*D) and
+    K (B, T, Hkv*D) come back head-major, (B, H, T, D), in their own dtype:
+    the layout the attention op reads."""
+    theta, eps = float(attrs["theta"]), float(attrs.get("epsilon", 1e-5))
+    d = int(attrs["head_dim"])
+
+    def one(x, scale):
+        b, t, width = x.shape
+        xf = x.astype(jnp.float32).reshape(b, t, width // d, d)
+        xf = jnp.transpose(xf, (0, 2, 1, 3))
+        if scale:
+            xf = xf * jax.lax.rsqrt(
+                jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps) \
+                * scale[0].astype(jnp.float32)
+        return rotate_half(xf, theta).astype(x.dtype)
+
+    return {"QOut": one(ins["Q"][0], ins.get("QScale")),
+            "KOut": one(ins["K"][0], ins.get("KScale"))}
+
+
+@register_shape_rule("rope_qk_norm")
+def _rope_qk_norm_rule(op, ins, attrs):
+    d = int(attrs["head_dim"])
+    out = {}
+    for slot in ("Q", "K"):
+        m = _x(ins, slot)
+        shape = None
+        if m.shape is not None and len(m.shape) == 3:
+            b, t, width = m.shape
+            heads = width // d if width not in (None, -1) else None
+            shape = (b, heads, t, d)
+        out[slot + "Out"] = [TensorMeta(shape, m.dtype)]
+    return out

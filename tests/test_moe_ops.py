@@ -1,0 +1,544 @@
+"""The sparse-expert ops against `jax.numpy` in value and gradient: the
+grouped matmul (Pallas kernels in interpret mode, and the XLA form) against a
+dense per-row product; `moe_route`, `moe_dispatch`, `moe_experts` and
+`moe_combine` composed as `layers.moe_ffn` composes them, and the layer
+through a Program, against the dense masked sum; the four shares of one layer
+adding up to the uncut layer; routing that puts every pick, or no pick, on
+the held experts; `rope_qk_norm`; and the gated width-3 convolution."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu import layers
+from paddle_tpu.ops import moe_ops
+from paddle_tpu.ops.pallas import grouped_matmul as gm
+from paddle_tpu.ops.registry import get_op
+
+
+# ---------------------------------------------------------------------------
+# the grouped matmul
+# ---------------------------------------------------------------------------
+
+def _dense_grouped(x, w, sizes, tm):
+    """out[r] = x[r] @ w[group(r)] with the rows of no group zeroed, and
+    the mask of the rows that belong to a group."""
+    lay = gm.layout(sizes, x.shape[0], tm)
+    row = jnp.arange(x.shape[0])
+    inside = (row % tm) < lay["tile_end"][row // tm]
+    out = jnp.einsum("rk,rkn->rn", x, w[lay["tile_group"][row // tm]])
+    return jnp.where(inside[:, None], out, 0.0), inside
+
+
+SIZES = [[5, 0, 17, 8], [0, 0, 0, 0], [30, 0, 0, 0], [0, 0, 0, 30],
+         [8, 8, 8, 6], [1, 1, 1, 1]]
+
+
+@pytest.mark.parametrize("form", ["pallas-interpret", "xla"])
+@pytest.mark.parametrize("sizes", SIZES, ids=lambda s: "-".join(map(str, s)))
+def test_grouped_matmul_equals_the_dense_product_and_its_gradients(form,
+                                                                   sizes):
+    tm, groups, k, n = 8, 4, 16, 24
+    sizes = jnp.asarray(sizes, jnp.int32)
+    rows = gm.buffer_rows(30, groups, tm)
+    kx, kw, kd = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(kx, (rows, k))
+    w = jax.random.normal(kw, (groups, k, n))
+    cot = jax.random.normal(kd, (rows, n))
+    want, inside = _dense_grouped(x, w, sizes, tm)
+    interpret = True if form == "pallas-interpret" else None
+
+    def mine(x_, w_):
+        out = gm.grouped_matmul(x_, w_, sizes, tm, interpret=interpret)
+        return jnp.where(inside[:, None], out, 0.0)
+
+    np.testing.assert_allclose(mine(x, w), want, rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda a, b: jnp.sum(mine(a, b) * cot), (0, 1))(x, w)
+    ref = jax.grad(lambda a, b: jnp.sum(
+        _dense_grouped(a, b, sizes, tm)[0] * cot), (0, 1))(x, w)
+    np.testing.assert_allclose(jnp.where(inside[:, None], got[0], 0.0),
+                               ref[0], rtol=1e-4, atol=1e-4)
+    # an empty group's weight gradient is written, as zeros
+    np.testing.assert_allclose(got[1], ref[1], rtol=1e-4, atol=1e-4)
+
+
+def test_grouped_matmul_kernels_at_mxu_tiles_in_bfloat16():
+    """128-row tiles, widths that `plan` tiles (K walked in two blocks by
+    dX, N in three by the forward): the Pallas path as the chip takes it,
+    in interpret mode."""
+    tm, groups, k, n = 128, 3, 256, 384
+    sizes = jnp.asarray([130, 0, 255], jnp.int32)
+    rows = gm.buffer_rows(512, groups, tm)
+    what = gm.plan(rows, k, n, tm)
+    assert what == gm.Tiles(128, (384, 256), (256, 384), (256, 384))
+    kx, kw = jax.random.split(jax.random.PRNGKey(1))
+    x = jax.random.normal(kx, (rows, k)).astype(jnp.bfloat16)
+    w = (0.1 * jax.random.normal(kw, (groups, k, n))).astype(jnp.bfloat16)
+    want, inside = _dense_grouped(x.astype(jnp.float32),
+                                  w.astype(jnp.float32), sizes, tm)
+
+    def mine(x_, w_):
+        out = gm.grouped_matmul(x_, w_, sizes, tm, interpret=True)
+        return jnp.where(inside[:, None], out.astype(jnp.float32), 0.0)
+
+    np.testing.assert_allclose(mine(x, w), want, rtol=2e-2, atol=2e-2)
+    dx, dw = jax.grad(lambda a, b: jnp.sum(mine(a, b) ** 2), (0, 1))(x, w)
+    rx, rw = jax.grad(lambda a, b: jnp.sum(
+        _dense_grouped(a, b, sizes, tm)[0] ** 2), (0, 1))(
+            x.astype(jnp.float32), w.astype(jnp.float32))
+    assert dx.dtype == dw.dtype == jnp.bfloat16
+    for got, ref in ((jnp.where(inside[:, None], dx, 0), rx), (dw, rw)):
+        scale = float(jnp.max(jnp.abs(ref)))
+        assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref))) \
+            <= 3e-2 * scale
+
+
+def test_the_tile_rule_and_the_buffer():
+    assert [gm.row_tile(p) for p in (64, 1023, 1024, 8191, 8192, 65536)] \
+        == [8, 8, 128, 128, 512, 512]
+    # the cell's call: 16,384 tokens x 4 picks over 8 held experts
+    assert gm.buffer_rows(65536, 8, 512) == 69632
+    assert gm.plan(69632, 2048, 3584, 512) == gm.Tiles(
+        512, (512, 2048), (512, 1792), (1024, 512))
+    assert gm.plan(69632, 1792, 2048, 512).fwd == (512, 1792)
+    assert gm.plan(96, 16, 24, 8) is None           # widths: the XLA form
+    assert gm.plan(69632, 2048, 3584, 8) is None    # rows: the XLA form
+    # any split of the pairs fits: one group takes all, or each a tile more
+    for sizes in ([30, 0, 0, 0], [8, 8, 7, 7], [1, 1, 1, 27]):
+        lay = gm.layout(jnp.asarray(sizes, jnp.int32), 64, 8)
+        assert int(lay["tiles"]) * 8 <= 64
+        assert int(jnp.sum(lay["tile_end"])) == 30
+    with pytest.raises(ValueError, match="do not fit"):
+        gm.grouped_matmul(jnp.zeros((16, 4)), jnp.zeros((2, 5, 4)),
+                          jnp.zeros((2,), jnp.int32), 8)
+
+
+# ---------------------------------------------------------------------------
+# the expert layer: ops composed as layers.moe_ffn composes them
+# ---------------------------------------------------------------------------
+
+def _op(name, ins, attrs=None):
+    return get_op(name).fn(None, {k: [v] for k, v in ins.items()},
+                           attrs or {})
+
+
+def moe_by_ops(x, w_r, bias, w13, w2, top_k, held, routed_picks=None):
+    route = _op("moe_route", {"X": x, "W": w_r, "Bias": bias},
+                {"top_k": top_k})
+    picks = route["TopE"] if routed_picks is None else routed_picks
+    d = _op("moe_dispatch", {"X": x, "TopE": picks},
+            {"experts_held": list(held)})
+    y = _op("moe_experts", {"Rows": d["Rows"], "W13": w13, "W2": w2,
+                            "GroupSizes": d["GroupSizes"],
+                            "TileGroup": d["TileGroup"]})["Out"]
+    out = _op("moe_combine", {"Y": y, "TopW": route["TopW"],
+                              "Pos": d["Pos"], "RowPair": d["RowPair"]})
+    return out["Out"], d["GroupSizes"], picks
+
+
+def moe_dense(x, w_r, bias, w13, w2, top_k, held, routed_picks=None):
+    """Every held expert over every token, times the token's weight for
+    it (0 where it did not pick it)."""
+    scores = jax.nn.sigmoid(jnp.dot(x, w_r, precision="highest"))
+    _t, picks = jax.lax.top_k(scores + bias, top_k)
+    weights = jnp.take_along_axis(scores, picks, 1)
+    weights = weights / (weights.sum(1, keepdims=True) + 1e-6)
+    if routed_picks is not None:
+        picks = routed_picks
+    out = jnp.zeros_like(x)
+    for g in range(held[1]):
+        gate = jnp.sum(weights * (picks == held[0] + g), axis=1)
+        a, b = jnp.split(jnp.dot(x, w13[g], precision="highest"), 2, axis=1)
+        out += gate[:, None] * jnp.dot(jax.nn.silu(a) * b, w2[g],
+                                       precision="highest")
+    return out
+
+
+def _layer_weights(tokens=48, d=16, ff=8, experts=8, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return {"x": jax.random.normal(k[0], (tokens, d)),
+            "w_r": jax.random.normal(k[1], (d, experts)),
+            "bias": 0.3 * jax.random.normal(k[2], (experts,)),
+            "w13": 0.5 * jax.random.normal(k[3], (experts, d, 2 * ff)),
+            "w2": 0.5 * jax.random.normal(k[4], (experts, ff, d))}
+
+
+def _share(p, held):
+    lo, hi = held[0], held[0] + held[1]
+    return p["x"], p["w_r"], p["bias"], p["w13"][lo:hi], p["w2"][lo:hi]
+
+
+@pytest.mark.parametrize("held", [(0, 8), (0, 2), (2, 2), (5, 3)])
+def test_the_expert_layer_equals_the_dense_masked_sum(held):
+    p = _layer_weights()
+    args = _share(p, held)
+    got, sizes, picks = moe_by_ops(*args, 2, held)
+    np.testing.assert_allclose(got, moe_dense(*args, 2, held), rtol=1e-4,
+                               atol=1e-5)
+    # the load counts what landed on each held expert
+    want = [(np.asarray(picks) == held[0] + g).sum() for g in range(held[1])]
+    assert list(np.asarray(sizes)) == want
+    cot = jax.random.normal(jax.random.PRNGKey(9), got.shape)
+    which = (0, 1, 3, 4)            # x, the router, the experts' matrices
+    mine = jax.grad(lambda *a: jnp.sum(moe_by_ops(*a, 2, held)[0] * cot),
+                    which)(*args)
+    ref = jax.grad(lambda *a: jnp.sum(moe_dense(*a, 2, held) * cot),
+                   which)(*args)
+    for name, g, r in zip(("x", "router", "w13", "w2"), mine, ref):
+        np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """What ties the chip's share to the model: each of four ranks holds 8
+    of 32 experts, routes over all 32 and computes its own experts' part;
+    the parts add up to the whole layer (nothing is counted twice: there
+    is no shared expert)."""
+    p = _layer_weights(tokens=64, experts=32, seed=3)
+    whole = moe_dense(*_share(p, (0, 32)), 4, (0, 32))
+    parts, landed = [], 0
+    for first in (0, 8, 16, 24):
+        out, sizes, _picks = moe_by_ops(*_share(p, (first, 8)), 4,
+                                        (first, 8))
+        parts.append(out)
+        landed += int(jnp.sum(sizes))
+    assert landed == 64 * 4         # every pick lands on exactly one rank
+    np.testing.assert_allclose(sum(parts), whole, rtol=1e-4, atol=1e-5)
+    # and each share is no trivial part of it
+    assert all(float(jnp.max(jnp.abs(part))) > 1e-3 for part in parts)
+
+
+@pytest.mark.parametrize("case", ["every pick on one held expert",
+                                  "no pick on any held expert"])
+def test_imbalance_loses_no_row(case):
+    """The buffer is sized for the worst case: with every token's picks
+    forced onto held expert 3 (and one more held expert, picks being
+    distinct), and with every pick forced onto absent experts, value and
+    gradients still equal the dense masked sum."""
+    p = _layer_weights(tokens=40, experts=8, seed=5)
+    held = (2, 4)
+    args = _share(p, held)
+    if case.startswith("every"):
+        forced = jnp.tile(jnp.asarray([[5, 2]], jnp.int32), (40, 1))
+    else:
+        forced = jnp.tile(jnp.asarray([[0, 7]], jnp.int32), (40, 1))
+    got, sizes, _ = moe_by_ops(*args, 2, held, routed_picks=forced)
+    want = moe_dense(*args, 2, held, routed_picks=forced)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    if case.startswith("every"):
+        assert list(np.asarray(sizes)) == [40, 0, 0, 40]
+        assert float(jnp.max(jnp.abs(got))) > 1e-3
+    else:
+        assert list(np.asarray(sizes)) == [0, 0, 0, 0]
+        assert float(jnp.max(jnp.abs(got))) == 0.0
+    cot = jax.random.normal(jax.random.PRNGKey(2), got.shape)
+    which = (0, 1, 3, 4)
+    mine = jax.grad(lambda *a: jnp.sum(moe_by_ops(
+        *a, 2, held, routed_picks=forced)[0] * cot), which)(*args)
+    ref = jax.grad(lambda *a: jnp.sum(moe_dense(
+        *a, 2, held, routed_picks=forced) * cot), which)(*args)
+    for name, g, r in zip(("x", "router", "w13", "w2"), mine, ref):
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+def test_the_dispatch_plan_is_a_permutation_of_the_held_pairs():
+    picks = jnp.asarray([[0, 3], [3, 1], [2, 3], [1, 0], [3, 2]], jnp.int32)
+    pos, row_pair, sizes, tile_group = moe_ops.dispatch_plan(picks, 1, 2)
+    rows = row_pair.shape[0]
+    assert list(np.asarray(sizes)) == [2, 2]        # experts 1 and 2
+    assert rows == gm.buffer_rows(10, 2, 8) and tile_group.shape == (4,)
+    pos, row_pair = np.asarray(pos), np.asarray(row_pair)
+    held = (np.asarray(picks) >= 1) & (np.asarray(picks) <= 2)
+    assert (pos[~held] == rows).all() and (pos[held] < rows).all()
+    # a held pair's row names that pair, and no other row does
+    for t, j in zip(*np.nonzero(held)):
+        assert row_pair[pos[t, j]] == t * 2 + j
+    assert (row_pair >= 0).sum() == held.sum()
+    # expert 1's rows come first, each group from the start of a tile
+    assert sorted(pos[np.asarray(picks) == 1]) == [0, 1]
+    assert sorted(pos[np.asarray(picks) == 2]) == [8, 9]
+
+
+def test_weights_left_as_scores_and_scaled():
+    """`norm_topk_prob=False` leaves the picks' scores as they are and
+    `routed_scaling_factor` multiplies them (other routers of the family
+    state other values than the benchmark's configuration)."""
+    p = _layer_weights(tokens=16, experts=4, seed=8)
+    ins = {"X": p["x"], "W": p["w_r"], "Bias": jnp.zeros((4,))}
+    got = _op("moe_route", ins, {"top_k": 2, "norm_topk_prob": False,
+                                 "routed_scaling_factor": 2.5})
+    scores = np.asarray(jax.nn.sigmoid(p["x"] @ p["w_r"]))
+    want = np.take_along_axis(scores, np.asarray(got["TopE"]), 1)
+    np.testing.assert_allclose(got["TopW"], 2.5 * want, rtol=1e-5)
+    same = _op("moe_route", ins, {"top_k": 2})
+    np.testing.assert_allclose(
+        same["TopW"], want / (want.sum(1, keepdims=True) + 1e-6), rtol=1e-5)
+
+
+def test_the_bias_moves_the_choice_and_not_the_weights():
+    p = _layer_weights(tokens=16, experts=4, seed=7)
+    plain = _op("moe_route", {"X": p["x"], "W": p["w_r"],
+                              "Bias": jnp.zeros((4,))}, {"top_k": 2})
+    pushed = _op("moe_route", {"X": p["x"], "W": p["w_r"],
+                               "Bias": jnp.asarray([0., 0., 0., 10.])},
+                 {"top_k": 2})
+    assert (np.asarray(pushed["TopE"])[:, 0] == 3).all()
+    assert not (np.asarray(plain["TopE"])[:, 0] == 3).all()
+    scores = jax.nn.sigmoid(p["x"] @ p["w_r"])
+    got = np.asarray(pushed["TopW"])
+    want = np.take_along_axis(np.asarray(scores),
+                              np.asarray(pushed["TopE"]), 1)
+    np.testing.assert_allclose(got, want / (want.sum(1, keepdims=True)
+                                            + 1e-6), rtol=1e-5)
+    assert pushed["TopW"].dtype == jnp.float32
+
+
+# ---------------------------------------------------------------------------
+# through a Program
+# ---------------------------------------------------------------------------
+
+def _run(build, feed):
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        fetch = build()
+    exe = pt.Executor()
+    exe.run(startup)
+    names = sorted(fetch)
+    out = exe.run(main, feed=feed, fetch_list=[fetch[n] for n in names])
+    return dict(zip(names, out)), main
+
+
+@pytest.mark.parametrize("held", [None, (4, 4)])
+def test_moe_ffn_through_a_program_with_its_gradients(held):
+    x = np.random.RandomState(0).randn(24, 16).astype(np.float32)
+    first, count = held or (0, 8)
+
+    def build():
+        xv = layers.data("x", [24, 16], dtype="float32",
+                         append_batch_size=False)
+        xv.stop_gradient = False
+        out, load = layers.moe_ffn(xv, 8, 2, 8, experts_held=held,
+                                   name="moe")
+        layers.moe_balance(load, "moe", held)
+        block = pt.default_main_program().global_block()
+        params = [block.var(n) for n in ("moe_router.w_0",
+                                         "moe_experts_gate_up",
+                                         "moe_experts_down")]
+        loss = layers.reduce_sum(layers.elementwise_mul(out, out))
+        grads = pt.gradients([loss], [xv] + params)
+        return dict({"out": out, "load": block.var("moe_expert_load"),
+                     "bias": block.var("moe_expert_bias")},
+                    **{"w%d" % i: v for i, v in enumerate(params)},
+                    **{"g%d" % i: g for i, g in enumerate(grads)})
+
+    got, main = _run(build, {"x": x})
+    names = {p.name for p in main.global_block().all_parameters()}
+    assert names == {"moe_router.w_0", "moe_experts_gate_up",
+                     "moe_experts_down"}        # the bias is no Parameter
+    assert got["w1"].shape == (count, 16, 16) and got["w2"].shape \
+        == (count, 8, 16) and got["w0"].dtype == np.float32
+    assert (got["bias"] == 0).all() and got["bias"].shape == (8,)
+    args = (jnp.asarray(x), got["w0"], got["bias"], got["w1"], got["w2"])
+    want = moe_dense(*args, 2, (first, count))
+    np.testing.assert_allclose(got["out"], want, rtol=1e-4, atol=1e-5)
+    picks = jax.lax.top_k(jax.nn.sigmoid(x @ got["w0"]), 2)[1]
+    assert list(got["load"]) == [int((picks == e).sum()) for e in range(8)]
+    ref = jax.grad(lambda *a: jnp.sum(moe_dense(*a, 2, (first, count))
+                                      ** 2), (0, 1, 3, 4))(*args)
+    for i, r in enumerate(ref):
+        np.testing.assert_allclose(got["g%d" % i], r, rtol=3e-4, atol=3e-5)
+
+
+@pytest.mark.parametrize("kw,match", [
+    (dict(experts_held=(6, 4)), "no range"),
+    (dict(experts_held=(0, 0)), "no range"),
+    (dict(top_k=9), "top_k 9")])
+def test_moe_ffn_refuses_what_it_cannot_hold(kw, match):
+    with pt.program_guard(pt.Program(), pt.Program()):
+        xv = layers.data("x", [8, 16], dtype="float32",
+                         append_batch_size=False)
+        with pytest.raises(ValueError, match=match):
+            layers.moe_ffn(xv, 8, kw.pop("top_k", 2), 8, **kw)
+
+
+def test_moe_balance_inside_a_segment_is_refused_by_name():
+    with pt.program_guard(pt.Program(), pt.Program()):
+        xv = layers.data("x", [8, 16], dtype="float32",
+                         append_batch_size=False)
+
+        def segment(h):
+            out, load = layers.moe_ffn(h, 4, 2, 8, name="seg")
+            layers.moe_balance(load, "seg")
+            return out
+
+        with pytest.raises(ValueError, match="where the segment's results"):
+            layers.recompute_segment(segment, [xv])
+
+
+def test_moe_bias_update_is_the_loss_free_balance_step():
+    """+ rate under the mean load, - rate over it, nothing at it; the op
+    takes no gradient."""
+    from paddle_tpu.ops.registry import get_op
+    op = get_op("moe_bias_update")
+    assert not op.differentiable
+    bias = jnp.asarray([0.0, 0.5, -0.25, 0.125], jnp.float32)
+    load = jnp.asarray([10, 2, 6, 6], jnp.int32)        # mean 6
+    out = op.fn(None, {"Bias": [bias], "Load": [load]}, {"rate": 0.01})
+    np.testing.assert_allclose(out["Out"],
+                               [-0.01, 0.51, -0.25, 0.125], atol=1e-7)
+
+
+@pytest.mark.parametrize("recompute", [False, True])
+def test_moe_balance_moves_the_bias_once_a_step_and_the_picks_follow(
+        recompute):
+    """A router that sends everything to experts 0 and 1: with the update
+    on, the bias of the two falls and the others' rises a step, the
+    forward pass (and its replay under recompute) reads the bias the step
+    began with, and after enough steps the picks spread."""
+    from paddle_tpu import optimizer
+    from paddle_tpu.framework.scope import Scope
+    x = np.abs(np.random.RandomState(1).randn(32, 16)).astype(np.float32)
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        xv = layers.data("x", [32, 16], dtype="float32",
+                         append_batch_size=False)
+
+        def segment(h):
+            return list(layers.moe_ffn(h, 8, 2, 8, experts_held=(0, 4),
+                                       name="moe"))
+
+        out, load = layers.recompute_segment(segment, [xv]) if recompute \
+            else segment(xv)
+        layers.moe_balance(load, "moe", (0, 4), bias_update_rate=0.05)
+        loss = layers.reduce_mean(layers.elementwise_mul(out, out))
+        optimizer.SGD(0.0).minimize(loss)       # the weights stay
+    scope, exe = Scope(), pt.Executor()
+    exe.run(startup, scope=scope)
+    router = np.zeros((16, 8), np.float32)
+    router[:, :2] = 0.05                        # x > 0: experts 0, 1 win
+    scope.set_var("moe_router.w_0", jnp.asarray(router))
+    loads, biases = [], []
+    for _ in range(12):
+        exe.run(main, feed={"x": x}, fetch_list=[loss], scope=scope)
+        loads.append(np.asarray(scope.find_var("moe_expert_load")))
+        biases.append(np.asarray(scope.find_var("moe_expert_bias")))
+    assert exe.cache_misses == 1                # one compiled step
+    assert list(loads[0]) == [32, 32, 0, 0, 0, 0, 0, 0]
+    np.testing.assert_allclose(biases[0], [-0.05] * 2 + [0.05] * 6,
+                               atol=1e-7)
+    # the step that wrote biases[0] routed with the zeros it began with
+    assert list(loads[1]) != list(loads[0]) or biases[1][0] < biases[0][0]
+    assert loads[-1].sum() == 64 and loads[-1].max() < 32
+    assert (loads[-1] > 0).sum() > 2
+
+
+# ---------------------------------------------------------------------------
+# rotary positions with per-head q/k norms; the gated short convolution
+# ---------------------------------------------------------------------------
+
+def _rope_reference(x, scale, heads, d, theta, eps):
+    b, t, _ = x.shape
+    x = x.reshape(b, t, heads, d).transpose(0, 2, 1, 3)
+    if scale is not None:
+        x = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
+    out = []
+    for i in range(d // 2):         # pair (i, i + d/2), one angle a pair
+        angle = jnp.arange(t) * theta ** (-2.0 * i / d)
+        lo, hi = x[..., i], x[..., i + d // 2]
+        out.append((lo * jnp.cos(angle) - hi * jnp.sin(angle),
+                    lo * jnp.sin(angle) + hi * jnp.cos(angle)))
+    return jnp.stack([p[0] for p in out] + [p[1] for p in out], axis=-1)
+
+
+@pytest.mark.parametrize("norm", [True, False])
+def test_rope_qk_norm_equals_the_pairwise_rotation(norm):
+    rng = np.random.RandomState(1)
+    q = rng.randn(2, 12, 4 * 8).astype(np.float32)
+    k = rng.randn(2, 12, 2 * 8).astype(np.float32)
+    qs = (1.0 + 0.1 * rng.randn(8)).astype(np.float32)
+    ks = (1.0 + 0.1 * rng.randn(8)).astype(np.float32)
+
+    def build():
+        qv = layers.data("q", [2, 12, 32], dtype="float32",
+                         append_batch_size=False)
+        kv = layers.data("k", [2, 12, 16], dtype="float32",
+                         append_batch_size=False)
+        qv.stop_gradient = kv.stop_gradient = False
+        attr = (lambda n, v: pt.ParamAttr(
+            name=n, initializer=pt.initializer.NumpyArrayInitializer(v))) \
+            if norm else (lambda n, v: False)
+        qo, ko = layers.rope_qk_norm(qv, kv, 8, theta=100.0, epsilon=1e-5,
+                                     q_norm_attr=attr("qs", qs),
+                                     k_norm_attr=attr("ks", ks))
+        loss = layers.elementwise_add(
+            layers.reduce_sum(layers.elementwise_mul(qo, qo)),
+            layers.reduce_sum(layers.scale(ko, scale=2.0)))
+        dq, dk = pt.gradients([loss], [qv, kv])
+        return {"qo": qo, "ko": ko, "dq": dq, "dk": dk}
+
+    got, main = _run(build, {"q": q, "k": k})
+    assert len(main.global_block().all_parameters()) == (2 if norm else 0)
+    want_q = _rope_reference(jnp.asarray(q), qs if norm else None, 4, 8,
+                             100.0, 1e-5)
+    want_k = _rope_reference(jnp.asarray(k), ks if norm else None, 2, 8,
+                             100.0, 1e-5)
+    assert got["qo"].shape == (2, 4, 12, 8) and got["ko"].shape \
+        == (2, 2, 12, 8)
+    np.testing.assert_allclose(got["qo"], want_q, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(got["ko"], want_k, rtol=1e-4, atol=1e-5)
+    dq = jax.grad(lambda a: jnp.sum(_rope_reference(
+        a, qs if norm else None, 4, 8, 100.0, 1e-5) ** 2))(jnp.asarray(q))
+    dk = jax.grad(lambda a: 2.0 * jnp.sum(_rope_reference(
+        a, ks if norm else None, 2, 8, 100.0, 1e-5)))(jnp.asarray(k))
+    np.testing.assert_allclose(got["dq"], dq, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(got["dk"], dk, rtol=1e-4, atol=1e-4)
+    # position 0 is not turned; a rotation keeps each pair's length
+    if not norm:
+        np.testing.assert_allclose(
+            got["qo"][:, :, 0], q.reshape(2, 12, 4, 8)[:, 0], rtol=1e-6)
+        np.testing.assert_allclose(
+            (got["qo"] ** 2).sum(-1),
+            (q.reshape(2, 12, 4, 8).transpose(0, 2, 1, 3) ** 2).sum(-1),
+            rtol=1e-4)
+
+
+def test_the_gated_width_3_convolution_without_bias():
+    """The short-convolution mixer's middle: (C * conv3(B * x)), through
+    `causal_conv1d(width=3, bias_attr=False)` and `elementwise_mul`."""
+    rng = np.random.RandomState(2)
+    b, c, x = (rng.randn(2, 9, 6).astype(np.float32) for _ in range(3))
+
+    def build():
+        vs = [layers.data(n, [2, 9, 6], dtype="float32",
+                          append_batch_size=False) for n in "bcx"]
+        for v in vs:
+            v.stop_gradient = False
+        conv = layers.causal_conv1d(
+            layers.elementwise_mul(vs[0], vs[2]), 3,
+            param_attr=pt.ParamAttr(name="cw"), bias_attr=False)
+        out = layers.elementwise_mul(vs[1], conv)
+        w = pt.default_main_program().global_block().var("cw")
+        loss = layers.reduce_sum(layers.elementwise_mul(out, out))
+        grads = pt.gradients([loss], vs + [w])
+        return dict({"out": out, "w": w},
+                    **{"g%d" % i: g for i, g in enumerate(grads)})
+
+    got, main = _run(build, {"b": b, "c": c, "x": x})
+    assert [p.name for p in main.global_block().all_parameters()] == ["cw"]
+    assert got["w"].shape == (3, 6)
+
+    def f(b_, c_, x_, w_):
+        p = jnp.pad(b_ * x_, ((0, 0), (2, 0), (0, 0)))
+        return c_ * sum(p[:, i:i + 9] * w_[i] for i in range(3))
+
+    args = (b, c, x, got["w"])
+    np.testing.assert_allclose(got["out"], f(*args), rtol=1e-5, atol=1e-6)
+    ref = jax.grad(lambda *a: jnp.sum(f(*a) ** 2), (0, 1, 2, 3))(*args)
+    for i, r in enumerate(ref):
+        np.testing.assert_allclose(got["g%d" % i], r, rtol=1e-4, atol=1e-5)
+    # position t reads t-2..t only
+    later = x.copy()
+    later[:, 5:] += 1.0
+    np.testing.assert_allclose(f(b, c, later, got["w"])[:, :5],
+                               f(*args)[:, :5], rtol=1e-6)

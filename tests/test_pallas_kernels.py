@@ -101,8 +101,21 @@ def test_bert_and_gpt_heads_emit_the_fused_op():
 # every kernel carries a name of the program's choosing
 # ---------------------------------------------------------------------------
 
+def _literal_names(value):
+    """The names a `name=` argument can take: a string constant, or either
+    arm of a conditional between two (one call site that serves two
+    kernels); [None] for anything computed."""
+    import ast
+    if isinstance(value, ast.Constant):
+        return [value.value]
+    if isinstance(value, ast.IfExp):
+        return _literal_names(value.body) + _literal_names(value.orelse)
+    return [None]
+
+
 def _pallas_call_sites():
-    """(file, line, name or None) of every pl.pallas_call( in ops/pallas."""
+    """(file, line, name or None) of every pl.pallas_call( in ops/pallas,
+    one entry a name the site can give."""
     import ast
     import glob
     root = os.path.join(os.path.dirname(os.path.dirname(
@@ -115,10 +128,11 @@ def _pallas_call_sites():
             if isinstance(node, ast.Call) and isinstance(
                     node.func, ast.Attribute) \
                     and node.func.attr == "pallas_call":
-                name = next((kw.value.value for kw in node.keywords
-                             if kw.arg == "name"
-                             and isinstance(kw.value, ast.Constant)), None)
-                sites.append((os.path.basename(path), node.lineno, name))
+                names = next((_literal_names(kw.value)
+                              for kw in node.keywords if kw.arg == "name"),
+                             [None])
+                sites.extend((os.path.basename(path), node.lineno, name)
+                             for name in names)
     return sites
 
 
@@ -126,7 +140,7 @@ _SITES = _pallas_call_sites()
 
 
 @pytest.mark.parametrize("site", _SITES,
-                         ids=["%s:%d" % s[:2] for s in _SITES])
+                         ids=["%s:%d:%s" % s for s in _SITES])
 def test_every_pallas_call_has_a_stable_name(site):
     """The device trace tells the kernels apart by `name=` (it becomes the
     instruction's name and a component of its op_name), not by whatever
@@ -135,12 +149,14 @@ def test_every_pallas_call_has_a_stable_name(site):
     assert name and name.isidentifier(), site
     stem = fname[:-3]
     assert name.startswith({"flash_attention": "flash_",
-                            "selective_scan": "ssm_scan_"}.get(stem, stem))
+                            "selective_scan": "ssm_scan_",
+                            "grouped_matmul": "moe_gmm_"}.get(stem, stem))
 
 
 def test_pallas_call_names_are_distinct():
     names = [s[2] for s in _SITES]
-    assert len(names) == 6      # three flash + the fused backward; two scan
+    # three flash + the fused backward; two scan; grouped matmul's three
+    assert len(names) == 9
     assert len(set(names)) == len(names), names
 
 
