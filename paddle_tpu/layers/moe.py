@@ -28,6 +28,10 @@ def moe_ffn(x, num_experts, top_k, ffn_size, experts_held=None,
     adds nothing (an expert-parallel rank's share; the sum over the ranks'
     results is the whole layer's). No row is dropped under any imbalance.
 
+    w_e is applied in `moe_combine`, in float32, as each pick's row is
+    added to its token's sum; the rows change between token order and
+    expert order as plain row gathers in x's dtype (ops/moe_ops.py).
+
     The router's (d, num_experts) matrix is float32; each of the held
     experts' two matrices is one stacked leaf in x's dtype, (count, d,
     2*ffn_size) gate and up side by side and (count, ffn_size, d). The

@@ -11,6 +11,14 @@ its own names:
   moe_combine   each token's weighted sum of its picks' rows
 and `moe_bias_update`, the loss-free balance step on the expert bias.
 
+Where the weights are applied: in `moe_combine`, in float32, to each pick's
+row as it is added to its token's sum; backward, to each buffer row's
+gradient (`w_row`), and the weight's own gradient is a row-wise dot in row
+space (y . dOut's row) read back over `Pos`. Every crossing between token
+order and expert order is a plain row gather in the rows' own dtype: X ->
+buffer one take, buffer -> tokens k takes (one a pick) accumulated in
+float32. Nothing of shape (tokens, top_k, d) is written out.
+
 The layer holds experts `first .. first + count - 1` of `num_experts` (the
 expert-parallel rank's share). A pick that lands on an absent expert adds
 nothing: the result is the part the held experts give. Nothing stands in for
@@ -24,7 +32,11 @@ that leaves this file's ops goes through a `where` on the pick's own mask.
 
 Gather both ways: `moe_dispatch` also returns the inverse map (`RowPair`:
 which pair sits in each row), so the backward of a gather over `Pos` is a
-gather over `RowPair` and no scatter is ever lowered.
+gather over `RowPair`, and the other way round: `_rows_of_tokens` and
+`sum_of_picks` are each other's transpose, `moe_dispatch` runs the first
+forward and the second backward, `moe_combine` the second forward and the
+first backward. No scatter is ever lowered (`moe_route` reads the picks'
+scores through the one-hot of the picks for the same reason).
 
 Reference parity: none (the reference predates sparse experts). The
 equations are the published `lfm2_moe` block's: sigmoid scores, an expert
@@ -58,12 +70,14 @@ def _moe_route(ctx, ins, attrs):
                                     precision=_HIGHEST))
     choose = scores + ins["Bias"][0].astype(jnp.float32)
     _top, picks = lax.top_k(lax.stop_gradient(choose), int(attrs["top_k"]))
-    weights = jnp.take_along_axis(scores, picks, axis=1)
+    # the picks' scores through the picks' one-hot: exact (one term a sum
+    # is not 0), and its backward is a select, not a scatter
+    picked = picks[..., None] == jnp.arange(w.shape[1])
+    weights = jnp.sum(jnp.where(picked, scores[:, None, :], 0.0), axis=-1)
     if attrs.get("norm_topk_prob", True):
         weights = weights / (jnp.sum(weights, axis=1, keepdims=True) + 1e-6)
     weights = weights * float(attrs.get("routed_scaling_factor", 1.0))
-    load = jnp.sum(picks.reshape(-1, 1) == jnp.arange(w.shape[1])[None, :],
-                   axis=0, dtype=jnp.int32)
+    load = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
     return {"TopW": weights, "TopE": picks.astype(jnp.int32), "Load": load}
 
 
@@ -106,7 +120,13 @@ def dispatch_plan(picks, first, count):
     """Where every pair goes. picks [tokens, k] int32 over all experts.
     Returns (pos [tokens, k]: the pair's buffer row, or `rows` (past the
     end) where its expert is absent; row_pair [rows]: the pair in each row,
-    -1 for padding; group_sizes [count]; tile_group [rows / tm])."""
+    -1 for padding; group_sizes [count]; tile_group [rows / tm]).
+
+    What the sort leaves in sorted-pair order reaches row order as `count`
+    shifted copies (group g's run lands on group g's tiles whole), and
+    `pos` by compares against the groups: on the chip a gather of 70k
+    scalars costs 0.5-0.7 ms, as much as one of 16k whole rows (PERF.md,
+    PR 32), so the plan has none over the rows or the pairs."""
     tokens, k = picks.shape
     pairs = tokens * k
     tm = gmm.row_tile(pairs)
@@ -118,32 +138,49 @@ def dispatch_plan(picks, first, count):
     lay = gmm.layout(sizes, rows, tm)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     rank_of = jnp.argsort(order).astype(jnp.int32)   # pair -> sorted index
-    sorted_start = jnp.cumsum(sizes) - sizes
-    shift = jnp.concatenate([lay["starts"] - sorted_start,
-                             jnp.zeros((1,), jnp.int32)])
-    pos = jnp.where(key < count, rank_of + shift[key], rows)
+    shift = lay["starts"] - (jnp.cumsum(sizes) - sizes)
+    pos = rows
+    for g in range(count):
+        pos = jnp.where(key == g, rank_of + shift[g], pos)
     row = jnp.arange(rows, dtype=jnp.int32)
-    group = lay["tile_group"][row // tm]
-    inside = (row % tm) < lay["tile_end"][row // tm]
-    row_pair = jnp.where(
-        inside, order[jnp.clip(row - shift[group], 0, pairs - 1)], -1)
+    pad = jnp.zeros((rows,), jnp.int32)
+    padded = jnp.concatenate([pad, order, pad])
+    row_pair = jnp.full((rows,), -1, jnp.int32)
+    for g in range(count):      # [r] = order[r - shift[g]] on group g's rows
+        start = lay["starts"][g]
+        run = lax.dynamic_slice(padded, (rows - shift[g],), (rows,))
+        row_pair = jnp.where((row >= start) & (row < start + sizes[g]), run,
+                             row_pair)
     return pos.reshape(tokens, k), row_pair, sizes, lay["tile_group"]
 
 
-def _take_held(buf, pos):
-    """(buf[pos] in float32, the picks whose expert is held): a pick on an
-    absent expert points past the buffer and reads its last row, which the
-    mask keeps out of every sum."""
+def _rows_of_tokens(x, row_pair, k):
+    """rows[r] = x[token of the pair in row r] (padding rows: token 0)."""
+    return jnp.take(x, jnp.maximum(row_pair, 0) // k, axis=0, mode="clip")
+
+
+def sum_of_picks(buf, pos, weights=None):
+    """out[t] = sum_j weights[t, j] buf[pos[t, j]] over the picks whose
+    expert is held (`weights` None: the plain sum): one plain row gather a
+    pick, accumulated in float32, cast once. A pick on an absent expert
+    points past the buffer and reads its last row (`clip`), which the
+    `where` keeps out of the sum. (The `where` in the rows' own dtype,
+    before the cast: XLA then folds the casts into the sum; cast first, it
+    wrote each gather out again in float32, PR 32.)"""
     rows = buf.shape[0]
-    got = jnp.take(buf, jnp.minimum(pos, rows - 1), axis=0)
-    return got.astype(jnp.float32), pos < rows
+    total = 0.0
+    for j in range(pos.shape[1]):
+        got = jnp.take(buf, pos[:, j], axis=0, mode="clip")
+        part = jnp.where((pos[:, j] < rows)[:, None], got,
+                         0).astype(jnp.float32)
+        total = total + (part if weights is None
+                         else part * weights[:, j, None])
+    return total.astype(buf.dtype)
 
 
 @jax.custom_vjp
 def _gather_rows(x, pos, row_pair):
-    """rows[r] = x[token of the pair in row r] (padding rows: token 0)."""
-    k = pos.shape[1]
-    return jnp.take(x, jnp.maximum(row_pair, 0) // k, axis=0)
+    return _rows_of_tokens(x, row_pair, pos.shape[1])
 
 
 def _gather_rows_fwd(x, pos, row_pair):
@@ -151,9 +188,7 @@ def _gather_rows_fwd(x, pos, row_pair):
 
 
 def _gather_rows_bwd(pos, d_rows):
-    got, held = _take_held(d_rows, pos)
-    dx = jnp.sum(jnp.where(held[..., None], got, 0.0), axis=1)
-    return dx.astype(d_rows.dtype), None, None
+    return sum_of_picks(d_rows, pos), None, None
 
 
 _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
@@ -218,11 +253,7 @@ def _moe_experts_rule(op, ins, attrs):
 
 @jax.custom_vjp
 def _combine(y, weights, pos, row_pair):
-    """out[t] = sum_j weights[t, j] * y[pos[t, j]] over the picks whose
-    expert is held, in float32."""
-    got, held = _take_held(y, pos)
-    part = jnp.where(held[..., None], got * weights[..., None], 0.0)
-    return jnp.sum(part, axis=1).astype(y.dtype)
+    return sum_of_picks(y, pos, weights)
 
 
 def _combine_fwd(y, weights, pos, row_pair):
@@ -230,14 +261,22 @@ def _combine_fwd(y, weights, pos, row_pair):
 
 
 def _combine_bwd(res, d_out):
+    """dy = w_row times dOut's row of each buffer row's token (one plain
+    gather); the weight's gradient a pick at a time, y's row dotted with
+    dOut's, under the held mask."""
     y, weights, pos, row_pair = res
-    pair = jnp.maximum(row_pair, 0)
-    w_row = jnp.where(row_pair >= 0, weights.reshape(-1)[pair], 0.0)
-    dy = (jnp.take(d_out, pair // pos.shape[1], axis=0).astype(jnp.float32)
+    rows, k = y.shape[0], pos.shape[1]
+    w_row = jnp.where(row_pair >= 0, jnp.take(
+        weights.reshape(-1), jnp.maximum(row_pair, 0), mode="clip"), 0.0)
+    dy = (_rows_of_tokens(d_out, row_pair, k).astype(jnp.float32)
           * w_row[:, None]).astype(y.dtype)
-    got, held = _take_held(y, pos)
-    dw = jnp.sum(got * d_out.astype(jnp.float32)[:, None, :], axis=-1)
-    return dy, jnp.where(held, dw, 0.0), None, None
+    dw = []
+    for j in range(k):
+        got = jnp.take(y, pos[:, j], axis=0, mode="clip")
+        got = jnp.where((pos[:, j] < rows)[:, None], got, 0)
+        dw.append(jnp.sum(got.astype(jnp.float32)
+                          * d_out.astype(jnp.float32), axis=-1))
+    return dy, jnp.stack(dw, axis=1), None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -245,6 +284,8 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 @register_op("moe_combine")
 def _moe_combine(ctx, ins, attrs):
+    """Out[t] = sum_j TopW[t, j] Y[Pos[t, j]] over the picks whose expert
+    is held, in float32."""
     return {"Out": _combine(ins["Y"][0], ins["TopW"][0], ins["Pos"][0],
                             ins["RowPair"][0])}
 
