@@ -18,6 +18,7 @@ from .rnn import *           # noqa: F401,F403
 from .attention import *     # noqa: F401,F403
 from .ssm import *           # noqa: F401,F403
 from .moe import *           # noqa: F401,F403
+from .linear_attn import *   # noqa: F401,F403
 from .collective import *    # noqa: F401,F403
 from .distributions import (Normal, Uniform, Categorical,  # noqa: F401
                             MultivariateNormalDiag)

@@ -27,7 +27,11 @@ def causal_conv1d(input, width, param_attr=None, bias_attr=None, act=None,
                   name=None):
     """Depthwise causal convolution along time of (B, T, E): a (width, E)
     weight and an (E,) bias (`bias_attr=False`: none), in the input's
-    dtype."""
+    dtype; `act` is applied to the result. The models' uses: width 4 with a
+    bias and `act="silu"` in front of the selective scan (phi4flash), width
+    3 without bias or activation between two gates (lfm2moe), width 4
+    without bias and `act="silu"` over the q, k, v projections of a
+    delta-rule layer (`layers.kda_attention`)."""
     helper = LayerHelper("causal_conv1d", param_attr=param_attr,
                          bias_attr=bias_attr, act=act, name=name)
     e = input.shape[-1]

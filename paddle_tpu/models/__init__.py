@@ -13,6 +13,10 @@ LARK/ERNIE repos, rebuilt on paddle_tpu layers).
 - gpt: GPT-style causal LM (long-context flagship: flash/ring/ulysses
   attention, greedy_generate decode)
 - dcgan: DCGAN adversarial training as one fused two-optimizer step
+- phi4flash, lfm2moe, kimi_linear: hybrid decoders the benchmark trains
+  (selective scan + differential attention; short convolutions + sparse
+  experts; delta-rule linear attention + latent attention + sparse experts
+  with a shared expert)
 """
 from . import bert
 from . import resnet
@@ -25,3 +29,6 @@ from . import sequence_labeling
 from . import ocr
 from . import gpt
 from . import dcgan
+from . import phi4flash
+from . import lfm2moe
+from . import kimi_linear

@@ -13,6 +13,7 @@ from . import rnn_ops       # noqa: F401
 from . import attention_ops  # noqa: F401
 from . import ssm_ops       # noqa: F401
 from . import moe_ops       # noqa: F401
+from . import linear_attn_ops  # noqa: F401
 from . import collective_ops  # noqa: F401
 from . import sequence_ops  # noqa: F401
 from . import quant_ops     # noqa: F401

@@ -56,7 +56,10 @@ The supported (heads, window, widths) space:
     can see (not the whole sequence), starting at the row's first visible
     block. `window <= 0`, or a window without `causal`, is a ValueError.
   - widths: the value width Dv may differ from the q/k width D (the
-    output and dO are Dv wide). D % 8 or Dv % 8 != 0 goes to XLA.
+    output and dO are Dv wide). D % 8 or Dv % 8 != 0 goes to XLA. D need
+    be no multiple of the 128 lanes: a block spans the whole head width,
+    and D = 192 with Dv = 128 (latent attention's decompressed heads)
+    compiles as it is, on the split backward ("split: widths").
 With n == 1, no window and Dv == D the forward kernel, its tile, index
 maps and VMEM request are the ones the plain causal call always had, and
 the backward is the fused kernel.
@@ -1018,7 +1021,8 @@ def attention_path(q_shape, k_shape, v_shape, dtype, causal, window,
       "no_keys"  causal with Tq > Tk: rows i < Tq - Tk see no key at all;
                  only the XLA reference defines that edge (uniform over
                  all-masked logits).
-      "no_tile"  a tile side under 8, or D % 8 or Dv % 8.
+      "no_tile"  a tile side under 8, or D % 8 or Dv % 8 (D = 64, 128,
+                 192 and 256 all pass: a block holds the whole width).
       "lanes"    compiled (`interpret` false) with a tile side under 128:
                  Mosaic wants the last two block dims 128-lane aligned
                  (the stats block puts block_q on the lane dim).

@@ -45,9 +45,10 @@ def _selective_scan_rule(op, ins, attrs):
 def _causal_conv1d(ctx, ins, attrs):
     """Depthwise causal convolution along time: Out[b, t, e] = Bias[e] +
     sum_k W[k, e] * X[b, t - (K-1) + k, e], reading zeros before t = 0.
-    X: (B, T, E), W: (K, E). K shifted multiply-adds in float32 (K is 4:
-    a `conv_general_dilated` with E groups of one channel would send a
-    bandwidth-bound pass to the MXU's layout)."""
+    X: (B, T, E), W: (K, E). K shifted multiply-adds in float32 (K is 3 or
+    4 in the models here, with or without a bias; a silu behind it is the
+    layer's `act`: a `conv_general_dilated` with E groups of one channel
+    would send a bandwidth-bound pass to the MXU's layout)."""
     x, w = ins["X"][0], ins["W"][0]
     k, t = w.shape[0], x.shape[1]
     xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
@@ -65,8 +66,9 @@ def _causal_conv1d_rule(op, ins, attrs):
         if len(x.shape) != 3 or len(w.shape) != 2 \
                 or (x.shape[2] is not None and w.shape[1] is not None
                     and x.shape[2] != w.shape[1]):
-            raise ShapeError("causal_conv1d wants X (B,T,E) and W (K,E); "
-                             "got %s and %s" % (x.shape, w.shape))
+            raise ShapeError("causal_conv1d wants X (B,T,E) and W (K,E), K "
+                             "the width (3 or 4 in the models here); got %s "
+                             "and %s" % (x.shape, w.shape))
     return {"Out": [TensorMeta(x.shape, x.dtype)]}
 
 

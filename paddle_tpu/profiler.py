@@ -63,16 +63,17 @@ def stop_profiler(sorted_key=None, profile_path=None):
     return rows
 
 
-PLAN_RECORDS = ("flash.plan", "ssm.plan", "head.plan")
+PLAN_RECORDS = ("flash.plan", "ssm.plan", "head.plan", "kda.plan")
 
 
 def print_kernel_plans():
     """What the lowerings made while obs was on planned to do, one line a
     record, under the table: `flash.plan` (tiles, tiles visited and skipped
     by causality and by the window, group size, widths, the fused or the
-    split backward), `ssm.plan` (chunk length, chunks, VMEM asked) and
+    split backward), `ssm.plan` (chunk length, chunks, VMEM asked),
     `head.plan` (the LM head: rows, vocab, block rows and blocks, weighted
-    or per-token form, operand dtype)."""
+    or per-token form, operand dtype) and `kda.plan` (the delta rule:
+    chunk, sub-block, chunks a group, heads, which kernels)."""
     for name in PLAN_RECORDS:
         for plan in obs.spans(name=name):
             print("%s %s" % (plan["name"], " ".join(
