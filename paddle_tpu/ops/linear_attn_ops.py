@@ -22,8 +22,9 @@ state is S_i = Diag(e^{G_i}) S0 + sum_{j<=i} Diag(e^{G_i-G_j}) k_j u_j^T, so
 
 (1) is a unit-lower-triangular system a chunk: X = (I + Diag(b) A)^-1 is
 formed once (the WY form: U = Wv - Wk S0 with Wv = X Diag(b) V and
-Wk = X Diag(b) K e^G), by forward substitution inside SUB-row blocks and a
-block forward substitution over them. Everything that does not read S0
+Wk = X Diag(b) K e^G): the SUB-row blocks on the diagonal as the finite
+series (I - L)(I + L^2)(I + L^4)(I + L^8), then a 2 x 2 block recursion over
+them. Everything that does not read S0
 (`_intra`) runs for GROUP chunks at once (its (SUB, SUB, K)-shaped decay
 differences and its pullback's residuals are then a group's, not the
 sequence's); (1)-(3) then walk the group's chunks in a `lax.scan` that
@@ -38,14 +39,29 @@ directly, (SUB, SUB, K) numbers a block.
 
 The backward is one `jax.custom_vjp`: it keeps q, k, v, g, beta and the
 state each chunk began with (T / CHUNK states of (K, V) float32 a head: no
-state a token), and, a group at a time from the last, rebuilds `_intra`
-(with jax's own pullback of it) and walks (1)-(3) backwards by hand. The
-parts lower under their own scopes inside the op's (`kda_intra`, `kda_walk`,
-`kda_walk_back`, `kda_intra_back`), so a device trace splits the op's time
-by them. The cumulative decay, the solve and the state are float32; the
-other matmuls take bfloat16 operands where the inputs are bfloat16 and give
-float32 results, as the flash kernels do (any other dtype: float32 at
-HIGHEST).
+state a token), and, a group at a time from the last, rebuilds `_intra`,
+walks (1)-(3) backwards by hand and pulls `_intra` back. That pullback is
+jax's own for the matmuls and the row-shaped passes, and written by hand for
+the two pieces where jax's costs several times the forward:
+
+  - the solve (`_unit_lower_inverse`): matmuls only, no row is written
+    into an array (a row scatter a row over a 16-wide minor dimension), and
+    the pullback reads X alone, d low = -strictly_lower(X^T dX X^T);
+  - the products inside a sub-block (`_decay_products`): with D_rsc =
+    e^{G_rc - G_sc} (r >= s), M = strictly_lower(d kk), N = scale d qk,
+        P_rc = sum_s M_rs k_sc D_rsc        R_rc = sum_s N_rs k_sc D_rsc
+        P^T_sc = sum_r M_rs k_rc D_rsc      R^T_sc = sum_r N_rs q_rc D_rsc
+        dq = R,  dk = P + P^T + R^T,  dG = k (P - P^T) + q R - k R^T:
+    products of the forward's shape summed over a SUB-long row axis into
+    lane-dense (SUB, K) results; no (SUB, SUB, K) cotangent of a broadcast
+    is formed and reduced. (A `kda_*` kernel's backward is held to these.)
+
+The parts lower under their own scopes inside the op's (`kda_intra`,
+`kda_walk`, `kda_walk_back`, `kda_intra_back`), so a device trace splits the
+op's time by them. The cumulative decay, the solve, both products and the
+state are float32; the other matmuls take bfloat16 operands where the inputs
+are bfloat16 and give float32 results, as the flash kernels do (any other
+dtype: float32 at HIGHEST).
 
 Reference parity: none (the reference predates linear attention).
 """
@@ -92,7 +108,11 @@ def plan(q_shape):
             "chunks_a_group": per, "groups": groups,
             "padded": per * groups * CHUNK - t,
             "kernels": "xla: batched matmuls a group of chunks + lax.scan "
-                       "over chunks"}
+                       "over chunks; solve: 16-row blocks as (I - L)(I + "
+                       "L^2)(I + L^4)(I + L^8), 2 x 2 block recursion to "
+                       "64, backward -strictly_lower(X^T dX X^T); in-block "
+                       "decay products: backward by hand, three products "
+                       "summed over rows, D formed again"}
 
 
 def _record_plan(q_shape):
@@ -132,17 +152,108 @@ def _tokens(x, t, dtype):
     return x.reshape((b, n * c, h) + x.shape[4:])[:, :t].astype(dtype)
 
 
-def _unit_lower_inverse(low):
-    """(I + low)^-1 for strictly lower triangular `low` (..., R, R), row by
-    row: x_i = e_i - low_i X (rows not yet reached are still the identity's,
-    and low_i is zero there)."""
-    r = low.shape[-1]
-    eye = jnp.eye(r, dtype=_F32)
-    x = jnp.broadcast_to(eye, low.shape)
-    for i in range(1, r):
-        row = eye[i] - jnp.sum(low[..., i, :, None] * x, axis=-2)
-        x = x.at[..., i, :].set(row)
+def _block_inverse(low):
+    """(I + low)^-1 for strictly lower triangular `low` (..., R, R), R a
+    power of two, as the product (I - L)(I + L^2)(I + L^4)...(I + L^{R/2}):
+    L^R = 0, so the series 1 - L + L^2 - ... ends there."""
+    eye = jnp.eye(low.shape[-1], dtype=_F32)
+    x, power, reach = eye - low, low, 1
+    while 2 * reach < low.shape[-1]:
+        power = _mm32("...rs,...sj->...rj", power, power)
+        x = _mm32("...rs,...sj->...rj", x, eye + power)
+        reach *= 2
     return x
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(low):
+    """X = (I + low)^-1 for strictly lower triangular `low` (..., C, C),
+    float32. Its pullback reads X alone: d low = -strictly_lower(X^T dX
+    X^T), two matmuls a chunk."""
+    return _unit_lower_inverse_fwd(low)[0]
+
+
+def _unit_lower_inverse_fwd(low):
+    """The SUB-row blocks on the diagonal by `_block_inverse`, then the
+    2 x 2 block recursion [[A, 0], [C, B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1,
+    B^-1]] up to the whole chunk (CHUNK / SUB is a power of two)."""
+    size, lead, c = SUB, low.shape[:-2], low.shape[-1]
+
+    def block(row, col):
+        return low[..., row * size:(row + 1) * size,
+                   col * size:(col + 1) * size]
+
+    x = _block_inverse(jnp.stack(
+        [block(n, n) for n in range(c // size)], axis=-3))
+    while size < c:
+        pairs = x.reshape(lead + (-1, 2, size, size))
+        top, bottom = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        below = jnp.stack(
+            [block(n + 1, n) for n in range(0, c // size, 2)], axis=-3)
+        under = -_mm32("...rs,...sj->...rj", bottom,
+                       _mm32("...rs,...sj->...rj", below, top))
+        x = jnp.concatenate([
+            jnp.concatenate([top, jnp.zeros_like(top)], axis=-1),
+            jnp.concatenate([under, bottom], axis=-1)], axis=-2)
+        size *= 2
+    x = x.reshape(low.shape)
+    return x, x
+
+
+def _unit_lower_inverse_bwd(x, d_x):
+    d_low = _mm32("...ji,...jk->...ik", x,
+                  _mm32("...jk,...lk->...jl", d_x, x))
+    return (-jnp.tril(d_low, -1),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _in_block_decay(cum_b):
+    """e^{G_r - G_s} for r >= s inside a sub-block, 0 elsewhere: (.., SUB,
+    SUB, K) from the cumulative decay (.., SUB, K), taken directly (the
+    exponent is never positive)."""
+    within = jnp.tril(jnp.ones((SUB, SUB), bool))
+    return jnp.exp(jnp.where(
+        within[..., None], cum_b[..., :, None, :] - cum_b[..., None, :, :],
+        -jnp.inf))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _decay_products(q_b, k_b, cum_b, scale):
+    """The two products inside a sub-block, D_rsc = `_in_block_decay`:
+    kk_rs = sum_c k_rc k_sc D_rsc for r > s and qk_rs = scale sum_c q_rc
+    k_sc D_rsc for r >= s, (.., SUB, SUB) each, float32."""
+    return _decay_products_fwd(q_b, k_b, cum_b, scale)[0]
+
+
+def _decay_products_fwd(q_b, k_b, cum_b, scale):
+    k_cols = k_b[..., None, :, :] * _in_block_decay(cum_b)
+    kk = jnp.tril(jnp.sum(k_b[..., :, None, :] * k_cols, axis=-1), -1)
+    qk = jnp.sum(q_b[..., :, None, :] * k_cols, axis=-1) * scale
+    return (kk, qk), (q_b, k_b, cum_b)
+
+
+def _decay_products_bwd(scale, res, cots):
+    """With M = strictly_lower(d kk) and N = scale d qk: P_rc = sum_s M_rs
+    k_sc D_rsc, R_rc = sum_s N_rs k_sc D_rsc and (P^T + R^T)_sc = sum_r
+    (M_rs k_rc + N_rs q_rc) D_rsc, three products of the forward's shape
+    summed over a row axis; dq = R, dk = P + P^T + R^T and d cum = k (P -
+    P^T - R^T) + q R. D is formed again (an `exp` a product element is
+    cheaper than D's bytes)."""
+    q_b, k_b, cum_b = res
+    m = jnp.tril(cots[0], -1)[..., None]
+    n = (cots[1] * scale)[..., None]
+    decay = _in_block_decay(cum_b)
+    k_cols = k_b[..., None, :, :] * decay
+    p = jnp.sum(m * k_cols, axis=-2)
+    r = jnp.sum(n * k_cols, axis=-2)
+    back = jnp.sum((m * k_b[..., :, None, :] + n * q_b[..., :, None, :])
+                   * decay, axis=-3)
+    return r, p + back, k_b * (p - back) + q_b * r
+
+
+_decay_products.defvjp(_decay_products_fwd, _decay_products_bwd)
 
 
 def _intra(q, k, v, g, beta, scale, mxu):
@@ -166,14 +277,7 @@ def _intra(q, k, v, g, beta, scale, mxu):
     left = jnp.exp(cum_b - base[..., None, :])
     k_left, q_left = k_b * left, q_b * left * scale
     # inside a sub-block: e^{G_r - G_s}, r >= s, taken directly
-    within = jnp.tril(jnp.ones((sub, sub), bool))
-    decay = jnp.exp(jnp.where(
-        within[..., None], cum_b[..., :, None, :] - cum_b[..., None, :, :],
-        -jnp.inf))
-    kk_diag = jnp.sum(k_b[..., :, None, :] * k_b[..., None, :, :] * decay,
-                      axis=-1) * jnp.tril(jnp.ones((sub, sub), _F32), -1)
-    qk_diag = jnp.sum(q_b[..., :, None, :] * k_b[..., None, :, :] * decay,
-                      axis=-1) * scale
+    kk_diag, qk_diag = _decay_products(q_b, k_b, cum_b, scale)
     kk_rows, qk_rows = [], []
     for i in range(ns):
         before, after = i * sub, c - (i + 1) * sub
@@ -181,7 +285,7 @@ def _intra(q, k, v, g, beta, scale, mxu):
         if before:
             # against every earlier row j: e^{G_i - b} e^{b - G_j}
             k_right = k[..., :before, :] * jnp.exp(
-                base[..., i, None, :] - cum[..., :before, :])
+                base[..., i:i + 1, :] - cum[..., :before, :])
             kk.insert(0, _mm32("...rk,...jk->...rj", k_left[..., i, :, :],
                                k_right))
             qk.insert(0, _mm32("...rk,...jk->...rj", q_left[..., i, :, :],
@@ -194,19 +298,7 @@ def _intra(q, k, v, g, beta, scale, mxu):
         qk_rows.append(jnp.concatenate(qk, axis=-1))
     low = beta[..., :, None] * jnp.concatenate(kk_rows, axis=-2)
     a_qk = jnp.concatenate(qk_rows, axis=-2)
-    # X = (I + low)^-1: the sub-blocks on the diagonal row by row, then a
-    # block forward substitution over them
-    diag_inv = _unit_lower_inverse(jnp.stack(
-        [low[..., i * sub:(i + 1) * sub, i * sub:(i + 1) * sub]
-         for i in range(ns)], axis=-3))
-    x = diag_inv[..., 0, :, :]
-    for i in range(1, ns):
-        before = i * sub
-        row = -_mm32("...rs,...sj->...rj", diag_inv[..., i, :, :], _mm32(
-            "...rs,...sj->...rj", low[..., before:before + sub, :before], x))
-        x = jnp.concatenate([
-            jnp.concatenate([x, jnp.zeros(lead + (before, sub), _F32)], -1),
-            jnp.concatenate([row, diag_inv[..., i, :, :]], -1)], axis=-2)
+    x = _unit_lower_inverse(low)
     grow = jnp.exp(cum)
     end = cum[..., -1:, :]
     w_v = _mm("...ij,...jv->...iv", x, beta[..., None] * v, mxu)

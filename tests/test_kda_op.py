@@ -4,7 +4,16 @@ gradients: typical decay, a decay so strong that a chunk's cumulative
 log-decay passes -100 (no exp of it may overflow, and a factored
 e^{G_i} e^{-G_j} would), and no decay at all (the plain delta rule); a
 sequence that is no whole number of chunks; the op through a Program; the
-three elementwise ops of a KDA layer against `jax.numpy`."""
+three elementwise ops of a KDA layer against `jax.numpy`. The two pieces of
+the chunk math with a hand-written backward, each alone: the unit-lower
+solve against `solve_triangular` and against jax's pullback of the plain
+row-by-row spelling, the in-block decay products against `jax.grad` of the
+plain products (both spellings live here as the references); no `scatter`
+in the op's gradient; `tools/mb_kda_intra.py` starts."""
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -86,6 +95,159 @@ def test_chunked_form_equals_the_recurrence_in_value_and_gradients(t, decay):
         assert float(jnp.linalg.norm(a - b)) <= 2e-4 * norm + 1e-6, name
 
 
+def plain_inverse(low):
+    """(I + low)^-1 the plain way, for jax to pull back: row i is e_i -
+    low_i X, written into the array (PR 33's spelling of every row of a
+    sub-block; here of the whole chunk)."""
+    r = low.shape[-1]
+    eye = jnp.eye(r, dtype=jnp.float32)
+    x = jnp.broadcast_to(eye, low.shape)
+    for i in range(1, r):
+        row = eye[i] - jnp.sum(low[..., i, :, None] * x, axis=-2)
+        x = x.at[..., i, :].set(row)
+    return x
+
+
+def plain_products(q_b, k_b, cum_b, scale):
+    """kk_rs = sum_c k_rc k_sc e^{G_rc - G_sc} (r > s) and qk_rs = scale
+    sum_c q_rc k_sc e^{G_rc - G_sc} (r >= s), for jax to pull back."""
+    sub = q_b.shape[-2]
+    within = jnp.tril(jnp.ones((sub, sub), bool))
+    decay = jnp.exp(jnp.where(
+        within[..., None], cum_b[..., :, None, :] - cum_b[..., None, :, :],
+        -jnp.inf))
+    kk = jnp.sum(k_b[..., :, None, :] * k_b[..., None, :, :] * decay,
+                 axis=-1) * jnp.tril(jnp.ones((sub, sub), jnp.float32), -1)
+    qk = jnp.sum(q_b[..., :, None, :] * k_b[..., None, :, :] * decay,
+                 axis=-1) * scale
+    return kk, qk
+
+
+def chunk_blocks(decay, seed=0):
+    """q, k and the cumulative decay of `inputs`' first chunks as (B, H,
+    chunks, sub-blocks, SUB, K), and beta (B, H, chunks, CHUNK)."""
+    q, k, _v, g, beta = inputs(2 * la.CHUNK, decay, seed=seed)
+
+    def heads_first(x):
+        x = jnp.moveaxis(x, 2, 1)                       # (B, H, T, ..)
+        return x.reshape(x.shape[:2] + (2, la.CHUNK) + x.shape[3:])
+
+    q, k, g, beta = (heads_first(x) for x in (q, k, g, beta))
+    cum = jnp.cumsum(g, axis=-2)
+
+    def blocks(x):
+        return x.reshape(x.shape[:3] + (la.CHUNK // la.SUB, la.SUB,
+                                        x.shape[-1]))
+
+    return blocks(q), blocks(k), blocks(cum), beta
+
+
+def lower_system(kind):
+    """`low` (B, H, chunks, CHUNK, CHUNK), strictly lower: random numbers,
+    or Diag(beta) A of the strong-decay inputs with every difference of
+    the cumulative decay taken directly."""
+    if kind == "random":
+        raw = jax.random.normal(jax.random.PRNGKey(4),
+                                (2, 2, 2, la.CHUNK, la.CHUNK))
+        return 0.3 * jnp.tril(raw, -1)
+    _q, k_b, cum_b, beta = chunk_blocks("strong")
+    k, cum = (x.reshape(x.shape[:3] + (la.CHUNK, -1)) for x in (k_b, cum_b))
+    kk, _qk = plain_products(k, k, cum, 1.0)
+    return beta[..., None] * kk
+
+
+@pytest.mark.parametrize("kind", ["random", "strong"])
+def test_the_solve_equals_solve_triangular(kind):
+    low = lower_system(kind)
+    eye = jnp.broadcast_to(jnp.eye(la.CHUNK), low.shape)
+    want = jax.scipy.linalg.solve_triangular(
+        eye + low, eye, lower=True, unit_diagonal=True)
+    got = la._unit_lower_inverse(low)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    # upper triangle zero, diagonal one: to the bit
+    assert bool(jnp.all(jnp.triu(got, 1) == 0.0))
+    assert bool(jnp.all(jnp.diagonal(got, axis1=-2, axis2=-1) == 1.0))
+
+
+@pytest.mark.parametrize("kind", ["random", "strong"])
+def test_the_solves_pullback_reads_x_alone_and_equals_jaxs(kind):
+    """d low = -strictly_lower(X^T dX X^T) against jax's pullback through
+    the row updates of the plain spelling."""
+    low = lower_system(kind)
+    cot = jax.random.normal(jax.random.PRNGKey(8), low.shape)
+
+    def loss(fn):
+        return lambda l: jnp.sum(fn(jnp.tril(l, -1)) * cot)
+
+    mine = jax.grad(loss(la._unit_lower_inverse))(low)
+    ref = jax.grad(loss(plain_inverse))(low)
+    assert bool(jnp.all(jnp.isfinite(mine)))
+    assert bool(jnp.all(jnp.triu(mine) == 0.0))
+    norm = float(jnp.linalg.norm(ref))
+    assert float(jnp.linalg.norm(mine - ref)) <= 2e-4 * norm + 1e-6
+
+
+@pytest.mark.parametrize("decay", ["typical", "strong", "none"])
+def test_the_decay_products_backward_equals_jaxs_of_the_plain_products(
+        decay):
+    q_b, k_b, cum_b, _beta = chunk_blocks(decay, seed=2)
+    scale = q_b.shape[-1] ** -0.5
+    if decay == "strong":
+        assert float((cum_b[..., -1, :] - cum_b[..., 0, :]).min()) < -40.0
+    want = plain_products(q_b, k_b, cum_b, scale)
+    got = la._decay_products(q_b, k_b, cum_b, scale)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-6)
+    cots = [jax.random.normal(key, want[0].shape)
+            for key in jax.random.split(jax.random.PRNGKey(6))]
+
+    def loss(fn):
+        return lambda *a: sum(jnp.sum(o * c)
+                              for o, c in zip(fn(*a, scale), cots))
+
+    mine = jax.grad(loss(la._decay_products), range(3))(q_b, k_b, cum_b)
+    ref = jax.grad(loss(plain_products), range(3))(q_b, k_b, cum_b)
+    for name, a, b in zip("q k cum".split(), mine, ref):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        norm = float(jnp.linalg.norm(b))
+        assert float(jnp.linalg.norm(a - b)) <= 2e-4 * norm + 1e-6, name
+
+
+def _primitives(jaxpr, seen):
+    for eqn in jaxpr.eqns:
+        seen.add(eqn.primitive.name)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, seen)
+    return seen
+
+
+def test_the_ops_gradient_holds_no_scatter_at_any_depth():
+    """Neither the forward nor the backward writes rows into an array or
+    pulls back an integer index: the jaxpr of the gradient, through every
+    scan body, custom_vjp rule and closed call, has no scatter (and no
+    gather, whose pullback would be one)."""
+    args = inputs(150, "typical")
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(la.kda_attention(*a)), range(5)))(*args)
+    seen = _primitives(jaxpr.jaxpr, set())
+    assert "scan" in seen and "dot_general" in seen     # it walked inside
+    assert not {p for p in seen if "scatter" in p or "gather" in p}, seen
+
+
+def test_the_microbenchmark_of_the_sub_block_math_starts():
+    tool = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "mb_kda_intra.py")
+    done = subprocess.run(
+        [sys.executable, tool, "--help"], capture_output=True, text=True,
+        timeout=300, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert "--walk-through" in done.stdout
+
+
 def test_a_sequence_that_is_no_whole_number_of_chunks():
     args = inputs(150, "typical", seed=3)
     np.testing.assert_allclose(la.kda_attention(*args),
@@ -109,6 +271,13 @@ def test_plan_says_what_a_call_will_do():
     assert plan["chunks"] == 128 and plan["chunk"] == 64
     assert plan["sub_block"] == 16 and plan["padded"] == 0
     assert la.plan((1, 150, 2, 32))["padded"] == 42
+
+
+def test_plan_names_the_solve_and_the_two_hand_written_backwards():
+    kernels = la.plan((2, 8192, 16, 128))["kernels"]
+    assert kernels.startswith("xla: ")          # no kda_* kernel yet
+    assert "solve: " in kernels and "X^T dX X^T" in kernels
+    assert "decay products: " in kernels and "by hand" in kernels
 
 
 def test_shape_rules():
