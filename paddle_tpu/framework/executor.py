@@ -132,6 +132,57 @@ def _hit_step_feed(feed):
     return feed if out is faultinject.DROP else out
 
 
+def _reader_feed(program, feed):
+    """``feed`` (a fresh dict) with the batches of the program's started
+    py_readers (reference create_py_reader_op: run-without-feed training
+    loops); an exhausted reader raises layers.io.EOFException here.
+    Two-phase so a sibling reader's EOF pushes already-dequeued batches
+    back (no lost data), and user-fed names are never overwritten."""
+    feed = dict(feed or {})
+    pulled = []
+    try:
+        for rdr in getattr(program, "_py_readers", ()):
+            if rdr._started and any(n not in feed for n in rdr._names):
+                pulled.append((rdr, rdr._next_feed()))
+    except Exception:
+        for rdr, batch in pulled:
+            rdr._push_back(batch)
+        raise
+    for rdr, batch in pulled:
+        for n, v in batch.items():
+            feed.setdefault(n, v)
+    return feed
+
+
+def _boundary(sp, name, kind=None, since=None):
+    """The one place a step reads the clock: at a phase boundary, once.
+    The reading closes ``exec.step``'s open phase and opens the phase
+    ``name`` (obs on; None opens none), feeds the always-on
+    ``executor_step_seconds{kind=}`` histogram of the phase that began at
+    ``since`` and ends here, and is returned: the next boundary's
+    ``since``, and what the straggler detector's latency is made of.
+    Phases no histogram reads (feed, prepare, records) open through
+    ``sp.phase`` alone, which reads nothing while obs is off."""
+    at = obs.now()
+    if kind is not None:
+        resilience.observe_executor_step(kind, at - since)
+    sp.phase(name, at)
+    return at
+
+
+def _watched(what, call, n_steps=1):
+    """A pipelined dispatch has no phases: its wall time a step goes to
+    the straggler detector (return_numpy syncs the fetches), when one
+    is armed."""
+    if watchdog.straggler_detector() is None:
+        return call()
+    t0 = time.perf_counter()
+    out = call()
+    watchdog.observe_step_latency((time.perf_counter() - t0) / n_steps,
+                                  what=what)
+    return out
+
+
 class Executor(object):
     def __init__(self, place=None):
         # Remember whether the caller chose the device. Only an EXPLICIT
@@ -225,144 +276,132 @@ class Executor(object):
         if program is None:
             program = default_main_program()
         scope = scope if scope is not None else global_scope()
-        feed = dict(feed or {})
-        # started py_readers supply their own variables' batches
-        # (reference create_py_reader_op: run-without-feed training loops);
-        # an exhausted reader raises layers.io.EOFException here. Two-phase
-        # so a sibling reader's EOF pushes already-dequeued batches back
-        # (no lost data), and user-fed names are never overwritten.
-        pulled = []
-        try:
-            for rdr in getattr(program, "_py_readers", ()):
-                if rdr._started and any(n not in feed
-                                        for n in rdr._names):
-                    pulled.append((rdr, rdr._next_feed()))
-        except Exception:
-            for rdr, batch in pulled:
-                rdr._push_back(batch)
-            raise
-        for rdr, batch in pulled:
-            for n, v in batch.items():
-                feed.setdefault(n, v)
-        fetch_names = _fetch_names(fetch_list or [])
-
-        if not fetch_names:
-            self._run_eager(program, feed, scope)
+        if not fetch_list:
+            self._run_eager(program, _reader_feed(program, feed), scope)
             return []
-
-        # chaos-harness injection point: one fire per jitted-step dispatch
-        # (startup/eager programs don't count). A no-op unless a
-        # FaultInjector is installed (resilience.inject / PADDLE_TPU_FAULTS).
-        resilience.fire("step", what="Executor.run")
-        feed = _hit_step_feed(feed)
-        # straggler wiring: when detection is armed, the whole dispatch+
-        # writeback (return_numpy syncs the fetches) is the step latency
-        det_t0 = time.perf_counter() \
-            if watchdog.straggler_detector() is not None else None
-
-        if getattr(program, "_pp_plan", None) is not None:
-            out = self._run_pipeline(program, feed, fetch_names, scope,
-                                     return_numpy)
-            if det_t0 is not None:
-                watchdog.observe_step_latency(time.perf_counter() - det_t0,
-                                              what="Executor.run")
-            return out
-        if strategy is not None and strategy._pp_enabled():
-            out = self._run_compiled_pp(strategy, program, feed,
-                                        fetch_names, scope, return_numpy)
-            if det_t0 is not None:
-                watchdog.observe_step_latency(time.perf_counter() - det_t0,
-                                              what="Executor.run")
-            return out
-
-        # ---- the jitted single-step path ---------------------------------
-        # phase spans that tile the step (exec.step > feed/prepare/
-        # compile/execute/writeback > fetch) + the always-on
-        # executor_step_seconds{kind=} histograms — the obs layer's
-        # executor leg
+        planned = getattr(program, "_pp_plan", None) is not None
+        if planned or (strategy is not None and strategy._pp_enabled()):
+            feed = self._step_feed(program, feed, "Executor.run")
+            fetch_names = _fetch_names(fetch_list)
+            if planned:
+                return _watched("Executor.run", lambda: self._run_pipeline(
+                    program, feed, fetch_names, scope, return_numpy))
+            return _watched("Executor.run", lambda: self._run_compiled_pp(
+                strategy, program, feed, fetch_names, scope, return_numpy))
+        # the jitted single-step path: exec.step covers the call, its
+        # phases tile it (see _run_jitted)
         with obs.span("exec.step", entry="run") as sp:
-            out = self._run_jitted(program, feed, fetch_names, scope,
-                                   return_numpy, use_program_cache,
-                                   strategy, sp)
-        if det_t0 is not None:
-            watchdog.observe_step_latency(time.perf_counter() - det_t0,
-                                          what="Executor.run")
-        return out
+            return self._run_jitted(program, feed, fetch_list, scope,
+                                    return_numpy, use_program_cache,
+                                    strategy, sp)
 
-    def _run_jitted(self, program, feed, fetch_names, scope,
+    @staticmethod
+    def _step_feed(program, feed, what):
+        """The feed a step dispatches: the readers' batches, then the
+        chaos-harness injection points, one fire per jitted-step dispatch
+        (startup/eager programs don't count). No-ops unless a
+        FaultInjector is installed (resilience.inject / PADDLE_TPU_FAULTS)."""
+        feed = _reader_feed(program, feed)
+        resilience.fire("step", what=what)
+        return _hit_step_feed(feed)
+
+    def _run_jitted(self, program, feed, fetch_list, scope,
                     return_numpy, use_program_cache, strategy, sp):
-        t_total = time.perf_counter()
-        with obs.span("exec.feed"):
-            feed_vals = self._convert_feed(program, feed)
-        with obs.span("exec.prepare"):
-            state_names, uses_rng = self._prepare_state(program, feed,
-                                                        scope)
-            check_numerics, policy, skip_budget = _numeric_config(
-                program, strategy)
-            key = (id(program), program._version,
-                   _feed_signature(feed_vals), tuple(fetch_names),
-                   tuple(state_names), check_numerics,
-                   None if strategy is None else strategy._cache_token())
-            entry = self._cache.get(key) if use_program_cache else None
-            state_vals = tuple(scope.find_var(n) for n in state_names)
-            feed_tuple = tuple(feed_vals[k] for k in sorted(feed_vals))
-        if entry is None:
+        """One jitted step under its ``exec.step`` span ``sp``. The phases
+        tile the span: prepare (the readers' pulls, fetch names, fault
+        hooks), feed, prepare (state selection, cache key and lookup),
+        compile on a miss, execute, writeback > fetch, release, and with
+        obs on records; ``_boundary`` reads the clock for the always-on
+        executor_step_seconds{kind=} histograms and the straggler
+        detector, the obs layer's executor leg."""
+        t_step = _boundary(sp, "exec.prepare")
+        feed = self._step_feed(program, feed, "Executor.run")
+        fetch_names = _fetch_names(fetch_list)
+        sp.phase("exec.feed")
+        feed_vals = self._convert_feed(program, feed)
+        sp.phase("exec.prepare")
+        state_names, uses_rng = self._prepare_state(program, feed, scope)
+        check_numerics, policy, skip_budget = _numeric_config(
+            program, strategy)
+        key = (id(program), program._version,
+               _feed_signature(feed_vals), tuple(fetch_names),
+               tuple(state_names), check_numerics,
+               None if strategy is None else strategy._cache_token())
+        step_fn = self._cache.get(key) if use_program_cache else None
+        state_vals = tuple(scope.find_var(n) for n in state_names)
+        feed_tuple = tuple(feed_vals[k] for k in sorted(feed_vals))
+        t_compile = None
+        if step_fn is None:
             self.cache_misses += 1
             sp.set(cache="miss")
-            t0 = time.perf_counter()
-            with obs.span("exec.compile"):
-                entry = self._compile(program, feed_vals, fetch_names,
-                                      state_names, uses_rng, strategy,
-                                      check_numerics, policy)
-            resilience.observe_executor_step(
-                "compile", time.perf_counter() - t0)
+            t_compile = _boundary(sp, "exec.compile")
+            step_fn = self._compile(program, feed_vals, fetch_names,
+                                    state_names, uses_rng, strategy,
+                                    check_numerics, policy)
             if use_program_cache:
-                self._cache[key] = entry
+                self._cache[key] = step_fn
+            t_execute = _boundary(sp, "exec.execute", "compile", t_compile)
         else:
             self.cache_hits += 1
             sp.set(cache="hit")
-        step_fn = entry
-
-        t0 = time.perf_counter()
-        with obs.span("exec.execute"):
-            if check_numerics:
-                fetches, new_state, finite = step_fn(state_vals,
-                                                     feed_tuple)
-                finite = np.asarray(finite)
-                if not finite.all():
-                    self._numeric_fault(scope, state_names, new_state,
-                                        finite, fetch_names, policy,
-                                        skip_budget)
-                elif policy == "skip":
-                    self._numeric_skips = 0   # clean step ends a streak
-            else:
-                fetches, new_state = step_fn(state_vals, feed_tuple)
-        resilience.observe_executor_step(
-            "execute", time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        with obs.span("exec.writeback"):
-            out = self._writeback(scope, state_names, new_state, fetches,
-                                  return_numpy)
-        resilience.observe_executor_step(
-            "writeback", time.perf_counter() - t0)
-        resilience.observe_executor_step(
-            "total", time.perf_counter() - t_total)
-        if obs.enabled() and getattr(program, "step_records", None):
-            self._record_step_state(program, scope)
+            t_execute = _boundary(sp, "exec.execute")
+        if check_numerics:
+            fetches, new_state, finite = step_fn(state_vals, feed_tuple)
+            finite = np.asarray(finite)
+            if not finite.all():
+                self._numeric_fault(scope, state_names, new_state,
+                                    finite, fetch_names, policy,
+                                    skip_budget)
+            elif policy == "skip":
+                self._numeric_skips = 0   # clean step ends a streak
+        else:
+            fetches, new_state = step_fn(state_vals, feed_tuple)
+        t_writeback = _boundary(sp, "exec.writeback", "execute", t_execute)
+        out = self._writeback(scope, state_names, new_state, fetches,
+                              return_numpy)
+        t_release = _boundary(sp, "exec.release", "writeback", t_writeback)
+        # the step's references to the old state (~a handle a persistable
+        # var), the feed and the new state die HERE, after the fetch has
+        # returned: where the frame's exit dropped them, now under a name
+        del state_vals, feed_vals, feed_tuple, new_state, fetches
+        t_end = _boundary(sp, None, "total", t_step)
+        self._end_step(program, scope, sp, "Executor.run", 1, t_step,
+                       t_compile, t_execute, t_writeback, t_release, t_end)
         return out
+
+    def _end_step(self, program, scope, sp, what, n_steps, t_step,
+                  t_compile, t_execute, t_writeback, t_release, t_end):
+        """What watches a finished step, each only where it is armed: the
+        straggler detector gets the step's latency with its phases (the
+        boundaries' readings: nothing is clocked again), and with obs on
+        the layers' registered counters become spans under
+        ``exec.records``."""
+        if watchdog.straggler_detector() is not None:
+            phases = {"feed_prepare_s": (t_execute if t_compile is None
+                                         else t_compile) - t_step,
+                      "execute_s": t_writeback - t_execute,
+                      "writeback_s": t_release - t_writeback,
+                      "release_s": t_end - t_release}
+            if t_compile is not None:
+                phases["compile_s"] = t_execute - t_compile
+            watchdog.observe_step_latency((t_end - t_step) / n_steps,
+                                          what=what, phases=phases)
+        if obs.enabled() and getattr(program, "step_records", None):
+            sp.phase("exec.records", t_end)
+            self._record_step_state(program, scope)
 
     @staticmethod
     def _record_step_state(program, scope):
         """One obs span a step for every counter a layer registered
         (`Program.record_step_state`), from the state the step itself
-        wrote. Reads scope arrays only: no program runs and `cache_misses`
-        stays."""
-        for span, name, labels, summarize in program.step_records:
-            kept = scope.find_var(name)
-            if kept is None:
-                continue
-            value = np.asarray(kept)
-            at = obs.now()
+        wrote. Reads scope arrays only, all of them in ONE `device_get`
+        (one host round trip a step, not one a counter): no program runs
+        and `cache_misses` stays."""
+        records = [rec for rec in program.step_records
+                   if scope.find_var(rec[1]) is not None]
+        values = jax.device_get([scope.find_var(rec[1]) for rec in records])
+        at = obs.now()
+        for (span, _name, labels, summarize), value in zip(records, values):
             obs.record(span, at, at, **dict(
                 labels, **(summarize(value) if summarize
                            else {"value": value.tolist()})))
@@ -490,62 +529,64 @@ class Executor(object):
         if n_steps == 0:
             raise ValueError("run_steps needs at least one step; the "
                              "stacked feeds have a leading axis of 0")
-        # one fire per scanned WINDOW (a window is one device dispatch —
-        # the granularity at which a real preemption would kill the step)
-        resilience.fire("step", what="Executor.run_steps")
-        feed = _hit_step_feed(feed)
-        # per-step straggler latency = window wall-clock / window length
-        det_t0 = time.perf_counter() \
-            if watchdog.straggler_detector() is not None else None
-
-        def _observe(result):
-            if det_t0 is not None:
-                watchdog.observe_step_latency(
-                    (time.perf_counter() - det_t0) / n_steps,
-                    what="Executor.run_steps")
-            return result
-        if getattr(program, "_pp_plan", None) is not None:
-            return _observe(self._run_pipeline_steps(
-                program, feed, fetch_names, scope, return_numpy, n_steps))
-        if strategy is not None and strategy._pp_enabled():
-            return _observe(self._run_compiled_pp(
-                strategy, program, feed, fetch_names, scope, return_numpy,
-                windowed=True))
+        planned = getattr(program, "_pp_plan", None) is not None
+        if planned or (strategy is not None and strategy._pp_enabled()):
+            feed = self._window_feed(feed)
+            if planned:
+                return _watched(
+                    "Executor.run_steps", lambda: self._run_pipeline_steps(
+                        program, feed, fetch_names, scope, return_numpy,
+                        n_steps), n_steps)
+            return _watched(
+                "Executor.run_steps", lambda: self._run_compiled_pp(
+                    strategy, program, feed, fetch_names, scope,
+                    return_numpy, windowed=True), n_steps)
         # one exec.step parent per window — the run() path's grouping,
-        # so the window's compile/execute/writeback phases share one
-        # trace even when no ambient span is open around the caller
+        # so the window's phases share one trace even when no ambient
+        # span is open around the caller
         with obs.span("exec.step", entry="run_steps",
                       steps=n_steps) as sp:
-            return _observe(self._run_steps_jitted(
+            return self._run_steps_jitted(
                 program, strategy, feed, fetch_names, scope,
-                return_numpy, use_program_cache, n_steps, sp))
+                return_numpy, use_program_cache, n_steps, sp)
+
+    @staticmethod
+    def _window_feed(feed):
+        """One fire per scanned WINDOW (a window is one device dispatch —
+        the granularity at which a real preemption would kill the step)."""
+        resilience.fire("step", what="Executor.run_steps")
+        return _hit_step_feed(feed)
 
     def _run_steps_jitted(self, program, strategy, feed, fetch_names,
                           scope, return_numpy, use_program_cache,
                           n_steps, sp):
-        with obs.span("exec.feed"):
-            staged = self._convert_feed(program, feed, steps_axis=True)
-        with obs.span("exec.prepare"):
-            check_numerics, policy, skip_budget = _numeric_config(
-                program, strategy)
-            state_names, uses_rng = self._prepare_state(program, staged,
-                                                        scope)
-            key = (id(program), program._version,
-                   _feed_signature(staged), tuple(fetch_names),
-                   tuple(state_names), check_numerics, "scan",
-                   None if strategy is None else strategy._cache_token())
-            fn = self._cache.get(key) if use_program_cache else None
-            state_vals = tuple(scope.find_var(n) for n in state_names)
-            feed_tuple = tuple(staged[k] for k in sorted(staged))
-        t_total = time.perf_counter()
+        """`_run_jitted`'s phases for a window; the straggler detector's
+        per-step latency is the window's wall-clock / its length."""
+        t_step = _boundary(sp, "exec.prepare")
+        feed = self._window_feed(feed)
+        sp.phase("exec.feed")
+        staged = self._convert_feed(program, feed, steps_axis=True)
+        sp.phase("exec.prepare")
+        check_numerics, policy, skip_budget = _numeric_config(
+            program, strategy)
+        state_names, uses_rng = self._prepare_state(program, staged,
+                                                    scope)
+        key = (id(program), program._version,
+               _feed_signature(staged), tuple(fetch_names),
+               tuple(state_names), check_numerics, "scan",
+               None if strategy is None else strategy._cache_token())
+        fn = self._cache.get(key) if use_program_cache else None
+        state_vals = tuple(scope.find_var(n) for n in state_names)
+        feed_tuple = tuple(staged[k] for k in sorted(staged))
+        t_compile = None
         if fn is not None:
             self.cache_hits += 1
             sp.set(cache="hit")
+            t_execute = _boundary(sp, "exec.execute")
         else:
             self.cache_misses += 1
             sp.set(cache="miss")
-            t_compile = time.perf_counter()
-            w_compile = obs.now()
+            t_compile = _boundary(sp, "exec.compile")
             from .compiler import verify_for_compile
             verify_for_compile(
                 program,
@@ -583,14 +624,8 @@ class Executor(object):
                         return jitted(state_vals, feed_tuple)
             if use_program_cache:
                 self._cache[key] = fn
-            resilience.observe_executor_step(
-                "compile", time.perf_counter() - t_compile)
-            obs.record("exec.compile", w_compile, obs.now())
-        t_exec = time.perf_counter()
-        with obs.span("exec.execute"):
-            ys, new_state = fn(state_vals, feed_tuple)
-        resilience.observe_executor_step(
-            "execute", time.perf_counter() - t_exec)
+            t_execute = _boundary(sp, "exec.execute", "compile", t_compile)
+        ys, new_state = fn(state_vals, feed_tuple)
         if check_numerics:
             finite = np.asarray(ys[1])
             # per-step verdicts: (n_steps, n_vars) mask rows, or the
@@ -655,14 +690,15 @@ class Executor(object):
                         "window%s" % (k, tail))
             elif policy == "skip":
                 self._numeric_skips = 0
-        t_wb = time.perf_counter()
-        with obs.span("exec.writeback"):
-            out = self._writeback(scope, state_names, new_state,
-                                  ys[0], return_numpy)
-        resilience.observe_executor_step(
-            "writeback", time.perf_counter() - t_wb)
-        resilience.observe_executor_step(
-            "total", time.perf_counter() - t_total)
+        t_writeback = _boundary(sp, "exec.writeback", "execute", t_execute)
+        out = self._writeback(scope, state_names, new_state, ys[0],
+                              return_numpy)
+        t_release = _boundary(sp, "exec.release", "writeback", t_writeback)
+        del state_vals, staged, feed_tuple, new_state, ys
+        t_end = _boundary(sp, None, "total", t_step)
+        self._end_step(program, scope, sp, "Executor.run_steps", n_steps,
+                       t_step, t_compile, t_execute, t_writeback,
+                       t_release, t_end)
         return out
 
     # ------------------------------------------------------------------

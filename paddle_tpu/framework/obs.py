@@ -49,10 +49,21 @@ Design:
     beside the device's operations — how a device-idle gap is named
     after the Executor phase the host was in (``exec.feed``,
     ``exec.execute``, ``exec.fetch``). It is the only clock bridge: no
-    offset is estimated. This module never imports JAX (coordination
-    servers and routers use it without): the annotation class is
-    picked up lazily, and only if ``jax`` is already in
-    ``sys.modules``. Retroactive :func:`record` spans stay obs-only.
+    offset is estimated. The benchmark CHECKS the bridge on every traced
+    step by the causality a blocking loop guarantees (the step program
+    starts no earlier than ``exec.execute`` opens and ends no later than
+    ``exec.fetch`` closes): ``host_device_skew_ms``
+    (``benchmark/layer_metrics/_account.py``) is the largest violation,
+    and with it the error bar of every ``idle_*_ms``. This module never
+    imports JAX (coordination servers and routers use it without): the
+    annotation class is picked up lazily, and only if ``jax`` is already
+    in ``sys.modules``. Retroactive :func:`record` spans stay obs-only.
+  * **Phases** (:meth:`_Span.phase`): a span whose work is a sequence
+    of named parts opens each as a child that closes when the next one
+    opens (or the span itself closes), both at ONE reading of the clock,
+    which the caller may hand in. The children then tile the span with
+    no time between them: how ``exec.step`` accounts for every instant
+    of an ``Executor.run``.
 
 Span taxonomy (what the built-in instrumentation emits) is documented
 in PORTING.md "Observability & tracing".
@@ -213,21 +224,36 @@ class _Span(object):
     mid-flight (outcome labels land just before close)."""
 
     __slots__ = ("trace", "id", "parent", "name", "t0", "labels",
-                 "mirror")
+                 "mirror", "leaf")
 
-    def __init__(self, name, trace, parent, labels):
+    def __init__(self, name, trace, parent, labels, t0=None):
         self.name = name
         self.trace = trace
         self.id = _new_span_id()
         self.parent = parent
         self.labels = labels
+        self.leaf = None
         annotation = _annotation_class()
         self.mirror = None if annotation is None else annotation(name)
-        self.t0 = now()
+        self.t0 = now() if t0 is None else t0
 
     def set(self, **labels):
         self.labels.update(labels)
         return self
+
+    def phase(self, name, at=None):
+        """Close this span's open phase, if it has one, and open the
+        child ``name`` (None: open none), both at the one reading ``at``
+        of :func:`now` (read here when not handed in). The open phase is
+        the thread's innermost span until the next one, so spans opened
+        meanwhile parent under it; the span's own close closes it."""
+        at = now() if at is None else at
+        if self.leaf is not None:
+            self.leaf._close(at)
+            self.leaf = None
+        if name is not None:
+            self.leaf = _Span(name, self.trace, self.id, {}, at)
+            self.leaf.__enter__()
 
     def __enter__(self):
         _push(self.trace, self.id)
@@ -236,16 +262,23 @@ class _Span(object):
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        t1 = now()
+        if self.leaf is not None:
+            self.leaf._close(t1, exc_type)
+            self.leaf = None
+        self._close(t1, exc_type)
+        return False
+
+    def _close(self, t1, exc_type=None):
         if self.mirror is not None:
-            self.mirror.__exit__(exc_type, exc, tb)
+            self.mirror.__exit__(None, None, None)
         _pop()
         if exc_type is not None and "error" not in self.labels:
             self.labels["error"] = exc_type.__name__
         _commit({"trace": self.trace, "id": self.id,
                  "parent": self.parent, "name": self.name,
-                 "t0": self.t0, "t1": now(), "labels": self.labels,
+                 "t0": self.t0, "t1": t1, "labels": self.labels,
                  "tid": threading.current_thread().name})
-        return False
 
 
 class _Noop(object):
@@ -256,6 +289,9 @@ class _Noop(object):
 
     def set(self, **labels):
         return self
+
+    def phase(self, name, at=None):
+        pass
 
     def __enter__(self):
         return self

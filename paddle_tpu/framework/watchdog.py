@@ -75,8 +75,11 @@ class StragglerDetector(object):
     def count(self):
         return self._n
 
-    def observe(self, seconds, what="step"):
-        """Feed one step latency; True if it was flagged as a straggler."""
+    def observe(self, seconds, what="step", phases=None):
+        """Feed one step latency; True if it was flagged as a straggler.
+        ``phases`` ({"execute_s": ...}: where the caller's own clock put
+        the step's time) rides on the ``straggler`` event of a flagged
+        step, so the event says which phase grew."""
         seconds = float(seconds)
         with self._lock:
             # ewma > 0: a zero baseline has no meaningful ratio (and
@@ -97,7 +100,7 @@ class StragglerDetector(object):
             from . import resilience
             resilience.record_event("straggler", what=what,
                                     latency_s=seconds, ewma_s=ewma,
-                                    ratio=seconds / ewma)
+                                    ratio=seconds / ewma, **(phases or {}))
         if critical:
             from . import resilience
             resilience.record_event("straggler_critical", what=what,
@@ -140,12 +143,12 @@ def straggler_detector():
     return _detector[0]
 
 
-def observe_step_latency(seconds, what="step"):
+def observe_step_latency(seconds, what="step", phases=None):
     """Feed the global detector (no-op when detection is disabled)."""
     det = _detector[0]
     if det is None:
         return False
-    return det.observe(seconds, what=what)
+    return det.observe(seconds, what=what, phases=phases)
 
 
 def straggler_action_due():

@@ -370,15 +370,30 @@ def _mlp_train_program(width=1024, depth=6, batch=256):
     return main, startup, feed, loss
 
 
+PHASES = ["exec.prepare", "exec.feed", "exec.prepare", "exec.execute",
+          "exec.writeback", "exec.release"]
+
+
+@pytest.mark.parametrize("records", [False, True],
+                         ids=["", "step_records"])
 @pytest.mark.parametrize("entry", ["run", "run_steps"])
-def test_exec_step_children_tile_the_step(entry):
-    """exec.step's children (feed, prepare, compile, execute, writeback)
-    cover it: what they leave out is a few statements between them, under
-    3% of a step that does some milliseconds of work. exec.fetch sits
-    inside exec.writeback, so that the scope writes are what is left."""
+def test_exec_step_children_tile_the_step(entry, records):
+    """exec.step covers the call, preamble and frame exit included, and
+    its phases (prepare, feed, prepare, compile, execute, writeback,
+    release, and records where a layer registered a counter) tile it:
+    each boundary is one reading of the clock, so what they leave out is
+    the span's own open and close, under 1% of a step that does some
+    milliseconds of work. exec.fetch sits inside exec.writeback, so that
+    the scope writes are what is left; release and records are its
+    siblings, never nested."""
     from paddle_tpu.framework.scope import Scope, scope_guard
+    calls = []
     with scope_guard(Scope()):
         main, startup, feed, loss = _mlp_train_program()
+        if records:
+            weight = main.global_block().all_parameters()[0].name
+            main.record_step_state("test.weight", weight, {"layer": 0},
+                                   lambda w: {"size": int(w.size)})
         exe = pt.Executor()
         exe.run(startup)
         if entry == "run_steps":
@@ -387,24 +402,178 @@ def test_exec_step_children_tile_the_step(entry):
         call(main, feed=feed, fetch_list=[loss])        # compiles
         obs.enable("exec")
         for _ in range(5):
+            t_call = obs.now()
             call(main, feed=feed, fetch_list=[loss])
+            calls.append((t_call, obs.now()))
     steps = obs.spans(name="exec.step")
     assert len(steps) == 5
     by_id = {}
     for s in obs.spans():
         by_id.setdefault(s["parent"], []).append(s)
-    covers = []
-    for step in steps:
-        kids = by_id[step["id"]]
-        assert [k["name"] for k in sorted(kids, key=lambda k: k["t0"])] \
-            == ["exec.feed", "exec.prepare", "exec.execute",
-                "exec.writeback"]
-        covers.append(sum(k["t1"] - k["t0"] for k in kids)
+    want = PHASES + (["exec.records"] if records else [])
+    covers, of_call = [], []
+    for step, (t_call, t_return) in zip(steps, calls):
+        leaves = sorted(by_id[step["id"]], key=lambda k: k["t0"])
+        assert [k["name"] for k in leaves] == want
+        # one reading a boundary: a phase opens where the last one closed
+        assert all(a["t1"] == b["t0"] for a, b in zip(leaves, leaves[1:]))
+        assert leaves[-1]["t1"] == (step["t1"] if records
+                                    else pytest.approx(step["t1"], abs=1e-3))
+        covers.append(sum(k["t1"] - k["t0"] for k in leaves)
                       / (step["t1"] - step["t0"]))
-        wb = next(k for k in kids if k["name"] == "exec.writeback")
+        assert t_call <= step["t0"] and step["t1"] <= t_return
+        of_call.append((step["t1"] - step["t0"]) / (t_return - t_call))
+        wb = next(k for k in leaves if k["name"] == "exec.writeback")
         assert [k["name"] for k in by_id[wb["id"]]] == ["exec.fetch"]
+        release = next(k for k in leaves if k["name"] == "exec.release")
+        assert release["id"] not in by_id       # nothing nests in it
+        if records:
+            # the counters' spans, and nothing else, under exec.records
+            mine = by_id[leaves[-1]["id"]]
+            assert [(k["name"], k["labels"]) for k in mine] \
+                == [("test.weight", {"layer": 0, "size": 1024 * 1024})]
+            assert leaves[-1]["t0"] <= mine[0]["t0"] <= leaves[-1]["t1"]
     # the median step: one preempted step must not fail the suite
-    assert sorted(covers)[len(covers) // 2] >= 0.97, covers
+    assert sorted(covers)[len(covers) // 2] >= 0.99, covers
+    assert sorted(of_call)[len(of_call) // 2] >= 0.99, of_call
+
+
+def _counted_program():
+    """A tiny train program whose two parameters are registered as step
+    records."""
+    from paddle_tpu import optimizer
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        x = layers.data("x", [4], dtype="float32")
+        loss = layers.reduce_mean(layers.square(layers.fc(x, 3)))
+        optimizer.SGD(0.1).minimize(loss)
+    for i, p in enumerate(main.global_block().all_parameters()):
+        main.record_step_state("test.param", p.name, {"layer": i},
+                               lambda v: {"size": int(v.size)})
+    assert len(main.step_records) == 2
+    return main, startup, {"x": np.ones((2, 4), np.float32)}, loss
+
+
+def test_obs_off_opens_no_span_and_reads_no_step_record(monkeypatch):
+    """With obs off the step's phases are the shared no-op: no _Span is
+    built, and the registered counters are not fetched."""
+    import jax
+    from paddle_tpu.framework.scope import Scope, scope_guard
+
+    def never(*a, **kw):
+        raise AssertionError("reached with obs off")
+    with scope_guard(Scope()):
+        main, startup, feed, loss = _counted_program()
+        exe = pt.Executor()
+        exe.run(startup)
+        exe.run(main, feed=feed, fetch_list=[loss])     # compiles
+        monkeypatch.setattr(obs._Span, "__init__", never)
+        monkeypatch.setattr(jax, "device_get", never)
+        before = resilience.executor_step_totals()["total"]["count"]
+        out = exe.run(main, feed=feed, fetch_list=[loss])
+        stacked = {k: np.stack([v] * 2) for k, v in feed.items()}
+        exe.run_steps(main, feed=stacked, fetch_list=[loss])
+    assert np.isfinite(out[0]).all()
+    assert obs.spans() == []
+    # the always-on histograms still tick, from the same boundaries
+    tot = resilience.executor_step_totals()
+    assert tot["total"]["count"] == before + 2
+    assert tot["execute"]["count"] == tot["writeback"]["count"] \
+        == tot["total"]["count"]
+
+
+def test_step_records_cost_one_device_get_a_step(monkeypatch):
+    """Two registered counters, one host round trip: the observer fetches
+    every registered array in ONE jax.device_get, under exec.records."""
+    import jax
+    from paddle_tpu.framework.scope import Scope, scope_guard
+    fetched = []
+    real = jax.device_get
+
+    def counting(x):
+        fetched.append(len(x))
+        return real(x)
+    with scope_guard(Scope()):
+        main, startup, feed, loss = _counted_program()
+        exe = pt.Executor()
+        exe.run(startup)
+        exe.run(main, feed=feed, fetch_list=[loss])     # compiles
+        obs.enable("exec")
+        monkeypatch.setattr(jax, "device_get", counting)
+        for _ in range(3):
+            exe.run(main, feed=feed, fetch_list=[loss])
+    assert fetched == [2, 2, 2]
+    records = obs.spans(name="exec.records")
+    assert len(records) == 3
+    mine = obs.spans(name="test.param")
+    assert sorted((s["labels"]["layer"], s["labels"]["size"])
+                  for s in mine) == sorted([(0, 12), (1, 3)] * 3)
+    assert {s["parent"] for s in mine} == {r["id"] for r in records}
+
+
+def test_a_phase_closes_with_the_next_one_and_with_its_span():
+    """_Span.phase: children that tile their parent, each boundary one
+    reading (handed in or read once); an exception closes the open phase
+    with its parent and labels both."""
+    obs.enable("alone")
+    with obs.span("whole") as sp:
+        sp.phase("a", 10.0)
+        with obs.span("inside_a"):
+            pass
+        sp.phase("b", 12.5)
+        sp.phase(None, 13.0)
+        assert obs.current()[1] == sp.id
+        sp.phase("c")
+    got = {s["name"]: s for s in obs.spans()}
+    assert (got["a"]["t0"], got["a"]["t1"]) == (10.0, 12.5)
+    assert (got["b"]["t0"], got["b"]["t1"]) == (12.5, 13.0)
+    assert got["c"]["t1"] == got["whole"]["t1"]
+    assert got["inside_a"]["parent"] == got["a"]["id"]
+    assert {got[n]["parent"] for n in "abc"} == {got["whole"]["id"]}
+    obs.clear()
+    with pytest.raises(KeyError):
+        with obs.span("whole") as sp:
+            sp.phase("a")
+            raise KeyError("x")
+    assert obs.current() is None
+    assert [(s["name"], s["labels"].get("error")) for s in obs.spans()] \
+        == [("a", "KeyError"), ("whole", "KeyError")]
+    obs.disable()
+    obs.clear()
+    obs.span("whole").phase("a", 1.0)       # the shared no-op's: nothing
+    assert obs.spans() == [] and obs.current() is None
+
+
+def test_the_straggler_detector_gets_the_steps_phases(monkeypatch):
+    """An armed detector is fed the step's latency WITH its phases, all
+    from the boundaries' one clock: the phases add up to the latency."""
+    from paddle_tpu.framework import watchdog
+    from paddle_tpu.framework.scope import Scope, scope_guard
+    seen = []
+    det = watchdog.enable_straggler_detection(warmup=1000)
+    monkeypatch.setattr(
+        det, "observe", lambda seconds, what="step", phases=None:
+        seen.append((seconds, what, phases)))
+    try:
+        with scope_guard(Scope()):
+            main, startup, feed, loss = _counted_program()
+            exe = pt.Executor()
+            exe.run(startup)
+            exe.run(main, feed=feed, fetch_list=[loss])     # compiles
+            exe.run(main, feed=feed, fetch_list=[loss])
+            stacked = {k: np.stack([v] * 4) for k, v in feed.items()}
+            exe.run_steps(main, feed=stacked, fetch_list=[loss])
+    finally:
+        watchdog.disable_straggler_detection()
+    assert [what for _s, what, _p in seen] \
+        == ["Executor.run", "Executor.run", "Executor.run_steps"]
+    base = {"feed_prepare_s", "execute_s", "writeback_s", "release_s"}
+    assert [set(p) for _s, _w, p in seen] \
+        == [base | {"compile_s"}, base, base | {"compile_s"}]
+    for (seconds, _what, phases), n_steps in zip(seen, (1, 1, 4)):
+        assert all(v >= 0 for v in phases.values())
+        assert sum(phases.values()) == pytest.approx(seconds * n_steps,
+                                                     rel=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -106,6 +106,21 @@ def test_straggler_flagged_after_warmup_with_event():
     assert ev["ratio"] == pytest.approx(10.0)
 
 
+def test_straggler_event_carries_the_phases_it_was_fed():
+    """Executor.run feeds the step's phase durations with its latency:
+    the event of a flagged step says which phase grew."""
+    det = StragglerDetector(alpha=0.5, k=3.0, warmup=1)
+    det.observe(0.1, phases={"execute_s": 0.01, "writeback_s": 0.08})
+    assert resilience.events("straggler") == []
+    assert det.observe(1.0, what="Executor.run",
+                       phases={"execute_s": 0.02, "writeback_s": 0.97})
+    ev = resilience.events("straggler")[-1]
+    assert (ev["execute_s"], ev["writeback_s"]) == (0.02, 0.97)
+    assert ev["latency_s"] == pytest.approx(1.0)
+    assert det.observe(50.0)                 # fed no phases: none reported
+    assert "writeback_s" not in resilience.events("straggler")[-1]
+
+
 def test_straggler_persistent_slowdown_recalibrates():
     """Straggler samples still feed the EWMA: a host that becomes slow
     and STAYS slow flags the transition, then stops paging — the new
