@@ -5,6 +5,8 @@ import numpy as np
 
 from ..initializer import ConstantInitializer
 from ..layer_helper import LayerHelper
+from ..ops import moe_ops
+from ..ops.pallas import grouped_matmul as gmm
 from ..param_attr import ParamAttr
 from .tensor import assign
 
@@ -82,12 +84,13 @@ def moe_ffn(x, num_experts, top_k, ffn_size, experts_held=None,
                "routed_scaling_factor": float(routed_scaling_factor)})
     rows, pos, row_pair = tmp(x.dtype), tmp("int32", None, True), \
         tmp("int32", None, True)
+    held_pair = tmp("int32", None, True)
     sizes, tile_group = tmp("int32", (count,), True), tmp("int32", None, True)
     helper.append_op(
         "moe_dispatch", inputs={"X": [x.name], "TopE": [top_e.name]},
         outputs={"Rows": [rows.name], "Pos": [pos.name],
-                 "RowPair": [row_pair.name], "GroupSizes": [sizes.name],
-                 "TileGroup": [tile_group.name]},
+                 "RowPair": [row_pair.name], "HeldPair": [held_pair.name],
+                 "GroupSizes": [sizes.name], "TileGroup": [tile_group.name]},
         attrs={"experts_held": held})
     y = tmp(x.dtype)
     helper.append_op(
@@ -99,7 +102,8 @@ def moe_ffn(x, num_experts, top_k, ffn_size, experts_held=None,
     helper.append_op(
         "moe_combine",
         inputs={"Y": [y.name], "TopW": [top_w.name], "Pos": [pos.name],
-                "RowPair": [row_pair.name]},
+                "RowPair": [row_pair.name], "HeldPair": [held_pair.name],
+                "GroupSizes": [sizes.name]},
         outputs={"Out": [out.name]})
     return out, load
 
@@ -122,7 +126,12 @@ def moe_balance(load, layer, experts_held=None, bias_update_rate=0.0):
     - keeps it as the persistable `<layer>_expert_load`. While obs is on,
       `Executor.run` reads it back after the step and records a `moe.load`
       span: `layer`, and over the experts held `rows_held` (their sum: the
-      picks that landed here), `rows_max`, `rows_mean`;
+      picks that landed here), `rows_max`, `rows_mean`, `rows_in_use` (the
+      tile-padded rows the plan laid out for those counts), `rows_buffer`
+      (the worst case for the step's pairs: the counts' sum over ALL
+      experts is tokens x top_k), and `bounded` (1 where the layer's row
+      passes followed the rows in use on that step:
+      `moe_ops.takes_bounded_form`, the rule the ops' own `cond`s go by);
     - where `bias_update_rate` > 0, the loss-free balance step on
       `<layer>_expert_bias`: + rate for every expert under the mean load,
       - rate for every one over it. The load over ALL experts is known on
@@ -144,9 +153,16 @@ def moe_balance(load, layer, experts_held=None, bias_update_rate=0.0):
     assign(load, output=kept)
 
     def summarize(counts, first=int(first), count=int(count)):
-        rows = np.asarray(counts)[first:first + count]
+        counts = np.asarray(counts)
+        rows = counts[first:first + count]
+        pairs = int(counts.sum())       # every pick fell on some expert
+        tm = gmm.row_tile(pairs)
+        in_use = int(moe_ops.rows_laid_out(rows, tm))
+        buffer = gmm.buffer_rows(pairs, count, tm)
         return {"rows_held": int(rows.sum()), "rows_max": int(rows.max()),
-                "rows_mean": float(rows.mean())}
+                "rows_mean": float(rows.mean()), "rows_in_use": in_use,
+                "rows_buffer": buffer, "bounded": int(
+                    moe_ops.takes_bounded_form(in_use, buffer))}
 
     program.record_step_state("moe.load", kept.name, {"layer": layer},
                               summarize)

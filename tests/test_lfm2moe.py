@@ -219,9 +219,15 @@ def test_moe_load_spans_appear_with_obs_on_and_cache_misses_stay():
     assert layers_seen == ["lfm_layer_%d" % i for i in (1, 2, 3, 4)]
     for s in spans:
         lab = s["labels"]
-        assert set(lab) == {"layer", "rows_held", "rows_max", "rows_mean"}
+        assert set(lab) == {"layer", "rows_held", "rows_max", "rows_mean",
+                            "rows_in_use", "rows_buffer", "bounded"}
         assert lab["rows_mean"] == pytest.approx(lab["rows_held"] / 4)
         assert lab["rows_max"] >= lab["rows_mean"]
+        # 2 x 32 tokens x 2 picks: tiles of 8 rows, 4 held experts
+        assert lab["rows_buffer"] == 128 + 4 * 8
+        assert lab["rows_held"] <= lab["rows_in_use"] <= lab["rows_buffer"]
+        assert lab["rows_in_use"] % 8 == 0
+        assert lab["bounded"] == int(2 * lab["rows_in_use"] <= 160)
     # the last step's spans are the state the step left
     for s in spans[-4:]:
         kept = np.asarray(scope.find_var(s["labels"]["layer"]

@@ -1,19 +1,29 @@
 #!/usr/bin/env python
-"""Microbenchmark of the expert layer's row movement, alone on the chip.
+"""Microbenchmark of the expert layer's row passes, alone on the chip.
 
-One line a form: what `ops/moe_ops.py` does between token order and expert
-order (and PR 31's forms it was measured against), each jitted by itself at
-the `lfm2-8b-a1b.t8192-b2` cell's shapes by default: X (16,384, 2,048)
-bfloat16, top-4 of 32 experts with 8 held, a row buffer of 69,632 rows, an
-expert width of 1,792. `ms` is wall time a call over `--calls` calls
-dispatched back to back behind one `block_until_ready`; `GB/s` is the bytes
-the form has to move (operands read once + results written once) over that
-time, so a form that writes an intermediate out reads low. Alone, XLA may
-hold a 64 MiB operand in VMEM (the token-order arrays; never the buffer),
-where a row gather runs several times faster than out of HBM: read a form's
-time inside the step from the step's trace, not from here.
+One line a form, each new form under the form it replaces: what
+`ops/moe_ops.py` does between token order and expert order, and over the
+rows of the buffer, each jitted by itself at the shapes of one of the two
+expert cells: `--cell lfm2` (the default: X (16,384, 2,048) bfloat16, top-4
+of 32 experts with 8 held, a row buffer of 69,632 rows, an expert width of
+1,792) or `--cell kimi` (X (16,384, 2,304), top-8 of 256 with 8 held,
+135,168 rows, width 1,024). "whole" is PR 32's spelling, which costs by the
+buffer (or by every pick of every token) and lives here and in
+`tests/test_moe_ops.py` alone (but for X -> buffer, where the op keeps it);
+"in use" is the op's, which costs by the rows the plan laid out. `--rows-in-use N` draws picks that put N pairs on the held
+experts (the plan lays out N rows and each group's padding); without it the
+picks are uniform (held share = held / experts). `ms` is wall time a call
+over `--calls` calls dispatched back to back behind one `block_until_ready`;
+`GB/s` is the bytes the form has to move at the rows in use (operands read
+once + results written once) over that time. Alone, XLA may hold a 64 MiB
+operand in VMEM (the token-order arrays; never the buffer), where a row
+gather runs several times faster than out of HBM; and a form that writes
+over an operand it has read (`moe_combine`'s backward over y, the silu
+pass's backward over its input) pays a copy of that operand here, where the
+operand lives on for the next call, and none in the step, where it is dead:
+read a form's time inside the step from the step's trace, not from here.
 
-  python tools/mb_moe_rows.py                 # on the chip tool
+  python tools/mb_moe_rows.py --cell kimi --rows-in-use 8192   # on the chip tool
   JAX_PLATFORMS=cpu python tools/mb_moe_rows.py --walk-through --tokens 256 \
       --d 128 --ffn 128 --calls 2             # no device time: exits 1 without the flag
 """
@@ -27,113 +37,191 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
 
 import jax                      # noqa: E402
 import jax.numpy as jnp         # noqa: E402
+import numpy as np              # noqa: E402
 
 from paddle_tpu.ops import moe_ops                          # noqa: E402
 from paddle_tpu.ops.pallas import grouped_matmul as gmm     # noqa: E402
 
 
-TOP_K, EXPERTS, HELD = 4, 32, 8     # the cell's routing
+#: a cell's routing and widths: tokens, d, expert width, top_k, experts, held
+CELLS = {"lfm2": (16384, 2048, 1792, 4, 32, 8),
+         "kimi": (16384, 2304, 1024, 8, 256, 8)}
 
 
-def forms(tokens, d, ffn):
-    """[(name, fn, args, bytes the form must move)], bfloat16."""
-    k, dtype = TOP_K, jnp.bfloat16
+def draw_picks(tokens, top_k, experts, held, held_pairs, seed=0):
+    """picks (tokens, top_k) int32, distinct a token. `held_pairs` None:
+    uniform over the experts. Else that many pairs on experts 0 .. held - 1,
+    spread over the tokens as evenly as their number allows."""
+    rng = np.random.default_rng(seed)
+    if held_pairs is None:
+        return np.argsort(rng.random((tokens, experts)),
+                          axis=1)[:, :top_k].astype(np.int32)
+    most = min(top_k, held)
+    if not 0 <= held_pairs <= tokens * most \
+            or top_k - held_pairs // tokens > experts - held:
+        sys.exit("--rows-in-use %d: %d tokens with %d picks over %d held of "
+                 "%d experts hold 0 .. %d pairs"
+                 % (held_pairs, tokens, top_k, held, experts, tokens * most))
+    mine = np.full((tokens,), held_pairs // tokens)
+    mine[rng.permutation(tokens)[:held_pairs % tokens]] += 1
+    here = np.argsort(rng.random((tokens, held)), axis=1)[:, :top_k]
+    away = held + np.argsort(rng.random((tokens, experts - held)),
+                             axis=1)[:, :top_k]
+    slot = np.arange(top_k)[None, :]
+    picks = np.where(slot < mine[:, None], here[:, :top_k],
+                     np.take_along_axis(away, np.maximum(
+                         slot - mine[:, None], 0), axis=1))
+    shuffle = np.argsort(rng.random((tokens, top_k)), axis=1)
+    return np.take_along_axis(picks, shuffle, axis=1).astype(np.int32)
+
+
+def forms(tokens, d, ffn, top_k, experts, held, held_pairs):
+    """(header facts, [(name, fn, args, bytes the form must move)])."""
+    k, dtype = top_k, jnp.bfloat16
     keys = jax.random.split(jax.random.PRNGKey(0), 6)
-    picks = jnp.argsort(jax.random.uniform(keys[0], (tokens, EXPERTS)),
-                        axis=1)[:, :k].astype(jnp.int32)
-    pos, row_pair, _sizes, _tg = jax.jit(
-        lambda p: moe_ops.dispatch_plan(p, 0, HELD))(picks)
-    rows = row_pair.shape[0]
+    picks = jnp.asarray(draw_picks(tokens, k, experts, held, held_pairs))
+    pos, row_pair, held_pair, sizes, _tg = jax.jit(
+        lambda p: moe_ops.dispatch_plan(p, 0, held))(picks)
+    rows, pairs = row_pair.shape[0], tokens * k
+    tm = gmm.row_tile(pairs)
+    in_use = int(moe_ops.rows_laid_out(np.asarray(sizes), tm))
+    landed = int(np.asarray(sizes).sum())
     x = jax.random.normal(keys[1], (tokens, d)).astype(dtype)
     buf = jax.random.normal(keys[2], (rows, d)).astype(dtype)
     d_out = jax.random.normal(keys[3], (tokens, d)).astype(dtype)
     w = jax.random.uniform(keys[4], (tokens, k), jnp.float32)
     both = jax.random.normal(keys[5], (rows, 2 * ffn)).astype(dtype)
+    d_act = both[:, :ffn]
     row_b = d * 2
-    pairs = tokens * k
-    key = jnp.where(picks.reshape(-1) < HELD, picks.reshape(-1), HELD)
+    key = jnp.where(picks.reshape(-1) < held, picks.reshape(-1), held)
+    chunk = moe_ops._chunk_rows(rows, tm)
 
-    def gathered(buf, pos):     # PR 31's read: one (tokens, k, d) gather
-        return jnp.take(buf, jnp.minimum(pos, rows - 1), axis=0)
+    def chunked_rows_of_tokens(x, row_pair, in_use):
+        """X -> buffer over the rows in use, chunk by chunk: measured, and
+        not what the op does (PERF.md, PR 37: XLA holds X in VMEM for the
+        one take, and in the step this form kept one more buffer alive)."""
+        def block(start, _outs):
+            pair = jax.lax.dynamic_slice_in_dim(row_pair, start, chunk)
+            return (jnp.take(x, jnp.maximum(pair, 0) // k, axis=0,
+                             mode="clip"),)
+        return moe_ops._by_chunks(
+            (moe_ops._anything((rows, x.shape[1]), x.dtype, x),), in_use,
+            chunk, block)[0]
 
-    def gather4_wsum(buf, w, pos):                  # PR 31's _combine
-        got = gathered(buf, pos).astype(jnp.float32)
-        part = jnp.where((pos < rows)[..., None], got * w[..., None], 0.0)
-        return jnp.sum(part, axis=1).astype(buf.dtype)
+    def whole_combine_bwd(y, w, d_out):
+        w_row = jnp.where(row_pair >= 0, jnp.take(
+            w.reshape(-1), jnp.maximum(row_pair, 0), mode="clip"), 0.0)
+        dy = (moe_ops._rows_of_tokens(d_out, row_pair, k).astype(jnp.float32)
+              * w_row[:, None]).astype(y.dtype)
+        dw = []
+        for j in range(k):
+            got = jnp.take(y, pos[:, j], axis=0, mode="clip")
+            got = jnp.where((pos[:, j] < rows)[:, None], got, 0)
+            dw.append(jnp.sum(got.astype(jnp.float32)
+                              * d_out.astype(jnp.float32), axis=-1))
+        return dy, jnp.stack(dw, axis=1)
 
-    def gather4_dot(buf, d_out, pos):               # PR 31's dw
-        got = gathered(buf, pos).astype(jnp.float32)
-        dw = jnp.sum(got * d_out.astype(jnp.float32)[:, None, :], axis=-1)
-        return jnp.where(pos < rows, dw, 0.0)
-
-    def one_gather_then_reduce(buf, pos):
-        got = jax.lax.optimization_barrier(gathered(buf, pos))
-        return jnp.sum(jnp.where((pos < rows)[..., None],
-                                 got.astype(jnp.float32), 0.0),
-                       axis=1).astype(buf.dtype)
-
-    def combine_bwd(buf, w, d_out):
-        _out, vjp = jax.vjp(lambda y, w_: moe_ops._combine(
-            y, w_, pos, row_pair), buf, w)
+    def combine_bwd(y, w, d_out, sizes):
+        _out, vjp = jax.vjp(lambda y_, w_: moe_ops._combine(
+            y_, w_, pos, row_pair, held_pair, sizes), y, w)
         return vjp(d_out)
 
-    def silu(both):
-        gate, up = jnp.split(both, 2, axis=1)
-        return (jax.nn.silu(gate.astype(jnp.float32))
-                * up.astype(jnp.float32)).astype(both.dtype)
+    def laid_out(sizes):        # traced, as in the ops: no static trip count
+        return moe_ops.rows_laid_out(sizes, tm)
 
-    return [
+    def gated_bwd(both, d_act, sizes):
+        _act, vjp = jax.vjp(lambda b: moe_ops._gated(b, sizes, tm), both)
+        return vjp(d_act)
+
+    def whole_gate_bwd(both, d_act):
+        _act, vjp = jax.vjp(moe_ops._gate, both)
+        return vjp(d_act)
+
+    sum_b = (landed + 2 * tokens) * row_b      # held rows in, a sum a token
+    facts = dict(rows=rows, in_use=in_use, landed=landed, tm=tm, chunk=chunk,
+                 bounded=bool(moe_ops.takes_bounded_form(in_use, rows)))
+    return facts, [
         ("argsort, stable, %d int32 keys" % pairs,
          lambda a: jnp.argsort(a, stable=True), (key,), 2 * 4 * pairs),
-        ("dispatch_plan whole (picks -> pos, row_pair, sizes, tile_group)",
-         lambda p: moe_ops.dispatch_plan(p, 0, HELD), (picks,),
-         4 * (2 * pairs + rows)),
-        ("take of %d rows, X -> the buffer (moe_ops._rows_of_tokens)" % rows,
+        ("dispatch_plan (picks -> pos, row_pair, held_pair, sizes, tiles)",
+         lambda p: moe_ops.dispatch_plan(p, 0, held), (picks,),
+         4 * (3 * pairs + rows)),
+        ("X -> buffer: one take of %d rows (moe_ops._rows_of_tokens)" % rows,
          lambda x, rp: moe_ops._rows_of_tokens(x, rp, k), (x, row_pair),
+         2 * in_use * row_b),
+        ("X -> buffer, in use: chunks of %d rows (measured, not taken)"
+         % chunk, lambda x, rp, s: chunked_rows_of_tokens(x, rp, laid_out(s)),
+         (x, row_pair, sizes), 2 * in_use * row_b),
+        ("X -> buffer, every chunk of the buffer (the loop at its worst)",
+         chunked_rows_of_tokens, (x, row_pair, jnp.int32(rows)),
          2 * rows * row_b),
         ("take of %d rows of the buffer (one pick)" % tokens,
          lambda b, p: jnp.take(b, p[:, 0], axis=0, mode="clip"), (buf, pos),
          2 * tokens * row_b),
-        ("PR 31: (tokens, %d) gather, float32 weighted sum" % k,
-         gather4_wsum, (buf, w, pos), (k + 1) * tokens * row_b),
-        ("PR 31: (tokens, %d) gather, dot against dOut" % k,
-         gather4_dot, (buf, d_out, pos), (k + 1) * tokens * row_b),
-        ("%d takes accumulated in float32 (moe_ops.sum_of_picks)" % k,
-         moe_ops.sum_of_picks, (buf, pos), (k + 1) * tokens * row_b),
-        ("%d takes, weighted (moe_combine's forward)" % k,
-         moe_ops.sum_of_picks, (buf, pos, w), (k + 1) * tokens * row_b),
-        ("one gather held in bfloat16, then the float32 reduce",
-         one_gather_then_reduce, (buf, pos), (k + 1) * tokens * row_b),
-        ("moe_combine's backward: dy (one take) and dw (%d takes, dotted)"
-         % k, combine_bwd, (buf, w, d_out),
-         (2 * rows + (k + 1) * tokens) * row_b),
-        ("silu(gate) * up over the whole buffer (moe_experts' pass)",
-         silu, (both,), 3 * rows * ffn * 2),
+        ("buffer -> tokens, whole: %d takes (moe_ops.sum_of_picks)" % k,
+         moe_ops.sum_of_picks, (buf, pos), sum_b),
+        ("buffer -> tokens, in use: the walk (moe_ops.sum_of_held_picks)",
+         lambda b, p, hp, s: moe_ops.sum_of_held_picks(b, p, hp, jnp.sum(s)),
+         (buf, pos, held_pair, sizes), sum_b),
+        ("buffer -> tokens, weighted, whole: %d takes (moe_combine)" % k,
+         moe_ops.sum_of_picks, (buf, pos, w), sum_b),
+        ("buffer -> tokens, weighted, in use: the walk",
+         lambda b, p, hp, s, w_: moe_ops.sum_of_held_picks(
+             b, p, hp, jnp.sum(s), w_),
+         (buf, pos, held_pair, sizes, w), sum_b),
+        ("buffer -> tokens, weighted, as the op chooses (its cond)",
+         lambda b, p, hp, s, w_: moe_ops._picked_sum(b, p, hp, s, w_),
+         (buf, pos, held_pair, sizes, w), sum_b),
+        ("moe_combine's backward, whole: dy one take, dw %d takes dotted"
+         % k, whole_combine_bwd, (buf, w, d_out),
+         (3 * in_use + tokens) * row_b),
+        ("moe_combine's backward, in use: one pass, dy and the row dot",
+         combine_bwd, (buf, w, d_out, sizes), (3 * in_use + tokens) * row_b),
+        ("silu(gate) * up, whole (the buffer's %d rows)" % rows,
+         moe_ops._gate, (both,), 3 * in_use * ffn * 2),
+        ("silu(gate) * up, in use (moe_ops._gated)",
+         lambda b, s: moe_ops._gated(b, s, tm), (both, sizes),
+         3 * in_use * ffn * 2),
+        ("its backward, whole", whole_gate_bwd, (both, d_act),
+         5 * in_use * ffn * 2),
+        ("its backward, in use", gated_bwd, (both, d_act, sizes),
+         5 * in_use * ffn * 2),
     ]
 
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--tokens", type=int, default=16384)
-    ap.add_argument("--d", type=int, default=2048)
-    ap.add_argument("--ffn", type=int, default=1792)
+    ap.add_argument("--cell", choices=sorted(CELLS), default="lfm2",
+                    help="whose shapes and routing (default lfm2)")
+    ap.add_argument("--rows-in-use", type=int, default=None, metavar="N",
+                    help="put N pairs on the held experts (default: "
+                         "uniform picks)")
+    ap.add_argument("--tokens", type=int)
+    ap.add_argument("--d", type=int)
+    ap.add_argument("--ffn", type=int)
     ap.add_argument("--calls", type=int, default=30)
     ap.add_argument("--walk-through", action="store_true",
                     help="run off the TPU too: the times are no device times")
     args = ap.parse_args()
+    tokens, d, ffn, top_k, experts, held = CELLS[args.cell]
+    tokens, d, ffn = args.tokens or tokens, args.d or d, args.ffn or ffn
     dev = jax.devices()[0]
-    pairs = args.tokens * TOP_K
-    print("device platform=%s kind=%r; X (%d, %d) bfloat16, top-%d of %d "
-          "experts, %d held, buffer %d rows, expert width %d, %d calls a form"
-          % (dev.platform, dev.device_kind, args.tokens, args.d, TOP_K,
-             EXPERTS, HELD, gmm.buffer_rows(pairs, HELD, gmm.row_tile(pairs)),
-             args.ffn, args.calls))
+    if dev.platform != "tpu" and not args.walk_through:
+        sys.exit("not a TPU: no device time to report (--walk-through "
+                 "runs the forms all the same)")
+    facts, lines = forms(tokens, d, ffn, top_k, experts, held,
+                         args.rows_in_use)
+    print("device platform=%s kind=%r; cell %s: X (%d, %d) bfloat16, top-%d "
+          "of %d experts, %d held, expert width %d; buffer %d rows in tiles "
+          "of %d, %d pairs landed, %d rows laid out (%.1f%%), bounded=%d, "
+          "chunks of %d rows; %d calls a form"
+          % (dev.platform, dev.device_kind, args.cell, tokens, d, top_k,
+             experts, held, ffn, facts["rows"], facts["tm"], facts["landed"],
+             facts["in_use"], 100.0 * facts["in_use"] / facts["rows"],
+             facts["bounded"], facts["chunk"], args.calls))
     if dev.platform != "tpu":
-        if not args.walk_through:
-            sys.exit("not a TPU: no device time to report (--walk-through "
-                     "runs the forms all the same)")
         print("not a TPU: the times below are no device times")
-    for name, fn, operands, nbytes in forms(args.tokens, args.d, args.ffn):
+    for name, fn, operands, nbytes in lines:
         fn = jax.jit(fn)
         jax.block_until_ready(fn(*operands))
         jax.block_until_ready(fn(*operands))
