@@ -15,7 +15,9 @@ __all__ = ["moe_ffn", "moe_balance"]
 
 def moe_ffn(x, num_experts, top_k, ffn_size, experts_held=None,
             norm_topk_prob=True, routed_scaling_factor=1.0,
-            router_attr=None, gate_up_attr=None, down_attr=None, name=None):
+            router_attr=None, gate_up_attr=None, down_attr=None, name=None,
+            router_input=None, scoring="sigmoid", gate="silu",
+            absent="nothing"):
     """(out, load). out is the sum over the picks e of
     w_e * W2_e(silu(W1_e x) * W3_e x) for the tokens x (tokens, d): the
     scores are sigmoid(x W_r) over all `num_experts`, the picks the top
@@ -24,6 +26,22 @@ def moe_ffn(x, num_experts, top_k, ffn_size, experts_held=None,
     count of picks each of the `num_experts` experts received this step
     (int32 (num_experts,)): hand it to `moe_balance`, out of the
     `recompute_segment` if the layer is in one.
+
+    Three things a model may say otherwise (the defaults are LFM2's and
+    Kimi's layer, op for op):
+    - `router_input` (tokens, d): what the router reads where that is not
+      the experts' input x (SmallThinker's router reads its block's input,
+      ahead of attention: the picks and the plan then wait for nothing the
+      mixer computes);
+    - `scoring="softmax"`: the picks are the top `top_k` of the LOGITS
+      r W_r, w the softmax over the picks' own logits; no expert bias is
+      made or read;
+    - `gate="relu"`: the experts are W2_e(relu(W1_e x) * W3_e x);
+    - `absent="folded"`: a pick on an absent expert is answered by the held
+      expert congruent to it modulo `count`, with the weight the router
+      gave it, so every pick is answered and the layer lays out tokens x
+      top_k rows whatever the router does (`load` is then over the held
+      experts alone).
 
     `experts_held=(first, count)` says which experts live here (default:
     all): the result is the part THEY give, and a pick on an absent expert
@@ -65,7 +83,25 @@ def moe_ffn(x, num_experts, top_k, ffn_size, experts_held=None,
     w2 = helper.create_parameter(attr(down_attr, "_experts_down"),
                                  shape=[count, ffn_size, d], dtype=x.dtype)
     held = [int(first), int(count)]
-    bias = _expert_bias(helper, name, num_experts)
+    if scoring not in moe_ops.SCORINGS or gate not in moe_ops.GATES:
+        raise ValueError("moe_ffn: scoring %r is one of %r and gate %r of "
+                         "%r" % (scoring, moe_ops.SCORINGS, gate,
+                                 sorted(moe_ops.GATES)))
+    # the attrs a default layer carries are the ones it always carried
+    route_ins = {"X": [(x if router_input is None else router_input).name],
+                 "W": [w_r.name]}
+    route_attrs = {"top_k": int(top_k),
+                   "norm_topk_prob": bool(norm_topk_prob),
+                   "routed_scaling_factor": float(routed_scaling_factor)}
+    if scoring == "sigmoid":
+        route_ins["Bias"] = [_expert_bias(helper, name, num_experts).name]
+    else:
+        route_attrs["scoring"] = scoring
+    if absent not in ("nothing", "folded"):
+        raise ValueError("moe_ffn: absent %r is neither 'nothing' nor "
+                         "'folded'" % (absent,))
+    if absent == "folded":
+        route_attrs["fold_onto"] = held
 
     def tmp(dtype, shape=None, stop_gradient=False):
         return helper.create_variable_for_type_inference(
@@ -76,12 +112,10 @@ def moe_ffn(x, num_experts, top_k, ffn_size, experts_held=None,
     top_e = tmp("int32", (tokens, top_k), True)
     load = tmp("int32", (num_experts,), True)
     helper.append_op(
-        "moe_route",
-        inputs={"X": [x.name], "W": [w_r.name], "Bias": [bias.name]},
+        "moe_route", inputs=route_ins,
         outputs={"TopW": [top_w.name], "TopE": [top_e.name],
                  "Load": [load.name]},
-        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob),
-               "routed_scaling_factor": float(routed_scaling_factor)})
+        attrs=route_attrs)
     rows, pos, row_pair = tmp(x.dtype), tmp("int32", None, True), \
         tmp("int32", None, True)
     held_pair = tmp("int32", None, True)
@@ -97,7 +131,8 @@ def moe_ffn(x, num_experts, top_k, ffn_size, experts_held=None,
         "moe_experts",
         inputs={"Rows": [rows.name], "W13": [w13.name], "W2": [w2.name],
                 "GroupSizes": [sizes.name], "TileGroup": [tile_group.name]},
-        outputs={"Out": [y.name]})
+        outputs={"Out": [y.name]},
+        attrs={} if gate == "silu" else {"gate": gate})
     out = tmp(x.dtype, x.shape)
     helper.append_op(
         "moe_combine",

@@ -13,10 +13,12 @@ LARK/ERNIE repos, rebuilt on paddle_tpu layers).
 - gpt: GPT-style causal LM (long-context flagship: flash/ring/ulysses
   attention, greedy_generate decode)
 - dcgan: DCGAN adversarial training as one fused two-optimizer step
-- phi4flash, lfm2moe, kimi_linear: hybrid decoders the benchmark trains
-  (selective scan + differential attention; short convolutions + sparse
-  experts; delta-rule linear attention + latent attention + sparse experts
-  with a shared expert)
+- phi4flash, lfm2moe, kimi_linear, smallthinker: hybrid decoders the
+  benchmark trains (selective scan + differential attention; short
+  convolutions + sparse experts; delta-rule linear attention + latent
+  attention + sparse experts with a shared expert; window and full
+  grouped-query attention 3:1 + sparse experts routed ahead of attention,
+  softmax over the picks, ReLU gates)
 """
 from . import bert
 from . import resnet
@@ -32,3 +34,4 @@ from . import dcgan
 from . import phi4flash
 from . import lfm2moe
 from . import kimi_linear
+from . import smallthinker

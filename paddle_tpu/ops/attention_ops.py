@@ -6,6 +6,8 @@ TPU-native: one fused op so XLA keeps QK^T / softmax / PV in registers, plus
 a Pallas flash-attention path (ops/pallas/) for long sequences that tiles the
 computation through VMEM without materializing the (T,T) scores in HBM.
 """
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -27,6 +29,13 @@ def _sdpa_xla(q, k, v, mask, scale, causal, window=None):
         logits = logits + mask.astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+
+
+#: the inner scope a WINDOWED call is lowered under (metadata only: the
+#: operations and the kernels' names are the same), so that a device trace
+#: tells a window layer's flash calls from a full-attention layer's:
+#: `.../scaled_dot_product_attention/window_attention/flash_fwd/pallas_call`
+WINDOW_SCOPE = "window_attention"
 
 
 #: what the op's `impl` attr (`layers.fused_attention(impl=)`, a model
@@ -83,18 +92,21 @@ def _sdpa(ctx, ins, attrs):
         return {"Out": ulysses_attention(q, k, v, mask=mask, mesh=mesh,
                                          axis_name=axis, causal=causal,
                                          scale=scale)}
-    if impl != "xla":
-        # `attention_path` decides from the call's shapes. Its "short" rule
-        # is this op's XLA attention; where it finds no tile,
-        # `flash_attention` runs its own XLA body (two bodies whose
-        # `precision` arguments differ: ROADMAP, named debt)
-        from .pallas.interpret import default_interpret
-        if attention_path(q.shape, k.shape, v.shape, q.dtype, causal, window,
-                          default_interpret(),
-                          auto=impl == "auto").why != "short":
-            return {"Out": flash_attention(q, k, v, mask=mask, scale=scale,
-                                           causal=causal, window=window)}
-    return {"Out": _sdpa_xla(q, k, v, mask, scale, causal, window)}
+    with (contextlib.nullcontext() if window is None
+          else jax.named_scope(WINDOW_SCOPE)):
+        if impl != "xla":
+            # `attention_path` decides from the call's shapes. Its "short"
+            # rule is this op's XLA attention; where it finds no tile,
+            # `flash_attention` runs its own XLA body (two bodies whose
+            # `precision` arguments differ: ROADMAP, named debt)
+            from .pallas.interpret import default_interpret
+            if attention_path(q.shape, k.shape, v.shape, q.dtype, causal,
+                              window, default_interpret(),
+                              auto=impl == "auto").why != "short":
+                return {"Out": flash_attention(
+                    q, k, v, mask=mask, scale=scale, causal=causal,
+                    window=window)}
+        return {"Out": _sdpa_xla(q, k, v, mask, scale, causal, window)}
 
 
 def rotate_half(x, theta):

@@ -3,7 +3,9 @@ experts it holds. Four ops, so that the device trace splits the layer by
 its own names:
 
   moe_route     scores over ALL experts, top-k, the picks' weights (float32),
-                and the picks each expert received (the step's load)
+                and the picks each expert received (the step's load); X is
+                whatever the layer says its router reads, not always the
+                experts' input
   moe_dispatch  the (token, pick) pairs that landed on a held expert, laid
                 out expert by expert in a row buffer; the rows gathered
   moe_experts   the gated MLP of every held expert over its rows: two
@@ -21,7 +23,11 @@ dtype. Nothing of shape (tokens, top_k, d) is written out.
 The layer holds experts `first .. first + count - 1` of `num_experts` (the
 expert-parallel rank's share). A pick that lands on an absent expert adds
 nothing: the result is the part the held experts give. Nothing stands in for
-the other ranks or their exchange.
+the other ranks or their exchange, unless the layer says `absent="folded"`
+(`moe_route`'s `fold_onto`): every pick is then answered, a pick on an absent
+expert by the held expert congruent to it, so the layer lays out tokens x
+top_k rows whatever the router does: the rows a rank's experts see when all
+ranks bring a batch like this one.
 
 Dropless: the buffer is SHAPED for the worst case (every pick on a held
 expert: tokens x top_k rows, and a tile's padding a group), so no imbalance
@@ -39,7 +45,7 @@ leaves this file's ops goes through a `where` on the pick's own mask.
 What each pass touches:
   X -> buffer (`_rows_of_tokens`: `moe_dispatch`, its replay)    the buffer, X
                                                                  held in VMEM
-  silu(a) * b and its pullback (`_gated`, inside `moe_experts`)  the rows in use
+  act(a) * b and its pullback (`_gated`, inside `moe_experts`)   the rows in use
   dOut -> dY with `w_row`, and y . dOut a row (`_combine_bwd`)   the rows in use
   buffer -> tokens (`_picked_sum`: `moe_combine`, its replay,    the held pairs
     and `moe_dispatch`'s backward)                               + one take of
@@ -64,8 +70,11 @@ lowered: a chunk lands by `dynamic_update_slice` (`moe_route` reads the
 picks' scores through the one-hot of the picks for the same reason).
 
 Reference parity: none (the reference predates sparse experts). The
-equations are the published `lfm2_moe` block's: sigmoid scores, an expert
-bias added for the choice only, weights renormalised over the picks.
+equations are the published `lfm2_moe` block's by default: sigmoid scores,
+an expert bias added for the choice only, weights renormalised over the
+picks, SiLU gates. `moe_route`'s `scoring` "softmax" and `moe_experts`'
+`gate` "relu" are SmallThinker's: the top-k of the logits, a softmax over
+the picks alone, no bias, ReLU gates.
 """
 import functools
 
@@ -87,25 +96,50 @@ def _held(attrs):
     return int(first), int(count)
 
 
+SCORINGS = ("sigmoid", "softmax")
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 @register_op("moe_route", nondiff=("Bias",))
 def _moe_route(ctx, ins, attrs):
-    """s = sigmoid(X W) in float32; the picks are the top-k of s + Bias;
-    their weights are s at the picks, over their sum + 1e-6 where
-    `norm_topk_prob`, times `routed_scaling_factor`. Load[e] is the count
-    of picks that fell on expert e, over all experts."""
+    """`scoring` "sigmoid" (the default): s = sigmoid(X W) in float32; the
+    picks are the top-k of s + Bias; their weights are s at the picks.
+    "softmax": the picks are the top-k of the logits X W themselves (no
+    Bias), their weights the softmax over the picks' own logits. Either
+    way the weights are then over their sum + 1e-6 where `norm_topk_prob`
+    (after the softmax the sum is 1), times `routed_scaling_factor`.
+    Load[e] is the count of picks that fell on expert e, over all
+    experts. `fold_onto` = (first, count), where given, hands each pick
+    AFTER its weight is taken to the expert of `first .. first + count - 1`
+    that is congruent to it modulo `count` (TopE and Load are then over
+    those experts alone): a rank that answers every pick with the experts
+    it holds."""
     x, w = ins["X"][0], ins["W"][0]
-    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                                    w.astype(jnp.float32),
-                                    precision=_HIGHEST))
-    choose = scores + ins["Bias"][0].astype(jnp.float32)
+    scoring = attrs.get("scoring", "sigmoid")
+    if scoring not in SCORINGS:
+        raise ValueError("moe_route: scoring %r is none of %r"
+                         % (scoring, SCORINGS))
+    scores = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                     precision=_HIGHEST)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(scores)
+        choose = scores + ins["Bias"][0].astype(jnp.float32)
+    else:
+        choose = scores
     _top, picks = lax.top_k(lax.stop_gradient(choose), int(attrs["top_k"]))
     # the picks' scores through the picks' one-hot: exact (one term a sum
     # is not 0), and its backward is a select, not a scatter
     picked = picks[..., None] == jnp.arange(w.shape[1])
     weights = jnp.sum(jnp.where(picked, scores[:, None, :], 0.0), axis=-1)
+    if scoring == "softmax":
+        weights = jax.nn.softmax(weights, axis=-1)
     if attrs.get("norm_topk_prob", True):
         weights = weights / (jnp.sum(weights, axis=1, keepdims=True) + 1e-6)
     weights = weights * float(attrs.get("routed_scaling_factor", 1.0))
+    if attrs.get("fold_onto") is not None:
+        first, count = (int(n) for n in attrs["fold_onto"])
+        picks = first + (picks - first) % count
+        picked = picks[..., None] == jnp.arange(w.shape[1])
     load = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
     return {"TopW": weights, "TopE": picks.astype(jnp.int32), "Load": load}
 
@@ -390,15 +424,16 @@ def _moe_dispatch_rule(op, ins, attrs):
             "TileGroup": [TensorMeta((tiles,), "int32")]}
 
 
-def _gate(both):
-    """silu(a) * b of rows [a, b], in float32, in the rows' dtype."""
-    gate, up = jnp.split(both, 2, axis=1)
-    return (jax.nn.silu(gate.astype(jnp.float32))
-            * up.astype(jnp.float32)).astype(both.dtype)
+def _gate(both, gate="silu"):
+    """act(a) * b of rows [a, b] (`gate`: "silu" or "relu"), in float32, in
+    the rows' dtype."""
+    a, b = jnp.split(both, 2, axis=1)
+    return (GATES[gate](a.astype(jnp.float32))
+            * b.astype(jnp.float32)).astype(both.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _gated(both, sizes, tm):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _gated(both, sizes, tm, gate="silu"):
     """`_gate` over the rows in use, a chunk of tiles at a time. (No
     fallback to the one pass: a step with the whole buffer in use pays 60%
     more a row here, 0.7 ms a call at LFM2's shapes, and a `cond` around
@@ -408,17 +443,17 @@ def _gated(both, sizes, tm):
     chunk = _chunk_rows(rows, tm)
 
     def block(start, _outs):
-        return (_gate(lax.dynamic_slice_in_dim(both, start, chunk)),)
+        return (_gate(lax.dynamic_slice_in_dim(both, start, chunk), gate),)
 
     return _by_chunks((_anything((rows, width // 2), both.dtype, both),),
                       rows_laid_out(sizes, tm), chunk, block)[0]
 
 
-def _gated_fwd(both, sizes, tm):
-    return _gated(both, sizes, tm), (both, sizes)
+def _gated_fwd(both, sizes, tm, gate):
+    return _gated(both, sizes, tm, gate), (both, sizes)
 
 
-def _gated_bwd(tm, res, d_act):
+def _gated_bwd(tm, gate, res, d_act):
     """jax's own pullback of `_gate`, over the same rows; chunk by chunk it
     lands where the chunk of `both` it was computed from lay (nothing reads
     `both` after it: no second buffer of its size)."""
@@ -427,7 +462,8 @@ def _gated_bwd(tm, res, d_act):
 
     def block(start, outs):
         _act, pullback = jax.vjp(
-            _gate, lax.dynamic_slice_in_dim(outs[0], start, chunk))
+            functools.partial(_gate, gate=gate),
+            lax.dynamic_slice_in_dim(outs[0], start, chunk))
         return pullback(lax.dynamic_slice_in_dim(d_act, start, chunk))
 
     return _by_chunks((both,), rows_laid_out(sizes, tm), chunk,
@@ -439,14 +475,19 @@ _gated.defvjp(_gated_fwd, _gated_bwd)
 
 @register_op("moe_experts")
 def _moe_experts(ctx, ins, attrs):
-    """Out[r] = W2_g (silu(a) * b), [a, b] = Rows[r] W13_g, g the group of
-    row r, for the rows the plan laid out. W13 [G, d, 2F] is gate and up
-    side by side, W2 [G, F, d]."""
+    """Out[r] = W2_g (act(a) * b), [a, b] = Rows[r] W13_g, g the group of
+    row r, for the rows the plan laid out; act is `gate`, "silu" (the
+    default) or "relu". W13 [G, d, 2F] is gate and up side by side, W2
+    [G, F, d]."""
     rows, w13, w2 = ins["Rows"][0], ins["W13"][0], ins["W2"][0]
     sizes, tile_group = ins["GroupSizes"][0], ins["TileGroup"][0]
+    gate = attrs.get("gate", "silu")
+    if gate not in GATES:
+        raise ValueError("moe_experts: gate %r is none of %r"
+                         % (gate, sorted(GATES)))
     tm = rows.shape[0] // tile_group.shape[0]
     both = gmm.grouped_matmul(rows, w13, sizes, tm)
-    act = _gated(both, sizes, tm)
+    act = _gated(both, sizes, tm, gate)
     return {"Out": gmm.grouped_matmul(act, w2, sizes, tm)}
 
 
