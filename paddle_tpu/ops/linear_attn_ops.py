@@ -54,14 +54,26 @@ the two pieces where jax's costs several times the forward:
         dq = R,  dk = P + P^T + R^T,  dG = k (P - P^T) + q R - k R^T:
     products of the forward's shape summed over a SUB-long row axis into
     lane-dense (SUB, K) results; no (SUB, SUB, K) cotangent of a broadcast
-    is formed and reduced. (A `kda_*` kernel's backward is held to these.)
+    is formed and reduced. (The `kda_*` kernels' backward is held to these.)
 
-The parts lower under their own scopes inside the op's (`kda_intra`,
-`kda_walk`, `kda_walk_back`, `kda_intra_back`), so a device trace splits the
-op's time by them. The cumulative decay, the solve, both products and the
-state are float32; the other matmuls take bfloat16 operands where the inputs
-are bfloat16 and give float32 results, as the flash kernels do (any other
-dtype: float32 at HIGHEST).
+Two implementations of the one algorithm. On the TPU, at head widths that
+are multiples of 128, a call runs as the `kda_fwd` / `kda_bwd` Pallas
+kernels of `pallas/delta_rule.py`: the state stays in VMEM across a
+sequential chunk axis, nothing a chunk needs between its own steps goes to
+HBM, and `_intra`'s six float32 arrays a group are never materialised. What
+is written in THIS module is the XLA form: the path off the TPU and at
+shapes the kernels do not tile, and the oracle the kernels are tested
+against. Which of the two a call takes is decided from its own shapes and
+the platform (`kernel_plan`), and by nothing else; `kda.plan`'s "kernels"
+line says which.
+
+The XLA form's parts lower under their own scopes inside the op's
+(`kda_intra`, `kda_walk`, `kda_walk_back`, `kda_intra_back`), so a device
+trace splits the op's time by them (the kernels are `kda_fwd` and `kda_bwd`
+there, under the op's scope too). In both forms the cumulative decay, the
+solve, both products and the state are float32; the other matmuls take
+bfloat16 operands where the inputs are bfloat16 and give float32 results,
+as the flash kernels do (any other dtype: float32 at HIGHEST).
 
 Reference parity: none (the reference predates linear attention).
 """
@@ -99,27 +111,46 @@ def _mxu_dtype(dtype):
     return jnp.bfloat16 if jnp.dtype(dtype) == jnp.bfloat16 else _F32
 
 
-def plan(q_shape):
-    """What a call will do, for `kda.plan`: Python ints and strings only."""
+def plan(q_shape, kernels=None):
+    """What a call will do, for `kda.plan`: Python ints and strings only.
+    `kernels` is `delta_rule.plan`'s answer where the call takes the Pallas
+    kernels (its "kernels" line then says so, with the heads a grid step
+    holds and its VMEM bytes); None is the XLA form."""
     b, t, h, k = q_shape
     per, groups = _groups_of(t)
-    return {"batch": b, "seq": t, "heads": h, "d_k": k, "chunk": CHUNK,
-            "sub_block": SUB, "chunks": -(-t // CHUNK),
-            "chunks_a_group": per, "groups": groups,
-            "padded": per * groups * CHUNK - t,
-            "kernels": "xla: batched matmuls a group of chunks + lax.scan "
-                       "over chunks; solve: 16-row blocks as (I - L)(I + "
-                       "L^2)(I + L^4)(I + L^8), 2 x 2 block recursion to "
-                       "64, backward -strictly_lower(X^T dX X^T); in-block "
-                       "decay products: backward by hand, three products "
-                       "summed over rows, D formed again"}
+    out = {"batch": b, "seq": t, "heads": h, "d_k": k, "chunk": CHUNK,
+           "sub_block": SUB, "chunks": -(-t // CHUNK),
+           "chunks_a_group": per, "groups": groups,
+           "padded": per * groups * CHUNK - t,
+           "kernels": "xla: batched matmuls a group of chunks + lax.scan "
+                      "over chunks; solve: 16-row blocks as (I - L)(I + "
+                      "L^2)(I + L^4)(I + L^8), 2 x 2 block recursion to "
+                      "64, backward -strictly_lower(X^T dX X^T); in-block "
+                      "decay products: backward by hand, three products "
+                      "summed over rows, D formed again"}
+    if kernels:
+        # the kernels pad to whole chunks, not to whole groups
+        out.update(kernels, padded=-(-t // CHUNK) * CHUNK - t)
+    return out
 
 
-def _record_plan(q_shape):
+def kernel_plan(q_shape, d_v, itemsize):
+    """`delta_rule.plan` of the call where it takes the Pallas kernels:
+    on the TPU (`default_interpret` is false) at shapes they tile; else
+    None, the XLA form. Decided from the call's shapes and the platform,
+    and by nothing else."""
+    from .pallas import delta_rule
+    from .pallas.interpret import default_interpret
+    if default_interpret():
+        return None
+    return delta_rule.plan(tuple(q_shape), d_v, itemsize)
+
+
+def _record_plan(q_shape, kernels):
     from ..framework import obs
     if obs.enabled():
         now = obs.now()
-        obs.record("kda.plan", now, now, **plan(tuple(q_shape)))
+        obs.record("kda.plan", now, now, **plan(tuple(q_shape), kernels))
 
 
 def _groups_of(t):
@@ -403,7 +434,11 @@ def kda_attention(q, k, v, g, beta, scale=None):
     float32. Returns o (B, T, H, V) in v's dtype. `scale` defaults to
     K^-1/2."""
     scale = float(q.shape[-1]) ** -0.5 if scale is None else float(scale)
-    _record_plan(q.shape)
+    kernels = kernel_plan(q.shape, v.shape[-1], jnp.dtype(q.dtype).itemsize)
+    _record_plan(q.shape, kernels)
+    if kernels:
+        from .pallas import delta_rule
+        return delta_rule.kda(q, k, v, g, beta, scale)
     return _kda(q, k, v, g, beta, scale)
 
 

@@ -16,6 +16,13 @@ own shapes, beside the kernel, and by nothing else.
                     ``flash_attention.flash_attention(...)``)
   selective_scan    the state-space layer's scan, forward and backward,
                     in chunks ``pick_chunk`` sizes from the shape
+  delta_rule        the chunked gated delta rule (``kda_attention``),
+                    ``kda_fwd`` and ``kda_bwd``: the (K, V) state of a
+                    head in VMEM across a sequential chunk axis, the chunk
+                    math (decay differences, the unit-lower solve, the WY
+                    factors) in VMEM, several heads a grid step side by side;
+                    ``plan`` maps a call's shapes to the tiling or to
+                    None (the XLA form of ``linear_attn_ops``)
   interpret         ``default_interpret``: interpret mode off the TPU,
                     or as PADDLE_TPU_PALLAS_INTERPRET says
 """

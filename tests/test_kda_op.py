@@ -273,11 +273,28 @@ def test_plan_says_what_a_call_will_do():
     assert la.plan((1, 150, 2, 32))["padded"] == 42
 
 
-def test_plan_names_the_solve_and_the_two_hand_written_backwards():
-    kernels = la.plan((2, 8192, 16, 128))["kernels"]
-    assert kernels.startswith("xla: ")          # no kda_* kernel yet
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+def test_plan_names_the_solve_and_the_two_hand_written_backwards(path):
+    """Both plans: the XLA form's line (off the TPU, or shapes the kernels
+    do not tile) and the `kda_*` kernels' (`delta_rule.plan`)."""
+    from paddle_tpu.ops.pallas import delta_rule
+    found = delta_rule.plan((2, 8192, 16, 128), 128, 2) \
+        if path == "pallas" else None
+    plan = la.plan((2, 8192, 16, 128), found)
+    kernels = plan["kernels"]
     assert "solve: " in kernels and "X^T dX X^T" in kernels
-    assert "decay products: " in kernels and "by hand" in kernels
+    assert "products: " in kernels and "by hand" in kernels
+    if path == "xla":
+        assert kernels.startswith("xla: ")
+        assert "decay products: " in kernels and "vmem_bwd" not in plan
+        assert la.plan((2, 8192, 16, 128)) == plan
+        assert la.plan((2, 8192, 16, 32), delta_rule.plan(
+            (2, 8192, 16, 32), 32, 2))["kernels"] == kernels
+    else:
+        assert kernels.startswith("pallas: kda_fwd, kda_bwd; ")
+        assert plan["heads_a_step"] == 8 and plan["levels"] == 6
+        assert plan["chunk"] == 64 and plan["sub_block"] == 16
+        assert plan["vmem_fwd"] < plan["vmem_bwd"] < 64 * 2 ** 20
 
 
 def test_shape_rules():

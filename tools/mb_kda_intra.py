@@ -17,7 +17,15 @@ pullback once, in 8 groups and 4 layers: a step's share is 32 times a line.
 Alone, XLA fuses and lays out as it likes: read a form's time inside the
 step from the step's trace, not from here.
 
+`--kernels` times the whole op instead, at the cell's call (B 2, T 8192, H 16,
+K = V 128, bfloat16 with g float32): the XLA form and the `kda_fwd` /
+`kda_bwd` Pallas kernels (`ops/pallas/delta_rule.py`), forward alone and
+forward + backward, ms a call, the median and the range of `--runs` runs of
+`--calls` calls each, one line a `--heads-a-step` tried; `gap` is against
+the XLA form on the same chip.
+
   python tools/mb_kda_intra.py                # on the chip tool
+  python tools/mb_kda_intra.py --kernels --heads-a-step 1,2,4
   JAX_PLATFORMS=cpu python tools/mb_kda_intra.py --walk-through --chunks 2 \
       --batch 1 --heads 2 --d-k 32 --calls 2  # no device time: exits 1 without the flag
 """
@@ -33,6 +41,7 @@ import jax                      # noqa: E402
 import jax.numpy as jnp         # noqa: E402
 
 from paddle_tpu.ops import linear_attn_ops as la            # noqa: E402
+from paddle_tpu.ops.pallas import delta_rule                # noqa: E402
 
 _F32 = jnp.float32
 _mm32 = la._mm32
@@ -155,8 +164,90 @@ def gap(got, want):
                                jax.tree_util.tree_leaves(want)))
 
 
+def op_inputs(batch, seq, heads, d_k, dtype):
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    shape = (batch, seq, heads, d_k)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    q, k = (unit(jax.random.normal(key, shape)) for key in keys[:2])
+    v = jax.random.normal(keys[2], shape)
+    g = -0.3 * jax.nn.softplus(jax.random.normal(keys[3], shape))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], shape[:3]))
+    cot = jax.random.normal(keys[5], shape)
+    return tuple(x.astype(dtype) for x in (q, k, v)) + (g,) \
+        + (beta.astype(dtype), cot.astype(dtype))
+
+
+def time_calls(fn, operands, calls, runs):
+    """ms a call: (median, least, most) over `runs` runs of `calls` calls
+    dispatched back to back behind one `block_until_ready`."""
+    jax.block_until_ready(fn(*operands))
+    jax.block_until_ready(fn(*operands))
+    found = []
+    for _ in range(runs):
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*operands)
+        jax.block_until_ready(out)
+        found.append((time.perf_counter() - t0) * 1e3 / calls)
+    found.sort()
+    return found[len(found) // 2], found[0], found[-1]
+
+
+def kernels_mode(args, interpret):
+    *operands, cot = op_inputs(args.batch, args.seq, args.heads, args.d_k,
+                               jnp.bfloat16)
+    scale = args.d_k ** -0.5
+
+    def both(fn):
+        def run(*xs):
+            out, pull = jax.vjp(fn, *xs)
+            return out, pull(cot)
+        return run
+
+    def xla(*xs):
+        return la._kda(*xs, scale)
+
+    def kernels(heads_a_step):
+        def run(*xs):       # a function a tile: jit keys its cache on it
+            delta_rule.pick_heads = lambda h: heads_a_step
+            return delta_rule.kda(*xs, scale, interpret)
+        return run
+
+    print("%-44s %9s %9s %9s" % ("form", "median ms", "least", "most"))
+    want = None
+    table = [("xla", xla)] + [
+        ("kda_fwd, kda_bwd: %d heads a step" % n, kernels(n))
+        for n in args.heads_a_step]
+    for name, fn in table:
+        jax.clear_caches()  # the kernels' own jitted calls hold the last tile
+        for what, run in (("forward", jax.jit(fn)),
+                          ("forward + backward", jax.jit(both(fn)))):
+            ms = time_calls(run, operands, args.calls, args.runs)
+            print("%-44s %9.3f %9.3f %9.3f"
+                  % (((name + ", " + what)[:44],) + ms), flush=True)
+        got = jax.jit(both(fn))(*operands)
+        if want is None:
+            want = got
+        else:
+            f32 = jax.tree_util.tree_map(lambda x: x.astype(_F32),
+                                         (got, want))
+            print("%-44s gap %.2e" % (name[:44], gap(*f32)))
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--kernels", action="store_true",
+                    help="time the whole op: the XLA form against the "
+                         "kda_fwd / kda_bwd kernels")
+    ap.add_argument("--seq", type=int, default=8192)
+    ap.add_argument("--runs", type=int, default=5)
+    ap.add_argument("--heads-a-step", default=None,
+                    type=lambda s: [int(n) for n in s.split(",")],
+                    help="heads a grid step holds, each tried in turn "
+                         "(default: what the op picks)")
     ap.add_argument("--chunks", type=int, default=la.GROUP)
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--heads", type=int, default=16)
@@ -175,6 +266,10 @@ def main():
             sys.exit("not a TPU: no device time to report (--walk-through "
                      "runs the forms all the same)")
         print("not a TPU: the times below are no device times")
+    if args.kernels:
+        if args.heads_a_step is None:
+            args.heads_a_step = [delta_rule.pick_heads(args.heads)]
+        return kernels_mode(args, interpret=dev.platform != "tpu")
     table = forms(args.chunks, args.batch, args.heads, args.d_k)
     results = []
     for name, fn, operands, _against in table:
