@@ -94,6 +94,27 @@ def rope_qk_norm(q, k, head_dim, theta=10000.0, epsilon=1e-5,
     return outs[0], outs[1]
 
 
+def partial_rope(q, k_pe, nope_dim, rope_dim, theta=10000.0, name=None):
+    """Latent attention's decoupled rotary part: q (B, T, H*(nope + rope))
+    with the last `rope_dim` numbers of every head turned, k_pe (B, T,
+    rope), the key part all heads share, turned whole; same shapes out, no
+    layout change (`partial_rope` op: x cos + partner(x) sin in float32, the
+    partners through a signed permutation; positions 0..T-1). Pair i of a
+    part is its neighbours (2i, 2i + 1), as the published latent-attention
+    checkpoints store them, and turns by t * theta^(-2i/rope)."""
+    helper = LayerHelper("partial_rope", name=name)
+    outs = [helper.create_variable_for_type_inference(x.dtype, x.shape)
+            for x in (q, k_pe)]
+    helper.append_op("partial_rope",
+                     inputs={"Q": [q.name], "KPe": [k_pe.name]},
+                     outputs={"QOut": [outs[0].name],
+                              "KPeOut": [outs[1].name]},
+                     attrs={"nope_dim": int(nope_dim),
+                            "rope_dim": int(rope_dim),
+                            "theta": float(theta)})
+    return outs[0], outs[1]
+
+
 def mha_kv_projection(keys, values, d_key, d_value, n_head,
                       param_initializer=None, name="multi_head_att"):
     """Project encoder output once into head-split K/V for cross-attention

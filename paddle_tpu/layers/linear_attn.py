@@ -2,15 +2,17 @@
 Attention: the gated delta rule of ops/linear_attn_ops.py with its
 projections, short convolutions, gates and output norm) and `mla_attention`
 (latent attention in its training form: the low-rank key/value latent is
-decompressed in front of the flash kernels). Both take `heads_held=(first,
-count)`, as `moe_ffn` takes `experts_held`: the layer then builds those
-heads' columns and rows alone and returns the part they give (a
-tensor-parallel rank's share; the sum over the ranks' results is the whole
-mixer's)."""
+decompressed in front of the flash kernels; positions are optional: with
+`rope_theta` the decoupled rotary part of every query head and the one key
+part the heads share are turned, by interleaved pairs, the key part once,
+before it is broadcast to the heads; without it nothing is turned). Both take `heads_held=(first, count)`, as `moe_ffn` takes
+`experts_held`: the layer then builds those heads' columns and rows alone
+and returns the part they give (a tensor-parallel rank's share; the sum
+over the ranks' results is the whole mixer's)."""
 from ..initializer import ConstantInitializer
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
-from .attention import fused_attention
+from .attention import fused_attention, partial_rope
 from .nn import expand, fc, reshape, split, transpose, unsqueeze
 from .ssm import causal_conv1d, rms_norm
 from .tensor import concat
@@ -104,14 +106,21 @@ def kda_attention(x, num_heads, head_dim, gate_rank=None, conv_width=4,
 
 def mla_attention(x, num_heads, qk_nope_dim, qk_rope_dim, v_dim, kv_rank,
                   heads_held=None, epsilon=1e-5, param_initializer=None,
-                  name=None):
-    """Latent attention without positions (the rotary part of the
-    published form is carried and never turned), causal, x (B, T, d) ->
-    (B, T, d). With H heads held:
+                  name=None, rope_theta=None):
+    """Latent attention, causal, x (B, T, d) -> (B, T, d). With H heads
+    held:
       q = x W_q -> (H, nope + rope) a token;
       [c | k_pe] = x W_kva -> (`kv_rank` | rope), the latent and the part
         of the key every head shares (W_kva is whole on every rank);
       [k_nope | v] = rmsnorm(c; scale of kv_rank) W_kvb -> (H, nope | v);
+      where `rope_theta` is a number, rotary positions t = 0..T-1 on the
+        last `rope` numbers of every query head and on k_pe, ONE vector a
+        token, turned before it is broadcast to the heads (`partial_rope`:
+        pair i is the neighbours (2i, 2i + 1), as the published
+        latent-attention checkpoints store them, and turns by
+        t * rope_theta^(-2i/rope)); where it is None
+        (a `mla_use_nope` model) nothing is turned and no such op is built:
+        the rope numbers are plain features;
       k = [k_nope | k_pe]; softmax(q k^T (nope + rope)^-1/2) v, causal,
       through `fused_attention` (D = nope + rope, Dv = v_dim: the flash
       kernels' split backward); out = concat W_o.
@@ -130,10 +139,18 @@ def mla_attention(x, num_heads, qk_nope_dim, qk_rope_dim, v_dim, kv_rank,
     def head_major(m):
         return transpose(m, [0, 2, 1, 3])
 
-    q = head_major(reshape(proj(x, held * d_qk, "_q.w_0"),
-                           [0, 0, held, d_qk]))
+    def by_head(m):
+        return head_major(reshape(m, [0, 0, held, d_qk]))
+
+    q = proj(x, held * d_qk, "_q.w_0")
+    if rope_theta is None:      # op for op the program it always built
+        q = by_head(q)
     latent, k_pe = split(proj(x, kv_rank + qk_rope_dim, "_kv_a.w_0"),
                          [kv_rank, qk_rope_dim], dim=2)
+    if rope_theta is not None:
+        q, k_pe = partial_rope(q, k_pe, qk_nope_dim, qk_rope_dim,
+                               theta=rope_theta)
+        q = by_head(q)
     latent = rms_norm(latent, epsilon=epsilon,
                       param_attr=ParamAttr(name=name + "_kv_a_norm_s"))
     k_nope, v = split(

@@ -40,13 +40,13 @@ give, the shared expert and everything else whole.
 TPU-first choices as models/lfm2moe.py: bf16 activations, the fused head
 (`fused_mlm_head_loss`), each layer a `recompute_segment`; an expert layer's
 load count leaves its segment as a second result and `layers.moe_balance`
-keeps it there.
+keeps it there. The blocks behind the mixer (the gated MLP, router +
+`moe_ffn` + shared expert) and the frame around the layers (embedding,
+segments, final norm, head) are `models/moe_decoder.py`'s, shared with
+`models/kimi_vl.py`; this file brings the config and the two mixers.
 """
-import paddle_tpu as pt
 from paddle_tpu import layers
-from paddle_tpu.initializer import TruncatedNormalInitializer
-from paddle_tpu.models.gpt import masked_mean_weights
-from paddle_tpu.param_attr import ParamAttr
+from paddle_tpu.models import moe_decoder
 
 KINDS = ("kda", "mla")
 
@@ -146,96 +146,28 @@ class KimiLinearConfig(object):
         return self.published_layer_index[i] <= self.first_k_dense
 
 
-def _init(cfg):
-    return TruncatedNormalInitializer(scale=cfg.initializer_range)
-
-
-def _w(cfg, name):
-    return ParamAttr(name=name, initializer=_init(cfg))
-
-
-def _norm(x, cfg, name):
-    return layers.rms_norm(x, epsilon=cfg.norm_eps,
-                           param_attr=ParamAttr(name=name + "_s"))
-
-
-def gated_mlp(u, width, cfg, name):
-    """W_d(silu(W_g u) * W_u u); W_g and W_u are one (d, 2 width) matrix."""
-    gate, up = layers.split(
-        layers.fc(u, 2 * width, num_flatten_dims=2,
-                  param_attr=_w(cfg, name + "_gate_up.w_0"),
-                  bias_attr=False), 2, dim=2)
-    return layers.fc(layers.elementwise_mul(layers.silu(gate), up),
-                     cfg.hidden_size, num_flatten_dims=2,
-                     param_attr=_w(cfg, name + "_down.w_0"), bias_attr=False)
-
-
-def expert_ffn(u, cfg, name):
-    """(shared(u) + the held experts' part (B,T,d), load)."""
-    out, load = layers.moe_ffn(
-        layers.reshape(u, [-1, cfg.hidden_size]), cfg.num_experts, cfg.top_k,
-        cfg.moe_ff_size, experts_held=cfg.experts_held,
-        norm_topk_prob=cfg.norm_topk_prob,
-        routed_scaling_factor=cfg.routed_scaling_factor,
-        router_attr=_w(cfg, name + "_router.w_0"),
-        gate_up_attr=_w(cfg, name + "_experts_gate_up"),
-        down_attr=_w(cfg, name + "_experts_down"), name=name)
-    out = layers.reshape(out, [-1, u.shape[1], cfg.hidden_size])
-    if cfg.num_shared_experts:
-        out = layers.elementwise_add(out, gated_mlp(
-            u, cfg.moe_ff_size * cfg.num_shared_experts, cfg,
-            name + "_shared"))
-    return out, load
-
-
 def mixer(u, cfg, i, name):
     if cfg.layer_kinds[i] == "kda":
         return layers.kda_attention(
             u, cfg.num_heads, cfg.kda_head_dim, gate_rank=cfg.gate_rank,
             conv_width=cfg.conv_width, heads_held=cfg.heads_held,
-            epsilon=cfg.norm_eps, param_initializer=_init(cfg),
+            epsilon=cfg.norm_eps, param_initializer=moe_decoder.init(cfg),
             name=name + "_kda")
     return layers.mla_attention(
         u, cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_dim,
         cfg.kv_rank, heads_held=cfg.heads_held, epsilon=cfg.norm_eps,
-        param_initializer=_init(cfg), name=name + "_mla")
+        param_initializer=moe_decoder.init(cfg), name=name + "_mla")
 
 
 def kimi_layer(x, cfg, i):
     """Layer i: [x'] for a dense layer, [x', load] for an expert layer."""
-    name = "kimi_layer_%d" % i
-    h = layers.elementwise_add(
-        x, mixer(_norm(x, cfg, name + "_attn_norm"), cfg, i, name))
-    u = _norm(h, cfg, name + "_ffn_norm")
-    if cfg.is_dense(i):
-        return [layers.elementwise_add(
-            h, gated_mlp(u, cfg.ff_size, cfg, name + "_mlp"))]
-    out, load = expert_ffn(u, cfg, name)
-    return [layers.elementwise_add(h, out), load]
+    return moe_decoder.layer(x, cfg, i, "kimi_layer_%d" % i, mixer)
 
 
 def kimi_linear_decoder(token_ids, cfg, is_test=False):
     """Embed -> the layers -> final RMS norm; (B, T, d) in cfg.dtype."""
-    x = layers.embedding(token_ids, [cfg.vocab_size, cfg.hidden_size],
-                         param_attr=_w(cfg, "kimi_word_embedding"),
-                         dtype="float32")
-    if cfg.dtype == "bfloat16":
-        x = layers.cast(x, "bfloat16")
-    for i in range(cfg.num_layers):
-        def run(h, i=i):
-            return kimi_layer(h, cfg, i)
-
-        if cfg.recompute and not is_test:
-            outs = layers.recompute_segment(run, [x])
-        else:
-            outs = run(x)
-        outs = outs if isinstance(outs, (list, tuple)) else [outs]
-        x = outs[0]
-        if len(outs) > 1:
-            layers.moe_balance(
-                outs[1], "kimi_layer_%d" % i, cfg.experts_held,
-                0.0 if is_test else cfg.expert_bias_update_rate)
-    return _norm(x, cfg, "kimi_norm_f")
+    return moe_decoder.decoder(token_ids, cfg, "kimi", mixer,
+                               is_test=is_test)
 
 
 def kimi_linear_pretrain_program(cfg, batch_size, seq_len, optimizer_fn=None,
@@ -244,19 +176,6 @@ def kimi_linear_pretrain_program(cfg, batch_size, seq_len, optimizer_fn=None,
     (N,T,1) float32 (1 = predict here). The head is its own (vocab, d)
     matrix (untied, as published), through the fused head, in bf16 with f32
     accumulation when cfg.dtype is bfloat16."""
-    main, startup = pt.Program(), pt.Program()
-    with pt.program_guard(main, startup):
-        tok = layers.data("token_ids", [seq_len, 1], dtype="int64")
-        lbl = layers.data("labels", [seq_len, 1], dtype="int64")
-        lmask = layers.data("loss_mask", [seq_len, 1], dtype="float32")
-        h = kimi_linear_decoder(tok, cfg, is_test=is_test)
-        head = layers.create_parameter(
-            [cfg.vocab_size, cfg.hidden_size], "float32",
-            attr=_w(cfg, "kimi_lm_head"))
-        loss = layers.fused_mlm_head_loss(
-            layers.reshape(h, [-1, cfg.hidden_size]), head,
-            layers.reshape(lbl, [-1, 1]), cast_bf16=cfg.dtype == "bfloat16",
-            token_weight=masked_mean_weights(lmask))
-        if optimizer_fn is not None:
-            optimizer_fn(loss)
-    return main, startup, ["token_ids", "labels", "loss_mask"], {"loss": loss}
+    return moe_decoder.pretrain_program(cfg, seq_len, "kimi", mixer,
+                                        optimizer_fn=optimizer_fn,
+                                        is_test=is_test)

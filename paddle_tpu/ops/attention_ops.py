@@ -7,9 +7,11 @@ a Pallas flash-attention path (ops/pallas/) for long sequences that tiles the
 computation through VMEM without materializing the (T,T) scores in HBM.
 """
 import contextlib
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .registry import register_op, register_shape_rule
 from .shape_rules import TensorMeta, _x
@@ -160,3 +162,79 @@ def _rope_qk_norm_rule(op, ins, attrs):
             shape = (b, heads, t, d)
         out[slot + "Out"] = [TensorMeta(shape, m.dtype)]
     return out
+
+
+def rotate_part(x, nope_dim, rope_dim, theta):
+    """Rotary positions on the LAST `rope_dim` of every `nope_dim + rope_dim`
+    numbers of x (..., T, width), position t at row t; the first `nope_dim`
+    of each are handed through. Pair i of a part is its neighbouring numbers
+    (2i, 2i + 1) (interleaved; `rotate_half` pairs (i, i + D/2) over a whole
+    head); it turns by t * theta^(-2i/rope_dim):
+    (a, b) -> (a cos - b sin, a sin + b cos). No slice, concat or transpose:
+    out = x cos + (x P) sin, P the signed permutation that hands every number
+    its partner (-b to a's place, a to b's, nothing where nothing is turned),
+    block-diagonal over lane-aligned runs of the width, so the partner comes
+    off the MXU exactly (one non-zero product a sum) and the rest is one
+    elementwise pass with cos 1 and sin 0 over the part that is not turned.
+    x in any float dtype; float32 out, so a bfloat16 caller rounds the
+    forward once. The `jax.vjp` pullback of a bfloat16 x rounds its two
+    terms (dy cos, and dy sin through the permutation) to bfloat16 apart
+    and then their sum: three roundings where the rotation back of dy in
+    float32 would make one. On the chip, at the Kimi-VL cell's size, that
+    moves no reading of the first gradient (PERF.md section 6, PR 40)."""
+    if rope_dim < 2 or rope_dim % 2:
+        raise ValueError("partial_rope: rotary positions pair numbers: "
+                         "rope_dim %d" % rope_dim)
+    t, width = x.shape[-2], x.shape[-1]
+    d = nope_dim + rope_dim
+    if width % d:
+        raise ValueError("partial_rope: width %d is no multiple of "
+                         "nope_dim + rope_dim = %d" % (width, d))
+    # a run of whole heads that is a whole number of 128-lane tiles, so that
+    # splitting the width into runs moves nothing
+    run = d * 128 // math.gcd(d, 128)
+    run = run if width % run == 0 else d
+    lanes = np.arange(run)
+    j = lanes % d - nope_dim                    # place inside the part
+    second, turned = j % 2 == 1, j >= 0
+    inv = np.where(turned, float(theta) ** (-2.0 * (j // 2) / rope_dim), 0.0)
+    partner = lanes + np.where(second, -1, 1)
+    swap = np.zeros((run, run), np.float32)
+    swap[partner[turned], lanes[turned]] = np.where(second, 1.0,
+                                                    -1.0)[turned]
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv, jnp.float32)[None, :]
+    runs = x.reshape(x.shape[:-1] + (width // run, run))
+    partners = jax.lax.dot_general(
+        runs, jnp.asarray(swap, x.dtype), (((runs.ndim - 1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST
+        if x.dtype == jnp.float32 else None,
+        preferred_element_type=jnp.float32)
+    out = runs.astype(jnp.float32) * jnp.cos(angle)[:, None, :] \
+        + partners * jnp.sin(angle)[:, None, :]
+    return out.reshape(x.shape)
+
+
+@register_op("partial_rope")
+def _partial_rope(ctx, ins, attrs):
+    """Latent attention's decoupled rotary part, between the projections and
+    the key's concat: Q (B, T, H * (nope + rope)) has the last `rope_dim`
+    numbers of each head turned, KPe (B, T, rope_dim), the one key part all
+    heads share, is turned whole (once, before it is broadcast). Same
+    shapes and dtypes out, the forward computed in float32 and rounded once
+    (`rotate_part`, which says what the pullback rounds); positions are an
+    iota over T, nothing is fed from the host."""
+    nope, rope = int(attrs["nope_dim"]), int(attrs["rope_dim"])
+    theta = float(attrs["theta"])
+
+    def one(x, nope_dim):
+        return rotate_part(x, nope_dim, rope, theta).astype(x.dtype)
+
+    return {"QOut": one(ins["Q"][0], nope), "KPeOut": one(ins["KPe"][0], 0)}
+
+
+@register_shape_rule("partial_rope")
+def _partial_rope_rule(op, ins, attrs):
+    return {slot + "Out": [TensorMeta(_x(ins, slot).shape,
+                                      _x(ins, slot).dtype)]
+            for slot in ("Q", "KPe")}
