@@ -17,6 +17,19 @@ whatever the buffer holds (the callers keep that finite), dW masks them out.
   moe_gmm_dx    dx[r]  = dy[r] @ w[group(r)]^T         grid (K/tn, tiles, N/tk)
   moe_gmm_dw    dw[g]  = sum_{r in g} x[r]^T dy[r]     grid (K/tk, N/tn, tiles)
 
+The tile widths decide how often an operand comes out of HBM again, for a
+block is fetched whenever its index moves between two grid steps. Forward
+and dX read their row operand once an output tile (N/tn, K/tn times) and
+the matrices once where the contraction is one block (the block stands
+still while consecutive tiles share a group), else once a row tile; dW
+reads x once an N tile and dy once a K tile. `plan` counts those bytes
+(`hbm_bytes`) for every pair of 128-multiple divisors of the two widths,
+the widths themselves included, and takes the pair that moves the fewest
+among those whose blocks fit half the VMEM limit (`vmem_bytes`): at an
+expert width of 1408 = 11 x 128, whose only other such divisor is 128, that
+is the whole width (PERF.md section 6, PR 41). With obs on a lowering
+records what it picked as `moe_gmm.plan`.
+
 Operands go to the MXU in their own dtype, sums are float32. Off the TPU, and
 where a width is no multiple of 128 or `tm` no multiple of 16, the same entry
 takes the plain XLA form, `jax.lax.ragged_dot` over the same layout
@@ -38,6 +51,9 @@ from .interpret import default_interpret
 
 _LANES = 128
 _VMEM_LIMIT = 64 * 2 ** 20
+#: what `plan` lets a kernel's blocks and accumulator take of it; the rest
+#: is the compiler's (dW's row mask, the product before it is added)
+_VMEM_BUDGET = _VMEM_LIMIT // 2
 
 
 def row_tile(pairs):
@@ -75,28 +91,118 @@ def layout(group_sizes, rows, tm):
             "tiles": upto[-1].astype(jnp.int32)}
 
 
-def _divisor(dim, cap):
-    """Largest multiple of 128 that divides `dim` and is at most `cap`."""
-    best = None
-    for t in range(_LANES, min(dim, cap) + 1, _LANES):
-        if dim % t == 0:
-            best = t
-    return best
-
-
 #: one call's tiles: rows a tile, then (output tile, contraction block) of
 #: the forward and of dX, and dW's (K tile, N tile)
 Tiles = collections.namedtuple("Tiles", "tm fwd dx dw")
 
+KERNELS = ("fwd", "dx", "dw")
 
-def plan(rows, k, n, tm):
+
+def _divisors(dim):
+    """Every multiple of 128 that divides `dim`, `dim` itself included."""
+    return [t for t in range(_LANES, dim + 1, _LANES) if dim % t == 0]
+
+
+def tiled_widths(kernel, k, n):
+    """The two tiled widths of a kernel in the order of its pair of tiles:
+    (output width, contraction width) of the forward and of dX, (K, N) of
+    dW."""
+    return {"fwd": (n, k), "dx": (k, n), "dw": (k, n)}[kernel]
+
+
+def grid(kernel, rows, k, n, tm, tiles):
+    """The kernel's grid over a buffer of `rows` rows (its row axis is as
+    long as the tiles in use, `rows / tm` at most)."""
+    a, b = tiled_widths(kernel, k, n)
+    ta, tb = tiles
+    return (a // ta, b // tb, rows // tm) if kernel == "dw" \
+        else (a // ta, rows // tm, b // tb)
+
+
+def hbm_bytes(kernel, rows, k, n, tm, tiles, groups, itemsize):
+    """Bytes the kernel moves between HBM and VMEM over `rows` rows at
+    these tiles (the header's re-read rules): a block is fetched again
+    whenever its index moves from one grid step to the next, and an output
+    block is written once."""
+    a, b = tiled_widths(kernel, k, n)
+    ta, tb = tiles
+    if kernel == "dw":      # x once an N tile, dy once a K tile, dw written
+        return (rows * k * (n // tb) + rows * n * (k // ta)
+                + groups * k * n) * itemsize
+    # the row operand once an output tile; the matrices once where the
+    # contraction is one block (their block stands still inside a group),
+    # else once a row tile; the rows of the result written
+    matrices = groups if tb == b else rows // tm
+    return (rows * b * (a // ta) + matrices * k * n + rows * a) * itemsize
+
+
+def least_bytes(rows, k, n, groups, itemsize):
+    """What any of the three has to move: each row and each matrix once."""
+    return (rows * k + rows * n + groups * k * n) * itemsize
+
+
+def vmem_bytes(kernel, tm, tiles, itemsize):
+    """VMEM the kernel's pipeline holds: two buffers of each operand block
+    and of the output block (the three blocks are the three faces of
+    tm x ta x tb in every kernel), and the float32 accumulator, which has
+    the output block's shape."""
+    ta, tb = tiles
+    acc = ta * tb if kernel == "dw" else tm * ta
+    return 2 * (tm * ta + tm * tb + ta * tb) * itemsize + 4 * acc
+
+
+def _pick(kernel, rows, k, n, tm, itemsize):
+    """The pair of tiles that moves the fewest bytes within the VMEM
+    budget; among equals the fewest grid steps."""
+    a, b = tiled_widths(kernel, k, n)
+    best = None
+    for ta in _divisors(a):
+        for tb in _divisors(b):
+            if vmem_bytes(kernel, tm, (ta, tb), itemsize) > _VMEM_BUDGET:
+                continue
+            # the groups are not plan's to know: one, the least there is
+            key = (hbm_bytes(kernel, rows, k, n, tm, (ta, tb), 1, itemsize),
+                   (a // ta) * (b // tb))
+            if best is None or key < best[0]:
+                best = (key, (ta, tb))
+    return best and best[1]
+
+
+def plan(rows, k, n, tm, itemsize=2):
     """The three kernels' tiles for one call, or None where the call takes
-    the XLA form."""
+    the XLA form. Decided from the shapes alone: a tile is any multiple of
+    128 that divides its width, the width itself included; of each kernel's
+    pairs that fit `_VMEM_BUDGET` (`vmem_bytes`) the one that moves the
+    fewest HBM bytes (`hbm_bytes`) over the buffer's rows wins. `tm` is the
+    caller's (`row_tile`): the buffer and the layout hang on it."""
     if k % _LANES or n % _LANES or tm % 16 or rows % tm:
         return None
-    return Tiles(tm, (_divisor(n, 512), _divisor(k, 2048)),
-                 (_divisor(k, 512), _divisor(n, 2048)),
-                 (_divisor(k, 1024), _divisor(n, 512)))
+    picked = [_pick(kernel, rows, k, n, tm, itemsize) for kernel in KERNELS]
+    if None in picked:
+        return None
+    return Tiles(tm, *picked)
+
+
+def describe(what, rows, k, n, groups, itemsize):
+    """`moe_gmm.plan`'s labels (ints, floats and strings): the call, and
+    for each kernel its tiles, its grid over the buffer, the bytes it moves
+    by `hbm_bytes`, their ratio to the least there is (`reread`) and its
+    VMEM."""
+    least = least_bytes(rows, k, n, groups, itemsize)
+    out = {"rows": rows, "k": k, "n": n, "groups": groups, "tm": what.tm,
+           "itemsize": itemsize, "least_bytes": least}
+    for kernel in KERNELS:
+        tiles = getattr(what, kernel)
+        moved = hbm_bytes(kernel, rows, k, n, what.tm, tiles, groups,
+                          itemsize)
+        out.update({
+            kernel + "_tiles": "%dx%d" % tiles,
+            kernel + "_grid": "%dx%dx%d" % grid(kernel, rows, k, n,
+                                                what.tm, tiles),
+            kernel + "_bytes": moved,
+            kernel + "_reread": round(moved / least, 3),
+            kernel + "_vmem": vmem_bytes(kernel, what.tm, tiles, itemsize)})
+    return out
 
 
 def _params(semantics):
@@ -254,12 +360,18 @@ def grouped_matmul(x, w, group_sizes, tm, interpret=None):
         interpret = default_interpret()
         if interpret:
             return grouped_matmul_xla(x, w, group_sizes, tm)
-    what = plan(x.shape[0], x.shape[1], w.shape[2], tm)
+    what = plan(x.shape[0], x.shape[1], w.shape[2], tm, x.dtype.itemsize)
     if what is None and not interpret:
         return grouped_matmul_xla(x, w, group_sizes, tm)
     if what is None:        # interpret mode takes any tile that divides
         k, n = x.shape[1], w.shape[2]
         what = Tiles(tm, (n, k), (k, n), (k, n))
+    from ...framework import obs
+    if obs.enabled():
+        now = obs.now()
+        obs.record("moe_gmm.plan", now, now, **describe(
+            what, x.shape[0], x.shape[1], w.shape[2], w.shape[0],
+            x.dtype.itemsize))
     lay = layout(group_sizes, x.shape[0], tm)
     return _grouped(x, w.astype(x.dtype), lay["tile_group"],
                     lay["tile_end"], lay["tiles"], what, bool(interpret))

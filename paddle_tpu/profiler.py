@@ -63,7 +63,8 @@ def stop_profiler(sorted_key=None, profile_path=None):
     return rows
 
 
-PLAN_RECORDS = ("flash.plan", "ssm.plan", "head.plan", "kda.plan")
+PLAN_RECORDS = ("flash.plan", "ssm.plan", "head.plan", "kda.plan",
+                "moe_gmm.plan")
 
 
 def print_kernel_plans():
@@ -72,8 +73,10 @@ def print_kernel_plans():
     by causality and by the window, group size, widths, the fused or the
     split backward), `ssm.plan` (chunk length, chunks, VMEM asked),
     `head.plan` (the LM head: rows, vocab, block rows and blocks, weighted
-    or per-token form, operand dtype) and `kda.plan` (the delta rule:
-    chunk, sub-block, chunks a group, heads, which kernels)."""
+    or per-token form, operand dtype), `kda.plan` (the delta rule: chunk,
+    sub-block, chunks a group, heads, which kernels) and `moe_gmm.plan`
+    (a grouped matmul: each kernel's tiles, grid, modelled HBM bytes and
+    their ratio to the least, VMEM)."""
     for name in PLAN_RECORDS:
         for plan in obs.spans(name=name):
             print("%s %s" % (plan["name"], " ".join(
