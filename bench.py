@@ -444,20 +444,22 @@ def _timed_attn_tokens(loss_fn, q, k, v, b, t, steps):
 
 
 def flash_kernel_ms(b, h, t, d, blocks, causal=True, key_mask=False,
-                    dtype="bfloat16", interpret=False, budget_s=0.25):
+                    dtype="bfloat16", interpret=False, budget_s=0.25,
+                    dv=None):
     """Milliseconds a call of each of the four flash kernels (forward,
     dK/dV, dQ, and "bwd": the fused backward that stands for the last two
     where `flash_attention.backward_rule` says so) takes at `blocks` =
     (block_q, block_k), each kernel timed on its own: warm (the compile),
     then enough back-to-back calls to fill `budget_s` behind one
-    `block_until_ready`. A kernel the compiler refuses reads
-    "failed: ..."."""
+    `block_until_ready`. `dv` is the value width where it is not `d`. A
+    kernel the compiler refuses reads "failed: ..."."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas import flash_attention as fa
     rng = np.random.RandomState(0)
-    q, k, v, g = (jnp.asarray(rng.randn(b, h, t, d), dtype)
-                  for _ in range(4))
+    dv = dv or d
+    q, k, v, g = (jnp.asarray(rng.randn(b, h, t, width), dtype)
+                  for width in (d, d, dv, dv))
     mask = None
     if key_mask:
         pad = np.zeros((b, 1, 1, t), np.float32)
@@ -500,8 +502,9 @@ def flash_kernel_ms(b, h, t, d, blocks, causal=True, key_mask=False,
 def bench_flashtune():
     """Flash-attention tile sweep: ms a call of each kernel (forward,
     dK/dV, dQ, the fused backward) per (block_q, block_k), at the
-    attention shapes of the benchmark's GPT cells and of BERT's phase 2
-    (key-padding mask), bf16. "rule" is the tile
+    attention shapes of the benchmark's GPT cells, of BERT's phase 2
+    (key-padding mask) and of the two Kimi cells' latent attention (D 192,
+    Dv 128), bf16. "rule" is the tile
     `flash_attention.pick_blocks` gives each kernel at that shape, and
     "backward" what `backward_rule` gives the call — the code applies both
     by itself; a sweep that disagrees with the rule is a reason to change
@@ -510,23 +513,25 @@ def bench_flashtune():
 
     on_tpu = _on_tpu()
     if on_tpu:
-        shapes = [(4, 12, 4096, 64, True, False),
-                  (16, 12, 1024, 64, True, False),
-                  (32, 12, 512, 64, False, True)]
+        shapes = [(4, 12, 4096, 64, None, True, False),
+                  (16, 12, 1024, 64, None, True, False),
+                  (32, 12, 512, 64, None, False, True),
+                  (2, 16, 8192, 192, 128, True, False)]
         sizes = (256, 512, 1024)
     else:
-        shapes = [(1, 2, 256, 32, True, False)]
+        shapes = [(1, 2, 256, 32, None, True, False),
+                  (1, 2, 256, 48, 32, True, False)]
         sizes = (128, 256)
     results = {}
-    for b, h, t, d, causal, key_mask in shapes:
+    for b, h, t, d, dv, causal, key_mask in shapes:
         tiles = [(128, 128)] + [(bq, bk) for bq in sizes for bk in sizes
                                 if bq <= t and bk <= t]
         table = {"%dx%d" % tile: flash_kernel_ms(
-            b, h, t, d, tile, causal, key_mask, interpret=not on_tpu)
+            b, h, t, d, tile, causal, key_mask, interpret=not on_tpu, dv=dv)
             for tile in dict.fromkeys(tiles)}
         kernels = fa.KERNELS + fa.FUSED_KERNELS[1:]
         rule = {kern: "%dx%d" % fa.pick_blocks(t, t, d, "bfloat16", kern,
-                                                causal)
+                                                causal, dv=dv)
                 for kern in kernels}
         best = {}
         for kern in kernels:
@@ -534,10 +539,11 @@ def bench_flashtune():
                      if isinstance(row.get(kern), float)}
             best[kern] = min(timed, key=timed.get) if timed else None
         shape = (b, h, t, d)
-        results["%dx%dx%dx%d%s" % (b, h, t, d, "" if causal else "-kmask")] = {
+        results["%dx%dx%dx%d%s%s" % (b, h, t, d, "|%d" % dv if dv else "",
+                                     "" if causal else "-kmask")] = {
             "ms": table, "best": best, "rule": rule,
-            "backward": fa.backward_rule(shape, shape, shape, "bfloat16",
-                                         causal, None)}
+            "backward": fa.backward_rule(shape, shape, (b, h, t, dv or d),
+                                         "bfloat16", causal, None)}
     # headline: the kernels a call at the first shape runs, at the rule's
     # tiles
     first = next(iter(results.values()))
@@ -686,10 +692,11 @@ def pallas_selfcheck(interpret=None):
     the long-context shape (2, 12, 4096, 64) bf16, and with grouped heads,
     a value width of twice the q/k width and a sliding window (T=512, and
     T=4096 with a 512 window); the fused backward kernel against the
-    dK/dV + dQ pair it stands for, each at its rule's tile (T=256, and the
-    GPT cells' (4, 12, 4096, 64) and (16, 12, 1024, 64) bf16: the calls
-    above without grouped heads or a window already take the fused one
-    against XLA); the selective-scan forward and backward
+    dK/dV + dQ pair it stands for, each at its rule's tile (T=256, also
+    at D 192 / Dv 128 in f32, and the GPT cells' (4, 12, 4096, 64) and
+    (16, 12, 1024, 64) and the Kimi cells' (2, 16, 8192, 192 | 128) bf16:
+    the calls above without grouped heads or a window already take the
+    fused one against XLA); the selective-scan forward and backward
     kernels (T=320: not a multiple of the chunk), f32 and bf16; each fwd+bwd
     against its pure-JAX reference. Every check runs; one the compiler
     refuses (or that raises) is recorded with its message and fails the
@@ -785,13 +792,14 @@ def pallas_selfcheck(interpret=None):
                                             "causal", hkv=2, dv=128,
                                             window=128))
 
-    def fused_case(dtype, tol, b, h, t, d):
-        q, k, v = (jnp.asarray(rng.randn(b, h, t, d), dtype)
-                   for _ in range(3))
-        w = jnp.asarray(rng.randn(b, h, t, d).astype(np.float32))
+    def fused_case(dtype, tol, b, h, t, d, dv=None):
+        dv = dv or d
+        q, k, v = (jnp.asarray(rng.randn(b, h, t, width), dtype)
+                   for width in (d, d, dv))
+        w = jnp.asarray(rng.randn(b, h, t, dv).astype(np.float32))
 
         def grads(kernels):
-            blocks = tuple(fa.pick_blocks(t, t, d, dtype, kern, True)
+            blocks = tuple(fa.pick_blocks(t, t, d, dtype, kern, True, dv=dv)
                            for kern in kernels)
             return jax.jit(jax.grad(
                 lambda q, k, v: jnp.sum(fa._flash(
@@ -807,6 +815,9 @@ def pallas_selfcheck(interpret=None):
     for dtype, tol in ((jnp.float32, 1e-5), (jnp.bfloat16, 1e-2)):
         run("flash_%s_T256_fused_vs_split" % np.dtype(dtype).name,
             fused_case(dtype, tol, 2, 4, 256, 64))
+    # unequal widths (latent attention's decompressed heads) are fused too
+    run("flash_float32_T256_d192_dv128_fused_vs_split",
+        fused_case(jnp.float32, 1e-5, 2, 4, 256, 192, dv=128))
     if not interpret:   # the interpreter needs minutes at these sizes
         run("flash_bfloat16_T4096_causal",
             flash_case(jnp.bfloat16, 1e-2, 2, 12, 4096, 64, "causal"))
@@ -816,6 +827,9 @@ def pallas_selfcheck(interpret=None):
         for b, t in ((4, 4096), (16, 1024)):    # the GPT cells' calls
             run("flash_bfloat16_%dx12x%dx64_fused_vs_split" % (b, t),
                 fused_case(jnp.bfloat16, 1e-2, b, 12, t, 64))
+        # the two Kimi cells' latent-attention call
+        run("flash_bfloat16_2x16x8192x192_dv128_fused_vs_split",
+            fused_case(jnp.bfloat16, 1e-2, 2, 16, 8192, 192, dv=128))
 
     def scan_case(dtype, tol, b, t, e, n):
         from paddle_tpu.ops.pallas import selective_scan as ss

@@ -123,7 +123,7 @@ def mla_attention(x, num_heads, qk_nope_dim, qk_rope_dim, v_dim, kv_rank,
         the rope numbers are plain features;
       k = [k_nope | k_pe]; softmax(q k^T (nope + rope)^-1/2) v, causal,
       through `fused_attention` (D = nope + rope, Dv = v_dim: the flash
-      kernels' split backward); out = concat W_o.
+      kernels' fused backward); out = concat W_o.
     The latent is decompressed before the kernel and nothing is cached:
     the training form."""
     helper = LayerHelper("mla_attention", name=name)

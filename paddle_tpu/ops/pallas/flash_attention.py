@@ -20,16 +20,17 @@ Backward: the kernels recompute p = exp(s - m) / l per tile from the saved
 (out, m, l) residuals — flash-attention-2 style, no (T,T) matrix in HBM in
 either direction, with the additive mask applied in-kernel. Which of two
 forms a call gets is `backward_rule`'s answer, from its shapes:
-  - fused (`flash_bwd`; one query head a key/value head, no window,
-    Dv == D, and dQ's row fits VMEM: GPT's plain causal call, BERT's
-    key-masked one): grid (B*H, Tk/BK, Tq/BQ), q-blocks innermost. A tile's
-    s, p, dp and ds are formed once and feed all three gradients: dK/dV in
-    accumulators written when the k-block's last q-block is done, dQ in a
-    float32 scratch of the head's whole query length, written to HBM once
-    a head. Five matmuls a tile.
+  - fused (`flash_bwd`; one query head a key/value head, no window, any
+    two widths, and dQ's row fits VMEM: GPT's plain causal call, BERT's
+    key-masked one, latent attention's D 192 | Dv 128): grid (B*H, Tk/BK,
+    Tq/BQ), q-blocks innermost. A tile's s, p, dp and ds are formed once
+    and feed all three gradients: dK/dV in accumulators written when the
+    k-block's last q-block is done, dQ in a float32 scratch of the head's
+    whole query length, written to HBM once a head. Five matmuls a tile.
   - split (`flash_bwd_dkv`, grid as above, and `flash_bwd_dq`, grid as the
     forward's): each forms s, p, dp, ds for itself, seven matmuls a tile
-    between them. Grouped heads, windows and unequal widths run here.
+    between them. Grouped heads and windows run here, and a query so long
+    that dQ's row does not fit.
 Under a causal mask a grid step above the diagonal runs no body, and its
 index maps name the block the nearest working step holds, so it fetches
 nothing either.
@@ -59,10 +60,11 @@ The supported (heads, window, widths) space:
     output and dO are Dv wide). D % 8 or Dv % 8 != 0 goes to XLA. D need
     be no multiple of the 128 lanes: a block spans the whole head width,
     and D = 192 with Dv = 128 (latent attention's decompressed heads)
-    compiles as it is, on the split backward ("split: widths").
+    compiles as it is, on the fused backward (192 pads to 256 lanes in
+    VMEM only: dQ's row is weighed at 256, dV's accumulator at 128).
 With n == 1, no window and Dv == D the forward kernel, its tile, index
-maps and VMEM request are the ones the plain causal call always had, and
-the backward is the fused kernel.
+maps and VMEM request are the ones the plain causal call always had; with
+n == 1 and no window the backward is the fused kernel whatever Dv is.
 """
 import functools
 from typing import NamedTuple, Optional
@@ -403,7 +405,10 @@ def _mask_input(mask, dtype):
 # once, beside the blocks and the accumulators: found by bisecting
 # `vmem_limit_bytes` on compiles for a v5e (bf16 D=64 and f32 D=128 with a
 # key mask, 512x512 and 1024x1024: at most 2.0 / 4.4 / 3.3, and 6.8 for
-# the fused backward, whose bf16 tiles need 1.9) and rounded up
+# the fused backward, whose bf16 tiles need 1.9) and rounded up. At D=192 /
+# Dv=128 the fused backward's 8 still bound it (bf16, Tq=8192: least limit
+# 30.3 MiB at 1024x1024 where 54.1 are reckoned, 21.6 of 27.1 at 512x512;
+# f32 with a key mask at Tq=2048, 512x512: 9.9 of 19.3)
 _TILE_TEMPS = {"fwd": 3, "bwd_dkv": 6, "bwd_dq": 5, "bwd": 8}
 _VMEM_DEFAULT = 16 * 2 ** 20    # Mosaic's scoped limit on a v5e
 _VMEM_CEILING = 96 * 2 ** 20    # of the chip's 128 MiB
@@ -738,9 +743,10 @@ def _pallas_bwd_dq(operands, h, mask_mode, scale, causal, block_q, block_k,
 
 def _pallas_bwd(operands, h, mask_mode, scale, causal, block_q, block_k,
                 interpret):
-    """The fused backward's call (one query head a kv head, no window,
-    equal widths: `backward_rule`). dQ's block is the head's whole row
-    under a constant index map, so it is written back once a head."""
+    """The fused backward's call (one query head a kv head, no window:
+    `backward_rule`). dQ's block is the head's whole row under a constant
+    index map, so it is written back once a head; dK's accumulator is as
+    wide as q and k, dV's as wide as v."""
     body, common, _, k_spec, v_spec = _bwd_call(
         _bwd_kernel, "bwd", operands, h, mask_mode, scale, causal, block_q,
         block_k, interpret, kj_innermost=False)
@@ -755,7 +761,7 @@ def _pallas_bwd(operands, h, mask_mode, scale, causal, block_q, block_k,
                    jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
         scratch_shapes=[pltpu.VMEM((tq // block_q, block_q, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, v3.shape[2]), jnp.float32)],
         name="flash_bwd",
         **common,
     )(*operands)
@@ -895,8 +901,11 @@ def pick_blocks(tq, tk, d, dtype, kernel, causal=False, window=None,
     saves. The fused backward ("bwd") takes the dK/dV kernel's tile: swept
     on a v5e at GPT's two shapes (PERF.md, PR 30; ms a call), 1024x1024
     4.71 at T=4096 (1024x512 4.78, 512x1024 4.80, 512x512 5.25) and
-    512x512 2.24 at T=1024 (1024x1024 2.32, 512x1024 2.34); the room its
-    dQ row takes is `backward_rule`'s to weigh, not this tile's.
+    512x512 2.24 at T=1024 (1024x1024 2.32, 512x1024 2.34), and at latent
+    attention's (2, 16, 8192, 192 | 128) (PERF.md, PR 43): 1024x1024 15.08
+    (1024x512 15.53, 512x1024 15.55, 512x512 15.91, 2048x1024 16.65: three
+    times the matmul a tile does not move the order); the room its dQ row
+    takes is `backward_rule`'s to weigh, not this tile's.
     Other dtypes run float32 operands at HIGHEST: twice the VMEM
     and six MXU passes a tile, so 512 is their cap (reckoned, not swept).
     Under a sliding `window` a tile row sees window + block_q keys whatever
@@ -987,20 +996,21 @@ def backward_rule(q_shape, k_shape, v_shape, dtype, causal, window):
       "group"   more than one query head a key/value head: dQ's row would
                 be group x Tq long, and dK/dV walks the group's heads;
       "window"  a sliding window: its grids walk another inner axis;
-      "widths"  Dv != D;
       "vmem"    the fused kernel at its tile, with dQ's whole row (Tq x D
-                in float32 and the output block), is reckoned over the
-                VMEM ceiling."""
+                in float32 and the output block, D padded to whole 128
+                lanes) and dV's accumulator at the value width, is
+                reckoned over the VMEM ceiling: bfloat16 at Tq = 65,536
+                for D = 64 or 128, at 32,768 for D = 192.
+    The value width alone decides nothing: Dv != D is fused like Dv == D
+    (until PR 43 it was a rule of its own, "widths")."""
     tq, tk, d, dv = q_shape[2], k_shape[2], q_shape[-1], v_shape[-1]
     if q_shape[1] != k_shape[1]:
         return "split: group"
     if window is not None:
         return "split: window"
-    if dv != d:
-        return "split: widths"
-    bq, bk = pick_blocks(tq, tk, d, dtype, "bwd", causal)
-    if vmem_bytes("bwd", bq, bk, d, jnp.dtype(dtype).itemsize, "qk",
-                  tq=tq) > _VMEM_CEILING:
+    bq, bk = pick_blocks(tq, tk, d, dtype, "bwd", causal, dv=dv)
+    if vmem_bytes("bwd", bq, bk, d, jnp.dtype(dtype).itemsize, "qk", dv,
+                  tq) > _VMEM_CEILING:
         return "split: vmem"
     return "fused"
 

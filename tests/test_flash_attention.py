@@ -196,37 +196,49 @@ def test_flash_qk_mask_backward_with_mask_cotangent():
 
 from paddle_tpu.ops.pallas import flash_attention as fa  # noqa: E402
 
-_RULE_SHAPES = [(t, t, d) for t in (128, 256, 384, 512, 1024, 4096, 8192)
-                for d in (64, 128)] + [(512, 1024, 64), (1024, 4096, 128)]
+_RULE_SHAPES = [(t, t, d, None) for t in (128, 256, 384, 512, 1024, 4096, 8192)
+                for d in (64, 128)] \
+    + [(512, 1024, 64, None), (1024, 4096, 128, None)] \
+    + [(t, t, d, dv) for d, dv in ((192, 128), (64, 128), (128, 64))
+       for t in (256, 1024, 8192, 24576)]     # Dv != D
 
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-@pytest.mark.parametrize("tq,tk,d", _RULE_SHAPES)
-def test_tile_rule_table(tq, tk, d, dtype, causal):
-    """The tile rule, for the benchmark cells' shapes among others: blocks
-    divide T, are >= 128 (the device path's floor), and the reckoned VMEM
+@pytest.mark.parametrize("tq,tk,d,dv", _RULE_SHAPES)
+def test_tile_rule_table(tq, tk, d, dv, dtype, causal):
+    """The tile rule, for the benchmark cells' shapes among others, with
+    the value width beside the q/k width where they differ: blocks divide
+    T, are >= 128 (the device path's floor), and the reckoned VMEM (dQ's
+    row at the q/k width's lanes, dV's accumulator at the value width's)
     stays under what the call asks Mosaic for (its default where it asks
     for nothing)."""
+    itemsize = jnp.dtype(dtype).itemsize
     for kernel in fa.KERNELS + fa.FUSED_KERNELS[1:]:
-        bq, bk = fa.pick_blocks(tq, tk, d, dtype, kernel, causal)
+        bq, bk = fa.pick_blocks(tq, tk, d, dtype, kernel, causal, dv=dv)
         assert tq % bq == 0 and tk % bk == 0
         assert bq >= 128 and bk >= 128
         assert bq % 128 == 0 or bq == tq
-        itemsize = jnp.dtype(dtype).itemsize
         # the fused backward also holds dQ's whole row: Tq is part of it
         row = {"tq": tq} if kernel == "bwd" else {}
         for mask_mode in ("none", "k", "qk"):
             need = fa.vmem_bytes(kernel, bq, bk, d, itemsize, mask_mode,
-                                 **row)
+                                 dv, **row)
+            if kernel == "bwd" and need > fa._VMEM_CEILING:
+                # `backward_rule` keeps such a call on the split pair
+                q_shape = (1, 1, tq, d)
+                assert fa.backward_rule(
+                    q_shape, (1, 1, tk, d), (1, 1, tk, dv or d), dtype,
+                    causal, None) == "split: vmem"
+                continue
             params = fa._compiler_params(kernel, bq, bk, d, dtype, mask_mode,
-                                         **row)
+                                         dv, **row)
             limit = (params and params.vmem_limit_bytes) \
                 or fa._VMEM_DEFAULT
             assert need <= limit <= fa._VMEM_CEILING
-        if kernel == "bwd":     # and it runs its inner axes in order
-            assert tuple(params.dimension_semantics) \
-                == ("parallel", "arbitrary", "arbitrary")
+            if kernel == "bwd":     # and it runs its inner axes in order
+                assert tuple(params.dimension_semantics) \
+                    == ("parallel", "arbitrary", "arbitrary")
 
 
 def test_tile_rule_follows_the_shape(monkeypatch):
@@ -264,13 +276,50 @@ def test_tile_rule_follows_the_shape(monkeypatch):
     assert explicit == ((64, 32),) * 2
     assert one_side == tuple((bq, 128) for bq, _ in rule)
     del seen[:]
-    k = jnp.zeros((1, 1, 1024, 128), jnp.bfloat16)     # Dv != D: split
+    k = jnp.zeros((1, 1, 1024, 128), jnp.bfloat16)     # Dv != D: fused too
     flash_attention(q, q, k, causal=True, interpret=True)
     flash_attention(q, q, k, causal=True, block_q=64, block_k=32,
                     interpret=True)
     assert seen == [tuple(fa.pick_blocks(1024, 1024, 64, q.dtype, kern,
                                          True, None, 128)
-                          for kern in fa.KERNELS), ((64, 32),) * 3]
+                          for kern in fa.FUSED_KERNELS), ((64, 32),) * 2]
+    del seen[:]
+    q2 = jnp.zeros((1, 2, 1024, 64), jnp.bfloat16)     # and grouped: split
+    flash_attention(q2, q, k, causal=True, interpret=True)
+    assert seen == [tuple(fa.pick_blocks(1024, 1024, 64, q.dtype, kern,
+                                         True, None, 128)
+                          for kern in fa.KERNELS)]
+
+
+def test_the_fused_backwards_vmem_counts_each_width_at_its_own_lanes():
+    """`vmem_bytes("bwd", ...)` at the Kimi cells' call, by its parts: the
+    blocks twice (q and dO, k and dK, v and dV, dQ's whole row, the two
+    statistics rows, the mask tile), the float32 accumulators (dK at 256
+    lanes, dV at 128, dQ's row at 256) and eight score tiles; 192 pads to
+    256 lanes, the values stay at 128."""
+    mib = 2.0 ** 20
+    bq = bk = 1024
+    tq = 8192
+    blocks = ((bq * 256 + bq * 128 + 2 * bk * 256 + 2 * bk * 128
+               + tq * 256) * 2 + 2 * 8 * bq * 4 + bq * bk * 2)
+    scratch = (bk * 256 + bk * 128 + tq * 256) * 4
+    want = 2 * blocks + scratch + 8 * bq * bk * 4
+    got = fa.vmem_bytes("bwd", bq, bk, 192, 2, "qk", dv=128, tq=tq)
+    assert got == want and round(got / mib, 1) == 58.1
+    assert got < fa._VMEM_CEILING
+    # no `dv` means the q/k width; a narrower value width needs less
+    assert fa.vmem_bytes("bwd", bq, bk, 192, 2, "qk", tq=tq) \
+        == fa.vmem_bytes("bwd", bq, bk, 192, 2, "qk", 192, tq) \
+        == fa.vmem_bytes("bwd", bq, bk, 192, 2, "qk", 256, tq) > got
+    # Tq enters through dQ's row alone: 256 lanes x (2 x 2 + 4) bytes
+    assert fa.vmem_bytes("bwd", bq, bk, 192, 2, "qk", 128, 2 * tq) - got \
+        == tq * 256 * 8
+    # and the rule weighs that row: the same call at four times the length
+    shape = (2, 16, 4 * tq)
+    assert fa.backward_rule(shape + (192,), shape + (192,), shape + (128,),
+                            "bfloat16", True, None) == "split: vmem"
+    assert fa.backward_rule(shape + (128,), shape + (128,), shape + (128,),
+                            "bfloat16", True, None) == "fused"
 
 
 def _loss_grads(fn, args, w):
@@ -356,16 +405,18 @@ def test_bf16_operands_against_f32_oracle(mode):
         assert np.abs(a - o).max() / max(np.abs(o).max(), 1.0) < 1e-2
 
 
-def _kernel_dots(dtype, kv_heads):
+def _kernel_dots(dtype, kv_heads, dv=8):
     """(lhs dtype, rhs dtype, result dtype, precision) of every
     dot_general that a call's kernels trace for inputs of `dtype`: with
-    two query heads to `kv_heads` key/value heads."""
+    two query heads to `kv_heads` key/value heads, q and k 8 wide and v
+    `dv` wide."""
     q = jnp.zeros((1, 2, 32, 8), dtype)
     k = jnp.zeros((1, kv_heads, 32, 8), dtype)
+    v = jnp.zeros((1, kv_heads, 32, dv), dtype)
     closed = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
         flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
                         interpret=True).astype(jnp.float32)),
-        argnums=(0, 1, 2)))(q, k, k)
+        argnums=(0, 1, 2)))(q, k, v)
     dots = []
 
     def walk(jaxpr):
@@ -380,17 +431,21 @@ def _kernel_dots(dtype, kv_heads):
     return dots
 
 
-@pytest.mark.parametrize("backward,count", [
-    ("fused", 2 + 5),           # forward; s and dp once, then dV, dK, dQ
-    ("split", 2 + 4 + 3)])      # forward, dK/dV, dQ: s and dp in both
+@pytest.mark.parametrize("backward,kv_heads,dv,count", [
+    ("fused", 2, 8, 2 + 5),     # forward; s and dp once, then dV, dK, dQ
+    ("fused_dv_16", 2, 16, 2 + 5),      # unequal widths: the same five
+    ("fused_dv_24", 2, 24, 2 + 5),
+    ("split", 1, 8, 2 + 4 + 3),     # forward, dK/dV, dQ: s and dp in both
+    ("split_dv_16", 1, 16, 2 + 4 + 3)])
 @pytest.mark.parametrize("dtype", ["float32", "float16", "bfloat16"])
-def test_mxu_operand_dtype_and_precision(dtype, backward, count):
+def test_mxu_operand_dtype_and_precision(dtype, backward, kv_heads, dv,
+                                         count):
     """float32/float16 inputs keep float32 operands at HIGHEST — the same
     operations, in the same order, as before this rule existed, so the same
     bits at equal tiles; bfloat16 inputs reach every dot as bfloat16 with
     a float32 result. The fused backward runs the mathematics' five
-    matmuls, the split kernels seven."""
-    dots = _kernel_dots(dtype, 2 if backward == "fused" else 1)
+    matmuls, the split kernels seven, whatever the value width."""
+    dots = _kernel_dots(dtype, kv_heads, dv)
     assert len(dots) == count
     highest = jax.lax.Precision.HIGHEST
     for lhs, rhs, out, precision in dots:

@@ -222,16 +222,19 @@ def _flash_grads(blocks, q, k, v, w, mask, causal):
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("mask_mode", ["none", "key", "qk"])
-@pytest.mark.parametrize("tq,tk,tile", [(64, 64, (16, 16)),
-                                        (32, 64, (8, 16)),
-                                        (32, 64, (16, 8))])
+@pytest.mark.parametrize("tq,tk,tile,d,dv", [
+    (64, 64, (16, 16), 16, 16), (32, 64, (8, 16), 16, 16),
+    (32, 64, (16, 8), 16, 16),
+    # Dv != D: a small pair, and latent attention's decompressed heads
+    (64, 64, (16, 16), 24, 16), (32, 64, (8, 16), 24, 16),
+    (64, 64, (16, 16), 192, 128), (32, 64, (8, 16), 192, 128)])
 def test_fused_backward_equals_the_split_kernels_to_the_bit(
-        dtype, causal, mask_mode, tq, tk, tile):
+        dtype, causal, mask_mode, tq, tk, tile, d, dv):
     """Equal tiles: both run `_bwd_p_ds` and then the same dots, dK/dV
     summed over ascending q-blocks and dQ over ascending k-blocks in both,
-    so every gradient is bit-equal; and within float32 rounding (bfloat16:
-    its 1e-2) of the float32 XLA oracle."""
-    q, k, v, w = _inputs(2, 2, 2, tq, tk, 16, 16, jnp.dtype(dtype), seed=11)
+    so every gradient is bit-equal, whatever the two widths; and within
+    float32 rounding (bfloat16: its 1e-2) of the float32 XLA oracle."""
+    q, k, v, w = _inputs(2, 2, 2, tq, tk, d, dv, jnp.dtype(dtype), seed=11)
     mask = _mask(mask_mode, 2, tq, tk, jnp.dtype(dtype))
     fused = _flash_grads((tile, tile), q, k, v, w, mask, causal)
     split = _flash_grads((tile,) * 3, q, k, v, w, mask, causal)
@@ -257,19 +260,24 @@ def test_fused_backward_with_unequal_backward_tiles_matches_the_split():
     assert _worst(fused, split) < 1e-6
 
 
-def test_the_fused_kernel_is_what_a_plain_call_differentiates_through():
-    q, k, v, _w = _inputs(1, 2, 2, 64, 64, 16, 16)
+@pytest.mark.parametrize("hq,hkv,d,dv,window,fused", [
+    (2, 2, 16, 16, None, True),
+    (2, 2, 24, 16, None, True),         # Dv != D, either way round
+    (2, 2, 16, 32, None, True),
+    (4, 2, 16, 16, None, False),        # grouped heads
+    (4, 2, 24, 16, None, False),
+    (2, 2, 24, 16, 32, False)])         # a window
+def test_which_backward_a_call_differentiates_through(hq, hkv, d, dv,
+                                                      window, fused):
+    q, k, v, _w = _inputs(1, hq, hkv, 64, 64, d, dv)
     text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
         fa.flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
-                           interpret=True)), (0, 1, 2)))(q, k, v))
-    assert text.count("pallas_call[") == 2
-    assert "name=flash_bwd\n" in text and "flash_bwd_dkv" not in text
-    q, k, v, _w = _inputs(1, 4, 2, 64, 64, 16, 16)      # grouped heads
-    text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
-        fa.flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
-                           interpret=True)), (0, 1, 2)))(q, k, v))
-    assert text.count("pallas_call[") == 3
-    assert "name=flash_bwd\n" not in text and "flash_bwd_dkv" in text
+                           window=window, interpret=True)),
+        (0, 1, 2)))(q, k, v))
+    assert text.count("pallas_call[") == (2 if fused else 3)
+    assert ("name=flash_bwd\n" in text) == fused
+    assert ("flash_bwd_dkv" in text) == (not fused)
+    assert ("flash_bwd_dq" in text) == (not fused)
 
 
 # ---------------------------------------------------------------------------
@@ -280,6 +288,8 @@ def test_the_fused_kernel_is_what_a_plain_call_differentiates_through():
 # in PR 30, when one `flash_bwd` took the place of `flash_bwd_dkv` +
 # `flash_bwd_dq` (3 pallas_calls -> 2; PERF.md, PR 30). Phi's calls keep
 # the two kernels ("split: group"), so their digests are the parent's.
+# The Kimi cells' call (D 192 | Dv 128) was recorded in PR 43, when the
+# fused kernel took unequal widths; its forward is as PR 41 traced it.
 # After a deliberate change to one of these paths, print the new digests
 # with `python tests/test_flash_modes.py` and say in PERF.md why.
 # ---------------------------------------------------------------------------
@@ -308,6 +318,15 @@ PHI_CALLS = {   # by window: the window layer; the full and cross layers
                      "501a0e3", "chars": 51164,
            "blocks": [(1024, 1024)] * 3},
 }
+
+
+KIMI_SHAPES = ((2, 16, 8192, 192), (2, 16, 8192, 192), (2, 16, 8192, 128))
+KIMI_CALL = {
+    "sha256": "2c2688081f6e954ae74038e036c2d6b5f609b0f29e8d663fbf5537518e34f"
+              "11c", "chars": 36746,
+    "fwd_sha256": "888f361976603edf0ca93588f4d7de459e2921a5760378067b9cff079"
+                  "a9fb275", "fwd_chars": 12932,
+    "blocks": [(1024, 1024), (1024, 1024)]}
 
 
 def lowered_text(q_shape, k_shape=None, v_shape=None, window=None,
@@ -358,6 +377,23 @@ def test_gpt2_attention_calls_lower_as_recorded(shape):
         == (want["fwd_chars"], want["fwd_sha256"])
 
 
+def test_the_latent_attention_call_lowers_to_the_fused_backward():
+    """The two Kimi cells' call, D 192 | Dv 128: one `flash_bwd` where the
+    parent commit (PR 41) ran the split pair for its widths alone (3
+    pallas_calls, 45,714 characters there); the forward is the parent's."""
+    want = KIMI_CALL
+    got = fa.attention_path(*KIMI_SHAPES, jnp.bfloat16, True, None, False)
+    assert got.backward == "fused" and list(got.blocks) == want["blocks"]
+    text = lowered_text(*KIMI_SHAPES)
+    assert text.count("pallas_call[") == 2
+    for name, there in (("flash_fwd", True), ("flash_bwd", True),
+                        ("flash_bwd_dkv", False), ("flash_bwd_dq", False)):
+        assert ("name=%s\n" % name in text) == there, name
+    assert digest(text) == (want["chars"], want["sha256"])
+    assert digest(lowered_text(*KIMI_SHAPES, backward=False)) \
+        == (want["fwd_chars"], want["fwd_sha256"])
+
+
 @pytest.mark.parametrize("window", sorted(PHI_CALLS, key=str))
 def test_phi_attention_calls_lower_as_the_parent_commit_did(window):
     want = PHI_CALLS[window]
@@ -375,4 +411,5 @@ if __name__ == "__main__":
     out.update({"phi window %s" % w: digest(lowered_text(*PHI_SHAPES,
                                                          window=w))
                 for w in PHI_CALLS})
+    out["kimi"] = digest(lowered_text(*KIMI_SHAPES))
     print(json.dumps(out, indent=1))

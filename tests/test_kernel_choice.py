@@ -110,9 +110,28 @@ SITES = [
     site("grouped_heads", (1, 4, 1024, 64), (1, 2, 1024, 64),
          blocks=((1024, 1024), (512, 512), (512, 512)),
          backward="split: group"),
+    # unequal widths alone keep no call off the fused kernel (PR 43): the
+    # two Kimi cells' latent attention, and what weighs is dQ's row at its
+    # 256 lanes (fused to Tq = 24,576 where D = 64 or 128 is to 32,768)
     site("value_width_differs", (1, 2, 1024, 64), (1, 2, 1024, 64),
-         (1, 2, 1024, 128), blocks=((1024, 1024), (512, 512), (512, 512)),
-         backward="split: widths"),
+         (1, 2, 1024, 128), blocks=((1024, 1024), (512, 512))),
+    site("kimi.t8192-b2/latent", (2, 16, 8192, 192), (2, 16, 8192, 192),
+         (2, 16, 8192, 128), through_op=True, blocks=(1024, 1024),
+         visited=(36, 64)),
+    site("value_width_differs_and_grouped_heads", (1, 4, 1024, 192),
+         (1, 2, 1024, 192), (1, 2, 1024, 128),
+         blocks=((1024, 1024), (512, 512), (512, 512)),
+         backward="split: group"),
+    site("value_width_differs_and_a_window", (1, 2, 1024, 192),
+         (1, 2, 1024, 192), (1, 2, 1024, 128), window=256,
+         blocks=(256, 256), backward="split: window"),
+    site("dq_row_of_24576_at_192_fits", (1, 1, 24576, 192),
+         (1, 1, 24576, 192), (1, 1, 24576, 128), blocks=(1024, 1024)),
+    site("dq_row_of_32768_at_192_passes_the_ceiling", (1, 1, 32768, 192),
+         (1, 1, 32768, 192), (1, 1, 32768, 128), blocks=(1024, 1024),
+         backward="split: vmem"),
+    site("dq_row_of_32768_at_64_with_values_of_128_fits", (1, 1, 32768, 64),
+         (1, 1, 32768, 64), (1, 1, 32768, 128), blocks=(1024, 1024)),
     site("dq_row_of_32768_fits", (1, 1, 32768, 64), blocks=(1024, 1024)),
     site("dq_row_of_65536_passes_the_ceiling", (1, 1, 65536, 64),
          blocks=(1024, 1024), backward="split: vmem"),

@@ -303,19 +303,20 @@ def test_the_shares_of_heads_and_experts_add_up_to_the_uncut_reference():
 # latent attention's widths through the flash kernels
 # ---------------------------------------------------------------------------
 
-def test_flash_at_d192_dv128_is_the_split_backward_and_agrees_with_xla():
+def test_flash_at_d192_dv128_is_the_fused_backward_and_agrees_with_xla():
     """`attention_path` sends the decompressed latent heads (D 192, Dv 128,
     one query head a key/value head, causal, no window) to the flash
-    kernels with the split backward at the cell's compiled shape, and the
-    interpret-mode kernels agree with XLA attention there in value and in
-    the three gradients."""
+    kernels with the fused backward at the cell's compiled shape (the
+    split pair until PR 43: "split: widths"), and the interpret-mode
+    kernels agree with XLA attention there in value and in the three
+    gradients."""
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas import flash_attention as fa
     big = (2, 16, 8192)
     path = fa.attention_path(big + (192,), big + (192,), big + (128,),
                              jnp.bfloat16, True, None, False, auto=True)
-    assert path.path == "flash" and path.backward == "split: widths"
-    assert len(path.blocks) == 3 and min(min(b) for b in path.blocks) >= 128
+    assert path.path == "flash" and path.backward == "fused"
+    assert len(path.blocks) == 2 and min(min(b) for b in path.blocks) >= 128
     keys = jax.random.split(jax.random.PRNGKey(2), 4)
     q = jax.random.normal(keys[0], (1, 2, 256, 192))
     k = jax.random.normal(keys[1], (1, 2, 256, 192))
@@ -324,7 +325,7 @@ def test_flash_at_d192_dv128_is_the_split_backward_and_agrees_with_xla():
     scale = 192 ** -0.5
     small = fa.attention_path(q.shape, k.shape, v.shape, q.dtype, True, None,
                               True, block_q=128, block_k=128)
-    assert small.path == "flash" and small.backward == "split: widths"
+    assert small.path == "flash" and small.backward == "fused"
 
     def flash(q_, k_, v_):
         return fa.flash_attention(q_, k_, v_, scale=scale, causal=True,
