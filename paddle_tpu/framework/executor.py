@@ -194,9 +194,8 @@ class Executor(object):
         self._explicit_place = place is not None
         self.place = place if place is not None else _current_expected_place()
         self._cache = {}
-        # step-cache accounting (bench_micro's executor-cache-hit-rate
-        # metric): a miss is a fresh trace+compile, a hit re-dispatches
-        # the cached executable
+        # step-cache accounting: a miss is a fresh trace+compile, a hit
+        # re-dispatches the cached executable
         self.cache_hits = 0
         self.cache_misses = 0
         # numeric_policy="skip" accounting: CONSECUTIVE steps discarded

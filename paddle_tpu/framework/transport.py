@@ -1064,7 +1064,7 @@ def replicated_group(n_hosts, n_members=2, host="127.0.0.1",
     """Build + wire + start a whole in-process replication group:
     member 0 boots primary, the rest warm standbys, all sharing the
     ordered endpoint list. Returns the server list (same order as the
-    endpoints clients should dial). Tests and bench_micro ride this;
+    endpoints clients should dial). Tests ride this;
     production deploys one ``coordsvc --peers ... --repl-index i``
     per member instead."""
     servers = [CoordServer(n_hosts, host=host,

@@ -45,7 +45,7 @@ def pytest_configure(config):
         "markers",
         "quant: quantized-collective / compressed-state-movement tests "
         "(block codec, quantize_collectives guardrails, compressed "
-        "checkpoints, bench_micro perf gates)")
+        "checkpoints)")
     config.addinivalue_line(
         "markers",
         "pallas: Pallas kernel batteries (the kernels' names in the "

@@ -637,34 +637,37 @@ def test_allowlist_applied_after_first_compile_takes_effect():
 # strict sweep over the model zoo programs
 # ---------------------------------------------------------------------------
 
-def test_models_verify_clean_in_strict_mode():
+def _zoo_program(name):
+    from paddle_tpu.models import bert, gpt, simple
+    if name == "bert":
+        cfg = bert.BertConfig(vocab_size=128, hidden_size=32, num_layers=2,
+                              num_heads=2, ff_size=64, max_position=64)
+        return bert.bert_pretrain_program(cfg, batch_size=4, seq_len=16,
+                                          max_preds_per_seq=4)
+    if name == "bert_base":     # 12 layers: the walk scales with the ops
+        return bert.bert_pretrain_program(bert.bert_base(), batch_size=8,
+                                          seq_len=128)
+    if name == "gpt":
+        cfg = gpt.GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
+                            num_heads=2, max_position=64)
+        return gpt.gpt_pretrain_program(cfg, batch_size=4, seq_len=16)
+    return simple.mlp_classifier_program(input_dim=16, hidden=(8,),
+                                         classes=4)
+
+
+@pytest.mark.parametrize("name", ["bert", "bert_base", "gpt", "mlp"])
+def test_models_verify_clean_in_strict_mode(name):
     """Representative model-zoo programs verify with ZERO errors —
     the no-false-positive acceptance bar (the rest of the zoo rides
     the compile seam across the whole strict-mode suite)."""
-    from paddle_tpu.models import bert, gpt, simple
-    cases = []
-    cfg = bert.BertConfig(vocab_size=128, hidden_size=32, num_layers=2,
-                          num_heads=2, ff_size=64, max_position=64)
-    main, startup, feeds, fetch = bert.bert_pretrain_program(
-        cfg, batch_size=4, seq_len=16, max_preds_per_seq=4)
-    cases.append(("bert", main, feeds, fetch))
-    gcfg = gpt.GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
-                         num_heads=2, max_position=64)
-    gmain, gstartup, gfeeds, gfetch = gpt.gpt_pretrain_program(
-        gcfg, batch_size=4, seq_len=16)
-    cases.append(("gpt", gmain, gfeeds, gfetch))
-    smain, sstartup, sfeeds, sfetch = simple.mlp_classifier_program(
-        input_dim=16, hidden=(8,), classes=4)
-    cases.append(("mlp", smain, sfeeds, sfetch))
-    for name, prog, feeds_, fetch_ in cases:
-        feed_names = list(feeds_.values() if isinstance(feeds_, dict)
-                          else feeds_)
-        feed_names = [getattr(f, "name", f) for f in feed_names]
-        fetch_list = list(fetch_.values()) if isinstance(fetch_, dict) \
-            else list(fetch_)
-        r = analysis.verify_program(prog, feeds=feed_names,
-                                    fetch_list=fetch_list)
-        assert not r.errors(), "%s: %s" % (name, r.summary())
+    prog, _startup, feeds, fetch = _zoo_program(name)
+    feed_names = [getattr(f, "name", f) for f in (
+        feeds.values() if isinstance(feeds, dict) else feeds)]
+    fetch_list = list(fetch.values()) if isinstance(fetch, dict) \
+        else list(fetch)
+    r = analysis.verify_program(prog, feeds=feed_names,
+                                fetch_list=fetch_list)
+    assert not r.errors(), "%s: %s" % (name, r.summary())
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,4 @@
-"""bench.py analysis-tool units: the dot_general inventory parser."""
+"""tools/dot_inventory.py: the dot_general inventory parser."""
 import numpy as np
 
 
@@ -13,8 +13,8 @@ SNIPPET = """
 
 
 def test_dot_inventory_parses_stablehlo(capsys):
-    import bench
-    dots = bench.dot_inventory(SNIPPET, top_k=5)
+    from tools.dot_inventory import dot_inventory
+    dots = dot_inventory(SNIPPET, top_k=5)
     assert len(dots) == 2
     by_out = {d["out"]: d for d in dots}
     d1 = by_out["512x1024xbf16"]

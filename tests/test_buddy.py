@@ -598,32 +598,42 @@ def test_mailbox_delta_refusals_are_typed():
     np.testing.assert_array_equal(got["w"], d2["w"])
 
 
-def test_delta_sends_skip_unchanged_leaves_and_rebase():
+@pytest.mark.parametrize("static,churning", [
+    ((64, 32), {"churn/w": (3, 4)}),
+    # one large static table under small heads that churn every window:
+    # what most of a real scope looks like
+    ((1024, 256), {"head/w%d" % i: (64, 64) for i in range(4)}),
+], ids=["one_leaf", "churn_skewed"])
+def test_delta_sends_skip_unchanged_leaves_and_rebase(static, churning):
     """Sender-side delta protocol over LocalCoordinator: unchanged
-    leaves never move again (delta wire << full wire on a static-heavy
-    scope), the chain re-bases to a forced full every rebase_every
-    sends, and the restore after a re-base boundary is bitwise."""
+    leaves never move again (delta wire under half the full wire on a
+    static-heavy scope), the chain re-bases to a forced full every
+    rebase_every sends, and the restore after a re-base boundary is
+    bitwise."""
     co = LocalCoordinator(2, timeout_s=5.0)
     tracker = buddy.DeltaTracker(rebase_every=2)
     rng = np.random.RandomState(0)
-    scope = {"static/table": rng.randn(64, 32).astype(np.float32),
-             "churn/w": rng.randn(3, 4).astype(np.float32)}
+
+    def churn():
+        return {n: rng.randn(*shape).astype(np.float32)
+                for n, shape in churning.items()}
+    scope = dict(churn(), **{
+        "static/table": rng.randn(*static).astype(np.float32)})
     assert buddy.send_snapshot(co, 0, [0, 1], 0, scope, tracker=tracker)
     full_wire = tracker.full_wire
     assert tracker.chain_len == 0 and full_wire
-    for gen in (1, 2):   # deltas: only churn/w moves
-        scope = dict(scope, **{"churn/w": rng.randn(3, 4)
-                               .astype(np.float32)})
+    for gen in (1, 2):   # deltas: only the churning leaves move
+        scope = dict(scope, **churn())
         assert buddy.send_snapshot(co, 0, [0, 1], gen, scope,
                                    tracker=tracker)
         assert tracker.chain_len == gen
         assert resilience.buddy_delta_ratio() < 0.5
     # the next send finds the chain at rebase_every: forced full, the
     # buddy slot's chain collapses
-    scope = dict(scope, **{"churn/w": rng.randn(3, 4)
-                           .astype(np.float32)})
+    scope = dict(scope, **churn())
     assert buddy.send_snapshot(co, 0, [0, 1], 3, scope, tracker=tracker)
     assert tracker.chain_len == 0
+    assert resilience.buddy_delta_ratio() == 1.0
     assert co.mailbox_of(1).meta(0) \
         == dict(co.mailbox_of(0).meta(0))   # both replicas identical
     assert co.mailbox_of(1).meta(0)["chain_len"] == 0

@@ -1,5 +1,6 @@
 """Executor trainer-loop tests (train_from_dataset / prefetch)."""
 import numpy as np
+import pytest
 
 
 def test_train_from_dataset_runs_all_batches():
@@ -234,3 +235,48 @@ def test_train_from_dataset_windows_pipeline_program():
     s2, l2 = run(2)
     assert s1 == s2 == W
     np.testing.assert_allclose(l1, l2, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("mesh", [None, {"dp": 8}], ids=["plain", "dp8"])
+@pytest.mark.parametrize("entry", ["run", "run_steps"])
+def test_repeated_steps_compile_once_and_hit_after(entry, mesh):
+    """Twelve dispatches of one program at one feed signature (fresh
+    arrays each time, as a training loop feeds them): exactly 1 miss and
+    11 hits through `run` and through `run_steps`, on one device and as a
+    CompiledProgram over a dp mesh. A key that churned with the feed's
+    identity would recompile every step."""
+    import paddle_tpu as pt
+    from paddle_tpu import layers, optimizer
+    from paddle_tpu.framework.compiler import BuildStrategy, CompiledProgram
+    from paddle_tpu.framework.scope import Scope, scope_guard
+    n, window = 12, 3
+    rng = np.random.RandomState(0)
+
+    def batch(*lead):
+        return {"x": rng.rand(*lead, 16, 64).astype(np.float32),
+                "y": rng.randint(0, 8, lead + (16, 1)).astype(np.int64)}
+
+    with scope_guard(Scope()):
+        main, startup = pt.Program(), pt.Program()
+        with pt.program_guard(main, startup):
+            x = layers.data("x", [64], dtype="float32")
+            y = layers.data("y", [1], dtype="int64")
+            logits = layers.fc(layers.fc(x, size=128, act="relu"), size=8)
+            loss = layers.mean(layers.softmax_with_cross_entropy(logits, y))
+            optimizer.SGD(0.1).minimize(loss)
+        exe = pt.Executor()
+        exe.run(startup)
+        assert (exe.cache_hits, exe.cache_misses) == (0, 0)   # eager
+        target = main
+        if mesh is not None:
+            strategy = BuildStrategy()
+            strategy.mesh_axes = mesh
+            target = CompiledProgram(main, strategy)
+        for _ in range(n):
+            if entry == "run":
+                out = exe.run(target, feed=batch(), fetch_list=[loss])
+            else:
+                out = exe.run_steps(target, feed=batch(window),
+                                    fetch_list=[loss])
+            assert np.isfinite(np.asarray(out[0])).all()
+        assert (exe.cache_misses, exe.cache_hits) == (1, n - 1)

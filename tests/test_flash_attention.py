@@ -479,3 +479,36 @@ def test_f32_unchanged_to_the_bit_at_equal_tiles():
                                             **kw), (q, k, v), w))(q, k, v)
     for a, b_ in zip(run(), run(block_q=bq, block_k=bk)):
         assert (np.asarray(a) == np.asarray(b_)).all()
+
+
+def test_the_microbenchmark_of_the_flash_tiles_walks_through():
+    """Off the TPU `tools/mb_flash_tiles.py` exits 1 without the flag; with
+    it, at tiny shapes in interpret mode: one JSON line a shape, every
+    kernel timed at every tile, beside the rule's tile and backward."""
+    import json
+    import os
+    import subprocess
+    import sys
+    tool = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "mb_flash_tiles.py")
+
+    def run(*argv):
+        return subprocess.run(
+            [sys.executable, tool] + list(argv), capture_output=True,
+            text=True, timeout=600, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    done = run()
+    assert done.returncode == 1 and "not a TPU" in done.stderr
+    done = run("--walk-through")
+    assert done.returncode == 0, done.stderr[-2000:]
+    rows = [json.loads(line) for line in done.stdout.splitlines()]
+    assert [row["shape"] for row in rows] == ["1x2x256x32", "1x2x256x48|32"]
+    kernels = list(fa.KERNELS + fa.FUSED_KERNELS[1:])
+    for row in rows:
+        assert row["platform"] == "cpu" and row["device_times"] is False
+        assert list(row["ms"]) == ["128x128", "256x256"]
+        for tile, timed in row["ms"].items():
+            assert list(timed) == kernels, (tile, timed)
+            assert all(isinstance(ms, float) for ms in timed.values()), timed
+        assert set(row["best"].values()) <= set(row["ms"])
+        assert row["rule"] == dict.fromkeys(kernels, "256x256")
+        assert row["backward"] == "fused"

@@ -14,6 +14,7 @@ path (elastic_pp_rewind reason="disabled") with bitwise replay.
 """
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -287,6 +288,67 @@ def test_pp_cache_toggles_relower_and_repeats_hit():
                     fetch_list=[loss])
         assert exe.cache_misses == 3
         assert exe.cache_hits == 6
+
+
+def test_pp_equal_strategies_share_one_executable():
+    """The cache keys on the strategy's token, not on the CompiledProgram
+    object: four fresh CompiledPrograms toggling 1f1b / gpipe lower twice
+    and hit twice (a hit rate of exactly one half)."""
+    (xv, yv), = _data(1)
+    main, startup, loss = _pp_program()
+    with scope_guard(Scope()):
+        exe = pt.Executor()
+        exe.run(startup)
+        for schedule in ("1f1b", "gpipe", "1f1b", "gpipe"):
+            exe.run(CompiledProgram(main, _pp_strategy(schedule)),
+                    feed={"pp_x": xv, "pp_y": yv}, fetch_list=[loss])
+        assert (exe.cache_misses, exe.cache_hits) == (2, 2)
+
+
+def _scan_lengths(jaxpr):
+    """Trip count of every lax.scan under `jaxpr`, in program order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.append(eqn.params["length"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_scan_lengths(sub))
+    return found
+
+
+@pytest.mark.parametrize("n_stage,n_micro", [(2, 4), (4, 8)])
+@pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
+def test_pp_bubble_fraction_is_the_schedules_closed_form(schedule, n_stage,
+                                                         n_micro):
+    """The step the executor cached runs the ticks the schedule's closed
+    form counts, each one micro-step a stage: GPipe M + K - 1 forward and
+    as many in the transposed scan, 1F1B M + 2(K - 1) of one forward and
+    one backward micro-step. M of a scan's ticks do a stage's real work;
+    the rest is the bubble, (K-1)/(M+K-1) and 2(K-1)/(M+2(K-1))."""
+    (xv, yv), = _data(1)
+    feed = {"pp_x": xv, "pp_y": yv}
+    main, startup, loss = _pp_program(n_stage=n_stage)
+    comp = CompiledProgram(main, _pp_strategy(schedule, n_stage=n_stage,
+                                              m=n_micro))
+    traced = []
+    with scope_guard(Scope()):
+        exe = pt.Executor()
+        exe.run(startup)
+        exe.run(comp, feed=feed, fetch_list=[loss])
+        (key, (names, step_fn)), = exe._cache.items()
+
+        def spy(state, feeds):
+            traced.append(jax.make_jaxpr(step_fn)(state, feeds))
+            return step_fn(state, feeds)
+        exe._cache[key] = (names, spy)
+        exe.run(comp, feed=feed, fetch_list=[loss])
+    fill = n_stage - 1
+    ticks, scans = {"gpipe": (n_micro + fill, 2),
+                    "1f1b": (n_micro + 2 * fill, 1)}[schedule]
+    assert _scan_lengths(traced[0].jaxpr) == [ticks] * scans
+    bubble = {"gpipe": fill / float(n_micro + fill),
+              "1f1b": 2 * fill / float(n_micro + 2 * fill)}[schedule]
+    assert 1.0 - n_micro / float(ticks) == pytest.approx(bubble)
 
 
 # ---------------------------------------------------------------------------
