@@ -37,6 +37,9 @@ def moe_ffn(x, num_experts, top_k, ffn_size, experts_held=None,
       r W_r, w the softmax over the picks' own logits; no expert bias is
       made or read;
     - `gate="relu"`: the experts are W2_e(relu(W1_e x) * W3_e x);
+    - `gate="relu2"`: the experts are NOT gated, W2_e(relu(W1_e x)^2)
+      (`nemotron_h`): the first stacked leaf is (count, d, ffn_size), named
+      `<name>_experts_up` unless `gate_up_attr` names it;
     - `absent="folded"`: a pick on an absent expert is answered by the held
       expert congruent to it modulo `count`, with the weight the router
       gave it, so every pick is answered and the layer lays out tokens x
@@ -75,18 +78,22 @@ def moe_ffn(x, num_experts, top_k, ffn_size, experts_held=None,
             given.name = name + suffix
         return given
 
+    plain = gate in moe_ops.PLAIN
+    if scoring not in moe_ops.SCORINGS or not (plain
+                                               or gate in moe_ops.GATES):
+        raise ValueError("moe_ffn: scoring %r is one of %r and gate %r of "
+                         "%r" % (scoring, moe_ops.SCORINGS, gate,
+                                 sorted(moe_ops.GATES)
+                                 + sorted(moe_ops.PLAIN)))
     w_r = helper.create_parameter(attr(router_attr, "_router.w_0"),
                                   shape=[d, num_experts], dtype="float32")
-    w13 = helper.create_parameter(attr(gate_up_attr, "_experts_gate_up"),
-                                  shape=[count, d, 2 * ffn_size],
-                                  dtype=x.dtype)
+    w13 = helper.create_parameter(
+        attr(gate_up_attr, "_experts_up" if plain else "_experts_gate_up"),
+        shape=[count, d, ffn_size if plain else 2 * ffn_size],
+        dtype=x.dtype)
     w2 = helper.create_parameter(attr(down_attr, "_experts_down"),
                                  shape=[count, ffn_size, d], dtype=x.dtype)
     held = [int(first), int(count)]
-    if scoring not in moe_ops.SCORINGS or gate not in moe_ops.GATES:
-        raise ValueError("moe_ffn: scoring %r is one of %r and gate %r of "
-                         "%r" % (scoring, moe_ops.SCORINGS, gate,
-                                 sorted(moe_ops.GATES)))
     # the attrs a default layer carries are the ones it always carried
     route_ins = {"X": [(x if router_input is None else router_input).name],
                  "W": [w_r.name]}

@@ -13,15 +13,18 @@ LARK/ERNIE repos, rebuilt on paddle_tpu layers).
 - gpt: GPT-style causal LM (long-context flagship: flash/ring/ulysses
   attention, greedy_generate decode)
 - dcgan: DCGAN adversarial training as one fused two-optimizer step
-- phi4flash, lfm2moe, kimi_linear, smallthinker, kimi_vl: hybrid decoders
-  the benchmark trains (selective scan + differential attention; short
-  convolutions + sparse experts; delta-rule linear attention + latent
+- phi4flash, lfm2moe, kimi_linear, smallthinker, kimi_vl, nemotron_h: hybrid
+  decoders the benchmark trains (selective scan + differential attention;
+  short convolutions + sparse experts; delta-rule linear attention + latent
   attention + sparse experts with a shared expert; window and full
   grouped-query attention 3:1 + sparse experts routed ahead of attention,
   softmax over the picks, ReLU gates; latent attention with its decoupled
   rotary part in every layer + sparse experts with two shared experts: the
-  text decoder of Kimi-VL, no vision tower); moe_decoder holds the blocks
-  kimi_linear and kimi_vl share
+  text decoder of Kimi-VL, no vision tower; Mamba-2 layers, non-gated
+  relu^2 experts with a shared expert and position-free grouped-query
+  attention, each layer one block alone: the `nemotron_h` stack, no
+  denoiser tower); moe_decoder holds the blocks and frames kimi_linear,
+  kimi_vl and nemotron_h share
 """
 from . import bert
 from . import resnet
@@ -39,3 +42,4 @@ from . import lfm2moe
 from . import kimi_linear
 from . import smallthinker
 from . import kimi_vl
+from . import nemotron_h

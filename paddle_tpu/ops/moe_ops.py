@@ -74,7 +74,8 @@ equations are the published `lfm2_moe` block's by default: sigmoid scores,
 an expert bias added for the choice only, weights renormalised over the
 picks, SiLU gates. `moe_route`'s `scoring` "softmax" and `moe_experts`'
 `gate` "relu" are SmallThinker's: the top-k of the logits, a softmax over
-the picks alone, no bias, ReLU gates.
+the picks alone, no bias, ReLU gates. `gate` "relu2" is `nemotron_h`'s: the
+experts are not gated, W2(relu(W1 x)^2) over one (G, d, F) leaf.
 """
 import functools
 
@@ -97,7 +98,10 @@ def _held(attrs):
 
 
 SCORINGS = ("sigmoid", "softmax")
+#: act(a) * b over a (G, d, 2F) leaf, gate and up side by side
 GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+#: act(a) alone over a (G, d, F) leaf: the non-gated experts
+PLAIN = {"relu2": lambda a: jnp.square(jax.nn.relu(a))}
 
 
 @register_op("moe_route", nondiff=("Bias",))
@@ -425,11 +429,18 @@ def _moe_dispatch_rule(op, ins, attrs):
 
 
 def _gate(both, gate="silu"):
-    """act(a) * b of rows [a, b] (`gate`: "silu" or "relu"), in float32, in
-    the rows' dtype."""
+    """act(a) * b of rows [a, b] (`gate` one of GATES), or act(a) of rows a
+    (one of PLAIN: no second half), in float32, in the rows' dtype."""
+    if gate in PLAIN:
+        return PLAIN[gate](both.astype(jnp.float32)).astype(both.dtype)
     a, b = jnp.split(both, 2, axis=1)
     return (GATES[gate](a.astype(jnp.float32))
             * b.astype(jnp.float32)).astype(both.dtype)
+
+
+def _act_width(width, gate):
+    """Columns `_gate` gives for rows `width` wide."""
+    return width if gate in PLAIN else width // 2
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -445,7 +456,8 @@ def _gated(both, sizes, tm, gate="silu"):
     def block(start, _outs):
         return (_gate(lax.dynamic_slice_in_dim(both, start, chunk), gate),)
 
-    return _by_chunks((_anything((rows, width // 2), both.dtype, both),),
+    return _by_chunks((_anything((rows, _act_width(width, gate)),
+                                 both.dtype, both),),
                       rows_laid_out(sizes, tm), chunk, block)[0]
 
 
@@ -478,13 +490,14 @@ def _moe_experts(ctx, ins, attrs):
     """Out[r] = W2_g (act(a) * b), [a, b] = Rows[r] W13_g, g the group of
     row r, for the rows the plan laid out; act is `gate`, "silu" (the
     default) or "relu". W13 [G, d, 2F] is gate and up side by side, W2
-    [G, F, d]."""
+    [G, F, d]. Under a `gate` of PLAIN ("relu2") the experts are not
+    gated: Out[r] = W2_g act(Rows[r] W13_g) with W13 [G, d, F]."""
     rows, w13, w2 = ins["Rows"][0], ins["W13"][0], ins["W2"][0]
     sizes, tile_group = ins["GroupSizes"][0], ins["TileGroup"][0]
     gate = attrs.get("gate", "silu")
-    if gate not in GATES:
+    if gate not in GATES and gate not in PLAIN:
         raise ValueError("moe_experts: gate %r is none of %r"
-                         % (gate, sorted(GATES)))
+                         % (gate, sorted(GATES) + sorted(PLAIN)))
     tm = rows.shape[0] // tile_group.shape[0]
     both = gmm.grouped_matmul(rows, w13, sizes, tm)
     act = _gated(both, sizes, tm, gate)
@@ -496,12 +509,15 @@ def _moe_experts_rule(op, ins, attrs):
     rows, w13, w2 = _x(ins, "Rows"), _x(ins, "W13"), _x(ins, "W2")
     if w13.shape is not None and w2.shape is not None \
             and None not in w13.shape and None not in w2.shape:
+        halves = 1 if attrs.get("gate", "silu") in PLAIN else 2
         if len(w13.shape) != 3 or len(w2.shape) != 3 \
                 or w13.shape[0] != w2.shape[0] \
-                or w13.shape[2] != 2 * w2.shape[1] \
+                or w13.shape[2] != halves * w2.shape[1] \
                 or w13.shape[1] != w2.shape[2]:
-            raise ShapeError("moe_experts wants W13 (G, d, 2F) and W2 (G, "
-                             "F, d); got %s and %s" % (w13.shape, w2.shape))
+            raise ShapeError("moe_experts wants W13 (G, d, %sF) and W2 (G, "
+                             "F, d); got %s and %s"
+                             % ("2" if halves == 2 else "", w13.shape,
+                                w2.shape))
     return {"Out": [TensorMeta(rows.shape, rows.dtype)]}
 
 

@@ -30,10 +30,20 @@ expert width of 1408 = 11 x 128, whose only other such divisor is 128, that
 is the whole width (PERF.md section 6, PR 41). With obs on a lowering
 records what it picked as `moe_gmm.plan`.
 
+A width that is no multiple of 128 (Nemotron-H's experts are 1856 = 14.5 x
+128 wide) has ONE tile, the whole width: a block as wide as its array is
+the one block shape Mosaic takes off the 128-lane grid, it lays the block
+out on 15 x 128 lanes in VMEM and masks the last half-vreg itself, in the
+loads, the stores and the contraction alike. Nothing is padded in HBM: the
+leaf, the row buffer and the FLOPs are the published width's, and
+`vmem_bytes` counts the lanes the block really takes. Such a width must
+still be a multiple of 8 (a matrix block's second-to-last axis).
+
 Operands go to the MXU in their own dtype, sums are float32. Off the TPU, and
-where a width is no multiple of 128 or `tm` no multiple of 16, the same entry
-takes the plain XLA form, `jax.lax.ragged_dot` over the same layout
-(`grouped_matmul_xla`), which is also what the kernels are tested against.
+where a width is no multiple of 8, `tm` no multiple of 16, or no pair of
+tiles fits VMEM, the same entry takes the plain XLA form,
+`jax.lax.ragged_dot` over the same layout (`grouped_matmul_xla`), which is
+also what the kernels are tested against.
 """
 import collections
 import functools
@@ -99,8 +109,14 @@ KERNELS = ("fwd", "dx", "dw")
 
 
 def _divisors(dim):
-    """Every multiple of 128 that divides `dim`, `dim` itself included."""
-    return [t for t in range(_LANES, dim + 1, _LANES) if dim % t == 0]
+    """Every multiple of 128 that divides `dim`, and `dim` itself (the one
+    tile of a width off the 128-lane grid)."""
+    return [t for t in range(_LANES, dim, _LANES) if dim % t == 0] + [dim]
+
+
+def _lanes(width):
+    """Lanes a block `width` wide takes in VMEM: whole vregs of 128."""
+    return -(-width // _LANES) * _LANES
 
 
 def tiled_widths(kernel, k, n):
@@ -146,7 +162,7 @@ def vmem_bytes(kernel, tm, tiles, itemsize):
     and of the output block (the three blocks are the three faces of
     tm x ta x tb in every kernel), and the float32 accumulator, which has
     the output block's shape."""
-    ta, tb = tiles
+    ta, tb = (_lanes(t) for t in tiles)
     acc = ta * tb if kernel == "dw" else tm * ta
     return 2 * (tm * ta + tm * tb + ta * tb) * itemsize + 4 * acc
 
@@ -171,11 +187,12 @@ def _pick(kernel, rows, k, n, tm, itemsize):
 def plan(rows, k, n, tm, itemsize=2):
     """The three kernels' tiles for one call, or None where the call takes
     the XLA form. Decided from the shapes alone: a tile is any multiple of
-    128 that divides its width, the width itself included; of each kernel's
-    pairs that fit `_VMEM_BUDGET` (`vmem_bytes`) the one that moves the
-    fewest HBM bytes (`hbm_bytes`) over the buffer's rows wins. `tm` is the
-    caller's (`row_tile`): the buffer and the layout hang on it."""
-    if k % _LANES or n % _LANES or tm % 16 or rows % tm:
+    128 that divides its width, or the width itself (the only tile of a
+    width that is no multiple of 128); of each kernel's pairs that fit
+    `_VMEM_BUDGET` (`vmem_bytes`) the one that moves the fewest HBM bytes
+    (`hbm_bytes`) over the buffer's rows wins. `tm` is the caller's
+    (`row_tile`): the buffer and the layout hang on it."""
+    if k % 8 or n % 8 or tm % 16 or rows % tm:
         return None
     picked = [_pick(kernel, rows, k, n, tm, itemsize) for kernel in KERNELS]
     if None in picked:

@@ -65,7 +65,7 @@ def stop_profiler(sorted_key=None, profile_path=None):
 
 
 PLAN_RECORDS = ("flash.plan", "ssm.plan", "head.plan", "kda.plan",
-                "moe_gmm.plan")
+                "ssd.plan", "moe_gmm.plan")
 
 
 def print_kernel_plans():
@@ -75,7 +75,9 @@ def print_kernel_plans():
     split backward), `ssm.plan` (chunk length, chunks, VMEM asked),
     `head.plan` (the LM head: rows, vocab, block rows and blocks, weighted
     or per-token form, operand dtype), `kda.plan` (the delta rule: chunk,
-    sub-block, chunks a group, heads, which kernels) and `moe_gmm.plan`
+    sub-block, chunks a group, heads, which kernels), `ssd.plan` (the
+    Mamba-2 scan: heads, groups, state, chunk, chunks, padding, the states
+    the backward keeps) and `moe_gmm.plan`
     (a grouped matmul: each kernel's tiles, grid, modelled HBM bytes and
     their ratio to the least, VMEM)."""
     for name in PLAN_RECORDS:

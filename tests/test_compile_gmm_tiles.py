@@ -1,8 +1,10 @@
 """Compile-only, for a described v5e:2x2 topology with no chip attached: the
 three grouped-matmul kernels at the tiles `plan` picks by the bytes they
-move, at the two calls of each of the four expert cells (the expert width
+move, at the two calls of each of the five expert cells (the expert width
 of 1408 = 11 x 128 of `kimi-vl-a3b.t8192-b2` first: whole-dimension tiles,
-no padding, no ragged tile), in bfloat16, and at float32 operands, whose
+no padding, no ragged tile; `nemotron-twotower-30b-a3b.t8192-b2`'s 1856 =
+14.5 x 128, off the lane grid: its one tile is the whole width, on either
+side of the matmul), in bfloat16, and at float32 operands, whose
 blocks are twice the size. Mosaic has to take every block (alignment, VMEM)
 and the call has to hold three custom calls. The topology is described
 inside `tests/benchmark_suite/test_compile_fullsize.py`'s fixture, which
@@ -31,6 +33,8 @@ CALLS = {
     "smallthinker-w2": (196608, 768, 2560, "bfloat16"),
     "kimi-w13": (131072, 2304, 2048, "bfloat16"),
     "kimi-w2": (131072, 1024, 2304, "bfloat16"),
+    "nemotron-w1": (98304, 2688, 1856, "bfloat16"),
+    "nemotron-w2": (98304, 1856, 2688, "bfloat16"),
     "kimi-vl-w13-float32": (98304, 2048, 2816, "float32"),
     "kimi-vl-w2-float32": (98304, 1408, 2048, "float32"),
 }

@@ -6,14 +6,16 @@ One line a kernel (`moe_gmm_fwd`, `moe_gmm_dx`, `moe_gmm_dw` of
 W13 (d, 2 x width) and W2 (width, d), in bfloat16 over the cell's row
 buffer: `--cell kimi-vl` (16,384 tokens x top-6 on 8 held experts, d 2,048,
 width 1,408, 102,400 rows), `lfm2` (top-4, 2,048 / 1,792, 69,632 rows),
-`smallthinker` (32,768 tokens x top-6, 2,560 / 768, 200,704 rows) or `kimi`
-(top-8, 2,304 / 1,024, 135,168 rows). `--rows-in-use N` puts N pairs on the
-held experts, split unevenly from `--seed` (default: what the cell's traced
-steps counted). `--tiles` names whose tiles, and may repeat: `plan` (the
-module's `plan`), `old` (the capped divisors `plan` took until PR 41, which
-live here alone) or explicit ones, `fwd=1408x2048,dw=1408x512` (a kernel not
-named is not run, nor a pair at the call it does not divide; a pair is the
-kernel's pair in `Tiles`' order).
+`smallthinker` (32,768 tokens x top-6, 2,560 / 768, 200,704 rows), `kimi`
+(top-8, 2,304 / 1,024, 135,168 rows) or `nemotron` (top-6, 2,688 / 1,856,
+102,400 rows; its experts are not gated, so its first call is W1 (d, width):
+the width off the 128-lane grid, whose one tile is the whole width).
+`--rows-in-use N` puts N pairs on the held experts, split unevenly from
+`--seed` (default: what the cell's traced steps counted). `--tiles` names
+whose tiles, and may repeat: `plan` (the module's `plan`), `old` (the capped
+divisors `plan` took until PR 41, which live here alone) or explicit ones,
+`fwd=1408x2048,dw=1408x512` (a kernel not named is not run, nor a pair at the
+call it does not divide; a pair is the kernel's pair in `Tiles`' order).
 
 `ms` is wall time a call over `--calls` calls dispatched back to back
 behind one `block_until_ready`; `roofline` is the least time of the call at
@@ -49,7 +51,10 @@ PEAK_FLOPS, PEAK_BYTES = 197e12, 819e9      # one v5e chip, bfloat16
 CELLS = {"kimi-vl": (16384, 2048, 1408, 6, 8, 98304),
          "lfm2": (16384, 2048, 1792, 4, 8, 18944),
          "smallthinker": (32768, 2560, 768, 6, 8, 196608),
-         "kimi": (16384, 2304, 1024, 8, 8, 5568)}
+         "kimi": (16384, 2304, 1024, 8, 8, 5568),
+         "nemotron": (16384, 2688, 1856, 6, 8, 98304)}
+#: cells whose experts are not gated: the first matrix is (d, width)
+PLAIN = ("nemotron",)
 
 
 def old_plan(k, n, tm):
@@ -139,7 +144,8 @@ def main():
     if not on_tpu:
         print("not a TPU: the times below are no device times")
     keys = jax.random.split(jax.random.PRNGKey(args.seed), 3)
-    for k, n in ((d, 2 * ffn), (ffn, d)):
+    first_n = ffn if args.cell in PLAIN else 2 * ffn
+    for k, n in ((d, first_n), (ffn, d)):
         x = jax.random.normal(keys[0], (rows, k)).astype(jnp.bfloat16)
         dy = jax.random.normal(keys[1], (rows, n)).astype(jnp.bfloat16)
         w = (0.02 * jax.random.normal(keys[2], (groups, k, n))).astype(
