@@ -20,8 +20,61 @@ os.environ.setdefault("PADDLE_TPU_VERIFY", "strict")
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+# the helper modules' asserts read as a test file's do
+pytest.register_assert_rewrite("_flash_cases", "_moe_cases")
+
+
+#: The files a whole tier-1 run is longest in, longest first (the seconds a
+#: file of `/tmp/_t1.xml`, PR 46). Under `--dist loadfile` a file is one
+#: worker's, so a long file that starts late is what five workers wait for
+#: at the end: these are handed out first, in this order, and the rest as
+#: collected. A stale tuple costs balance, never a test.
+LONGEST_FIRST = (
+    "benchmark_suite/test_phi4flash.py",
+    "benchmark_suite/test_nemotronh_family.py",
+    "benchmark_suite/test_smallthinker_family.py",
+    "benchmark_suite/test_lfm2moe_family.py",
+    "benchmark_suite/test_compile_kimivl.py",
+    "benchmark_suite/test_compile_fullsize.py",
+    "benchmark_suite/test_kimivl_family.py",
+    "test_moe_ops.py",
+    "test_kimi_vl.py",
+    "test_moe_layer.py",
+    "benchmark_suite/test_kimilinear_family.py",
+    "test_flash_fused_backward_bf16.py",
+    "test_flash_fused_backward.py",
+    "test_pod_transport.py",
+    "benchmark_suite/test_reference.py",
+    "test_flash_attention.py",
+    "benchmark_suite/test_compile_smallthinker.py",
+    "test_model_zoo_extra.py",
+    "benchmark_suite/test_harness.py",
+    "benchmark_suite/test_compile_kimilinear.py",
+    "test_nemotron_h.py",
+    "test_flash_modes.py",
+    "test_sequence_parallel.py",
+    "benchmark_suite/test_compile_lfm2moe.py",
+    "test_distributed.py",
+    "test_smallthinker.py",
+    "test_kda_op.py",
+    "test_lfm2moe.py",
+    "test_kda_kernels.py",
+    "test_detection_ops.py",
+)
+
+
+def pytest_collection_modifyitems(items):
+    here = os.path.dirname(os.path.abspath(__file__))
+    rank = {name: at for at, name in enumerate(LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(      # stable: files stay whole
+        os.path.relpath(str(item.path), here).replace(os.sep, "/"),
+        len(rank)))
+
 
 def pytest_configure(config):
+    # pytest-xdist hands files out by their number of tests, most first,
+    # unless told to keep the collection's order, which is the one above
+    config.option.loadscopereorder = False
     # registered markers so tier-1 (-m 'not slow') runs warning-free:
     # fast chaos tests carry `faultinject`; long soaks hide behind `slow`
     config.addinivalue_line(
@@ -84,6 +137,16 @@ def disarmed_failpoints():
     yield
     faultinject.disarm()
     faultinject.reset_counters()
+
+
+@pytest.fixture(autouse=True)
+def mesh_put_back():
+    """No test leaves its mesh to the next one of its worker: the global
+    mesh (and the axes a re-init rebuilds it from) is what it was."""
+    from paddle_tpu.distributed import mesh
+    old = mesh._mesh, mesh._mesh_axes
+    yield
+    mesh._mesh, mesh._mesh_axes = old
 
 
 @pytest.fixture(autouse=True)

@@ -70,8 +70,12 @@ def test_the_call_compiles_to_flash_fwd_and_one_flash_bwd(
     assert not [s for s in _shapes(text) if s.count(t) >= 2]
 
 
+@pytest.mark.slow
 def test_the_kimi_vl_step_compiles_fits_and_holds_the_fused_backward(
         topo, no_compile_cache, monkeypatch):     # noqa: F811
+    """Behind `slow`: `tests/benchmark_suite/test_compile_kimivl.py::
+    test_step_compiles_for_v5e_fits_and_holds_no_scores` compiles the same
+    step; its re-pin (ROADMAP C1 (j)) brings this guard back into tier-1."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "0")
     compiled = lower_step(CELL, topo.devices[:1])
     need = device_bytes(compiled)

@@ -10,6 +10,8 @@ import os
 import re
 import sys
 
+import pytest
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 for p in (os.path.dirname(HERE), os.path.join(HERE, "benchmark_suite")):
     if p not in sys.path:
@@ -33,8 +35,12 @@ def _op_names(text, opcode):
     return found
 
 
+@pytest.mark.slow
 def test_the_kimi_step_holds_the_delta_rule_as_kernels_and_no_loop_of_it(
         topo, no_compile_cache, monkeypatch):     # noqa: F811
+    """Behind `slow`: `tests/benchmark_suite/test_compile_kimilinear.py::
+    test_step_compiles_for_v5e_fits_and_holds_no_history` compiles the same
+    step; its re-pin (ROADMAP C1 (j)) brings this guard back into tier-1."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "0")
     compiled = lower_step(CELL, topo.devices[:1])
     need = device_bytes(compiled)

@@ -405,19 +405,26 @@ def test_param_specs_equal_the_programs_parameters():
         assert str(have.dtype) == dtype, name
 
 
-def _reference_step(family, params, batch):
+def _reference_stepper(family):
+    """(params, batch) -> the reference's loss and gradient, row by row;
+    `reference_loss` is traced once here, and every row of every step runs
+    the one compiled walk."""
     from benchmark import reference
     mm = reference.matmul_at("float32")
-    with jax.default_matmul_precision("highest"):
-        want, grads = 0.0, None
-        for lo in range(2):
-            part, g = jax.value_and_grad(family.reference_loss)(
-                params, family.block_of(batch, lo, lo + 1), CONFIG,
-                TRAFFIC, mm)
-            want += float(part)
-            grads = g if grads is None else jax.tree_util.tree_map(
-                jnp.add, grads, g)
-    return want, grads
+    value_and_grad = jax.jit(jax.value_and_grad(
+        lambda p, blk: family.reference_loss(p, blk, CONFIG, TRAFFIC, mm)))
+
+    def step(params, batch):
+        with jax.default_matmul_precision("highest"):
+            want, grads = 0.0, None
+            for lo in range(2):
+                part, g = value_and_grad(
+                    params, family.block_of(batch, lo, lo + 1))
+                want += float(part)
+                grads = g if grads is None else jax.tree_util.tree_map(
+                    jnp.add, grads, g)
+        return want, grads
+    return step
 
 
 def test_loss_first_gradient_and_three_adam_steps_follow_the_reference():
@@ -446,12 +453,13 @@ def test_loss_first_gradient_and_three_adam_steps_follow_the_reference():
         scope.set_var(name, value)
     m1 = jax.tree_util.tree_map(jnp.zeros_like, params)
     m2 = jax.tree_util.tree_map(jnp.zeros_like, params)
+    reference_step = _reference_stepper(family)
     rng = weights.host_rng(17, 1)
     for step in range(3):
         batch = family.make_batch(CONFIG, TRAFFIC, rng)
         got = float(exe.run(main, feed=batch, fetch_list=[loss],
                             scope=scope)[0].reshape(-1)[0])
-        want, grads = _reference_step(family, params, batch)
+        want, grads = reference_step(params, batch)
         assert got == pytest.approx(want, rel=1e-5), step
         if step == 0:
             moments = {n.rpartition("_moment1_")[0]: scope.find_var(n)

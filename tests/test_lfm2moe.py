@@ -164,6 +164,9 @@ def test_three_adam_steps_follow_the_plain_reference():
     m1 = jax.tree_util.tree_map(jnp.zeros_like, params)
     m2 = jax.tree_util.tree_map(jnp.zeros_like, params)
     mm = reference.matmul_at("float32")
+    # jitted once: every row of every step runs the one compiled walk
+    value_and_grad = jax.jit(jax.value_and_grad(
+        lambda p, blk: family.reference_loss(p, blk, CONFIG, TRAFFIC, mm)))
     rng = weights.host_rng(17, 1)
     for step in range(3):
         batch = family.make_batch(CONFIG, TRAFFIC, rng)
@@ -172,9 +175,8 @@ def test_three_adam_steps_follow_the_plain_reference():
         with jax.default_matmul_precision("highest"):
             want, grads = 0.0, None
             for lo in range(2):
-                part, g = jax.value_and_grad(family.reference_loss)(
-                    params, family.block_of(batch, lo, lo + 1), CONFIG,
-                    TRAFFIC, mm)
+                part, g = value_and_grad(
+                    params, family.block_of(batch, lo, lo + 1))
                 want += float(part)
                 grads = g if grads is None else jax.tree_util.tree_map(
                     jnp.add, grads, g)

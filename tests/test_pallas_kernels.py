@@ -2,9 +2,9 @@
 every `pallas_call` carries a name of the program's choosing and that name
 reaches the jaxpr; the model heads emit the fused head op, and the op
 trains as the matmul + softmax_with_cross_entropy chain it replaced does.
-The kernels' own oracles are in test_flash_attention.py, test_flash_modes.py
-and test_selective_scan.py; which path a call takes, in
-test_kernel_choice.py.
+The kernels' own oracles are in test_flash_attention.py, test_flash_modes.py,
+test_flash_fused_backward.py and test_selective_scan.py; which path a call
+takes, in test_kernel_choice.py.
 """
 import os
 

@@ -31,9 +31,9 @@ def _inputs(b, t, e, n, dtype=jnp.float32, seed=0):
 
 
 def _value_and_grads(fn, args, w):
-    return jax.value_and_grad(
+    return jax.jit(jax.value_and_grad(
         lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
-        tuple(range(6)))(*args)
+        tuple(range(6))))(*args)
 
 
 def _oracle(x, delta, a, bm, c, d):
