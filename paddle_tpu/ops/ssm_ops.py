@@ -24,15 +24,28 @@ T / chunk_size states of (N, P) a head, carried in float32. Every decay is
 formed as e^{L_i - L_j} with i >= j: no `exp` of a positive number. T is
 padded to whole chunks with tokens of dt = 0 (decay 1, nothing written).
 
-The backward is one `jax.custom_vjp` (`_ssd`): it keeps x, dt, A, B, C and
-the state each chunk began with (no state a token, none of the (chunk,
-chunk) score matrices), and restarts from those states: the in-chunk part
-is pulled back by jax from the same functions the forward ran, the
-recurrence over chunks backwards by hand (lambda_c = dS0_c + a_c
-lambda_{c+1}). The decays, the cumulative sums and the state are float32;
-the matmuls take bfloat16 operands where the inputs are bfloat16 and give
-float32 results, as the flash and `kda_*` kernels do (any other dtype:
-float32 at HIGHEST). With obs on a lowering records `ssd.plan`.
+Two implementations of the one algorithm. On the TPU, at chunks of 128
+tokens, a state that is a multiple of 128 and a group whose heads stand as
+whole 128-lane tiles side by side, a call runs as the `ssd_fwd` / `ssd_bwd`
+Pallas kernels of `pallas/ssd.py`: the heads' states stay in VMEM across a
+sequential chunk axis and the (chunk, chunk) decay scores never reach HBM.
+What is written in THIS module (`_ssd`) is the XLA form: the path off the
+TPU and at shapes the kernels do not tile, and the oracle the kernels are
+tested against. Which of the two a call takes is decided from its own shapes
+and the platform (`kernel_plan`), and by nothing else; with obs on a
+lowering records `ssd.plan`, whose "kernels" line says which.
+
+Either backward is one `jax.custom_vjp`: it keeps x, dt, A, B, C and the
+state each chunk began with (no state a token, none of the (chunk, chunk)
+score matrices), and restarts from those states. In the XLA form the
+in-chunk part is pulled back by jax from the same functions the forward
+ran (under the scopes `ssd_states`, `ssd_outputs` and their `_back`s in a
+device trace), the recurrence over chunks backwards by hand (lambda_c =
+dS0_c + a_c lambda_{c+1}); `ssd_bwd` walks the chunks in reverse with
+lambda in VMEM and pulls each chunk back by hand. In both the decays, the
+cumulative sums and the state are float32; the matmuls take bfloat16
+operands where the inputs are bfloat16 and give float32 results, as the
+flash and `kda_*` kernels do (any other dtype: float32 at HIGHEST).
 
 Reference parity: none — the reference predates state-space layers; the
 equations are Gu & Dao 2023 (arXiv:2312.00752) section 3, Dao & Gu 2024 and
@@ -187,18 +200,35 @@ def _ssd_bwd(res, dy):
 _ssd.defvjp(_ssd_fwd, _ssd_bwd)
 
 
-def ssd_plan(x_shape, groups, state, chunk):
-    """What a call will do, for `ssd.plan`: Python ints and strings only."""
+def ssd_plan(x_shape, groups, state, chunk, kernels=None):
+    """What a call will do, for `ssd.plan`: Python ints and strings only.
+    `kernels` is `pallas/ssd.plan`'s answer where the call takes the Pallas
+    kernels (its "kernels" line then says so, with the heads a grid step
+    holds and its VMEM bytes); None is the XLA form."""
     b, t, h, p = x_shape
     chunks = -(-t // chunk)
-    return {"batch": b, "seq": t, "heads": h, "head_dim": p,
-            "groups": groups, "state": state, "chunk": chunk,
-            "chunks": chunks, "padded": chunks * chunk - t,
-            "state_bytes_kept": 4 * b * chunks * h * state * p,
-            "form": "xla: C B^T a group and chunk, masked decay scores "
-                    "times x, the chunks' own states, a lax.scan over the "
-                    "chunks' float32 states; custom_vjp from the states "
-                    "the chunks began with"}
+    out = {"batch": b, "seq": t, "heads": h, "head_dim": p,
+           "groups": groups, "state": state, "chunk": chunk,
+           "chunks": chunks, "padded": chunks * chunk - t,
+           "state_bytes_kept": 4 * b * chunks * h * state * p,
+           "kernels": "xla: C B^T a group and chunk, masked decay scores "
+                      "times x, the chunks' own states, a lax.scan over "
+                      "the chunks' float32 states; custom_vjp from the "
+                      "states the chunks began with"}
+    out.update(kernels or {})
+    return out
+
+
+def kernel_plan(x_shape, groups, state, chunk, itemsize):
+    """`pallas/ssd.plan` of the call where it takes the Pallas kernels: on
+    the TPU (`default_interpret` is false) at shapes they tile; else None,
+    the XLA form (`_ssd`). Decided from the call's shapes and the
+    platform, and by nothing else."""
+    from .pallas import ssd
+    from .pallas.interpret import default_interpret
+    if default_interpret():
+        return None
+    return ssd.plan(tuple(x_shape), groups, state, chunk, itemsize)
 
 
 def mamba2_scan(x, dt, dt_bias, a_log, b, c, d, chunk=128):
@@ -208,11 +238,13 @@ def mamba2_scan(x, dt, dt_bias, a_log, b, c, d, chunk=128):
     Returns y (B, T, H, P) in x's dtype."""
     bsz, t, h, p = x.shape
     groups, state = b.shape[2], b.shape[3]
+    kernels = kernel_plan(x.shape, groups, state, chunk,
+                          jnp.dtype(x.dtype).itemsize)
     from ..framework import obs
     if obs.enabled():
         now = obs.now()
-        obs.record("ssd.plan", now, now,
-                   **ssd_plan(tuple(x.shape), groups, state, chunk))
+        obs.record("ssd.plan", now, now, **ssd_plan(
+            tuple(x.shape), groups, state, chunk, kernels))
     step = jax.nn.softplus(dt.astype(_F32) + dt_bias.astype(_F32))
     a = -jnp.exp(a_log.astype(_F32))
     chunks = -(-t // chunk)
@@ -223,7 +255,12 @@ def mamba2_scan(x, dt, dt_bias, a_log, b, c, d, chunk=128):
             m = jnp.pad(m, ((0, 0), (0, pad)) + ((0, 0),) * (m.ndim - 2))
         return m.reshape((bsz, chunks, chunk) + m.shape[2:])
 
-    y = _ssd(cut(x), cut(step), a, cut(b), cut(c))
+    operands = (cut(x), cut(step), a, cut(b), cut(c))
+    if kernels:
+        from .pallas import ssd
+        y = ssd.ssd(*operands)
+    else:
+        y = _ssd(*operands)
     y = y.reshape(bsz, chunks * chunk, h, p)[:, :t]
     y = y + d.astype(_F32)[None, None, :, None] * x.astype(_F32)
     return y.astype(x.dtype)

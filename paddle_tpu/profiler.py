@@ -77,7 +77,8 @@ def print_kernel_plans():
     or per-token form, operand dtype), `kda.plan` (the delta rule: chunk,
     sub-block, chunks a group, heads, which kernels), `ssd.plan` (the
     Mamba-2 scan: heads, groups, state, chunk, chunks, padding, the states
-    the backward keeps) and `moe_gmm.plan`
+    the backward keeps, which kernels: `ssd_fwd` / `ssd_bwd` with the heads
+    a grid step holds and their VMEM, or the XLA form) and `moe_gmm.plan`
     (a grouped matmul: each kernel's tiles, grid, modelled HBM bytes and
     their ratio to the least, VMEM)."""
     for name in PLAN_RECORDS:

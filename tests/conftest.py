@@ -60,6 +60,7 @@ LONGEST_FIRST = (
     "test_lfm2moe.py",
     "test_kda_kernels.py",
     "test_detection_ops.py",
+    "test_ssd_kernels.py",
 )
 
 

@@ -140,6 +140,41 @@ def test_the_ops_are_registered_with_shape_rules_and_a_plan_record():
     assert "ssd.plan" in profiler.PLAN_RECORDS
 
 
+def test_the_plan_record_names_the_kernels_where_a_tpu_call_takes_them(
+        monkeypatch):
+    """Traced as a TPU process would trace it (nothing is lowered): at a
+    shape the kernels tile, `ssd.plan` keeps its keys and gains the
+    kernels' line, the heads a grid step holds and its VMEM bytes; off the
+    TPU the same call records the XLA form."""
+    args, _w = _scan_inputs(300, h=8, p=64, g=2, n=128)
+    low = [a.astype(jnp.bfloat16) if a.ndim == 4 else a for a in args]
+
+    def plan_of():
+        obs.clear()
+        obs.enable()
+        try:
+            out = jax.eval_shape(      # a fresh function: traced anew
+                lambda *a: ssm_ops.mamba2_scan(*a), *low)
+            return out, obs.spans(name="ssd.plan")[0]["labels"]
+        finally:
+            obs.disable()
+            obs.clear()
+
+    out, off = plan_of()
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "0")
+    same, on = plan_of()
+    assert out == same and out.shape == (2, 300, 8, 64)
+    assert off["kernels"].startswith("xla: ")
+    assert on["kernels"].startswith("pallas: ssd_fwd, ssd_bwd; ")
+    assert {k: v for k, v in on.items() if k in off and k != "kernels"} \
+        == {k: v for k, v in off.items() if k != "kernels"}
+    assert (on["chunks"], on["padded"], on["state_bytes_kept"]) \
+        == (3, 84, 4 * 2 * 3 * 8 * 128 * 64)
+    assert on["heads_a_step"] == 4
+    assert 0 < on["vmem_fwd"] < on["vmem_bwd"] < 2 ** 27
+    assert set(on) - set(off) == {"heads_a_step", "vmem_fwd", "vmem_bwd"}
+
+
 def test_the_gate_norm_gates_first_then_norms_each_group():
     rng = np.random.default_rng(1)
     x, z = (rng.standard_normal((2, 5, 32)).astype(np.float32)
