@@ -34,13 +34,21 @@ def scaled_dot_product_attention(queries, keys, values, num_heads=1,
 
 
 def fused_attention(q, k, v, mask=None, scale=None, causal=False,
-                    impl="auto", sp_axis="sp", name=None, window=None):
+                    impl="auto", sp_axis="sp", name=None, window=None,
+                    block_diffusion=None):
     """q: (B, Hq, T, Dh), k: (B, Hkv, T, Dh), v: (B, Hkv, T, Dv) — one
     fused op; Pallas flash path when available. Reference composes this
     from matmul+softmax+matmul ops. Hq may be any whole multiple of Hkv
     (query head h reads kv head h // (Hq // Hkv)), Dv may differ from Dh
     (the output is Dv wide), and `window=W` with `causal=True` lets query
-    t see keys s with t - W < s <= t.
+    t see keys s with t - W < s <= t. `block_diffusion=(L, T)` (Python
+    ints; 2T rows; instead of `causal`, `window` or `mask`) is the
+    block-diffusion training mask over a noisy copy (rows 0..T-1) and a
+    clean copy (rows T..2T-1) of a T-token document in blocks of L: a clean
+    query sees the clean keys of its own and earlier blocks, a noisy query
+    the noisy keys of its own block and the clean keys of strictly earlier
+    blocks. The flash kernels compute it from row indices and skip the
+    tiles nobody sees.
 
     impl: "auto" | "xla" | "flash" | "ring" | "ulysses" — the last two
     run sequence-parallel attention over the installed mesh's `sp_axis`:
@@ -53,20 +61,25 @@ def fused_attention(q, k, v, mask=None, scale=None, causal=False,
     inputs = {"Q": [q.name], "K": [k.name], "V": [v.name]}
     if mask is not None:
         inputs["Mask"] = [mask.name]
+    attrs = {"scale": scale, "causal": causal, "impl": impl,
+             "sp_axis": sp_axis, "window": window}
+    if block_diffusion is not None:     # an op without the rule has no attr
+        attrs["block_diffusion"] = [int(n) for n in block_diffusion]
     helper.append_op("scaled_dot_product_attention", inputs=inputs,
-                     outputs={"Out": [out.name]},
-                     attrs={"scale": scale, "causal": causal, "impl": impl,
-                            "sp_axis": sp_axis, "window": window})
+                     outputs={"Out": [out.name]}, attrs=attrs)
     return out
 
 
 def rope_qk_norm(q, k, head_dim, theta=10000.0, epsilon=1e-5,
-                 q_norm_attr=None, k_norm_attr=None, name=None):
+                 q_norm_attr=None, k_norm_attr=None, name=None,
+                 position_period=None):
     """q (B, T, Hq*D), k (B, T, Hkv*D) -> (q', k') head-major, (B, H, T, D):
     an RMS norm over each head's D numbers with a learned float32 scale of
     D (`*_norm_attr=False`: no norm), then rotary positions over the whole
     head (pairs (i, i + D/2), base `theta`), in one elementwise op in
-    front of `fused_attention`."""
+    front of `fused_attention`. Row t turns by t, or with
+    `position_period=P` by t mod P (copies of a document side by side,
+    each counted from 0)."""
     from ..initializer import ConstantInitializer
     helper = LayerHelper("rope_qk_norm", name=name)
     inputs = {"Q": [q.name], "K": [k.name]}
@@ -86,11 +99,13 @@ def rope_qk_norm(q, k, head_dim, theta=10000.0, epsilon=1e-5,
         width = x.shape[2]
         outs.append(helper.create_variable_for_type_inference(
             x.dtype, (x.shape[0], width // head_dim, x.shape[1], head_dim)))
+    attrs = {"head_dim": int(head_dim), "theta": float(theta),
+             "epsilon": float(epsilon)}
+    if position_period is not None:     # an op without it has no attr
+        attrs["position_period"] = int(position_period)
     helper.append_op("rope_qk_norm", inputs=inputs,
                      outputs={"QOut": [outs[0].name],
-                              "KOut": [outs[1].name]},
-                     attrs={"head_dim": int(head_dim), "theta": float(theta),
-                            "epsilon": float(epsilon)})
+                              "KOut": [outs[1].name]}, attrs=attrs)
     return outs[0], outs[1]
 
 

@@ -13,8 +13,8 @@ LARK/ERNIE repos, rebuilt on paddle_tpu layers).
 - gpt: GPT-style causal LM (long-context flagship: flash/ring/ulysses
   attention, greedy_generate decode)
 - dcgan: DCGAN adversarial training as one fused two-optimizer step
-- phi4flash, lfm2moe, kimi_linear, smallthinker, kimi_vl, nemotron_h: hybrid
-  decoders the benchmark trains (selective scan + differential attention;
+- phi4flash, lfm2moe, kimi_linear, smallthinker, kimi_vl, nemotron_h,
+  sdar_moe: decoders the benchmark trains (selective scan + differential attention;
   short convolutions + sparse experts; delta-rule linear attention + latent
   attention + sparse experts with a shared expert; window and full
   grouped-query attention 3:1 + sparse experts routed ahead of attention,
@@ -23,8 +23,12 @@ LARK/ERNIE repos, rebuilt on paddle_tpu layers).
   text decoder of Kimi-VL, no vision tower; Mamba-2 layers, non-gated
   relu^2 experts with a shared expert and position-free grouped-query
   attention, each layer one block alone: the `nemotron_h` stack, no
-  denoiser tower); moe_decoder holds the blocks and frames kimi_linear,
-  kimi_vl and nemotron_h share
+  denoiser tower; grouped-query attention with q/k norms over a 128-wide
+  softmax router with 8 picks, trained as a block-diffusion model: a noisy
+  and a clean copy of a document in one pass under the block-diffusion
+  mask, a masked-denoising loss over the noisy half, no generation loop);
+  moe_decoder holds the blocks and frames kimi_linear, kimi_vl, nemotron_h
+  and sdar_moe share
 """
 from . import bert
 from . import resnet
@@ -43,3 +47,4 @@ from . import kimi_linear
 from . import smallthinker
 from . import kimi_vl
 from . import nemotron_h
+from . import sdar_moe

@@ -1,15 +1,16 @@
-"""What the decoders over sparse experts with a shared expert share
-(`models/kimi_linear.py`, `models/kimi_vl.py`, `models/nemotron_h.py`): the
+"""What the decoders over sparse experts share (`models/kimi_linear.py`,
+`models/kimi_vl.py`, `models/nemotron_h.py`, `models/sdar_moe.py`): the
 blocks behind a layer's mixer and the frame around the layers. A model
 brings its config, its mixer and a prefix for its parameter names; `cfg` is
 read for `hidden_size`, `vocab_size`, `norm_eps`, `initializer_range`,
 `dtype`, `recompute`, `num_layers`, and for the expert layer `moe_ff_size`,
 `num_experts`, `top_k`, `num_shared_experts`, `experts_held`,
 `norm_topk_prob`, `routed_scaling_factor`, `expert_bias_update_rate` and,
-where it has them, `absent_picks`, `expert_act` ("relu2": the experts and
-the shared expert are NOT gated, W_d(relu(W_u u)^2); default the gated
-silu form) and `shared_ff_size` (the shared expert's width where that is
-no multiple of the experts').
+where it has them, `absent_picks`, `scoring` ("softmax": the top-k of the
+logits, weights the softmax over the picks' logits, no expert bias; default
+"sigmoid"), `expert_act` ("relu2": the experts and the shared expert are NOT
+gated, W_d(relu(W_u u)^2); default the gated silu form) and `shared_ff_size`
+(the shared expert's width where that is no multiple of the experts').
 
 Two frames. `layer` (the default; the Kimis'): every layer is
 `h = x + mixer(RMSNorm(x)); y = h + FFN(RMSNorm(h))`, the FFN a dense gated
@@ -66,7 +67,8 @@ def relu2_mlp(u, width, cfg, name):
 def expert_ffn(u, cfg, name):
     """(shared(u) + the held experts' part (B,T,d), load): a sigmoid router
     over all `num_experts`, top `top_k` of scores + bias, the picks' scores
-    over their sum, times `routed_scaling_factor`; `num_shared_experts`
+    over their sum, times `routed_scaling_factor` (under `cfg.scoring`
+    "softmax" `moe_ffn`'s softmax form); `num_shared_experts`
     shared experts side by side are ONE MLP of their widths' sum (the same
     products; `cfg.shared_ff_size` where the config states that width),
     every token, added unweighted. Experts and shared expert are gated silu
@@ -83,6 +85,7 @@ def expert_ffn(u, cfg, name):
                                          else "_experts_gate_up")),
         down_attr=weight(cfg, name + "_experts_down"), name=name,
         absent=getattr(cfg, "absent_picks", "nothing"),
+        scoring=getattr(cfg, "scoring", "sigmoid"),
         gate="relu2" if plain else "silu")
     out = layers.reshape(out, [-1, u.shape[1], cfg.hidden_size])
     if cfg.num_shared_experts:

@@ -17,16 +17,19 @@ from .registry import register_op, register_shape_rule
 from .shape_rules import TensorMeta, _x
 
 
-def _sdpa_xla(q, k, v, mask, scale, causal, window=None):
+def _sdpa_xla(q, k, v, mask, scale, causal, window=None,
+              block_diffusion=None):
     # q: (B, Hq, Tq, D), k: (B, Hkv, Tk, D), v: (B, Hkv, Tk, Dv): the same
-    # grouped heads, sliding window and value width as the flash kernels
+    # grouped heads, sliding window, block-diffusion rule and value width
+    # as the flash kernels
     from .pallas.flash_attention import _repeat_kv, visible_mask
     k, v = _repeat_kv(q, k, v)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
-    if causal:
+    if causal or block_diffusion is not None:
         tq, tk = logits.shape[-2], logits.shape[-1]
-        logits = jnp.where(visible_mask(tq, tk, window), logits, -1e30)
+        logits = jnp.where(visible_mask(tq, tk, window, block_diffusion),
+                           logits, -1e30)
     if mask is not None:
         logits = logits + mask.astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -38,6 +41,11 @@ def _sdpa_xla(q, k, v, mask, scale, causal, window=None):
 #: tells a window layer's flash calls from a full-attention layer's:
 #: `.../scaled_dot_product_attention/window_attention/flash_fwd/pallas_call`
 WINDOW_SCOPE = "window_attention"
+
+
+#: the inner scope a BLOCK-DIFFUSION call is lowered under, for the same
+#: reason: `.../block_diffusion_attention/flash_fwd/pallas_call`
+BLOCK_DIFFUSION_SCOPE = "block_diffusion_attention"
 
 
 #: what the op's `impl` attr (`layers.fused_attention(impl=)`, a model
@@ -54,10 +62,12 @@ def _sdpa(ctx, ins, attrs):
         scale = 1.0 / (q.shape[-1] ** 0.5)
     causal = attrs.get("causal", False)
     window = attrs.get("window", None)
+    bd = attrs.get("block_diffusion", None)
+    bd = None if bd is None else (int(bd[0]), int(bd[1]))
     from .pallas.flash_attention import (attention_path, check_call,
                                          flash_attention)
-    check_call(q.shape, k.shape, v.shape, causal, window)
-    plain = (window is None and q.shape[1] == k.shape[1]
+    check_call(q.shape, k.shape, v.shape, causal, window, bd, mask)
+    plain = (window is None and bd is None and q.shape[1] == k.shape[1]
              and v.shape[-1] == q.shape[-1])
     impl = attrs.get("impl", "auto")
     if impl not in IMPLS:
@@ -67,8 +77,8 @@ def _sdpa(ctx, ins, attrs):
         if not plain:
             raise ValueError(
                 "fused_attention(impl=%r) runs equal heads, equal widths "
-                "and no window; grouped heads, a window or Dv != D need "
-                "impl 'auto', 'flash' or 'xla'" % impl)
+                "and no window; grouped heads, a window, block_diffusion "
+                "or Dv != D need impl 'auto', 'flash' or 'xla'" % impl)
         # sequence-parallel attention over the installed mesh's sp axis —
         # the declarative (static-graph) route to the long-context paths
         # in distributed/{ring,ulysses}_attention.py
@@ -94,7 +104,8 @@ def _sdpa(ctx, ins, attrs):
         return {"Out": ulysses_attention(q, k, v, mask=mask, mesh=mesh,
                                          axis_name=axis, causal=causal,
                                          scale=scale)}
-    with (contextlib.nullcontext() if window is None
+    with (jax.named_scope(BLOCK_DIFFUSION_SCOPE) if bd is not None
+          else contextlib.nullcontext() if window is None
           else jax.named_scope(WINDOW_SCOPE)):
         if impl != "xla":
             # `attention_path` decides from the call's shapes. Its "short"
@@ -104,20 +115,24 @@ def _sdpa(ctx, ins, attrs):
             from .pallas.interpret import default_interpret
             if attention_path(q.shape, k.shape, v.shape, q.dtype, causal,
                               window, default_interpret(),
-                              auto=impl == "auto").why != "short":
+                              auto=impl == "auto",
+                              block_diffusion=bd).why != "short":
                 return {"Out": flash_attention(
                     q, k, v, mask=mask, scale=scale, causal=causal,
-                    window=window)}
-        return {"Out": _sdpa_xla(q, k, v, mask, scale, causal, window)}
+                    window=window, block_diffusion=bd)}
+        return {"Out": _sdpa_xla(q, k, v, mask, scale, causal, window, bd)}
 
 
-def rotate_half(x, theta):
+def rotate_half(x, theta, position_period=None):
     """Rotary positions over the whole head of x (..., T, D), position t at
-    row t: pairs (i, i + D/2) turn by t * theta^(-2i/D). float32 in and
-    out."""
+    row t (t mod `position_period` where one is given: several copies of a
+    document side by side, each counted from 0): pairs (i, i + D/2) turn
+    by t * theta^(-2i/D). float32 in and out."""
     t, d = x.shape[-2], x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    pos = jnp.arange(t) if position_period is None \
+        else jnp.arange(t) % int(position_period)
+    angle = pos.astype(jnp.float32)[:, None] * inv[None, :]
     cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
     sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
     x1, x2 = jnp.split(x, 2, axis=-1)
@@ -131,9 +146,11 @@ def _rope_qk_norm(ctx, ins, attrs):
     KScale: (D,); left out where the slot is empty), then rotary positions
     (`rotate_half`), one elementwise pass in float32. Q (B, T, Hq*D) and
     K (B, T, Hkv*D) come back head-major, (B, H, T, D), in their own dtype:
-    the layout the attention op reads."""
+    the layout the attention op reads. With the attr `position_period` = P
+    row t turns by t mod P."""
     theta, eps = float(attrs["theta"]), float(attrs.get("epsilon", 1e-5))
     d = int(attrs["head_dim"])
+    period = attrs.get("position_period", None)
 
     def one(x, scale):
         b, t, width = x.shape
@@ -143,7 +160,7 @@ def _rope_qk_norm(ctx, ins, attrs):
             xf = xf * jax.lax.rsqrt(
                 jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps) \
                 * scale[0].astype(jnp.float32)
-        return rotate_half(xf, theta).astype(x.dtype)
+        return rotate_half(xf, theta, period).astype(x.dtype)
 
     return {"QOut": one(ins["Q"][0], ins.get("QScale")),
             "KOut": one(ins["K"][0], ins.get("KScale"))}

@@ -169,6 +169,95 @@ def _window_last_q_block(kj, causal_offset, block_q, block_k, window, nq):
     return min(hi, nq - 1) if isinstance(hi, int) else jnp.minimum(hi, nq - 1)
 
 
+def _div(x, n):
+    """x // n for x >= 0, Python ints and int32 arrays alike: a shift where
+    n is a power of two (the TPU's vector unit has no integer divide)."""
+    return x >> (n.bit_length() - 1) if n & (n - 1) == 0 else x // n
+
+
+def _bd_tile_blocks(i, block, bd):
+    """Of tile `i` of `block` rows among the 2T rows [noisy | clean] of a
+    block-diffusion call (`bd` = (L, T); tiles divide T, so a tile lies in
+    one half): (whether it is of the noisy half, the first and the last
+    diffusion block its rows belong to; positions count from 0 in each
+    half). Python ints and traced scalars alike."""
+    length, t = bd
+    row = i * block
+    pos = row % t
+    return row < t, pos // length, (pos + block - 1) // length
+
+
+def _bd_tile_visible(qi, kj, block_q, block_k, bd):
+    """Whether any query of q-tile `qi` sees any key of k-tile `kj` under
+    the block-diffusion rule (`_block_diffusion_keep`). Python ints (the
+    plan's count) and traced scalars (the kernels' `pl.when`) alike."""
+    q_noisy, q_lo, q_hi = _bd_tile_blocks(qi, block_q, bd)
+    k_noisy, k_lo, k_hi = _bd_tile_blocks(kj, block_k, bd)
+    q_clean, k_clean = qi * block_q >= bd[1], kj * block_k >= bd[1]
+    return ((q_noisy & k_noisy & (k_lo <= q_hi) & (q_lo <= k_hi))
+            | (q_noisy & k_clean & (k_lo < q_hi))
+            | (q_clean & k_clean & (k_lo <= q_hi)))
+
+
+def _block_diffusion_keep(qi, kj, block_q, block_k, length, t):
+    """Bool (BQ, BK) tile of the block-diffusion mask over 2T rows, the
+    noisy copy first: row r has position r mod T and block position // L;
+    a clean query sees the clean keys of its own and earlier blocks, a
+    noisy query the noisy keys of its own block and the clean keys of
+    strictly earlier blocks, and no clean query a noisy key. From the
+    tile's indices alone: the blocks of the tile's rows as a column, of its
+    keys as a row (one shift each where L is a power of two), and the
+    range of key blocks a row sees, [own - below, own - above], which the
+    tile's two halves set as scalars."""
+    q_blk = _div(qi * block_q % t + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, 1), 0), length)
+    k_blk = _div(kj * block_k % t + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_k), 1), length)
+    q_noisy, k_noisy = qi * block_q < t, kj * block_k < t
+    none = 2 * t                # more than any block's index
+    above = jnp.where(k_noisy, jnp.where(q_noisy, 0, none),
+                      jnp.where(q_noisy, 1, 0))
+    below = jnp.where(k_noisy & q_noisy, 0, none)
+    return (k_blk <= q_blk - above) & (k_blk >= q_blk - below)
+
+
+def _bd_nearest_k(qi, kj, block_q, block_k, bd):
+    """The k-block a (qi, kj) grid step of the forward and dQ kernels
+    names under the block-diffusion rule: kj where the tile runs, else the
+    nearest k-block of row `qi` whose tile does, so that a step with no
+    work fetches nothing. A noisy row's tiles are its diagonal ones in the
+    noisy half and the clean tiles that begin before its last block; a
+    clean row's the clean tiles up to its own."""
+    length, t = bd
+    half = t // block_k
+    noisy, q_lo, q_hi = _bd_tile_blocks(qi, block_q, bd)
+    d_lo = q_lo * length // block_k
+    d_hi = ((q_hi + 1) * length - 1) // block_k
+    seen = jnp.where(noisy, (q_hi * length + block_k - 1) // block_k,
+                     d_hi + 1)              # clean k-tiles the row sees
+    diagonal = jnp.clip(kj, d_lo, d_hi)
+    clean = jnp.minimum(jnp.maximum(kj, half), half + seen - 1)
+    return jnp.where(noisy & ((kj < half) | (seen == 0)), diagonal, clean)
+
+
+def _bd_nearest_q(kj, qi, block_q, block_k, bd):
+    """`_bd_nearest_k`'s counterpart for the dK/dV kernels, whose innermost
+    axis walks q-blocks: a noisy key tile is seen by its diagonal q-tiles
+    of the noisy half alone, a clean one by the noisy q-tiles that end
+    after its first block and by the clean q-tiles from its own on."""
+    length, t = bd
+    half = t // block_q
+    noisy, k_lo, k_hi = _bd_tile_blocks(kj, block_k, bd)
+    e_lo = k_lo * length // block_q
+    e_hi = ((k_hi + 1) * length - 1) // block_q
+    first_noisy = (k_lo + 1) * length // block_q
+    clean = jnp.maximum(qi, half + e_lo)
+    return jnp.where(
+        noisy, jnp.clip(qi, e_lo, e_hi),
+        jnp.where((qi < half) & (first_noisy < half),
+                  jnp.maximum(qi, first_noisy), clean))
+
+
 def window_grid(tq, tk, block_q, block_k, window, kj_innermost):
     """Length of the innermost grid axis of a windowed kernel: the most
     blocks any tile row (kj_innermost: a q-block's k-blocks; else a
@@ -185,12 +274,17 @@ def window_grid(tq, tk, block_q, block_k, window, kj_innermost):
 
 
 def _for_visible_tile(body, qi, kj, *, causal, causal_offset, block_q,
-                      block_k, last_q=None):
+                      block_k, last_q=None, block_diffusion=None):
     """Run `body` unless the (qi, kj) tile lies wholly above the causal
     diagonal (no query of the tile sees any of its keys). Under a window
     the grid starts each row at its first visible block, so only the far
     end is left to test: in the dK/dV kernel that is `last_q`, the
-    k-block's last q-block."""
+    k-block's last q-block. Under the block-diffusion rule the grid is the
+    whole square and `_bd_tile_visible` says which of its tiles run."""
+    if block_diffusion is not None:
+        pl.when(_bd_tile_visible(qi, kj, block_q, block_k,
+                                 block_diffusion))(body)
+        return
     if not causal:
         body()
         return
@@ -202,7 +296,7 @@ def _for_visible_tile(body, qi, kj, *, causal, causal_offset, block_q,
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
               qi, kj, *, scale, causal, causal_offset, block_q, block_k,
-              mask_mode, precision, window=None):
+              mask_mode, precision, window=None, block_diffusion=None):
     """Recompute the probability tile p = exp(s - m) / l — the forward's
     own normalization — and the logit cotangent ds = p * (dO V^T - delta)
     from the forward residuals: the shared core of both backward
@@ -227,6 +321,9 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
     if causal:
         p = jnp.where(_keep_tile(qi, kj, causal_offset, block_q, block_k,
                                  window), p, 0.0)
+    if block_diffusion is not None:
+        p = jnp.where(_block_diffusion_keep(qi, kj, block_q, block_k,
+                                            *block_diffusion), p, 0.0)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -237,7 +334,8 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
                 m_ref, l_ref, *, scale, causal, causal_offset, block_q,
-                block_k, mask_mode, precision, window=None):
+                block_k, mask_mode, precision, window=None,
+                block_diffusion=None):
     qi = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -267,6 +365,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
             # tril(..., tk - tq)): query i sees keys j <= i + (tk - tq)
             s = jnp.where(_keep_tile(qi, kj, causal_offset, block_q,
                                      block_k, window), s, _NEG_INF)
+        if block_diffusion is not None:
+            # a row no key of which lies in this tile gathers weights of 1
+            # here; the first tile that holds one of its keys scales them
+            # to nothing (corr = exp(-1e30 - m)), and every row has a key
+            s = jnp.where(_block_diffusion_keep(
+                qi, kj, block_q, block_k, *block_diffusion), s, _NEG_INF)
 
         m_prev = m_ref[:, :1]                      # (BQ, 1)
         m_blk = jnp.max(s, axis=-1, keepdims=True)
@@ -284,7 +388,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
 
     _for_visible_tile(body, qi, kj, causal=causal,
                       causal_offset=causal_offset, block_q=block_q,
-                      block_k=block_k)
+                      block_k=block_k, block_diffusion=block_diffusion)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -300,7 +404,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
 
 def _seq_specs(h, d, mask_mode, causal, causal_offset, block_q, block_k,
                kj_innermost, dv=None, group=1, window=None, n_inner=None,
-               nq=None):
+               nq=None, block_diffusion=None):
     """BlockSpecs of one kernel: `q_spec` for what is tiled along the
     queries at the q/k width (q, dq: (bh, block_q, D)), `row_spec` for the
     per-row statistics ((bh, 8, block_q)), `k_spec` for k and dk,
@@ -315,10 +419,14 @@ def _seq_specs(h, d, mask_mode, causal, causal_offset, block_q, block_k,
     last. With `group` query heads to a kv head, the first grid axis is
     over query heads where kj_innermost (the kv row is bb // group) and
     over kv heads for dK/dV, whose innermost axis then walks the group's
-    heads, `n_inner` q-blocks each. `h` is the heads of the first axis."""
+    heads, `n_inner` q-blocks each. `h` is the heads of the first axis.
+    Under `block_diffusion` a step whose tile no query sees names the
+    nearest block of its row that one does (`_bd_nearest_k` / `_q`)."""
     if kj_innermost:
         def ij(a, b_):
-            if window is not None:
+            if block_diffusion is not None:
+                b_ = _bd_nearest_k(a, b_, block_q, block_k, block_diffusion)
+            elif window is not None:
                 b_ = jnp.minimum(
                     b_ + _window_first_k_block(a, causal_offset, block_q,
                                                block_k, window),
@@ -331,7 +439,9 @@ def _seq_specs(h, d, mask_mode, causal, causal_offset, block_q, block_k,
         def ij(a, b_):
             if group != 1:
                 b_ = b_ % n_inner
-            if window is not None:
+            if block_diffusion is not None:
+                b_ = _bd_nearest_q(a, b_, block_q, block_k, block_diffusion)
+            elif window is not None:
                 b_ = jnp.minimum(
                     b_ + _first_q_block(a, causal_offset, block_q, block_k),
                     _window_last_q_block(a, causal_offset, block_q, block_k,
@@ -466,13 +576,17 @@ def _compiler_params(kernel, block_q, block_k, d, dtype, mask_mode,
     return pltpu.CompilerParams(vmem_limit_bytes=limit)
 
 
-def _window_kwargs(window):
-    """The kernels' `window` keyword, left out where there is none."""
-    return {} if window is None else {"window": window}
+def _rule_kwargs(window, block_diffusion):
+    """The kernels' `window` and `block_diffusion` keywords, each left out
+    where there is none."""
+    extra = {} if window is None else {"window": window}
+    if block_diffusion is not None:
+        extra["block_diffusion"] = tuple(block_diffusion)
+    return extra
 
 
 def _pallas_forward(q, k, v, mask, scale, causal, block_q, block_k,
-                    interpret, window=None):
+                    interpret, window=None, block_diffusion=None):
     if not _HAS_TPU_PALLAS:
         raise NotImplementedError("pallas tpu backend unavailable")
     b, h, tq, d = q.shape
@@ -485,7 +599,8 @@ def _pallas_forward(q, k, v, mask, scale, causal, block_q, block_k,
     mask_mode = _mask_mode(mask)
     q_spec, row_spec, k_spec, mask_spec, o_spec, v_spec = _seq_specs(
         h, d, mask_mode, causal, tk - tq, block_q, block_k,
-        kj_innermost=True, dv=dv, group=h // hkv, window=window)
+        kj_innermost=True, dv=dv, group=h // hkv, window=window,
+        block_diffusion=block_diffusion)
     nk = tk // block_k if window is None else window_grid(
         tq, tk, block_q, block_k, window, True)
     out, stats = pl.pallas_call(
@@ -493,7 +608,7 @@ def _pallas_forward(q, k, v, mask, scale, causal, block_q, block_k,
                           causal_offset=tk - tq, block_q=block_q,
                           block_k=block_k, mask_mode=mask_mode,
                           precision=_dot_precision(q.dtype),
-                          **_window_kwargs(window)),
+                          **_rule_kwargs(window, block_diffusion)),
         grid=(bh, tq // block_q, nk),
         in_specs=[q_spec, k_spec, v_spec, mask_spec],
         out_specs=[o_spec, row_spec],
@@ -528,7 +643,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
                     mask_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale, causal, causal_offset, block_q, block_k,
                     mask_mode, precision, window=None, n_inner=None,
-                    nq=None):
+                    nq=None, block_diffusion=None):
     """dK/dV for one k-block, accumulating over q-blocks (innermost grid
     dim; with grouped heads over the group's heads x their `n_inner`
     q-blocks). Recomputes p from the residuals — no (T,T) in HBM."""
@@ -553,12 +668,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
             qi, kj, scale=scale, causal=causal,
             causal_offset=causal_offset, block_q=block_q,
             block_k=block_k, mask_mode=mask_mode, precision=precision,
-            **_window_kwargs(window))
+            **_rule_kwargs(window, block_diffusion))
         _add_dkv(dk_acc, dv_acc, q, do, p, ds, scale, precision)
 
     _for_visible_tile(body, qi, kj, causal=causal,
                       causal_offset=causal_offset, block_q=block_q,
-                      block_k=block_k, last_q=last_q)
+                      block_k=block_k, last_q=last_q,
+                      block_diffusion=block_diffusion)
 
     @pl.when(i == nq_steps - 1)
     def _finalize():
@@ -569,7 +685,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
                    mask_ref, dq_ref, dq_acc, *, scale, causal,
                    causal_offset, block_q, block_k, mask_mode, precision,
-                   window=None):
+                   window=None, block_diffusion=None):
     qi = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -586,14 +702,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
             qi, kj, scale=scale, causal=causal,
             causal_offset=causal_offset, block_q=block_q,
             block_k=block_k, mask_mode=mask_mode, precision=precision,
-            **_window_kwargs(window))
+            **_rule_kwargs(window, block_diffusion))
         dq_acc[:] = dq_acc[:] + scale * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
 
     _for_visible_tile(body, qi, kj, causal=causal,
                       causal_offset=causal_offset, block_q=block_q,
-                      block_k=block_k)
+                      block_k=block_k, block_diffusion=block_diffusion)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -603,7 +719,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale,
                 causal, causal_offset, block_q, block_k, mask_mode,
-                precision):
+                precision, block_diffusion=None):
     """The fused backward: p and ds of a tile are formed once and feed all
     three gradients. Grid (bh, k-blocks, q-blocks) as dK/dV's, whose
     accumulators it keeps; dQ is summed in `dq_acc`, a float32 scratch of
@@ -628,7 +744,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
             q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
             qi, kj, scale=scale, causal=causal,
             causal_offset=causal_offset, block_q=block_q,
-            block_k=block_k, mask_mode=mask_mode, precision=precision)
+            block_k=block_k, mask_mode=mask_mode, precision=precision,
+            block_diffusion=block_diffusion)
         _add_dkv(dk_acc, dv_acc, q, do, p, ds, scale, precision)
         # dq[q-block] += scale * ds k
         dq_acc[qi] = dq_acc[qi] + scale * jax.lax.dot_general(
@@ -637,7 +754,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
 
     _for_visible_tile(body, qi, kj, causal=causal,
                       causal_offset=causal_offset, block_q=block_q,
-                      block_k=block_k)
+                      block_k=block_k, block_diffusion=block_diffusion)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -667,7 +784,8 @@ def _bwd_inputs(q, k, v, mask, out, stats, g):
 
 
 def _bwd_call(kernel, which, operands, h, mask_mode, scale, causal, block_q,
-              block_k, interpret, kj_innermost, window=None):
+              block_k, interpret, kj_innermost, window=None,
+              block_diffusion=None):
     """What the backward kernels' pallas_calls share (`which`: the
     kernel's name in KERNELS or FUSED_KERNELS): the kernel with its
     parameters and the keyword arguments for the seven operands (q, k, v,
@@ -686,8 +804,8 @@ def _bwd_call(kernel, which, operands, h, mask_mode, scale, causal, block_q,
     q_spec, row_spec, k_spec, mask_spec, o_spec, v_spec = _seq_specs(
         h if kj_innermost else h // group, d, mask_mode, causal, tk - tq,
         block_q, block_k, kj_innermost, dv=dv, group=group, window=window,
-        n_inner=n_q, nq=nq)
-    extra = _window_kwargs(window)
+        n_inner=n_q, nq=nq, block_diffusion=block_diffusion)
+    extra = _rule_kwargs(window, block_diffusion)
     if not kj_innermost:
         if window is not None:
             extra["nq"] = nq
@@ -708,10 +826,11 @@ def _bwd_call(kernel, which, operands, h, mask_mode, scale, causal, block_q,
 
 
 def _pallas_bwd_dkv(operands, h, mask_mode, scale, causal, block_q, block_k,
-                    interpret, window=None):
+                    interpret, window=None, block_diffusion=None):
     body, common, _, k_spec, v_spec = _bwd_call(
         _bwd_dkv_kernel, "bwd_dkv", operands, h, mask_mode, scale, causal,
-        block_q, block_k, interpret, kj_innermost=False, window=window)
+        block_q, block_k, interpret, kj_innermost=False, window=window,
+        block_diffusion=block_diffusion)
     k3, v3 = operands[1:3]
     return pl.pallas_call(
         body,
@@ -726,10 +845,11 @@ def _pallas_bwd_dkv(operands, h, mask_mode, scale, causal, block_q, block_k,
 
 
 def _pallas_bwd_dq(operands, h, mask_mode, scale, causal, block_q, block_k,
-                   interpret, window=None):
+                   interpret, window=None, block_diffusion=None):
     body, common, q_spec, _, _ = _bwd_call(
         _bwd_dq_kernel, "bwd_dq", operands, h, mask_mode, scale, causal,
-        block_q, block_k, interpret, kj_innermost=True, window=window)
+        block_q, block_k, interpret, kj_innermost=True, window=window,
+        block_diffusion=block_diffusion)
     q3 = operands[0]
     return pl.pallas_call(
         body,
@@ -742,14 +862,15 @@ def _pallas_bwd_dq(operands, h, mask_mode, scale, causal, block_q, block_k,
 
 
 def _pallas_bwd(operands, h, mask_mode, scale, causal, block_q, block_k,
-                interpret):
+                interpret, block_diffusion=None):
     """The fused backward's call (one query head a kv head, no window:
     `backward_rule`). dQ's block is the head's whole row under a constant
     index map, so it is written back once a head; dK's accumulator is as
     wide as q and k, dV's as wide as v."""
     body, common, _, k_spec, v_spec = _bwd_call(
         _bwd_kernel, "bwd", operands, h, mask_mode, scale, causal, block_q,
-        block_k, interpret, kj_innermost=False)
+        block_k, interpret, kj_innermost=False,
+        block_diffusion=block_diffusion)
     q3, k3, v3 = operands[:3]
     _bh, tq, d = q3.shape
     return pl.pallas_call(
@@ -768,24 +889,38 @@ def _pallas_bwd(operands, h, mask_mode, scale, causal, block_q, block_k,
 
 
 def _pallas_backward(q, k, v, mask, out, stats, g, scale, causal, blocks,
-                     interpret, window=None):
+                     interpret, window=None, block_diffusion=None):
     b, h, tq, d = q.shape
     operands = _bwd_inputs(q, k, v, mask, out, stats, g)
     mask_mode = _mask_mode(mask)
     if _kernels_of(blocks) is FUSED_KERNELS:
         dq3, dk3, dv3 = _pallas_bwd(operands, h, mask_mode, scale, causal,
-                                    *blocks[1], interpret)
+                                    *blocks[1], interpret,
+                                    block_diffusion=block_diffusion)
     else:
         dk3, dv3 = _pallas_bwd_dkv(operands, h, mask_mode, scale, causal,
-                                   *blocks[1], interpret, window=window)
+                                   *blocks[1], interpret, window=window,
+                                   block_diffusion=block_diffusion)
         dq3 = _pallas_bwd_dq(operands, h, mask_mode, scale, causal,
-                             *blocks[2], interpret, window=window)
+                             *blocks[2], interpret, window=window,
+                             block_diffusion=block_diffusion)
     return dq3.reshape(q.shape), dk3.reshape(k.shape), dv3.reshape(v.shape)
 
 
-def visible_mask(tq, tk, window=None):
+def visible_mask(tq, tk, window=None, block_diffusion=None):
     """Bool (Tq, Tk): the bottom-right-aligned causal mask, cut to the
-    last `window` keys where there is one."""
+    last `window` keys where there is one; or, with `block_diffusion` =
+    (L, T) and Tq = Tk = 2T, the block-diffusion mask over [noisy | clean]
+    rows (`_block_diffusion_keep` says it in words)."""
+    if block_diffusion is not None:
+        length, t = block_diffusion
+        row = jnp.arange(2 * t)
+        noisy, blk = row < t, row % t // length
+        own, earlier = blk[:, None] == blk[None, :], \
+            blk[None, :] < blk[:, None]
+        return jnp.where(
+            noisy[:, None], jnp.where(noisy[None, :], own, earlier),
+            ~noisy[None, :] & (own | earlier))
     cm = jnp.tril(jnp.ones((tq, tk), bool), tk - tq)
     if window is not None:
         cm = cm & ~jnp.tril(jnp.ones((tq, tk), bool), tk - tq - window)
@@ -800,7 +935,8 @@ def _repeat_kv(q, k, v):
     return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
 
 
-def _xla_attention(q, k, v, mask, scale, causal, window=None):
+def _xla_attention(q, k, v, mask, scale, causal, window=None,
+                   block_diffusion=None):
     prec = _dot_precision(q.dtype)
     k, v = _repeat_kv(q, k, v)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -808,26 +944,29 @@ def _xla_attention(q, k, v, mask, scale, causal, window=None):
                         precision=prec) * scale
     if mask is not None:
         logits = logits + mask.astype(jnp.float32)
-    if causal:
+    if causal or block_diffusion is not None:
         tq, tk = logits.shape[-2], logits.shape[-1]
-        logits = jnp.where(visible_mask(tq, tk, window), logits, _NEG_INF)
+        logits = jnp.where(visible_mask(tq, tk, window, block_diffusion),
+                           logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v,
                       precision=prec)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, mask, scale, causal, blocks, interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, mask, scale, causal, blocks, interpret, window,
+           block_diffusion=None):
     """`blocks`: the (block_q, block_k) of each kernel the call runs, in
     KERNELS' order, or in FUSED_KERNELS' where the backward is fused."""
     out, _ = _pallas_forward(q, k, v, mask, scale, causal, *blocks[0],
-                             interpret, window)
+                             interpret, window, block_diffusion)
     return out
 
 
-def _flash_fwd(q, k, v, mask, scale, causal, blocks, interpret, window):
+def _flash_fwd(q, k, v, mask, scale, causal, blocks, interpret, window,
+               block_diffusion):
     out, stats = _pallas_forward(q, k, v, mask, scale, causal, *blocks[0],
-                                 interpret, window)
+                                 interpret, window, block_diffusion)
     return out, (q, k, v, mask, out, stats)
 
 
@@ -855,13 +994,15 @@ def _xla_dmask(q, k, v, mask, out, lse, g, scale, causal, window=None):
     return jnp.sum(ds, axis=reduce_axes, keepdims=True).astype(mask.dtype)
 
 
-def _flash_bwd(scale, causal, blocks, interpret, window, res, g):
+def _flash_bwd(scale, causal, blocks, interpret, window, block_diffusion,
+               res, g):
     q, k, v, mask, out, stats = res
     # Pallas backward: recompute p from the (m, l, delta) residuals with
     # the mask applied in-kernel — the (T,T) matrix never touches HBM for
     # dq/dk/dv in either direction
     dq, dk, dv = _pallas_backward(q, k, v, mask, out, stats, g, scale,
-                                  causal, blocks, interpret, window)
+                                  causal, blocks, interpret, window,
+                                  block_diffusion)
     if mask is None:
         return dq, dk, dv, None
     lse = (stats[:, 0] + jnp.log(stats[:, 1])).reshape(q.shape[:3])
@@ -885,7 +1026,7 @@ def _fit(block, t):
 
 
 def pick_blocks(tq, tk, d, dtype, kernel, causal=False, window=None,
-                dv=None):
+                dv=None, block_diffusion=None):
     """(block_q, block_k) of `kernel` (of KERNELS or FUSED_KERNELS) for a
     call's shape.
 
@@ -913,9 +1054,17 @@ def pick_blocks(tq, tk, d, dtype, kernel, causal=False, window=None,
     window's size (the next power of two, at least 256): a wider one does
     masked work, a narrower one pays more steps (reckoned from PR 25's
     step costs, not swept). A tile reckoned over the VMEM ceiling (`dv`:
-    the value width where it differs from `d`) is halved until it fits."""
+    the value width where it differs from `d`) is halved until it fits.
+    Under `block_diffusion` = (L, T) the two lower-triangular quadrants of
+    the 2T square are causal calls of T rows at a tile's scale (L is far
+    under a tile), so the tile is the causal rule's at T, and it divides T:
+    no tile straddles the two halves (reckoned; PERF.md, PR 48 has what
+    the chip read)."""
     itemsize = jnp.dtype(dtype).itemsize
     side = 1024 if jnp.dtype(dtype) == jnp.bfloat16 else 512
+    if block_diffusion is not None:
+        tq = tk = int(block_diffusion[1])
+        causal = True
     if window is not None:
         side = min(side, max(256, 1 << (int(window) - 1).bit_length()))
     elif causal and kernel != "fwd":
@@ -935,19 +1084,37 @@ def _kernels_of(blocks):
     return FUSED_KERNELS if len(blocks) == len(FUSED_KERNELS) else KERNELS
 
 
-def plan(q_shape, k_shape, v_shape, causal, window, blocks, backward=None):
+def plan(q_shape, k_shape, v_shape, causal, window, blocks, backward=None,
+         block_diffusion=None):
     """What a call will do, for `flash.plan`: per kernel the tile, the grid
     steps and how many of the (q-block, k-block) tiles run, how many lie
     wholly above the causal diagonal and how many wholly outside the
-    window; with the group size, the two widths and which backward the
-    call got (`backward_rule`'s answer). Python ints only."""
+    window; with the group size, the two widths, which visibility rule the
+    call runs under (`mask`: "none", "causal", "window" or
+    "block_diffusion") and which backward it got (`backward_rule`'s
+    answer). Under the block-diffusion rule a kernel's row is its tile,
+    `tiles_run` (the tiles some query of which sees some key: the only
+    ones whose body runs and whose blocks are fetched) and `tiles_grid`
+    (the whole square the grid walks). Python ints only."""
     (_b, hq, tq, d), hkv, tk, dv = q_shape, k_shape[1], k_shape[2], \
         v_shape[-1]
     off, out = tk - tq, {"group": hq // hkv, "d_qk": d, "d_v": dv,
                          "window": window, "causal": bool(causal),
-                         "backward": backward}
+                         "backward": backward,
+                         "mask": mask_name(causal, window, block_diffusion)}
+    if block_diffusion is not None:
+        out["block_diffusion"] = list(block_diffusion)
     for kernel, (bq, bk) in zip(_kernels_of(blocks), blocks):
         nq, nk = tq // bq, tk // bk
+        if block_diffusion is not None:
+            out[kernel] = {
+                "block_q": bq, "block_k": bk,
+                "grid_inner": nq if kernel in ("bwd_dkv", "bwd") else nk,
+                "tiles_run": sum(
+                    bool(_bd_tile_visible(qi, kj, bq, bk, block_diffusion))
+                    for qi in range(nq) for kj in range(nk)),
+                "tiles_grid": nq * nk}
+            continue
         above = outside = 0
         for qi in range(nq):
             last = (qi * bq + bq - 1 + off) // bk if causal else nk - 1
@@ -967,14 +1134,23 @@ def plan(q_shape, k_shape, v_shape, causal, window, blocks, backward=None):
     return out
 
 
-def _record_plan(q, k, v, causal, window, blocks, backward):
+def mask_name(causal, window, block_diffusion=None):
+    """The visibility rule a call runs under, by name."""
+    if block_diffusion is not None:
+        return "block_diffusion"
+    return "window" if window is not None else \
+        "causal" if causal else "none"
+
+
+def _record_plan(q, k, v, causal, window, blocks, backward,
+                 block_diffusion=None):
     """One `flash.plan` record a lowering, while obs is on."""
     from ...framework import obs
     if obs.enabled():
         now = obs.now()
         obs.record("flash.plan", now, now,
                    **plan(q.shape, k.shape, v.shape, causal, window, blocks,
-                          backward))
+                          backward, block_diffusion))
 
 
 class AttentionPath(NamedTuple):
@@ -989,7 +1165,8 @@ class AttentionPath(NamedTuple):
     backward: Optional[str] = None
 
 
-def backward_rule(q_shape, k_shape, v_shape, dtype, causal, window):
+def backward_rule(q_shape, k_shape, v_shape, dtype, causal, window,
+                  block_diffusion=None):
     """Which backward a flash call gets, from its shapes: "fused" (one
     kernel, `_bwd_kernel`) or "split: <rule>" (dK/dV and dQ kernels), the
     first rule that holds:
@@ -1002,13 +1179,15 @@ def backward_rule(q_shape, k_shape, v_shape, dtype, causal, window):
                 reckoned over the VMEM ceiling: bfloat16 at Tq = 65,536
                 for D = 64 or 128, at 32,768 for D = 192.
     The value width alone decides nothing: Dv != D is fused like Dv == D
-    (until PR 43 it was a rule of its own, "widths")."""
+    (until PR 43 it was a rule of its own, "widths"). Nor does the
+    block-diffusion rule: all three backward kernels take it."""
     tq, tk, d, dv = q_shape[2], k_shape[2], q_shape[-1], v_shape[-1]
     if q_shape[1] != k_shape[1]:
         return "split: group"
     if window is not None:
         return "split: window"
-    bq, bk = pick_blocks(tq, tk, d, dtype, "bwd", causal, dv=dv)
+    bq, bk = pick_blocks(tq, tk, d, dtype, "bwd", causal, dv=dv,
+                         block_diffusion=block_diffusion)
     if vmem_bytes("bwd", bq, bk, d, jnp.dtype(dtype).itemsize, "qk", dv,
                   tq) > _VMEM_CEILING:
         return "split: vmem"
@@ -1016,7 +1195,8 @@ def backward_rule(q_shape, k_shape, v_shape, dtype, causal, window):
 
 
 def attention_path(q_shape, k_shape, v_shape, dtype, causal, window,
-                   interpret, auto=False, block_q=None, block_k=None):
+                   interpret, auto=False, block_q=None, block_k=None,
+                   block_diffusion=None):
     """Which attention a call gets and with which tiles, from the call's
     own arguments (Python ints and strings; nothing is traced): the one
     place that decides it. `ops/attention_ops._sdpa` and `flash_attention`
@@ -1038,19 +1218,31 @@ def attention_path(q_shape, k_shape, v_shape, dtype, causal, window,
                  (the stats block puts block_q on the lane dim).
     Otherwise "flash", with the backward `backward_rule` names and each
     kernel with `pick_blocks`' tile; an explicit `block_q`/`block_k`
-    replaces that side of every kernel's."""
+    replaces that side of every kernel's. Under `block_diffusion` = (L, T)
+    a tile has to divide T (no tile may hold rows of both halves): an
+    explicit side that does not is a ValueError."""
     tq, tk, d, dv = q_shape[2], k_shape[2], q_shape[-1], v_shape[-1]
+    fit_q, fit_k = tq, tk
+    if block_diffusion is not None:
+        fit_q = fit_k = half = int(block_diffusion[1])
+        for side in (block_q, block_k):
+            if side and half % side:
+                raise ValueError(
+                    "attention: a tile of %d rows does not divide the %d "
+                    "rows of a block-diffusion call's half (a tile holds "
+                    "noisy rows or clean rows, never both)" % (side, half))
     if auto and tq * tk <= 256 * 256:
         return AttentionPath("xla", None, "short")
     if causal and tq > tk:
         return AttentionPath("xla", None, "no_keys")
     backward = backward_rule(q_shape, k_shape, v_shape, dtype, causal,
-                             window)
+                             window, block_diffusion)
     blocks = []
     for kernel in (FUSED_KERNELS if backward == "fused" else KERNELS):
         bq, bk = pick_blocks(tq, tk, d, dtype, kernel, causal, window,
-                             None if dv == d else dv)
-        blocks.append((_fit(block_q or bq, tq), _fit(block_k or bk, tk)))
+                             None if dv == d else dv, block_diffusion)
+        blocks.append((_fit(block_q or bq, fit_q),
+                       _fit(block_k or bk, fit_k)))
     least = min(min(pair) for pair in blocks)
     if least < 8 or d % 8 or dv % 8:
         return AttentionPath("xla", None, "no_tile")
@@ -1061,7 +1253,7 @@ def attention_path(q_shape, k_shape, v_shape, dtype, causal, window,
 
 def flash_attention(q, k, v, mask=None, scale=1.0, causal=False,
                     block_q=None, block_k=None, interpret=None,
-                    window=None):
+                    window=None, block_diffusion=None):
     """Flash attention entry. q: (B,Hq,Tq,D), k: (B,Hkv,Tk,D), v:
     (B,Hkv,Tk,Dv) (the module docstring states the supported space).
     Falls back to interpret mode off-TPU so tests exercise the same
@@ -1070,24 +1262,50 @@ def flash_attention(q, k, v, mask=None, scale=1.0, causal=False,
 
     Each kernel's tile comes from the call's shape (`pick_blocks`), as
     does the backward it runs (`backward_rule`). An explicit
-    `block_q`/`block_k` replaces that side of every kernel's tile."""
+    `block_q`/`block_k` replaces that side of every kernel's tile.
+
+    `block_diffusion=(L, T)` (Python ints; Tq = Tk = 2T, L divides T; no
+    `causal`, `window` or `mask` beside it) is the block-diffusion
+    training mask over a noisy copy (rows 0..T-1) and a clean copy (rows
+    T..2T-1) of a T-token document cut into blocks of L:
+    `_block_diffusion_keep` states it. It is computed in the tile from row
+    indices, the three quarters of the square nobody sees run no body and
+    fetch no block, and no (2T, 2T) array exists anywhere."""
     if interpret is None:
         interpret = default_interpret()
-    check_call(q.shape, k.shape, v.shape, causal, window)
+    if block_diffusion is not None:
+        block_diffusion = (int(block_diffusion[0]), int(block_diffusion[1]))
+    check_call(q.shape, k.shape, v.shape, causal, window, block_diffusion,
+               mask)
     path, blocks, _why, backward = attention_path(
         q.shape, k.shape, v.shape, q.dtype, causal, window, interpret,
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k, block_diffusion=block_diffusion)
     if path == "xla":
-        return _xla_attention(q, k, v, mask, scale, causal, window)
-    _record_plan(q, k, v, causal, window, blocks, backward)
+        return _xla_attention(q, k, v, mask, scale, causal, window,
+                              block_diffusion)
+    _record_plan(q, k, v, causal, window, blocks, backward, block_diffusion)
     return _flash(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                   None if mask is None else jnp.asarray(mask),
-                  scale, causal, blocks, interpret, window)
+                  scale, causal, blocks, interpret, window, block_diffusion)
 
 
-def check_call(q_shape, k_shape, v_shape, causal, window):
+def check_call(q_shape, k_shape, v_shape, causal, window,
+               block_diffusion=None, mask=None):
     """A clear error for a call outside the supported space, before any
     kernel is built (Mosaic's own would name a block shape)."""
+    if block_diffusion is not None:
+        length, half = block_diffusion
+        if causal or window is not None or mask is not None:
+            raise ValueError(
+                "attention: block_diffusion is a visibility rule of its "
+                "own: no causal, window or additive mask beside it")
+        if length < 1 or half < 1 or half % length \
+                or q_shape[2] != 2 * half or k_shape[2] != 2 * half:
+            raise ValueError(
+                "attention: block_diffusion=(%r, %r) wants blocks of L that "
+                "divide T and 2T = %d query and key rows (a noisy and a "
+                "clean copy), got %d and %d" % (length, half, 2 * half,
+                                               q_shape[2], k_shape[2]))
     hq, hkv = q_shape[1], k_shape[1]
     if hkv < 1 or hq % hkv:
         raise ValueError(

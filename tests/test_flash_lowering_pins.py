@@ -59,12 +59,24 @@ KIMI_CALL = {
     "blocks": [(1024, 1024), (1024, 1024)]}
 
 
+SDAR_SHAPES = ((1, 32, 16384, 128), (1, 4, 16384, 128), (1, 4, 16384, 128))
+SDAR_RULE = (4, 8192)
+SDAR_CALL = {
+    "sha256": "73dba223ea6e085bea539b3086a2eed6b4599fe5e2dcf06090efa31b36436"
+              "b66", "chars": 88189,
+    "blocks": [(1024, 1024)] * 3}
+
+
 def lowered_text(q_shape, k_shape=None, v_shape=None, window=None,
-                 backward=True):
+                 backward=True, block_diffusion=None):
     q, k, v = (jax.ShapeDtypeStruct(s or q_shape, jnp.bfloat16)
                for s in (q_shape, k_shape, v_shape))
 
     def forward(q, k, v):
+        if block_diffusion is not None:
+            return fa.flash_attention(q, k, v, scale=q_shape[-1] ** -0.5,
+                                      interpret=False,
+                                      block_diffusion=block_diffusion)
         return fa.flash_attention(q, k, v, scale=q_shape[-1] ** -0.5,
                                   causal=True, window=window,
                                   interpret=False)
@@ -136,10 +148,28 @@ def test_phi_attention_calls_lower_as_the_parent_commit_did(window):
     assert digest(text) == (want["chars"], want["sha256"])
 
 
+def test_the_block_diffusion_call_lowers_as_recorded():
+    """The SDAR cell's call (PR 48): 32 query heads on 4 key heads of 128
+    over the 16,384 rows of an 8,192-token document's two copies in blocks
+    of 4; the split pair for its group, every kernel at 1024 x 1024, the
+    whole square as the grid with the rule in the index maps."""
+    want = SDAR_CALL
+    got = fa.attention_path(*SDAR_SHAPES, jnp.bfloat16, False, None, False,
+                            block_diffusion=SDAR_RULE)
+    assert got.backward == "split: group"
+    assert list(got.blocks) == want["blocks"]
+    text = lowered_text(*SDAR_SHAPES, block_diffusion=SDAR_RULE)
+    assert text.count("pallas_call[") == 3
+    assert "name=flash_bwd\n" not in text
+    assert digest(text) == (want["chars"], want["sha256"])
+
+
 if __name__ == "__main__":
     out = {str(s): digest(lowered_text(s)) for s in GPT2_CALLS}
     out.update({"phi window %s" % w: digest(lowered_text(*PHI_SHAPES,
                                                          window=w))
                 for w in PHI_CALLS})
     out["kimi"] = digest(lowered_text(*KIMI_SHAPES))
+    out["sdar"] = digest(lowered_text(*SDAR_SHAPES,
+                                      block_diffusion=SDAR_RULE))
     print(json.dumps(out, indent=1))

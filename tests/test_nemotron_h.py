@@ -339,6 +339,11 @@ def _older_program(model):
                 layer_kinds=["kda", "kda", "mla"],
                 published_layer_index=[1, 2, 4], recompute=True,
                 dtype="bfloat16"), 2, 64, optimizer_fn=adam)[0]
+    if model == "nemotron_h":
+        return nh.nemotron_h_pretrain_program(
+            nh.NemotronHConfig.from_published(CONFIG, dtype="bfloat16",
+                                              recompute=True), 2, 32,
+            optimizer_fn=adam)[0]
     if model == "kimi_vl":
         return kimi_vl.kimi_vl_pretrain_program(kimi_vl.KimiVLConfig(
             vocab_size=64, hidden_size=64, num_heads=4, qk_nope_dim=16,
@@ -375,3 +380,29 @@ def test_the_four_older_expert_programs_are_op_for_op_what_they_were(model):
                            for op in experts)
     assert not [op for blk in main.blocks for op in blk.ops
                 if op.type.startswith("mamba2_")]
+
+
+#: Nemotron's own program, by `_op_digest` on the tree before `sdar_moe`
+#: (commit fac9114)
+NEMOTRON = (133, "5b058e75c3ac556d")
+
+
+@pytest.mark.parametrize("model", sorted(OLDER) + ["nemotron_h"])
+def test_the_older_programs_carry_nothing_of_the_block_diffusion_model(
+        model):
+    """`moe_decoder.expert_ffn`'s `scoring` read, `rope_qk_norm`'s
+    `position_period` and `fused_attention`'s `block_diffusion` at their
+    defaults add no op, no slot and no attr: an op without them serialises
+    none, and Nemotron's program (through `moe_decoder`) is op for op what
+    it was."""
+    main = _older_program(model)
+    if model == "nemotron_h":
+        assert _op_digest(main) == NEMOTRON
+    ops = [op for blk in main.blocks for op in blk.ops]
+    assert not [op.type for op in ops
+                if "block_diffusion" in op.attrs
+                or "position_period" in op.attrs]
+    routes = [op for op in ops if op.type == "moe_route"]
+    assert routes and all(
+        ("scoring" in op.attrs) == (model == "smallthinker")
+        for op in routes)
