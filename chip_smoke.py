@@ -365,19 +365,25 @@ def pallas_selfcheck(interpret):
                                             "causal", hkv=2, dv=128,
                                             window=128))
 
-    def fused_case(dtype, tol, b, h, t, d, dv=None):
-        dv = dv or d
-        q, k, v = (jnp.asarray(rng.randn(b, h, t, width), dtype)
-                   for width in (d, d, dv))
+    def fused_case(dtype, tol, b, h, t, d, dv=None, hkv=None, bd=None):
+        """The fused backward against the split pair, each at its own
+        tile: `hkv` key/value heads where they are fewer than `h` (the
+        fused kernel then sums dK/dV over the group), `bd` the
+        block-diffusion rule (L, T) over t = 2T rows in place of causal."""
+        dv, hkv = dv or d, hkv or h
+        q, k, v = (jnp.asarray(rng.randn(b, heads, t, width), dtype)
+                   for heads, width in ((h, d), (hkv, d), (hkv, dv)))
         w = jnp.asarray(rng.randn(b, h, t, dv).astype(np.float32))
 
         def grads(kernels):
-            blocks = tuple(fa.pick_blocks(t, t, d, dtype, kern, True, dv=dv)
+            blocks = tuple(fa.pick_blocks(t, t, d, dtype, kern, bd is None,
+                                          dv=dv, block_diffusion=bd,
+                                          group=h // hkv)
                            for kern in kernels)
             return jax.jit(jax.grad(
                 lambda q, k, v: jnp.sum(fa._flash(
-                    q, k, v, None, 1.0 / np.sqrt(d), True, blocks,
-                    interpret, None).astype(jnp.float32) * w),
+                    q, k, v, None, 1.0 / np.sqrt(d), bd is None, blocks,
+                    interpret, None, bd).astype(jnp.float32) * w),
                 argnums=(0, 1, 2)))(q, k, v)
 
         def check():
@@ -391,6 +397,14 @@ def pallas_selfcheck(interpret):
     # unequal widths (latent attention's decompressed heads) are fused too
     run("flash_float32_T256_d192_dv128_fused_vs_split",
         fused_case(jnp.float32, 1e-5, 2, 4, 256, 192, dv=128))
+    # and grouped heads: dK/dV summed over the group in the one kernel
+    for dtype, tol in ((jnp.float32, 1e-5), (jnp.bfloat16, 1e-2)):
+        name = "flash_%s_T256_group" % np.dtype(dtype).name
+        run(name + "4_fused_vs_split",
+            fused_case(dtype, tol, 2, 8, 256, 64, hkv=2))
+        run(name + "2_dv128_bd4_fused_vs_split",
+            fused_case(dtype, tol, 1, 4, 256, 64, dv=128, hkv=2,
+                       bd=(4, 128)))
     if not interpret:   # the interpreter needs minutes at these sizes
         run("flash_bfloat16_T4096_causal",
             flash_case(jnp.bfloat16, 1e-2, 2, 12, 4096, 64, "causal"))
@@ -403,6 +417,19 @@ def pallas_selfcheck(interpret):
         # the two Kimi cells' latent-attention call
         run("flash_bfloat16_2x16x8192x192_dv128_fused_vs_split",
             fused_case(jnp.bfloat16, 1e-2, 2, 16, 8192, 192, dv=128))
+        # the five cells' grouped calls: SDAR's under its rule,
+        # SmallThinker's global layer, LFM2's, Nemotron's, Phi's full layer
+        for b, h, hkv, t, d, dv, bd in (
+                (1, 32, 4, 16384, 128, None, (4, 8192)),
+                (2, 28, 4, 16384, 128, None, None),
+                (2, 32, 8, 8192, 64, None, None),
+                (2, 32, 2, 8192, 128, None, None),
+                (2, 20, 10, 8192, 64, 128, None)):
+            run("flash_bfloat16_%dx%d:%dx%dx%d%s%s_fused_vs_split" % (
+                b, h, hkv, t, d, "_dv%d" % dv if dv else "",
+                "_bd%d" % bd[0] if bd else ""),
+                fused_case(jnp.bfloat16, 1e-2, b, h, t, d, dv=dv, hkv=hkv,
+                           bd=bd))
 
     def scan_case(dtype, tol, b, t, e, n):
         from paddle_tpu.ops.pallas import selective_scan as ss

@@ -20,17 +20,24 @@ Backward: the kernels recompute p = exp(s - m) / l per tile from the saved
 (out, m, l) residuals — flash-attention-2 style, no (T,T) matrix in HBM in
 either direction, with the additive mask applied in-kernel. Which of two
 forms a call gets is `backward_rule`'s answer, from its shapes:
-  - fused (`flash_bwd`; one query head a key/value head, no window, any
-    two widths, and dQ's row fits VMEM: GPT's plain causal call, BERT's
-    key-masked one, latent attention's D 192 | Dv 128): grid (B*H, Tk/BK,
-    Tq/BQ), q-blocks innermost. A tile's s, p, dp and ds are formed once
-    and feed all three gradients: dK/dV in accumulators written when the
-    k-block's last q-block is done, dQ in a float32 scratch of the head's
-    whole query length, written to HBM once a head. Five matmuls a tile.
-  - split (`flash_bwd_dkv`, grid as above, and `flash_bwd_dq`, grid as the
-    forward's): each forms s, p, dp, ds for itself, seven matmuls a tile
-    between them. Grouped heads and windows run here, and a query so long
-    that dQ's row does not fit.
+  - fused (`flash_bwd`; no window, any group of query heads a key/value
+    head, any two widths, and its rows fit VMEM: GPT's plain causal call,
+    BERT's key-masked one, latent attention's D 192 | Dv 128, and the
+    grouped-query calls of SDAR, SmallThinker's global layer, LFM2,
+    Nemotron and Phi's full layers): grid (B*H, Tk/BK, Tq/BQ), q-blocks
+    innermost. A tile's s, p, dp and ds are formed once and feed all three
+    gradients: dK/dV in accumulators written when the k-block's last
+    q-block is done, dQ in a float32 scratch of the head's whole query
+    length, written to HBM once a head. Five matmuls a tile. With n > 1
+    query heads a kv head the grid is (B*Hkv, n, Tk/BK, Tq/BQ): dQ's row
+    stays one head's, and dK/dV are summed over the group's heads in
+    float32 scratch as long as the kv head's whole row, each block written
+    to HBM once, when the group's last head is through with it.
+  - split (`flash_bwd_dkv`, grid as the fused kernel's at n = 1 with the
+    group's heads folded into the innermost axis, and `flash_bwd_dq`, grid
+    as the forward's): each forms s, p, dp, ds for itself, seven matmuls a
+    tile between them. Windows run here (their grids walk a banded inner
+    axis), and a call so long that the fused kernel's rows do not fit.
 Under a causal mask a grid step above the diagonal runs no body, and its
 index maps name the block the nearest working step holds, so it fetches
 nothing either.
@@ -48,9 +55,10 @@ Dv); additive mask broadcastable (B, 1, 1, Tk) or (B, 1, Tq, Tk). On CPU
 The supported (heads, window, widths) space:
   - heads: Hq = n * Hkv for any whole n >= 1 (grouped-query attention):
     query head h reads key/value head h // n. The forward and dQ kernels
-    name the kv head in their index maps; the dK/dV kernel walks the
-    group's n query heads on its innermost grid axis and sums them in its
-    accumulators. `Hq % Hkv != 0` is a ValueError.
+    name the kv head in their index maps; the fused backward walks the
+    group's n query heads on a grid axis of its own and the dK/dV kernel
+    on its innermost one, and both sum them in float32.
+    `Hq % Hkv != 0` is a ValueError.
   - window: `window=W` (only with `causal=True`) makes key s visible to
     query t iff t - W < s <= t (bottom-right aligned like the causal
     mask). The innermost grid axis then spans only the blocks a tile row
@@ -64,7 +72,8 @@ The supported (heads, window, widths) space:
     VMEM only: dQ's row is weighed at 256, dV's accumulator at 128).
 With n == 1, no window and Dv == D the forward kernel, its tile, index
 maps and VMEM request are the ones the plain causal call always had; with
-n == 1 and no window the backward is the fused kernel whatever Dv is.
+no window the backward is the fused kernel whatever n and Dv are, and at
+n == 1 it lowers to the text it had before it took a group.
 """
 import functools
 from typing import NamedTuple, Optional
@@ -404,7 +413,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, stats_ref, acc_ref,
 
 def _seq_specs(h, d, mask_mode, causal, causal_offset, block_q, block_k,
                kj_innermost, dv=None, group=1, window=None, n_inner=None,
-               nq=None, block_diffusion=None):
+               nq=None, block_diffusion=None, head_axis=False):
     """BlockSpecs of one kernel: `q_spec` for what is tiled along the
     queries at the q/k width (q, dq: (bh, block_q, D)), `row_spec` for the
     per-row statistics ((bh, 8, block_q)), `k_spec` for k and dk,
@@ -421,7 +430,9 @@ def _seq_specs(h, d, mask_mode, causal, causal_offset, block_q, block_k,
     over kv heads for dK/dV, whose innermost axis then walks the group's
     heads, `n_inner` q-blocks each. `h` is the heads of the first axis.
     Under `block_diffusion` a step whose tile no query sees names the
-    nearest block of its row that one does (`_bd_nearest_k` / `_q`)."""
+    nearest block of its row that one does (`_bd_nearest_k` / `_q`).
+    `head_axis` is the fused backward's grid for a group: (kv head, head of
+    the group, j, i), the head on an axis of its own."""
     if kj_innermost:
         def ij(a, b_):
             if block_diffusion is not None:
@@ -437,7 +448,7 @@ def _seq_specs(h, d, mask_mode, causal, causal_offset, block_q, block_k,
             return a, b_
     else:
         def ij(a, b_):
-            if group != 1:
+            if group != 1 and not head_axis:
                 b_ = b_ % n_inner
             if block_diffusion is not None:
                 b_ = _bd_nearest_q(a, b_, block_q, block_k, block_diffusion)
@@ -452,50 +463,56 @@ def _seq_specs(h, d, mask_mode, causal, causal_offset, block_q, block_k,
             return b_, a
 
     if group == 1:
-        def q_row(bb, b_):
+        def q_row(bb, b_, head):
             return bb
 
         kv_row = q_row
     elif kj_innermost:
-        def q_row(bb, b_):
+        def q_row(bb, b_, head):
             return bb
 
-        def kv_row(bb, b_):
+        def kv_row(bb, b_, head):
             return bb // group
     else:
-        def q_row(bb, b_):
-            return bb * group + b_ // n_inner
+        def q_row(bb, b_, head):
+            return bb * group + (head if head_axis else b_ // n_inner)
 
-        def kv_row(bb, b_):
+        def kv_row(bb, b_, head):
             return bb
 
-    def q_map(bb, a, b_):
-        return (q_row(bb, b_), ij(a, b_)[0], 0)
+    def q_map(bb, a, b_, head=None):
+        return (q_row(bb, b_, head), ij(a, b_)[0], 0)
 
-    def row_map(bb, a, b_):
-        return (q_row(bb, b_), 0, ij(a, b_)[0])
+    def row_map(bb, a, b_, head=None):
+        return (q_row(bb, b_, head), 0, ij(a, b_)[0])
 
-    def kv_map(bb, a, b_):
-        return (kv_row(bb, b_), ij(a, b_)[1], 0)
+    def kv_map(bb, a, b_, head=None):
+        return (kv_row(bb, b_, head), ij(a, b_)[1], 0)
+
+    def on_grid(index_map):
+        if not head_axis:
+            return index_map
+        return lambda bb, head, a, b_: index_map(bb, a, b_, head)
 
     if mask_mode == "none":
-        mask_spec = pl.BlockSpec((1, 1, 1, 1), lambda bb, a, b_: (0, 0, 0, 0))
+        mask_spec = pl.BlockSpec(
+            (1, 1, 1, 1), on_grid(lambda bb, a, b_, head=None: (0, 0, 0, 0)))
     elif mask_mode == "k":
         mask_spec = pl.BlockSpec(
-            (1, 1, 1, block_k),
-            lambda bb, a, b_: (bb // h, 0, 0, ij(a, b_)[1]))
+            (1, 1, 1, block_k), on_grid(
+                lambda bb, a, b_, head=None: (bb // h, 0, 0, ij(a, b_)[1])))
     else:
         mask_spec = pl.BlockSpec(
-            (1, 1, block_q, block_k),
-            lambda bb, a, b_: (bb // h, 0) + ij(a, b_))
-    q_spec = pl.BlockSpec((1, block_q, d), q_map)
-    k_spec = pl.BlockSpec((1, block_k, d), kv_map)
+            (1, 1, block_q, block_k), on_grid(
+                lambda bb, a, b_, head=None: (bb // h, 0) + ij(a, b_)))
+    q_spec = pl.BlockSpec((1, block_q, d), on_grid(q_map))
+    k_spec = pl.BlockSpec((1, block_k, d), on_grid(kv_map))
     if dv is None or dv == d:
         o_spec, v_spec = q_spec, k_spec
     else:
-        o_spec = pl.BlockSpec((1, block_q, dv), q_map)
-        v_spec = pl.BlockSpec((1, block_k, dv), kv_map)
-    return (q_spec, pl.BlockSpec((1, 8, block_q), row_map), k_spec,
+        o_spec = pl.BlockSpec((1, block_q, dv), on_grid(q_map))
+        v_spec = pl.BlockSpec((1, block_k, dv), on_grid(kv_map))
+    return (q_spec, pl.BlockSpec((1, 8, block_q), on_grid(row_map)), k_spec,
             mask_spec, o_spec, v_spec)
 
 
@@ -525,13 +542,16 @@ _VMEM_CEILING = 96 * 2 ** 20    # of the chip's 128 MiB
 
 
 def vmem_bytes(kernel, block_q, block_k, d, itemsize, mask_mode="none",
-               dv=None, tq=0):
+               dv=None, tq=0, tk=0):
     """Upper reckoning of the VMEM one grid step of `kernel` holds: every
     block twice (the pipeline's two buffers) with its lanes padded to 128,
     the f32 accumulators, and `_TILE_TEMPS` f32 score-shaped tiles. `dv`
     is the value width where it differs from `d`; `tq` the query length,
     which only the fused backward ("bwd") holds whole: its dQ block and
-    the f32 row that dQ is summed in."""
+    the f32 row that dQ is summed in; `tk` the key length where that
+    kernel sums dK and dV over a group of query heads, in f32 rows as long
+    as the kv head's (0: one query head a kv head, a k-block's
+    accumulators; `_bwd_rows` gives both)."""
     lanes = -(-d // 128) * 128
     lanes_v = lanes if dv is None else -(-dv // 128) * 128
     q_blk, k_blk = block_q * lanes, block_k * lanes
@@ -549,7 +569,8 @@ def vmem_bytes(kernel, block_q, block_k, d, itemsize, mask_mode="none",
     elif kernel == "bwd":
         blocks = (q_blk + o_blk + 2 * k_blk + 2 * v_blk
                   + tq * lanes) * itemsize + 2 * row_blk
-        scratch = (k_blk + v_blk + tq * lanes) * 4
+        scratch = (max(k_blk, tk * lanes) + max(v_blk, tk * lanes_v)
+                   + tq * lanes) * 4
     else:
         blocks = (2 * q_blk + o_blk + k_blk + v_blk) * itemsize \
             + 2 * row_blk
@@ -558,18 +579,26 @@ def vmem_bytes(kernel, block_q, block_k, d, itemsize, mask_mode="none",
             + _TILE_TEMPS[kernel] * block_q * block_k * 4)
 
 
+def _bwd_rows(tq, tk, group):
+    """`vmem_bytes`' `tq` and `tk` of a fused backward: dQ's row always,
+    dK/dV's only where a group of query heads is summed in them."""
+    return {"tq": tq, "tk": tk if group > 1 else 0}
+
+
 def _compiler_params(kernel, block_q, block_k, d, dtype, mask_mode,
-                     dv=None, tq=0):
+                     dv=None, tq=0, tk=0):
     """Ask Mosaic for the VMEM the tile is reckoned to need where its
     default would not do, so that a large tile compiles instead of
-    failing. The fused backward also says that its two inner grid axes
-    run in order: dQ's row and dK/dV's accumulators live across them."""
+    failing. The fused backward also says that its inner grid axes (two,
+    and the group's heads where `tk` says there is a group) run in order:
+    dQ's row and dK/dV's accumulators live across them."""
     need = vmem_bytes(kernel, block_q, block_k, d, jnp.dtype(dtype).itemsize,
-                      mask_mode, dv, tq)
+                      mask_mode, dv, tq, tk)
     limit = None if need <= _VMEM_DEFAULT else min(need, _VMEM_CEILING)
     if kernel == "bwd":
         return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel",)
+            + ("arbitrary",) * (3 if tk else 2),
             vmem_limit_bytes=limit)
     if limit is None:
         return None
@@ -629,12 +658,14 @@ def _pallas_forward(q, k, v, mask, scale, causal, block_q, block_k,
     return out.reshape(b, h, tq, dv), stats
 
 
-def _add_dkv(dk_acc, dv_acc, q, do, p, ds, scale, precision):
-    """dv += p^T dO ; dk += scale * ds^T q, into the f32 accumulators."""
-    dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
+def _add_dkv(dk_acc, dv_acc, q, do, p, ds, scale, precision,
+             at=slice(None)):
+    """dv += p^T dO ; dk += scale * ds^T q, into the f32 accumulators
+    (`at`: the k-block's slab of them, where they hold a whole row)."""
+    dv_acc[at] = dv_acc[at] + jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32, precision=precision)
-    dk_acc[:] = dk_acc[:] + scale * jax.lax.dot_general(
+    dk_acc[at] = dk_acc[at] + scale * jax.lax.dot_general(
         ds, q, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32, precision=precision)
 
@@ -719,25 +750,42 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref,
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale,
                 causal, causal_offset, block_q, block_k, mask_mode,
-                precision, block_diffusion=None):
+                precision, block_diffusion=None, group=1):
     """The fused backward: p and ds of a tile are formed once and feed all
     three gradients. Grid (bh, k-blocks, q-blocks) as dK/dV's, whose
     accumulators it keeps; dQ is summed in `dq_acc`, a float32 scratch of
     the head's whole query length (one (block_q, D) slab a q-block), over
     ascending k-blocks as the dQ kernel sums it, and leaves for HBM once,
-    after the head's last tile."""
-    kj = pl.program_id(1)
-    qi = pl.program_id(2)
-    nk, nq = pl.num_programs(1), pl.num_programs(2)
+    after the head's last tile.
+
+    With `group` query heads a kv head the grid is (kv head, head of the
+    group, k-blocks, q-blocks): dQ's row stays one head's, and dK/dV are
+    summed over the group's heads, in the dK/dV kernel's order (heads,
+    then q-blocks), in float32 scratch as long as the kv head's whole row
+    (one (block_k, D | Dv) slab a k-block), each slab leaving for HBM when
+    the group's last head is through with it."""
+    if group == 1:
+        kj, qi = pl.program_id(1), pl.program_id(2)
+        nk, nq = pl.num_programs(1), pl.num_programs(2)
+        at = slice(None)
+    else:
+        head, kj, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+        nk, nq = pl.num_programs(2), pl.num_programs(3)
+        at = kj             # the k-block's slab of dK/dV's rows
+
+    def k_block_at(q_block, head_of_group):
+        """Whether this step is that one of the k-block's walk."""
+        here = qi == q_block
+        return here if group == 1 else here & (head == head_of_group)
 
     @pl.when((kj == 0) & (qi == 0))
     def _init_head():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(qi == 0)
+    @pl.when(k_block_at(0, 0))
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[at] = jnp.zeros(dk_ref.shape[1:], jnp.float32)
+        dv_acc[at] = jnp.zeros(dv_ref.shape[1:], jnp.float32)
 
     def body():
         q, k, do, p, ds = _bwd_p_ds(
@@ -746,7 +794,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
             causal_offset=causal_offset, block_q=block_q,
             block_k=block_k, mask_mode=mask_mode, precision=precision,
             block_diffusion=block_diffusion)
-        _add_dkv(dk_acc, dv_acc, q, do, p, ds, scale, precision)
+        _add_dkv(dk_acc, dv_acc, q, do, p, ds, scale, precision, at)
         # dq[q-block] += scale * ds k
         dq_acc[qi] = dq_acc[qi] + scale * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
@@ -756,10 +804,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, stats_ref, delta_ref, mask_ref,
                       causal_offset=causal_offset, block_q=block_q,
                       block_k=block_k, block_diffusion=block_diffusion)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(k_block_at(nq - 1, group - 1))
     def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = dk_acc[at].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[at].astype(dv_ref.dtype)
 
     @pl.when((kj == nk - 1) & (qi == nq - 1))
     def _finalize_head():
@@ -791,11 +839,13 @@ def _bwd_call(kernel, which, operands, h, mask_mode, scale, causal, block_q,
     parameters and the keyword arguments for the seven operands (q, k, v,
     dO, stats, delta, mask) all take; then the BlockSpecs for the outputs
     (q-tiled, k-tiled, v-tiled). `h` is the query heads of a batch row;
-    the kv heads follow from the operands."""
+    the kv heads follow from the operands. The fused backward of a group
+    walks (kv head, head of the group, k-blocks, q-blocks)."""
     q3, k3, v3 = operands[:3]
     bh, tq, d = q3.shape
     bhkv, tk, dv = k3.shape[0], k3.shape[1], v3.shape[2]
     group = bh // bhkv
+    head_axis = which == "bwd" and group != 1
     nq, nk = tq // block_q, tk // block_k
     n_q = nq if window is None else window_grid(tq, tk, block_q, block_k,
                                                 window, False)
@@ -804,9 +854,16 @@ def _bwd_call(kernel, which, operands, h, mask_mode, scale, causal, block_q,
     q_spec, row_spec, k_spec, mask_spec, o_spec, v_spec = _seq_specs(
         h if kj_innermost else h // group, d, mask_mode, causal, tk - tq,
         block_q, block_k, kj_innermost, dv=dv, group=group, window=window,
-        n_inner=n_q, nq=nq, block_diffusion=block_diffusion)
+        n_inner=n_q, nq=nq, block_diffusion=block_diffusion,
+        head_axis=head_axis)
     extra = _rule_kwargs(window, block_diffusion)
-    if not kj_innermost:
+    if head_axis:
+        extra["group"] = group
+        grid = (bhkv, group, nk, nq)
+    elif kj_innermost:
+        grid = (bh, nq, n_k)
+    else:
+        grid = (bhkv, nk, group * n_q)
         if window is not None:
             extra["nq"] = nq
         if group != 1:
@@ -815,12 +872,13 @@ def _bwd_call(kernel, which, operands, h, mask_mode, scale, causal, block_q,
                              causal_offset=tk - tq, block_q=block_q,
                              block_k=block_k, mask_mode=mask_mode,
                              precision=_dot_precision(q3.dtype), **extra)
+    rows = _bwd_rows(tq, tk, group) if which == "bwd" else {}
     common = dict(
-        grid=((bh, nq, n_k) if kj_innermost else (bhkv, nk, group * n_q)),
+        grid=grid,
         in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec,
                   mask_spec],
         compiler_params=_compiler_params(which, block_q, block_k, d,
-                                         q3.dtype, mask_mode, dv, tq),
+                                         q3.dtype, mask_mode, dv, **rows),
         interpret=interpret)
     return body, common, q_spec, k_spec, v_spec
 
@@ -863,26 +921,42 @@ def _pallas_bwd_dq(operands, h, mask_mode, scale, causal, block_q, block_k,
 
 def _pallas_bwd(operands, h, mask_mode, scale, causal, block_q, block_k,
                 interpret, block_diffusion=None):
-    """The fused backward's call (one query head a kv head, no window:
-    `backward_rule`). dQ's block is the head's whole row under a constant
-    index map, so it is written back once a head; dK's accumulator is as
-    wide as q and k, dV's as wide as v."""
+    """The fused backward's call (no window: `backward_rule`). dQ's block
+    is the head's whole row under an index map that moves with the head
+    alone, so it is written back once a head; dK's accumulator is as wide
+    as q and k, dV's as wide as v. With a group of query heads a kv head
+    the accumulators are the kv head's whole rows, and a dK/dV block is
+    named only on the group's last head (before it: block 0, which that
+    head names first), so each is written back once, summed."""
     body, common, _, k_spec, v_spec = _bwd_call(
         _bwd_kernel, "bwd", operands, h, mask_mode, scale, causal, block_q,
         block_k, interpret, kj_innermost=False,
         block_diffusion=block_diffusion)
     q3, k3, v3 = operands[:3]
     _bh, tq, d = q3.shape
+    tk, dv = k3.shape[1], v3.shape[2]
+    group = q3.shape[0] // k3.shape[0]
+    if group == 1:
+        dq_map, kv_rows = (lambda bb, a, b_: (bb, 0, 0)), (block_k,)
+    else:
+        def dq_map(bb, head, a, b_):
+            return (bb * group + head, 0, 0)
+
+        def summed(bb, head, a, b_):
+            return (bb, jnp.where(head == group - 1, a, 0), 0)
+
+        k_spec = pl.BlockSpec((1, block_k, d), summed)
+        v_spec = pl.BlockSpec((1, block_k, dv), summed)
+        kv_rows = (tk // block_k, block_k)
     return pl.pallas_call(
         body,
-        out_specs=[pl.BlockSpec((1, tq, d), lambda bb, a, b_: (bb, 0, 0)),
-                   k_spec, v_spec],
+        out_specs=[pl.BlockSpec((1, tq, d), dq_map), k_spec, v_spec],
         out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
                    jax.ShapeDtypeStruct(k3.shape, k3.dtype),
                    jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
         scratch_shapes=[pltpu.VMEM((tq // block_q, block_q, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, v3.shape[2]), jnp.float32)],
+                        pltpu.VMEM(kv_rows + (d,), jnp.float32),
+                        pltpu.VMEM(kv_rows + (dv,), jnp.float32)],
         name="flash_bwd",
         **common,
     )(*operands)
@@ -1026,7 +1100,7 @@ def _fit(block, t):
 
 
 def pick_blocks(tq, tk, d, dtype, kernel, causal=False, window=None,
-                dv=None, block_diffusion=None):
+                dv=None, block_diffusion=None, group=1):
     """(block_q, block_k) of `kernel` (of KERNELS or FUSED_KERNELS) for a
     call's shape.
 
@@ -1046,7 +1120,14 @@ def pick_blocks(tq, tk, d, dtype, kernel, causal=False, window=None,
     attention's (2, 16, 8192, 192 | 128) (PERF.md, PR 43): 1024x1024 15.08
     (1024x512 15.53, 512x1024 15.55, 512x512 15.91, 2048x1024 16.65: three
     times the matmul a tile does not move the order); the room its dQ row
-    takes is `backward_rule`'s to weigh, not this tile's.
+    takes is `backward_rule`'s to weigh, not this tile's. With a `group` of
+    query heads a kv head it also holds dK/dV's float32 rows, as long as
+    the kv head's, and its tile is weighed with both rows: 1024x1024 at
+    the cells' lengths (71 of 96 MiB at T = 16,384, D 128), halved beyond
+    (512x1024 at 32,768). Swept at that shape too (PERF.md, PR 49):
+    (1, 32:4, 16384, 128) under block diffusion 1024x1024 23.16 (1024x512
+    26.42, 512x512 32.86), (2, 28:4, 16384, 128) causal 61.40 (66.05,
+    80.12).
     Other dtypes run float32 operands at HIGHEST: twice the VMEM
     and six MXU passes a tile, so 512 is their cap (reckoned, not swept).
     Under a sliding `window` a tile row sees window + block_q keys whatever
@@ -1062,6 +1143,7 @@ def pick_blocks(tq, tk, d, dtype, kernel, causal=False, window=None,
     the chip read)."""
     itemsize = jnp.dtype(dtype).itemsize
     side = 1024 if jnp.dtype(dtype) == jnp.bfloat16 else 512
+    rows = _bwd_rows(tq, tk, group) if kernel == "bwd" and group > 1 else {}
     if block_diffusion is not None:
         tq = tk = int(block_diffusion[1])
         causal = True
@@ -1070,8 +1152,8 @@ def pick_blocks(tq, tk, d, dtype, kernel, causal=False, window=None,
     elif causal and kernel != "fwd":
         side = min(side, max(512, min(tq, tk) // 4))
     bq, bk = _fit(side, tq), _fit(side, tk)
-    while (vmem_bytes(kernel, bq, bk, d, itemsize, "qk", dv) > _VMEM_CEILING
-           and max(bq, bk) > 128):
+    while (vmem_bytes(kernel, bq, bk, d, itemsize, "qk", dv, **rows)
+           > _VMEM_CEILING and max(bq, bk) > 128):
         if bq >= bk:
             bq = _fit(bq // 2, tq)
         else:
@@ -1170,26 +1252,29 @@ def backward_rule(q_shape, k_shape, v_shape, dtype, causal, window,
     """Which backward a flash call gets, from its shapes: "fused" (one
     kernel, `_bwd_kernel`) or "split: <rule>" (dK/dV and dQ kernels), the
     first rule that holds:
-      "group"   more than one query head a key/value head: dQ's row would
-                be group x Tq long, and dK/dV walks the group's heads;
-      "window"  a sliding window: its grids walk another inner axis;
+      "window"  a sliding window, grouped heads or not: its grids walk a
+                banded inner axis the fused kernel does not have;
       "vmem"    the fused kernel at its tile, with dQ's whole row (Tq x D
                 in float32 and the output block, D padded to whole 128
-                lanes) and dV's accumulator at the value width, is
-                reckoned over the VMEM ceiling: bfloat16 at Tq = 65,536
-                for D = 64 or 128, at 32,768 for D = 192.
+                lanes), dV's accumulator at the value width and, for a
+                group of query heads, dK's and dV's float32 rows as long
+                as the kv head's (Tk x (D + Dv)), is reckoned over the
+                VMEM ceiling: bfloat16 at Tq = 65,536 for D = 64 or 128,
+                at 32,768 for D = 192 (a group's tile is halved first:
+                `pick_blocks`).
     The value width alone decides nothing: Dv != D is fused like Dv == D
-    (until PR 43 it was a rule of its own, "widths"). Nor does the
+    (until PR 43 it was a rule of its own, "widths"). Nor does a group of
+    query heads a key/value head (until PR 49 the rule "group": the fused
+    kernel sums dK/dV over the group's heads itself), nor the
     block-diffusion rule: all three backward kernels take it."""
     tq, tk, d, dv = q_shape[2], k_shape[2], q_shape[-1], v_shape[-1]
-    if q_shape[1] != k_shape[1]:
-        return "split: group"
+    group = q_shape[1] // k_shape[1]
     if window is not None:
         return "split: window"
     bq, bk = pick_blocks(tq, tk, d, dtype, "bwd", causal, dv=dv,
-                         block_diffusion=block_diffusion)
+                         block_diffusion=block_diffusion, group=group)
     if vmem_bytes("bwd", bq, bk, d, jnp.dtype(dtype).itemsize, "qk", dv,
-                  tq) > _VMEM_CEILING:
+                  **_bwd_rows(tq, tk, group)) > _VMEM_CEILING:
         return "split: vmem"
     return "fused"
 
@@ -1240,7 +1325,8 @@ def attention_path(q_shape, k_shape, v_shape, dtype, causal, window,
     blocks = []
     for kernel in (FUSED_KERNELS if backward == "fused" else KERNELS):
         bq, bk = pick_blocks(tq, tk, d, dtype, kernel, causal, window,
-                             None if dv == d else dv, block_diffusion)
+                             None if dv == d else dv, block_diffusion,
+                             q_shape[1] // k_shape[1])
         blocks.append((_fit(block_q or bq, fit_q),
                        _fit(block_k or bk, fit_k)))
     least = min(min(pair) for pair in blocks)

@@ -9,8 +9,8 @@ one row per ``<role>/<op type>`` the program lowered its ops under
 (``forward/mul``, ``backward/layer_norm``, ``optimize/adam``; Pallas
 kernels under their own names: ``backward/flash_bwd``, or
 ``backward/flash_bwd_dkv`` and ``backward/flash_bwd_dq`` where a call's
-shapes keep the two backward kernels (grouped heads, a window, or a dQ
-row too long for VMEM: ``flash_attention.backward_rule``); ``unscoped`` for what XLA added),
+shapes keep the two backward kernels (a window, or rows too long for
+VMEM: ``flash_attention.backward_rule``); ``unscoped`` for what XLA added),
 with calls, total, average and share, read from the
 trace just written (``framework/xplane.py``). A CPU trace has no device
 plane and gives no table.

@@ -1,14 +1,43 @@
 """What the flash kernels' test files share (`test_flash_modes.py`,
-`test_flash_fused_backward.py`, `test_flash_fused_backward_bf16.py`): seeded
-q, k, v and a cotangent, the worst relative gap of two lists of arrays, and
-the fused backward against the split pair to the bit, whose cases the last
-two files run a dtype each."""
+`test_flash_fused_backward.py`, `test_flash_fused_backward_bf16.py`,
+`test_flash_fused_backward_groups.py`, `test_flash_lowering_pins.py`,
+`test_compile_flash_fused_groups.py`): seeded q, k, v and a cotangent, the
+worst relative gap of two lists of arrays, the fused backward against the
+split pair to the bit, whose cases the second and third file run a dtype
+each, and the attention calls of the cells whose query heads share
+key/value heads."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from paddle_tpu.ops.pallas import flash_attention as fa
+
+
+#: the calls of the five cells with more than one query head a key/value
+#: head (`benchmark/families/*.py:attention_calls`), bfloat16: (batch,
+#: query heads, kv heads, T, D, Dv, window, block_diffusion); causal where
+#: no rule stands in the last place
+GROUPED_CELL_CALLS = {
+    "sdar-30b-a3b.bd4-t8192-b1": (1, 32, 4, 16384, 128, 128, None,
+                                  (4, 8192)),
+    "smallthinker-21b-a3b.t16384-b2/global": (2, 28, 4, 16384, 128, 128,
+                                              None, None),
+    "smallthinker-21b-a3b.t16384-b2/window": (2, 28, 4, 16384, 128, 128,
+                                              4096, None),
+    "lfm2-8b-a1b.t8192-b2": (2, 32, 8, 8192, 64, 64, None, None),
+    "nemotron-twotower-30b-a3b.t8192-b2": (2, 32, 2, 8192, 128, 128, None,
+                                           None),
+    "phi4-mini-flash.t8192-b1/full": (2, 20, 10, 8192, 64, 128, None, None),
+    "phi4-mini-flash.t8192-b1/window": (2, 20, 10, 8192, 64, 128, 512,
+                                        None),
+}
+
+
+def call_shapes(call):
+    """The q, k and v shapes of one of GROUPED_CELL_CALLS' rows."""
+    b, hq, hkv, t, d, dv = call[:6]
+    return (b, hq, t, d), (b, hkv, t, d), (b, hkv, t, dv)
 
 
 def _inputs(b, hq, hkv, tq, tk, d, dv, dtype=jnp.float32, seed=0):

@@ -59,8 +59,8 @@ def test_the_kernels_equal_the_explicit_mask(length, t, bq, bk, group):
                              True, block_q=bq, block_k=bk,
                              block_diffusion=(length, t))
     assert path.path == "flash"
-    assert path.backward == ("fused" if group == 1 else "split: group")
-    assert set(path.blocks) == {(bq, bk)}
+    # the fused backward whatever the group: both kernels at the tile
+    assert path.backward == "fused" and path.blocks == ((bq, bk),) * 2
     got, vjp = jax.vjp(lambda *a: fa.flash_attention(
         *a, scale=0.25, block_q=bq, block_k=bk, interpret=True,
         block_diffusion=(length, t)), q, k, v)
@@ -117,9 +117,12 @@ def test_the_tiles_that_run_are_the_tiles_the_rule_predicts(length, t, bq,
     np.testing.assert_array_equal(mine, runs)
     shape = (1, 8, 2 * t, 16)
     plan = fa.plan(shape, (1, 1, 2 * t, 16), (1, 1, 2 * t, 16), False, None,
-                   ((bq, bk),) * 3, "split: group", bd)
-    assert plan["mask"] == "block_diffusion"
-    for kernel in fa.KERNELS:
+                   ((bq, bk),) * 3, "split: vmem", bd)
+    fused = fa.plan(shape, (1, 1, 2 * t, 16), (1, 1, 2 * t, 16), False, None,
+                    ((bq, bk),) * 2, "fused", bd)
+    plan["bwd"] = fused["bwd"]
+    assert plan["mask"] == fused["mask"] == "block_diffusion"
+    for kernel in fa.KERNELS + fa.FUSED_KERNELS[1:]:
         assert plan[kernel]["tiles_run"] == runs.sum()
         assert plan[kernel]["tiles_grid"] == nq * nk
     # the quadrant nobody sees never runs
@@ -145,23 +148,27 @@ def test_at_the_cells_size_a_quarter_of_the_square_and_the_diagonals_run():
     bd = (4, 8192)
     path = fa.attention_path(q_shape, kv_shape, kv_shape, jnp.bfloat16,
                              False, None, False, block_diffusion=bd)
-    assert path == ("flash", ((1024, 1024),) * 3, None, "split: group")
+    assert path == ("flash", ((1024, 1024),) * 2, None, "fused")
     plan = fa.plan(q_shape, kv_shape, kv_shape, False, None, path.blocks,
                    path.backward, bd)
     assert plan["group"] == 8 and plan["block_diffusion"] == [4, 8192]
-    for kernel in fa.KERNELS:
+    for kernel in fa.FUSED_KERNELS:
         # 8 x 8 tiles a quadrant: two lower triangles with their diagonals
         # (36 each) and the noisy quadrant's 8 diagonal tiles
         assert (plan[kernel]["tiles_run"], plan[kernel]["tiles_grid"]) \
             == (80, 256)
         assert plan[kernel]["grid_inner"] == 16
-    # one query head a key head takes the fused backward under the rule too
-    fused = fa.attention_path(kv_shape, kv_shape, kv_shape, jnp.bfloat16,
-                              False, None, False, block_diffusion=bd)
-    assert fused.backward == "fused" and len(fused.blocks) == 2
-    # the VMEM a tile asks for is a plain call's
+    # one query head a key head takes the same backward at the same tiles
+    assert fa.attention_path(kv_shape, kv_shape, kv_shape, jnp.bfloat16,
+                             False, None, False, block_diffusion=bd) == path
+    # the VMEM a tile asks for is a plain call's; the fused kernel's for a
+    # group weighs the rows of all 2T = 16,384 queries and keys, not T's
     assert fa.pick_blocks(16384, 16384, 128, jnp.bfloat16, "bwd_dkv",
                           block_diffusion=bd) == (1024, 1024)
+    assert fa.pick_blocks(16384, 16384, 128, jnp.bfloat16, "bwd",
+                          block_diffusion=bd, group=8) == (1024, 1024)
+    assert fa.pick_blocks(32768, 32768, 128, jnp.bfloat16, "bwd",
+                          block_diffusion=(4, 16384), group=8) == (512, 1024)
 
 
 def test_a_tile_that_does_not_divide_the_half_is_refused_with_a_message():
@@ -194,15 +201,16 @@ def test_a_call_outside_the_rules_space_is_told_why(kwargs, message):
 
 
 #: `plan` of two calls as the tree before the rule gave it (PR 47), to which
-#: only the rule's name has been added
+#: only the rule's name has been added; since PR 49 the full layer's
+#: backward is the fused kernel and the window layer's rule is the window
 OLD_PLANS = {
     None: {"group": 2, "d_qk": 64, "d_v": 128, "window": None,
-           "causal": True, "backward": "split: group", "mask": "causal",
+           "causal": True, "backward": "fused", "mask": "causal",
            "fwd": {"block_q": 1024, "block_k": 1024, "grid_inner": 8,
                    "tiles_visited": 36, "tiles_skipped_causal": 28,
                    "tiles_skipped_window": 0}},
     512: {"group": 2, "d_qk": 64, "d_v": 128, "window": 512, "causal": True,
-          "backward": "split: group", "mask": "window",
+          "backward": "split: window", "mask": "window",
           "fwd": {"block_q": 512, "block_k": 512, "grid_inner": 2,
                   "tiles_visited": 31, "tiles_skipped_causal": 120,
                   "tiles_skipped_window": 105}},
@@ -216,7 +224,8 @@ def test_a_call_without_the_rule_plans_what_it_planned(window):
     got = fa.plan(*shapes, True, window, path.blocks, path.backward)
     want = OLD_PLANS[window]
     assert {k: got[k] for k in want} == want
-    assert set(got) == set(want) | {"bwd_dkv", "bwd_dq"}
+    assert set(got) == set(want) | (
+        {"bwd"} if window is None else {"bwd_dkv", "bwd_dq"})
     assert fa.plan(*shapes, False, None, path.blocks)["mask"] == "none"
     assert fa.pick_blocks(8192, 8192, 64, jnp.bfloat16, "bwd_dkv", True) \
         == fa.pick_blocks(8192, 8192, 64, jnp.bfloat16, "bwd_dkv", True,

@@ -284,11 +284,13 @@ def test_tile_rule_follows_the_shape(monkeypatch):
                                          True, None, 128)
                           for kern in fa.FUSED_KERNELS), ((64, 32),) * 2]
     del seen[:]
-    q2 = jnp.zeros((1, 2, 1024, 64), jnp.bfloat16)     # and grouped: split
+    q2 = jnp.zeros((1, 2, 1024, 64), jnp.bfloat16)     # and grouped: fused
     flash_attention(q2, q, k, causal=True, interpret=True)
+    flash_attention(q2, q, k, causal=True, window=512, interpret=True)
     assert seen == [tuple(fa.pick_blocks(1024, 1024, 64, q.dtype, kern,
-                                         True, None, 128)
-                          for kern in fa.KERNELS)]
+                                         True, None, 128, group=2)
+                          for kern in fa.FUSED_KERNELS),
+                    ((512, 512),) * 3]      # a window: the split pair
 
 
 def test_the_fused_backwards_vmem_counts_each_width_at_its_own_lanes():
@@ -405,7 +407,7 @@ def test_bf16_operands_against_f32_oracle(mode):
         assert np.abs(a - o).max() / max(np.abs(o).max(), 1.0) < 1e-2
 
 
-def _kernel_dots(dtype, kv_heads, dv=8):
+def _kernel_dots(dtype, kv_heads, dv=8, window=None):
     """(lhs dtype, rhs dtype, result dtype, precision) of every
     dot_general that a call's kernels trace for inputs of `dtype`: with
     two query heads to `kv_heads` key/value heads, q and k 8 wide and v
@@ -415,7 +417,7 @@ def _kernel_dots(dtype, kv_heads, dv=8):
     v = jnp.zeros((1, kv_heads, 32, dv), dtype)
     closed = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
         flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
-                        interpret=True).astype(jnp.float32)),
+                        window=window, interpret=True).astype(jnp.float32)),
         argnums=(0, 1, 2)))(q, k, v)
     dots = []
 
@@ -431,21 +433,23 @@ def _kernel_dots(dtype, kv_heads, dv=8):
     return dots
 
 
-@pytest.mark.parametrize("backward,kv_heads,dv,count", [
-    ("fused", 2, 8, 2 + 5),     # forward; s and dp once, then dV, dK, dQ
-    ("fused_dv_16", 2, 16, 2 + 5),      # unequal widths: the same five
-    ("fused_dv_24", 2, 24, 2 + 5),
-    ("split", 1, 8, 2 + 4 + 3),     # forward, dK/dV, dQ: s and dp in both
-    ("split_dv_16", 1, 16, 2 + 4 + 3)])
+@pytest.mark.parametrize("backward,kv_heads,dv,window,count", [
+    ("fused", 2, 8, None, 2 + 5),   # forward; s, dp once, then dV, dK, dQ
+    ("fused_dv_16", 2, 16, None, 2 + 5),    # unequal widths: the same five
+    ("fused_dv_24", 2, 24, None, 2 + 5),
+    ("fused_group_2", 1, 8, None, 2 + 5),   # and a group of query heads
+    ("fused_group_2_dv_16", 1, 16, None, 2 + 5),
+    ("split", 1, 8, 16, 2 + 4 + 3),     # a window: forward, dK/dV, dQ
+    ("split_dv_16", 1, 16, 16, 2 + 4 + 3)])     # (s and dp in both)
 @pytest.mark.parametrize("dtype", ["float32", "float16", "bfloat16"])
 def test_mxu_operand_dtype_and_precision(dtype, backward, kv_heads, dv,
-                                         count):
+                                         window, count):
     """float32/float16 inputs keep float32 operands at HIGHEST — the same
     operations, in the same order, as before this rule existed, so the same
     bits at equal tiles; bfloat16 inputs reach every dot as bfloat16 with
     a float32 result. The fused backward runs the mathematics' five
     matmuls, the split kernels seven, whatever the value width."""
-    dots = _kernel_dots(dtype, kv_heads, dv)
+    dots = _kernel_dots(dtype, kv_heads, dv, window)
     assert len(dots) == count
     highest = jax.lax.Precision.HIGHEST
     for lhs, rhs, out, precision in dots:
@@ -501,14 +505,20 @@ def test_the_microbenchmark_of_the_flash_tiles_walks_through():
     done = run("--walk-through")
     assert done.returncode == 0, done.stderr[-2000:]
     rows = [json.loads(line) for line in done.stdout.splitlines()]
-    assert [row["shape"] for row in rows] == ["1x2x256x32", "1x2x256x48|32"]
+    assert [row["shape"] for row in rows] == [
+        "1x2x256x32", "1x2x256x48|32", "1x4:2x256x32-bd4"]
     kernels = list(fa.KERNELS + fa.FUSED_KERNELS[1:])
     for row in rows:
         assert row["platform"] == "cpu" and row["device_times"] is False
-        assert list(row["ms"]) == ["128x128", "256x256"]
+        grouped = ":" in row["shape"]   # the rule's tile divides T = 128
+        assert list(row["ms"]) == ["128x128"] + ["256x256"] * (not grouped)
         for tile, timed in row["ms"].items():
             assert list(timed) == kernels, (tile, timed)
             assert all(isinstance(ms, float) for ms in timed.values()), timed
         assert set(row["best"].values()) <= set(row["ms"])
-        assert row["rule"] == dict.fromkeys(kernels, "256x256")
+        assert row["rule"] == dict.fromkeys(
+            kernels, "128x128" if grouped else "256x256")
         assert row["backward"] == "fused"
+    only = run("--walk-through", "--only", "4:2")
+    assert [json.loads(line)["shape"] for line in only.stdout.splitlines()] \
+        == ["1x4:2x256x32-bd4"]

@@ -31,9 +31,11 @@ def test_fused_backward_with_unequal_backward_tiles_matches_the_split():
     (2, 2, 16, 16, None, True),
     (2, 2, 24, 16, None, True),         # Dv != D, either way round
     (2, 2, 16, 32, None, True),
-    (4, 2, 16, 16, None, False),        # grouped heads
-    (4, 2, 24, 16, None, False),
-    (2, 2, 24, 16, 32, False)])         # a window
+    (4, 2, 16, 16, None, True),         # grouped heads (PR 49)
+    (4, 2, 24, 16, None, True),
+    (14, 2, 16, 16, None, True),
+    (2, 2, 24, 16, 32, False),          # a window, whatever the heads
+    (4, 2, 16, 16, 32, False)])
 def test_which_backward_a_call_differentiates_through(hq, hkv, d, dv,
                                                       window, fused):
     q, k, v, _w = _inputs(1, hq, hkv, 64, 64, d, dv)

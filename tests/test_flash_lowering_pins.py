@@ -10,16 +10,22 @@ import pytest
 
 from paddle_tpu.ops.pallas import flash_attention as fa
 
+from _flash_cases import GROUPED_CELL_CALLS, call_shapes
+
 # ---------------------------------------------------------------------------
 # The jaxpr of the cells' attention calls (forward and backward: kernel
 # bodies, grids, block shapes, the VMEM request) with every BlockSpec's
 # index map, held by digest. gpt2's two calls: the forward ("fwd_*") is
 # as the parent commit (PR 25) traced it; the whole call was re-recorded
 # in PR 30, when one `flash_bwd` took the place of `flash_bwd_dkv` +
-# `flash_bwd_dq` (3 pallas_calls -> 2; PERF.md, PR 30). Phi's calls keep
-# the two kernels ("split: group"), so their digests are the parent's.
-# The Kimi cells' call (D 192 | Dv 128) was recorded in PR 43, when the
-# fused kernel took unequal widths; its forward is as PR 41 traced it.
+# `flash_bwd_dq` (3 pallas_calls -> 2; PERF.md, PR 30). The Kimi cells'
+# call (D 192 | Dv 128) was recorded in PR 43, when the fused kernel took
+# unequal widths; its forward is as PR 41 traced it. Phi's full and cross
+# layers (group 2) and SDAR's call (group 8 under the block-diffusion
+# rule) were re-recorded in PR 49, when the fused kernel took grouped
+# heads (3 pallas_calls -> 2: 51,164 and 88,189 characters at the parent);
+# Phi's window layer keeps the two kernels (now "split: window") and the
+# parent's digest, and every group-1 digest above is the parent's too.
 # After a deliberate change to one of these paths, print the new digests
 # with `python tests/test_flash_lowering_pins.py` and say in PERF.md why.
 # ---------------------------------------------------------------------------
@@ -43,10 +49,10 @@ PHI_SHAPES = ((2, 20, 8192, 64), (2, 10, 8192, 64), (2, 10, 8192, 128))
 PHI_CALLS = {   # by window: the window layer; the full and cross layers
     512: {"sha256": "dfa607c7a19b7d3ad2a5b529bd113481f53d1bb4aea493df8de988ad6"
                     "38a2eba", "chars": 58129,
-          "blocks": [(512, 512)] * 3},
-    None: {"sha256": "9904d1e1e6d77351f99711e247503cf1004bd7e7b1583c54396119b92"
-                     "501a0e3", "chars": 51164,
-           "blocks": [(1024, 1024)] * 3},
+          "blocks": [(512, 512)] * 3, "backward": "split: window"},
+    None: {"sha256": "4ccbd0ea649af216c6b8fbf5bff5704fb43bd6b57890a88b53fcd83ef"
+                     "6dc889c", "chars": 36029,
+           "blocks": [(1024, 1024)] * 2, "backward": "fused"},
 }
 
 
@@ -62,13 +68,18 @@ KIMI_CALL = {
 SDAR_SHAPES = ((1, 32, 16384, 128), (1, 4, 16384, 128), (1, 4, 16384, 128))
 SDAR_RULE = (4, 8192)
 SDAR_CALL = {
-    "sha256": "73dba223ea6e085bea539b3086a2eed6b4599fe5e2dcf06090efa31b36436"
-              "b66", "chars": 88189,
-    "blocks": [(1024, 1024)] * 3}
+    "sha256": "9eaba8592d6791cad24f0f65dbdbed6d0ae29a29c864c0aea8badb07a2ae9"
+              "1f4", "chars": 62639,
+    "blocks": [(1024, 1024)] * 2}
+
+#: the cells' grouped calls that take no window (`_flash_cases`)
+GROUPED_CALLS = {name: call for name, call in GROUPED_CELL_CALLS.items()
+                 if call[6] is None}
 
 
-def lowered_text(q_shape, k_shape=None, v_shape=None, window=None,
-                 backward=True, block_diffusion=None):
+def lowered(q_shape, k_shape=None, v_shape=None, window=None,
+            backward=True, block_diffusion=None):
+    """The call's closed jaxpr, forward with its pullback or alone."""
     q, k, v = (jax.ShapeDtypeStruct(s or q_shape, jnp.bfloat16)
                for s in (q_shape, k_shape, v_shape))
 
@@ -85,7 +96,11 @@ def lowered_text(q_shape, k_shape=None, v_shape=None, window=None,
         out, vjp = jax.vjp(forward, q, k, v)
         return out, vjp(out)
 
-    closed = jax.make_jaxpr(call if backward else forward)(q, k, v)
+    return jax.make_jaxpr(call if backward else forward)(q, k, v)
+
+
+def lowered_text(*shapes, **how):
+    closed = lowered(*shapes, **how)
     parts = [str(closed)]
     for eqn in closed.jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
@@ -137,31 +152,65 @@ def test_the_latent_attention_call_lowers_to_the_fused_backward():
 
 
 @pytest.mark.parametrize("window", sorted(PHI_CALLS, key=str))
-def test_phi_attention_calls_lower_as_the_parent_commit_did(window):
+def test_phi_attention_calls_lower_as_recorded(window):
+    """The window layer as the parent commit (PR 48) lowered it, to the
+    byte; the full and cross layers through the fused kernel's group
+    grid."""
     want = PHI_CALLS[window]
     got = fa.attention_path(*PHI_SHAPES, jnp.bfloat16, True, window, False)
-    assert got.backward == "split: group"
+    assert got.backward == want["backward"]
     assert list(got.blocks) == want["blocks"]
     text = lowered_text(*PHI_SHAPES, window=window)
-    assert text.count("pallas_call[") == 3
-    assert "name=flash_bwd\n" not in text
+    assert text.count("pallas_call[") == len(want["blocks"])
+    assert ("name=flash_bwd\n" in text) == (window is None)
+    assert ("name=flash_bwd_dkv\n" in text) == (window is not None)
     assert digest(text) == (want["chars"], want["sha256"])
 
 
 def test_the_block_diffusion_call_lowers_as_recorded():
     """The SDAR cell's call (PR 48): 32 query heads on 4 key heads of 128
     over the 16,384 rows of an 8,192-token document's two copies in blocks
-    of 4; the split pair for its group, every kernel at 1024 x 1024, the
-    whole square as the grid with the rule in the index maps."""
+    of 4; since PR 49 the fused backward for its group of 8, both kernels
+    at 1024 x 1024, the whole square as the grid with the rule in the
+    index maps."""
     want = SDAR_CALL
     got = fa.attention_path(*SDAR_SHAPES, jnp.bfloat16, False, None, False,
                             block_diffusion=SDAR_RULE)
-    assert got.backward == "split: group"
+    assert got.backward == "fused"
     assert list(got.blocks) == want["blocks"]
     text = lowered_text(*SDAR_SHAPES, block_diffusion=SDAR_RULE)
-    assert text.count("pallas_call[") == 3
-    assert "name=flash_bwd\n" not in text
+    assert text.count("pallas_call[") == 2
+    assert "name=flash_bwd\n" in text and "flash_bwd_dkv" not in text
     assert digest(text) == (want["chars"], want["sha256"])
+
+
+@pytest.mark.parametrize("cell", sorted(GROUPED_CALLS))
+def test_a_cells_grouped_call_lowers_to_one_flash_bwd_over_its_group(cell):
+    """What `flash.plan` will record in the five cells: "fused" with the
+    cell's group; and the one backward kernel walks (kv head, head of the
+    group, k-blocks, q-blocks), with dQ's row one head's and dK/dV's rows
+    the kv head's in its scratch."""
+    b, hq, hkv, t, d, dv, _window, rule = GROUPED_CALLS[cell]
+    shapes, group = call_shapes(GROUPED_CALLS[cell]), hq // hkv
+    assert group == {"sdar": 8, "smal": 7, "lfm2": 4, "nemo": 16,
+                     "phi4": 2}[cell[:4]]
+    got = fa.attention_path(*shapes, jnp.bfloat16, rule is None, None, False,
+                            block_diffusion=rule)
+    assert got == ("flash", ((1024, 1024),) * 2, None, "fused")
+    plan = fa.plan(*shapes, rule is None, None, got.blocks, got.backward,
+                   rule)
+    assert (plan["backward"], plan["group"]) == ("fused", group)
+    assert "bwd_dkv" not in plan and plan["bwd"]["grid_inner"] == t // 1024
+    calls = {str(e.params["name"]): e for e in lowered(
+        *shapes, block_diffusion=rule).jaxpr.eqns
+        if e.primitive.name == "pallas_call"}
+    assert sorted(calls) == ["flash_bwd", "flash_fwd"]
+    mapping = calls["flash_bwd"].params["grid_mapping"]
+    n = t // 1024
+    assert tuple(mapping.grid) == (b * hkv, group, n, n)
+    scratch = [tuple(v.aval.shape) for v in
+               calls["flash_bwd"].params["jaxpr"].invars[-3:]]
+    assert scratch == [(n, 1024, d), (n, 1024, d), (n, 1024, dv)]
 
 
 if __name__ == "__main__":

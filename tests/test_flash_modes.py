@@ -160,7 +160,32 @@ def test_a_lowering_records_one_flash_plan_while_obs_is_on():
     labels = plans[0]["labels"]
     assert labels["window"] == 16 and labels["group"] == 2
     assert labels["fwd"]["tiles_skipped_window"] > 0
-    assert labels["backward"] == "split: group" and "bwd" not in labels
+    assert labels["backward"] == "split: window" and "bwd" not in labels
+
+
+@pytest.mark.parametrize("heads,block_diffusion", [
+    ((4, 2), None), ((14, 2), None), ((8, 1), (4, 32))])
+def test_a_grouped_call_records_the_fused_backward_and_its_group(
+        heads, block_diffusion):
+    """What the five cells with shared key/value heads read back from
+    `flash.plan`: "fused" beside the group, one "bwd" row and no pair."""
+    hq, hkv = heads
+    q, k, v, _w = _inputs(1, hq, hkv, 64, 64, 16, 16)
+    obs.clear()
+    obs.enable()
+    try:
+        jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, causal=block_diffusion is None, block_q=16, block_k=16,
+            interpret=True, block_diffusion=block_diffusion))))(q, k, v)
+        plans = obs.spans(name="flash.plan")
+    finally:
+        obs.disable()
+        obs.clear()
+    assert len(plans) == 1
+    labels = plans[0]["labels"]
+    assert labels["backward"] == "fused" and labels["group"] == hq // hkv
+    assert "bwd_dkv" not in labels and "bwd_dq" not in labels
+    assert labels["bwd"]["grid_inner"] == 4     # q-blocks innermost
 
 
 def test_the_plan_names_the_fused_backward_and_its_tile():

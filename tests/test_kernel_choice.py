@@ -62,14 +62,23 @@ SITES = [
     site("gpt2.t4096-b4", (4, 12, 4096, 64), through_op=True,
          blocks=(1024, 1024), visited=(10, 16)),
     site("phi4-mini-flash.t8192-b1/window", PHI_Q, PHI_K, PHI_V, window=512,
-         through_op=True, blocks=(512, 512), backward="split: group",
+         through_op=True, blocks=(512, 512), backward="split: window",
          visited=(31, 256)),
     site("phi4-mini-flash.t8192-b1/full", PHI_Q, PHI_K, PHI_V,
-         through_op=True, blocks=(1024, 1024), backward="split: group",
-         visited=(36, 64)),
+         through_op=True, blocks=(1024, 1024), visited=(36, 64)),
     site("phi4-mini-flash.t8192-b1/cross", PHI_Q, PHI_K, PHI_V,
-         through_op=True, blocks=(1024, 1024), backward="split: group",
-         visited=(36, 64)),
+         through_op=True, blocks=(1024, 1024), visited=(36, 64)),
+    # the other cells whose query heads share key/value heads (PR 49: the
+    # fused kernel sums dK/dV over the group; a window keeps the pair)
+    site("smallthinker-21b-a3b.t16384-b2/global", (2, 28, 16384, 128),
+         (2, 4, 16384, 128), blocks=(1024, 1024), visited=(136, 256)),
+    site("smallthinker-21b-a3b.t16384-b2/window", (2, 28, 16384, 128),
+         (2, 4, 16384, 128), window=4096, blocks=(1024, 1024),
+         backward="split: window", visited=(70, 256)),
+    site("lfm2-8b-a1b.t8192-b2", (2, 32, 8192, 64), (2, 8, 8192, 64),
+         blocks=(1024, 1024), visited=(36, 64)),
+    site("nemotron-twotower-30b-a3b.t8192-b2", (2, 32, 8192, 128),
+         (2, 2, 8192, 128), blocks=(1024, 1024), visited=(36, 64)),
     # the cells that wait (PERF.md §7)
     site("bert-base.s512-b32", (32, 12, 512, 64), mask="key", causal=False,
          through_op=True, blocks=(512, 512), visited=(1, 1)),
@@ -106,10 +115,12 @@ SITES = [
          blocks=(512, 512)),
     site("window_64_t512", (1, 2, 512, 16), window=64, blocks=(256, 256),
          backward="split: window", visited=(3, 4)),
-    # which backward: the first rule that holds keeps the two kernels
+    # which backward: the first rule that holds keeps the two kernels;
+    # grouped heads alone keep no call off the fused kernel (PR 49)
     site("grouped_heads", (1, 4, 1024, 64), (1, 2, 1024, 64),
-         blocks=((1024, 1024), (512, 512), (512, 512)),
-         backward="split: group"),
+         blocks=((1024, 1024), (512, 512))),
+    site("grouped_heads_and_a_window", (1, 4, 1024, 64), (1, 2, 1024, 64),
+         window=256, blocks=(256, 256), backward="split: window"),
     # unequal widths alone keep no call off the fused kernel (PR 43): the
     # two Kimi cells' latent attention, and what weighs is dQ's row at its
     # 256 lanes (fused to Tq = 24,576 where D = 64 or 128 is to 32,768)
@@ -120,8 +131,7 @@ SITES = [
          visited=(36, 64)),
     site("value_width_differs_and_grouped_heads", (1, 4, 1024, 192),
          (1, 2, 1024, 192), (1, 2, 1024, 128),
-         blocks=((1024, 1024), (512, 512), (512, 512)),
-         backward="split: group"),
+         blocks=((1024, 1024), (512, 512))),
     site("value_width_differs_and_a_window", (1, 2, 1024, 192),
          (1, 2, 1024, 192), (1, 2, 1024, 128), window=256,
          blocks=(256, 256), backward="split: window"),
@@ -137,6 +147,12 @@ SITES = [
          blocks=(1024, 1024), backward="split: vmem"),
     site("dq_row_of_65536_in_float32_passes_it", (1, 1, 65536, 128),
          dtype="float32", blocks=(512, 512), backward="split: vmem"),
+    # a group's dK/dV rows are weighed too: the tile is halved where the
+    # rows leave it less room, and the pair runs where no tile fits
+    site("group_of_8_at_32768_halves_the_fused_tile", (1, 8, 32768, 128),
+         (1, 1, 32768, 128), blocks=((1024, 1024), (512, 1024))),
+    site("group_of_8_at_65536_passes_the_ceiling", (1, 8, 65536, 128),
+         (1, 1, 65536, 128), blocks=(1024, 1024), backward="split: vmem"),
     site("not_causal_with_a_key_mask_is_fused_too", (2, 4, 2048, 64),
          mask="key", causal=False, blocks=(1024, 1024)),
 ]
@@ -320,12 +336,14 @@ def digest(text):
 
 # GPT's was re-recorded in PR 30: its attention calls are plain causal ones
 # and differentiate through one `flash_bwd` where the parent ran
-# `flash_bwd_dkv` + `flash_bwd_dq` (181,920 characters at the parent);
-# Phi's grouped heads keep the two kernels, and its step the parent's text
+# `flash_bwd_dkv` + `flash_bwd_dq` (181,920 characters at the parent).
+# Phi's was re-recorded in PR 49: its full and cross layers (4 query heads
+# on 2 key heads) go through the fused kernel too, the window layer keeps
+# the two (491,187 characters at the parent)
 GPT_STEP = {"sha256": "19dfc12216236a754b9404fec054bee688a6f738e0d9618a2965c4cc4f"
                       "a5843c", "chars": 171662}
-PHI_STEP = {"sha256": "690d521276607cd31eb4bc8c5ae0a007b80f684f3efceb81f3c6967f40"
-                      "9f4ce3", "chars": 491187}
+PHI_STEP = {"sha256": "bd863e314b3a6914ba2d1b0996eb9838279859100df48ca915c8737438"
+                      "ab6621", "chars": 481776}
 # BERT's is the parent's less one dead equation: test_fused_head_blocks.py,
 # which holds the "bert-executor" case, says which
 BERT_STEP = {"sha256": "8e83920bc2ca135b0fa0e7460e2a526e90f0c9dc46196c214b92bfa7b5"

@@ -105,10 +105,10 @@ def main():
         "platform": dev.platform, "device_kind": dev.device_kind,
         "device_times": on_tpu,
         "rule": {kern: "%dx%d" % tile for kern, tile in zip(
-            fa.KERNELS, path.blocks)},
+            fa._kernels_of(path.blocks), path.blocks)},
         "backward": path.backward,
         "tiles": {kern: [plan[kern]["tiles_run"], plan[kern]["tiles_grid"]]
-                  for kern in fa.KERNELS},
+                  for kern in fa._kernels_of(path.blocks)},
         "ms": forms(hq, hkv, t, d, length, sides, not on_tpu)}), flush=True)
 
 
