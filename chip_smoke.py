@@ -76,34 +76,31 @@ def check(cond, msg):
 
 class Run(object):
     """State of one smoke run: the device fields every line carries, the
-    sizes in effect, and JAX's own compile / persistent-cache counters
-    (read per phase, so cold and warm runs can be told apart)."""
+    sizes in effect, and what the Executor's step-cache misses of a phase
+    cost (`executor.miss_log()`: the tree's one set of compile listeners),
+    so cold and warm runs can be told apart."""
 
     def __init__(self, rehearsal):
         self.rehearsal = rehearsal
         self.sizes = REHEARSAL_SIZES if rehearsal else CHIP_SIZES
         self.device = {}
-        self._compile_s = 0.0
-        self._cache_hits = 0
-        self._cache_misses = 0
 
-    def watch_compiles(self):
-        import jax
-
-        def on_duration(event, duration, **kw):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self._compile_s += duration
-
-        def on_event(event, **kw):
-            if event == "/jax/compilation_cache/cache_hits":
-                self._cache_hits += 1
-            elif event == "/jax/compilation_cache/cache_misses":
-                self._cache_misses += 1
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
-    def compile_counters(self):
-        return (self._compile_s, self._cache_hits, self._cache_misses)
+    @staticmethod
+    def compile_counters(since):
+        """Of the Executor misses since `since` on obs's clock (the log
+        keeps the last 32): trace + lower seconds, backend compile
+        seconds, the persistent cache's hits, and the requests it did not
+        answer. Compiles outside `Executor` (eager ops, a kernel called
+        alone) are not in it."""
+        from paddle_tpu.framework import executor
+        log = [m for m in executor.miss_log() if m["t0"] >= since]
+        return {
+            "trace_lower_s": round(sum(m["trace_s"] + m["lower_s"]
+                                       for m in log), 2),
+            "compile_s": round(sum(m["backend_s"] for m in log), 2),
+            "persistent_cache_hits": sum(m["cache_hits"] for m in log),
+            "persistent_cache_misses": sum(
+                m["cache_requests"] - m["cache_hits"] for m in log)}
 
     def place(self):
         import paddle_tpu as pt
@@ -134,7 +131,6 @@ def attach(run):
     from paddle_tpu.framework.compile_cache import place_compile_cache
     cache_dir = place_compile_cache()
     import jax
-    run.watch_compiles()
     devices = jax.devices()
     dev = devices[0]
     want = "cpu" if run.rehearsal else "tpu"
@@ -648,11 +644,12 @@ def main(argv):
     unknown = sorted(set(args.phases) - set(PHASES))
     if unknown:
         parser.error("unknown phase(s) %s" % ", ".join(unknown))
+    from paddle_tpu.framework import obs
     run = Run(args.rehearse_cpu)
     for name, phase in PHASES.items():
         if name != "attach" and args.phases and name not in args.phases:
             continue
-        compile_s, hits, misses = run.compile_counters()
+        since = obs.now()
         t0 = time.perf_counter()
         try:
             fields = phase(run)
@@ -661,11 +658,8 @@ def main(argv):
                              % (name, time.perf_counter() - t0,
                                 traceback.format_exc()))
             return 1
-        after = run.compile_counters()
         run.emit(name, ok=True, phase_s=round(time.perf_counter() - t0, 2),
-                 compile_s=round(after[0] - compile_s, 2),
-                 persistent_cache_hits=after[1] - hits,
-                 persistent_cache_misses=after[2] - misses, **fields)
+                 **dict(run.compile_counters(since), **fields))
     result = {"ok": True, "device": {
         "platform": run.device["platform"],
         "kind": run.device["device_kind"],

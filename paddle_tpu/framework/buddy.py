@@ -79,7 +79,7 @@ import time
 
 import numpy as np
 
-from . import faultinject, obs, resilience
+from . import faultinject, resilience
 from .resilience import record_event
 
 __all__ = ["ring_buddies", "buddy_of", "send_snapshot", "plan_restore",
@@ -389,49 +389,47 @@ def send_snapshot(co, host_id, members, gen, scope, compress="zlib",
     if hid not in buds:
         return False
     try:
-        with obs.span("buddy.send", host=hid, gen=gen,
-                      buddy=buds[hid]):
-            arrays = {}
-            for name, val in sorted(scope.items()):
-                if val is None:
-                    continue
-                arrays[name] = np.asarray(val)
-            feed_state = None if feed is None else feed.global_state()
-            # the failpoint fires BEFORE any deposit: a fault mid-send
-            # must leave the previous generation committed
-            faultinject.hit("buddy.send", {"gen": gen}, host=hid)
-            if not p2p:
-                blob, raw, wire = io_mod.encode_state_blob(
-                    arrays, gen, compress=compress,
-                    feed_state=feed_state)
-                co.put_blob(hid, gen, buds[hid], blob, reset=reset)
-                kind, digests, ack = "full", None, None
-            else:
-                payload, raw, wire, digests, kind = _encode_payload(
-                    io_mod, arrays, gen, compress, feed_state,
-                    tracker, reset, force_full=False)
+        arrays = {}
+        for name, val in sorted(scope.items()):
+            if val is None:
+                continue
+            arrays[name] = np.asarray(val)
+        feed_state = None if feed is None else feed.global_state()
+        # the failpoint fires BEFORE any deposit: a fault mid-send
+        # must leave the previous generation committed
+        faultinject.hit("buddy.send", {"gen": gen}, host=hid)
+        if not p2p:
+            blob, raw, wire = io_mod.encode_state_blob(
+                arrays, gen, compress=compress,
+                feed_state=feed_state)
+            co.put_blob(hid, gen, buds[hid], blob, reset=reset)
+            kind, digests, ack = "full", None, None
+        else:
+            payload, raw, wire, digests, kind = _encode_payload(
+                io_mod, arrays, gen, compress, feed_state,
+                tracker, reset, force_full=False)
+            ack, refused = _deposit_dual(co, hid, buds[hid],
+                                         payload)
+            if ack is None and kind == "delta" \
+                    and refused in DELTA_REFUSALS:
+                # the receiver cannot extend its chain — typed
+                # fallback to ONE forced full, same boundary
+                record_event("buddy_delta_refused", host=hid,
+                             gen=gen, reason=refused)
+                payload, raw, wire, digests, kind = \
+                    _encode_payload(io_mod, arrays, gen, compress,
+                                    feed_state, tracker, reset,
+                                    force_full=True)
                 ack, refused = _deposit_dual(co, hid, buds[hid],
                                              payload)
-                if ack is None and kind == "delta" \
-                        and refused in DELTA_REFUSALS:
-                    # the receiver cannot extend its chain — typed
-                    # fallback to ONE forced full, same boundary
-                    record_event("buddy_delta_refused", host=hid,
-                                 gen=gen, reason=refused)
-                    payload, raw, wire, digests, kind = \
-                        _encode_payload(io_mod, arrays, gen, compress,
-                                        feed_state, tracker, reset,
-                                        force_full=True)
-                    ack, refused = _deposit_dual(co, hid, buds[hid],
-                                                 payload)
-                if ack is None:
-                    raise ConnectionError(
-                        "buddy mailbox refused deposit: %s" % refused)
-                # ack-before-commit: the metadata row moves only now
-                co.put_buddy_meta(hid, gen, buds[hid],
-                                  payload.get("digest"),
-                                  int(ack.get("nbytes", wire)),
-                                  reset=reset)
+            if ack is None:
+                raise ConnectionError(
+                    "buddy mailbox refused deposit: %s" % refused)
+            # ack-before-commit: the metadata row moves only now
+            co.put_buddy_meta(hid, gen, buds[hid],
+                              payload.get("digest"),
+                              int(ack.get("nbytes", wire)),
+                              reset=reset)
         resilience.record_bytes("buddy_snapshot", raw, wire)
         resilience.record_buddy_gen(hid, gen)
         if p2p and tracker is not None:
@@ -608,9 +606,8 @@ def restore_agreed(co, hid, name, gen, scope, shardings=None,
     t0 = time.perf_counter()
     ok, arrays, feed_state = True, None, None
     try:
-        with obs.span("buddy.restore", host=int(hid), gen=int(gen)):
-            arrays, feed_state = fetch_and_decode(
-                co, hid, gen, need_feed_state=need_feed_state, p2p=p2p)
+        arrays, feed_state = fetch_and_decode(
+            co, hid, gen, need_feed_state=need_feed_state, p2p=p2p)
     except Exception as e:
         ok = False
         record_event("buddy_decode_fail", host=int(hid), gen=int(gen),

@@ -16,8 +16,10 @@ fused ParallelExecutor graph, but compiler-driven.
 Programs with no fetch_list (e.g. the startup program) run eagerly op-by-op —
 initializers don't deserve a compile.
 """
+import collections
 import contextlib
 import logging
+import threading
 import time
 import warnings
 
@@ -170,6 +172,197 @@ def _boundary(sp, name, kind=None, since=None):
     return at
 
 
+# ---------------------------------------------------------------------------
+# the miss log: where a step-cache miss's time went
+# ---------------------------------------------------------------------------
+# jax.jit is lazy: `_compile` only builds a closure and a wrapper, and the
+# trace, the lowering and the backend compile all run inside the first
+# call of the step, under ``exec.execute``. JAX times those stages itself
+# (dispatch.log_elapsed_time) and hands them to jax.monitoring listeners;
+# a miss listens and changes nothing of what it executes.
+_STAGE_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+_miss_tls = threading.local()       # .open: the thread's _OpenMiss, or None
+_misses = collections.deque(maxlen=32)
+_listen_lock = threading.Lock()
+_listening = False                  # True once the listeners are in
+
+
+class _OpenMiss(object):
+    """What the listeners gather between a miss's ``exec.compile``
+    boundary and the return of the step's first call."""
+
+    __slots__ = ("entry", "program", "version", "t0", "skew", "spans",
+                 "requests", "hits", "retrieval_s")
+
+    def __init__(self, entry, program, t0):
+        self.entry, self.t0 = entry, t0
+        self.program, self.version = id(program), program._version
+        # JAX stamps its stages with time.time(); obs's clock is
+        # wall-anchored monotonic: one reading of both places them
+        self.skew = t0 - time.time()
+        self.spans = {"trace": [], "lower": [], "backend": []}
+        self.requests = self.hits = 0
+        self.retrieval_s = 0.0
+
+
+def _on_time_span(event, start, end, **_kw):
+    miss = getattr(_miss_tls, "open", None)
+    if miss is None:
+        return
+    stage = _STAGE_OF.get(event)
+    if stage is not None:
+        miss.spans[stage].append((start, end))
+
+
+def _on_event(event, **_kw):
+    miss = getattr(_miss_tls, "open", None)
+    if miss is None:
+        return
+    if event == _CACHE_REQUEST:
+        miss.requests += 1
+    elif event == _CACHE_HIT:
+        miss.hits += 1
+
+
+def _on_duration(event, seconds, **_kw):
+    miss = getattr(_miss_tls, "open", None)
+    if miss is None:
+        return
+    if event == _CACHE_RETRIEVAL:
+        miss.retrieval_s += seconds
+
+
+def _open_miss(entry, program, t0):
+    """Mark this thread's miss open at its ``exec.compile`` boundary
+    ``t0``; the listeners go in at the process's first miss (so a
+    ``jax.monitoring.clear_event_listeners()`` before it costs nothing)."""
+    global _listening
+    with _listen_lock:
+        if not _listening:
+            jax.monitoring.register_event_time_span_listener(_on_time_span)
+            jax.monitoring.register_event_listener(_on_event)
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _listening = True
+    _miss_tls.open = miss = _OpenMiss(entry, program, t0)
+    return miss
+
+
+def _merged(spans):
+    out = []
+    for s, e in sorted(spans):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+def _without(spans, holes):
+    """Merged ``spans`` minus merged ``holes``."""
+    out = []
+    for s, e in spans:
+        for hs, he in holes:
+            if he <= s or hs >= e:
+                continue
+            if hs > s:
+                out.append([s, hs])
+            s = max(s, he)
+            if s >= e:
+                break
+        if s < e:
+            out.append([s, e])
+    return out
+
+
+def _end_execute(sp, miss, t_execute):
+    """The ``exec.writeback`` boundary, one clock reading: on a hit
+    (``miss`` None) that is all; on a miss the first call has returned, so
+    make the miss's log entry from what the listeners gathered and feed
+    the always-on histograms from it. Returns (the reading, the entry or
+    None).
+
+    A stage's time is the UNION of its intervals (a jitted function
+    called inside the step fires its own trace event inside the outer
+    one's), and an instant belongs to one stage: backend over lower over
+    trace (a lowering rule that traces is lowering). With obs on the four
+    stages become children of this step's ``exec.execute``."""
+    if miss is None:
+        return _boundary(sp, "exec.writeback", "execute", t_execute), None
+    at = obs.now()
+    _miss_tls.open = None
+    backend = _merged(miss.spans["backend"])
+    lower = _merged(miss.spans["lower"])
+    trace = _without(_without(_merged(miss.spans["trace"]), backend), lower)
+    stages = {"trace": trace, "lower": _without(lower, backend),
+              "backend": backend}
+    seconds = {k: sum(e - s for s, e in v) for k, v in stages.items()}
+    # the first run begins where the last compile ended (nothing
+    # compiled: where the call began)
+    ran_from = backend[-1][1] + miss.skew if backend else t_execute
+    ran_from = min(max(ran_from, t_execute), at)
+    # JAX asks its cache even where no directory is placed: that is "off"
+    requests = miss.requests if jax.config.jax_compilation_cache_dir else 0
+    cache = "off" if not requests else \
+        "hit" if miss.hits == requests else "miss"
+    builder_s = t_execute - miss.t0
+    compile_s = min(builder_s + sum(seconds.values()), at - miss.t0)
+    entry = {"entry": miss.entry, "program": miss.program,
+             "version": miss.version, "t0": miss.t0, "t1": at,
+             "trace_s": seconds["trace"], "lower_s": seconds["lower"],
+             "backend_s": seconds["backend"], "cache": cache,
+             "cache_requests": requests, "cache_hits": miss.hits,
+             "retrieval_s": miss.retrieval_s,
+             "first_run_s": at - ran_from, "builder_s": builder_s,
+             "compile_s": compile_s}
+    _misses.append(entry)
+    if obs.enabled():
+        # retroactive, obs-only: no profiler session covers a miss
+        for stage, labels in (("trace", {}), ("lower", {}), ("backend", {
+                "cache": cache, "retrieval_s": miss.retrieval_s})):
+            if stages[stage]:
+                obs.record("exec." + stage,
+                           stages[stage][0][0] + miss.skew,
+                           stages[stage][-1][1] + miss.skew,
+                           seconds=seconds[stage], **labels)
+        obs.record("exec.first_run", ran_from, at)
+    resilience.observe_executor_step("compile", compile_s)
+    resilience.observe_executor_step("execute", at - miss.t0 - compile_s)
+    sp.phase("exec.writeback", at)
+    return at, entry
+
+
+def miss_log():
+    """The process's last 32 step-cache misses, oldest first: where each
+    one's time went. One plain dict a miss, kept whether obs is on or off
+    (it is to a miss what ``Executor.cache_misses`` is to the count; the
+    pipeline routes' misses are counted and not logged):
+
+    ``entry`` ("run" / "run_steps" / "compiled": a CompiledProgram's
+    step), ``program`` (its id) and ``version``; ``t0`` (the
+    ``exec.compile`` boundary) and ``t1`` (the first call's return) on
+    obs's clock; ``builder_s`` (verification, the closure, the
+    ``jax.jit`` wrapper: the ``exec.compile`` phase); ``trace_s``,
+    ``lower_s``, ``backend_s`` from JAX's own compile events on this
+    thread between the two; ``cache`` ("hit" when the persistent compile
+    cache answered every request, else "miss"; "off" when no compile
+    asked it or no directory is placed), ``cache_requests``,
+    ``cache_hits``, ``retrieval_s``; ``first_run_s`` (from the last
+    backend compile's end to ``t1``: executable load, donation, the first
+    dispatch); ``compile_s`` = builder + trace + lower + backend, what the
+    ``executor_step_seconds{kind="compile"}`` histogram and the
+    ``straggler`` event take for the miss."""
+    return [dict(e) for e in _misses]
+
+
 def _watched(what, call, n_steps=1):
     """A pipelined dispatch has no phases: its wall time a step goes to
     the straggler detector (return_numpy syncs the fetches), when one
@@ -290,9 +483,13 @@ class Executor(object):
         # the jitted single-step path: exec.step covers the call, its
         # phases tile it (see _run_jitted)
         with obs.span("exec.step", entry="run") as sp:
-            return self._run_jitted(program, feed, fetch_list, scope,
-                                    return_numpy, use_program_cache,
-                                    strategy, sp)
+            try:
+                return self._run_jitted(program, feed, fetch_list, scope,
+                                        return_numpy, use_program_cache,
+                                        strategy, sp)
+            except BaseException:
+                _miss_tls.open = None   # a miss that raised leaves no mark
+                raise
 
     @staticmethod
     def _step_feed(program, feed, what):
@@ -329,17 +526,18 @@ class Executor(object):
         step_fn = self._cache.get(key) if use_program_cache else None
         state_vals = tuple(scope.find_var(n) for n in state_names)
         feed_tuple = tuple(feed_vals[k] for k in sorted(feed_vals))
-        t_compile = None
+        miss = None
         if step_fn is None:
             self.cache_misses += 1
             sp.set(cache="miss")
-            t_compile = _boundary(sp, "exec.compile")
+            miss = _open_miss("run" if strategy is None else "compiled",
+                              program, _boundary(sp, "exec.compile"))
             step_fn = self._compile(program, feed_vals, fetch_names,
                                     state_names, uses_rng, strategy,
                                     check_numerics, policy)
             if use_program_cache:
                 self._cache[key] = step_fn
-            t_execute = _boundary(sp, "exec.execute", "compile", t_compile)
+            t_execute = _boundary(sp, "exec.execute")
         else:
             self.cache_hits += 1
             sp.set(cache="hit")
@@ -355,7 +553,7 @@ class Executor(object):
                 self._numeric_skips = 0   # clean step ends a streak
         else:
             fetches, new_state = step_fn(state_vals, feed_tuple)
-        t_writeback = _boundary(sp, "exec.writeback", "execute", t_execute)
+        t_writeback, miss = _end_execute(sp, miss, t_execute)
         out = self._writeback(scope, state_names, new_state, fetches,
                               return_numpy)
         t_release = _boundary(sp, "exec.release", "writeback", t_writeback)
@@ -365,24 +563,28 @@ class Executor(object):
         del state_vals, feed_vals, feed_tuple, new_state, fetches
         t_end = _boundary(sp, None, "total", t_step)
         self._end_step(program, scope, sp, "Executor.run", 1, t_step,
-                       t_compile, t_execute, t_writeback, t_release, t_end)
+                       miss, t_execute, t_writeback, t_release, t_end)
         return out
 
     def _end_step(self, program, scope, sp, what, n_steps, t_step,
-                  t_compile, t_execute, t_writeback, t_release, t_end):
+                  miss, t_execute, t_writeback, t_release, t_end):
         """What watches a finished step, each only where it is armed: the
         straggler detector gets the step's latency with its phases (the
-        boundaries' readings: nothing is clocked again), and with obs on
-        the layers' registered counters become spans under
+        boundaries' readings: nothing is clocked again; on a miss
+        ``compile_s`` is the builder and the three compile stages of its
+        ``miss_log()`` entry, ``execute_s`` the rest of that call), and
+        with obs on the layers' registered counters become spans under
         ``exec.records``."""
         if watchdog.straggler_detector() is not None:
-            phases = {"feed_prepare_s": (t_execute if t_compile is None
-                                         else t_compile) - t_step,
+            phases = {"feed_prepare_s": (t_execute if miss is None
+                                         else miss["t0"]) - t_step,
                       "execute_s": t_writeback - t_execute,
                       "writeback_s": t_release - t_writeback,
                       "release_s": t_end - t_release}
-            if t_compile is not None:
-                phases["compile_s"] = t_execute - t_compile
+            if miss is not None:
+                phases["compile_s"] = miss["compile_s"]
+                phases["execute_s"] = t_writeback - miss["t0"] \
+                    - miss["compile_s"]
             watchdog.observe_step_latency((t_end - t_step) / n_steps,
                                           what=what, phases=phases)
         if obs.enabled() and getattr(program, "step_records", None):
@@ -545,9 +747,13 @@ class Executor(object):
         # span is open around the caller
         with obs.span("exec.step", entry="run_steps",
                       steps=n_steps) as sp:
-            return self._run_steps_jitted(
-                program, strategy, feed, fetch_names, scope,
-                return_numpy, use_program_cache, n_steps, sp)
+            try:
+                return self._run_steps_jitted(
+                    program, strategy, feed, fetch_names, scope,
+                    return_numpy, use_program_cache, n_steps, sp)
+            except BaseException:
+                _miss_tls.open = None   # a miss that raised leaves no mark
+                raise
 
     @staticmethod
     def _window_feed(feed):
@@ -577,7 +783,7 @@ class Executor(object):
         fn = self._cache.get(key) if use_program_cache else None
         state_vals = tuple(scope.find_var(n) for n in state_names)
         feed_tuple = tuple(staged[k] for k in sorted(staged))
-        t_compile = None
+        miss = None
         if fn is not None:
             self.cache_hits += 1
             sp.set(cache="hit")
@@ -585,7 +791,9 @@ class Executor(object):
         else:
             self.cache_misses += 1
             sp.set(cache="miss")
-            t_compile = _boundary(sp, "exec.compile")
+            miss = _open_miss(
+                "run_steps" if strategy is None else "compiled", program,
+                _boundary(sp, "exec.compile"))
             from .compiler import verify_for_compile
             verify_for_compile(
                 program,
@@ -623,7 +831,7 @@ class Executor(object):
                         return jitted(state_vals, feed_tuple)
             if use_program_cache:
                 self._cache[key] = fn
-            t_execute = _boundary(sp, "exec.execute", "compile", t_compile)
+            t_execute = _boundary(sp, "exec.execute")
         ys, new_state = fn(state_vals, feed_tuple)
         if check_numerics:
             finite = np.asarray(ys[1])
@@ -689,14 +897,14 @@ class Executor(object):
                         "window%s" % (k, tail))
             elif policy == "skip":
                 self._numeric_skips = 0
-        t_writeback = _boundary(sp, "exec.writeback", "execute", t_execute)
+        t_writeback, miss = _end_execute(sp, miss, t_execute)
         out = self._writeback(scope, state_names, new_state, ys[0],
                               return_numpy)
         t_release = _boundary(sp, "exec.release", "writeback", t_writeback)
         del state_vals, staged, feed_tuple, new_state, ys
         t_end = _boundary(sp, None, "total", t_step)
         self._end_step(program, scope, sp, "Executor.run_steps", n_steps,
-                       t_step, t_compile, t_execute, t_writeback,
+                       t_step, miss, t_execute, t_writeback,
                        t_release, t_end)
         return out
 

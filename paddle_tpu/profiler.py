@@ -40,8 +40,7 @@ _session = {"path": None}
 def profiler(state="All", sorted_key=None, profile_path=DEFAULT_PATH):
     start_profiler(state, profile_path=profile_path)
     try:
-        with obs.span("profiler.trace", path=str(profile_path)):
-            yield
+        yield
     finally:
         stop_profiler(sorted_key, profile_path)
 
